@@ -1,0 +1,485 @@
+"""chip_smoke.py — does the system still start on the chip?
+
+    python3 chip_smoke.py              # on a TPU: full width, ~minutes
+    python3 chip_smoke.py --rehearse   # anywhere: toy shapes on the CPU
+
+One process drives the three main paths through the entry point a user
+calls (`mpi_cuda_cnn_tpu.cli.main`, `--device tpu`) and checks what
+comes out by the repo's own records (`--metrics-jsonl`):
+
+- kernels: every Pallas kernel the other phases reach (flash forward
+  and both backward kernels, paged attention, int8 GEMV), called once
+  at the smoke's shapes and compared on the device with its XLA twin
+  under `jax.default_matmul_precision("highest")`; on the chip the
+  lowered program must hold a Mosaic custom call (nothing interpreted).
+- cnn: the source paper's path — 4 IDX files, `reference_cnn`, 60,000
+  samples, batch 32 per chip, 2 scanned epochs + eval.
+- lm: `lm --dim 4096 --depth 3 --heads 32 --seq-len 2048`, bf16, 6 steps.
+- serve: `serve-bench --mode continuous` at the same width, twice: the
+  defaults (gather read, f32 cache) and the serving configuration
+  (GQA-8, auto cache/weights dtypes, Pallas paged read).
+
+With no arguments it needs a TPU and fails before compiling anything
+without one; `--rehearse` is the only way it runs on a CPU, at toy
+shapes, and every line it then prints says `"rehearsal": true`. Every
+stdout line is one JSON object naming platform, device_kind and
+device_count; the last is `{"ok": ..., "device": {...}}`. Exit code 0
+only if every phase passed. Compile seconds per phase (JAX's own
+compile events) are reported as set-up time, with persistent-cache
+hits and writes, so a second run shows what the cache saved.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import json
+import sys
+import time
+import traceback
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parent
+OUT = ROOT / "chiprun_out" / "chip_smoke"      # JSONL records (small)
+IDX = ROOT / ".cache" / "chip_smoke" / "idx"   # generated dataset (47 MB)
+
+# One LM width for every phase: the d=4096 x 3, 32 heads of 128 shape
+# the repo has chip history for (PERF.md). Depth is the only cut.
+FULL = dict(
+    cnn=dict(train=60_000, test=10_000, per_chip_batch=32),
+    lm=dict(dim=4096, depth=3, heads=32, seq=2048, per_chip_batch=2,
+            steps=6),
+    serve=dict(dim=4096, depth=3, heads=32, kv_heads=8, max_seq=2048,
+               slots=8, page_size=16, prompt_max=512, out_max=64,
+               requests=8, prefill_chunk=32),
+)
+# Same phases, same code paths, sizes a CPU finishes in seconds.
+TOY = dict(
+    cnn=dict(train=2_000, test=500, per_chip_batch=32),
+    lm=dict(dim=32, depth=1, heads=2, seq=128, per_chip_batch=2, steps=6),
+    serve=dict(dim=32, depth=1, heads=4, kv_heads=2, max_seq=64,
+               slots=2, page_size=8, prompt_max=16, out_max=8,
+               requests=3, prefill_chunk=8),
+)
+# A tick slower than this is a compile (or a stall) inside the serving
+# window: warm-up is supposed to have compiled every program.
+WATCHDOG_MS = 1000
+
+
+class SmokeFailure(AssertionError):
+    """A phase ran to the end and what came out is wrong."""
+
+
+def check(cond, msg):
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+class CompileMeter:
+    """Per-phase set-up time from the events JAX itself records around
+    every backend compile: seconds spent in compile-or-load
+    (`compile_s`), the part of that spent reading the persistent cache
+    (`cache_read_s`), what the hits saved by JAX's own account
+    (`compile_saved_s`), and the cache's hits and writes."""
+
+    EVENTS = {
+        "/jax/core/compile/backend_compile_duration": "compile_s",
+        "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_s",
+        "/jax/compilation_cache/compile_time_saved_sec": "compile_saved_s",
+        "/jax/compilation_cache/cache_hits": "cache_hits",
+        "/jax/compilation_cache/cache_misses": "cache_writes",
+    }
+
+    def __init__(self):
+        from jax import monitoring
+
+        self.totals = dict.fromkeys(self.EVENTS.values(), 0)
+        monitoring.register_event_duration_secs_listener(self._add)
+        monitoring.register_event_listener(self._add)
+
+    def _add(self, event, seconds=1, **_):
+        if event in self.EVENTS:
+            self.totals[self.EVENTS[event]] += seconds
+
+    def take(self) -> dict:
+        out = {k: round(v, 2) for k, v in self.totals.items()}
+        self.totals = dict.fromkeys(self.totals, 0)
+        return out
+
+
+def run_cli(argv: list[str], jsonl: Path) -> dict[str, list[dict]]:
+    """One in-process CLI run; its records, grouped by event. The CLI's
+    own stdout (bench summaries) goes to stderr: stdout carries only
+    this script's stamped lines."""
+    from mpi_cuda_cnn_tpu.cli import main as cli_main
+    from mpi_cuda_cnn_tpu.obs.schema import load_records
+
+    jsonl.parent.mkdir(parents=True, exist_ok=True)
+    jsonl.unlink(missing_ok=True)
+    with contextlib.redirect_stdout(sys.stderr):
+        rc = cli_main([*argv, "--metrics-jsonl", str(jsonl)])
+    check(rc == 0, f"cli exited {rc}: {' '.join(argv)}")
+    by_event: dict[str, list[dict]] = {}
+    for rec in load_records(jsonl):
+        by_event.setdefault(rec["event"], []).append(rec)
+    first = next(iter(by_event), None)
+    check(first == "device",
+          f"first record is {first!r}, not the device stamp")
+    return by_event
+
+
+def check_data_parallel(recs: dict, ndev: int) -> dict:
+    """What a >1-chip run must show, from the run's own records: the
+    default mesh spans every chip, every chip holds bytes, and the
+    compiled step reduces across them."""
+    mesh = recs["device"][0]["mesh"]
+    check(mesh == {"data": ndev}, f"mesh {mesh}, want data:{ndev}")
+    collectives: dict[str, int] = {}
+    for p in recs.get("program", []):
+        for name, n in p["collectives"].items():
+            collectives[name] = collectives.get(name, 0) + n
+    in_use = [d["stats"]["bytes_in_use"] if d["stats"] else None
+              for d in recs["memory"][-1]["devices"]] \
+        if recs.get("memory") else []
+    if ndev > 1:
+        check(collectives.get("all-reduce", 0) >= 1,
+              f"no all-reduce in the compiled step: {collectives}")
+        if recs["device"][0]["platform"] != "cpu":  # cpu has no stats
+            check(len(in_use) == ndev and all(in_use),
+                  f"bytes_in_use per device: {in_use}")
+    return {"mesh": mesh, "collectives": collectives,
+            "bytes_in_use": in_use}
+
+
+# ------------------------------------------------------------- phases
+
+
+def phase_kernels(cfg, dev, rehearsal):
+    """Each Pallas kernel vs its XLA twin, on the device. The error
+    measure is max|got - want| / max|want| — absolute error normalized
+    by the output's scale, so near-zero entries don't dominate."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mpi_cuda_cnn_tpu.models.generate import _quant_kv
+    from mpi_cuda_cnn_tpu.ops.attention import attention
+    from mpi_cuda_cnn_tpu.ops.pallas_attention import flash_attention
+    from mpi_cuda_cnn_tpu.ops.pallas_gemv import (
+        dequantize_weight,
+        int8_gemv,
+        quantize_weight,
+    )
+    from mpi_cuda_cnn_tpu.serve.paged_cache import paged_update_attend
+
+    # Tolerances on max|got - want| / max|want|, each with its reason.
+    # Every f32 bound must still fail a bf16 computation of the same
+    # case, whose operand rounding alone is 2^-9 = 2e-3 (measured on
+    # this chip: a default-precision f32 dot, which rounds its operands
+    # to bf16, is 2.5e-3 off; at HIGHEST it is 3e-7 — PERF.md).
+    #
+    # f32 paged attention and int8 GEMV: both sides compute in f32 (the
+    # kernels at HIGHEST, the twin under "highest"), so only reduction
+    # order differs — a few 1e-7 over <= 16k-term sums. 2e-5 leaves two
+    # orders of margin.
+    F32_TOL = 2e-5
+    # f32 flash: the kernels rebuild p = exp(s - lse) from HIGHEST-
+    # precision logits, and exp turns an absolute logit error (~1e-6 at
+    # |s| ~ 10) into a relative error in p that the backward multiplies
+    # by (dO.V - D), a cancelling difference. The kernel's documented
+    # gradient accuracy is ~4e-5 (ops/pallas_attention.py); 2e-4 is 5x
+    # that and still 10x below the bf16 floor.
+    FLASH_F32_TOL = 2e-4
+    # bf16 flash (the LM's training dtype): inputs are bf16 on both
+    # sides; the kernel additionally rounds the probabilities to bf16
+    # for the PV dot and its output to bf16 (2^-9 each), and the
+    # backward differentiates through both. 2e-2 is ~5 bf16 roundings.
+    FLASH_BF16_TOL = 2e-2
+
+    errs: dict[str, float] = {}
+    over: list[str] = []
+
+    def compare(name, got, want, tol):
+        """Record the error; violations are collected, not raised, so a
+        failing run still reports every kernel's number."""
+        got = np.asarray(got, np.float32)
+        want = np.asarray(want, np.float32)
+        check(np.isfinite(got).all(), f"{name}: non-finite kernel output")
+        e = errs[name] = float(np.max(np.abs(got - want))
+                               / np.max(np.abs(want)))
+        if e > tol:
+            over.append(f"{name}: rel err {e:.2e} > {tol}")
+
+    def mosaic(fn, *args):
+        """On the chip the lowered program must hold a Mosaic custom
+        call; in rehearsal the same kernel body runs interpreted."""
+        if rehearsal:
+            return
+        text = jax.jit(fn).lower(*args).as_text()
+        check("tpu_custom_call" in text,
+              "no Mosaic custom call in the lowered program")
+
+    def twin(fn, *args):
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(fn)(*args)
+
+    rng = np.random.default_rng(0)
+    lm, sv = cfg["lm"], cfg["serve"]
+    hd = lm["dim"] // lm["heads"]
+
+    # Flash forward + both backward kernels at the LM step's shape.
+    shape = (lm["per_chip_batch"], lm["seq"], lm["heads"], hd)
+    for dtype, tol in (("float32", FLASH_F32_TOL),
+                       ("bfloat16", FLASH_BF16_TOL)):
+        q, k, v = (jnp.asarray(rng.normal(size=shape), dtype)
+                   for _ in range(3))
+
+        def fwd_bwd(attn):
+            def f(q, k, v):
+                out, vjp = jax.vjp(attn, q, k, v)
+                # A fixed non-uniform cotangent (the same on both
+                # sides) drives the dq and the dk/dv kernels.
+                g = jnp.cos(3.0 * q.astype(jnp.float32)).astype(out.dtype)
+                return (out, *vjp(g))
+            return f
+
+        flash = fwd_bwd(lambda q, k, v: flash_attention(q, k, v, True))
+        oracle = fwd_bwd(lambda q, k, v: attention(q, k, v, causal=True))
+        mosaic(flash, q, k, v)
+        got, want = jax.jit(flash)(q, k, v), twin(oracle, q, k, v)
+        for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+            compare(f"flash_{dtype}_{name}", g, w, tol)
+
+    # Paged attention at the serving engine's two program shapes (decode
+    # tick, prefill chunk), GQA, int8 pages (the serving configuration)
+    # and f32 pages (the case a hidden bf16 computation would fail).
+    h, hkv, ps = sv["heads"], sv["kv_heads"], sv["page_size"]
+    hd = sv["dim"] // h
+    per = -(-(sv["prompt_max"] + sv["out_max"]) // ps)
+    pool = sv["slots"] * per + 1
+    for dtype in ("float32", "int8"):
+        rows = jnp.asarray(rng.normal(size=(2, 1, pool * ps, hkv, hd)),
+                           jnp.float32)
+        if dtype == "int8":
+            (qk, sk), (qv, sv_) = _quant_kv(rows[0]), _quant_kv(rows[1])
+            c = {"k": qk.reshape(pool, ps, hkv, hd),
+                 "ks": sk.reshape(pool, ps, hkv, 1),
+                 "v": qv.reshape(pool, ps, hkv, hd),
+                 "vs": sv_.reshape(pool, ps, hkv, 1)}
+        else:
+            c = {"k": rows[0].reshape(pool, ps, hkv, hd),
+                 "v": rows[1].reshape(pool, ps, hkv, hd)}
+        for b, kk in ((sv["slots"], 1), (1, sv["prefill_chunk"])):
+            q = jnp.asarray(rng.normal(size=(b, kk, h, hd)), jnp.float32)
+            k, v = (jnp.asarray(rng.normal(size=(b, kk, hkv, hd)),
+                                jnp.float32) for _ in range(2))
+            table = jnp.asarray(np.stack([
+                rng.choice(np.arange(1, pool), per, replace=False)
+                for _ in range(b)]), jnp.int32)
+            pos0 = rng.integers(0, per * ps - kk + 1, (b, 1))
+            positions = jnp.asarray(pos0 + np.arange(kk), jnp.int32)
+            valid = jnp.ones((b, kk), bool)
+
+            def read(kernel):
+                return lambda c, q, k, v: paged_update_attend(
+                    c, q, k, v, positions, valid, table, ps,
+                    kernel=kernel)[0]
+
+            mosaic(read("pallas"), c, q, k, v)
+            compare(f"paged_{dtype}_b{b}_kk{kk}",
+                    jax.jit(read("pallas"))(c, q, k, v),
+                    twin(read("gather"), c, q, k, v), F32_TOL)
+
+    # int8 GEMV at the decode tick's widest matrices: the MLP pair
+    # (w2's din = 4*dim is the contraction that overflowed VMEM untiled).
+    dim = sv["dim"]
+    for n, din, dout in ((sv["slots"], dim, 4 * dim),
+                         (sv["slots"], 4 * dim, dim),
+                         (sv["prefill_chunk"], 4 * dim, dim)):
+        x = jnp.asarray(rng.normal(size=(n, din)), jnp.float32)
+        w = quantize_weight(
+            jnp.asarray(rng.normal(size=(din, dout)), jnp.float32))
+        mosaic(int8_gemv, x, w)
+        compare(f"gemv_n{n}_{din}x{dout}", jax.jit(int8_gemv)(x, w),
+                twin(lambda x, w: x @ dequantize_weight(w), x, w), F32_TOL)
+
+    rounded = {k: float(f"{v:.2e}") for k, v in errs.items()}
+    check(not over, f"{'; '.join(over)} (all: {rounded})")
+    return {"max_rel_err": rounded, "mosaic_checked": not rehearsal}
+
+
+def phase_cnn(cfg, dev, rehearsal):
+    from mpi_cuda_cnn_tpu.data.datasets import (
+        synthetic_stripes,
+        write_synthetic_idx,
+    )
+
+    c = cfg["cnn"]
+    ndev = dev["device_count"]
+    paths = write_synthetic_idx(
+        IDX, synthetic_stripes(num_train=c["train"], num_test=c["test"]))
+    recs = run_cli(
+        [str(paths[k]) for k in ("train_images", "train_labels",
+                                 "test_images", "test_labels")]
+        + ["--model", "reference_cnn", "--epochs", "2", "--batch-size",
+           str(c["per_chip_batch"] * ndev), "--device", dev["platform"]],
+        OUT / "cnn.jsonl")
+    ev = recs["eval"][-1]
+    acc = ev["ncorrect"] / ev["ntests"]
+    check(ev["ntests"] == c["test"], f"ntests {ev['ntests']}")
+    check(acc >= 0.99, f"accuracy {ev['ncorrect']}/{ev['ntests']} < 0.99")
+    flops = [p["flops"] for p in recs.get("program", [])]
+    check(flops and all(flops), f"program-cost flops missing: {flops}")
+    return {"ncorrect": ev["ncorrect"], "ntests": ev["ntests"],
+            "epoch_s": [round(e["seconds"], 3) for e in recs["epoch"]],
+            "step_flops": flops[0], **check_data_parallel(recs, ndev)}
+
+
+def phase_lm(cfg, dev, rehearsal):
+    import math
+
+    c = cfg["lm"]
+    ndev = dev["device_count"]
+    recs = run_cli(
+        ["lm", "--corpus", "synthetic", "--dim", str(c["dim"]),
+         "--depth", str(c["depth"]), "--heads", str(c["heads"]),
+         "--seq-len", str(c["seq"]),
+         "--batch-size", str(c["per_chip_batch"] * ndev),
+         "--compute-dtype", "bfloat16", "--steps", str(c["steps"]),
+         # Six steps must show a falling loss: no warm-up ramp.
+         "--lr-schedule", "constant", "--warmup-steps", "0",
+         "--log-every", "1", "--device", dev["platform"]],
+        OUT / "lm.jsonl")
+    # On the chip "auto" must resolve to the fused kernel at this
+    # 128-aligned bf16 shape; on the CPU the oracle is the deliberate
+    # pick (train/lm.pick_attn_impl).
+    attn = recs["device"][0]["attn"]
+    want_attn = "oracle" if rehearsal else "flash"
+    check(attn == want_attn, f"attn resolved to {attn!r}, want {want_attn!r}")
+    losses = [r["loss"] for r in recs["train"]]
+    check(len(losses) == c["steps"], f"{len(losses)} train records")
+    check(all(math.isfinite(x) for x in losses), f"non-finite loss {losses}")
+    check(losses[-1] < losses[0], f"loss did not fall: {losses}")
+    return {"attn": attn, "losses": [round(x, 4) for x in losses],
+            **check_data_parallel(recs, ndev)}
+
+
+def phase_serve(cfg, dev, rehearsal, extra=(), tag="default"):
+    c = cfg["serve"]
+    recs = run_cli(
+        ["serve-bench", "--mode", "continuous", "--dim", str(c["dim"]),
+         "--depth", str(c["depth"]), "--heads", str(c["heads"]),
+         "--max-seq", str(c["max_seq"]), "--slots", str(c["slots"]),
+         "--page-size", str(c["page_size"]),
+         "--prefill-chunk", str(c["prefill_chunk"]),
+         "--prompt-max", str(c["prompt_max"]),
+         "--out-max", str(c["out_max"]),
+         "--requests", str(c["requests"]), "--rate", "0",
+         "--watchdog-ms", str(WATCHDOG_MS),
+         "--device", dev["platform"], *extra],
+        OUT / f"serve_{tag}.jsonl")
+    s = recs["serve"][-1]
+    n = c["requests"]
+    check(s["statuses"] == {"finished": n}, f"statuses {s['statuses']}")
+    reqs = recs["request"]
+    check(len(reqs) == n and all(r["output_tokens"] > 0 for r in reqs),
+          f"output tokens per request: "
+          f"{[r['output_tokens'] for r in reqs]}")
+    check(s["watchdog_slow_ticks"] == 0,
+          f"{s['watchdog_slow_ticks']} ticks slower than {WATCHDOG_MS} ms "
+          "(a compile inside the serving window?)")
+    return {k: s[k] for k in ("cache_dtype", "attn_kernel", "weights_dtype",
+                              "output_tokens", "decode_ticks",
+                              "prefill_chunks", "duration_s")}
+
+
+def phase_serve_config(cfg, dev, rehearsal):
+    """README's serving configuration: GQA, auto cache/weights dtypes
+    (int8 both under GQA), the Pallas paged read."""
+    return phase_serve(
+        cfg, dev, rehearsal, tag="serving_config",
+        extra=["--kv-heads", str(cfg["serve"]["kv_heads"]),
+               "--cache-dtype", "auto", "--decode-weights-dtype", "auto",
+               "--attn-kernel", "pallas"])
+
+
+PHASES = (
+    # Kernels first: a broken kernel fails here, with its number,
+    # before the phases that reach it.
+    ("kernels", phase_kernels),
+    ("cnn", phase_cnn),
+    ("lm", phase_lm),
+    ("serve_default", phase_serve),
+    ("serve_serving_config", phase_serve_config),
+)
+
+
+def main(argv: list[str] | None = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearse", action="store_true",
+                    help="toy shapes on the CPU (sandbox, tier-1); never "
+                         "reports a chip run")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    if args.rehearse:
+        jax.config.update("jax_platforms", "cpu")
+    d0 = jax.devices()[0]
+    dev = {"platform": d0.platform, "device_kind": d0.device_kind,
+           "device_count": len(jax.devices())}
+    if not args.rehearse and dev["platform"] != "tpu":
+        print(f"chip_smoke: no TPU (JAX found platform "
+              f"{dev['platform']!r}); nothing was compiled. "
+              "`--rehearse` runs toy shapes on the CPU.", file=sys.stderr)
+        return 2
+
+    from mpi_cuda_cnn_tpu.utils.backend import enable_compile_cache
+
+    stamp = dict(dev, rehearsal=True) if args.rehearse else dev
+
+    def emit(**fields):
+        print(json.dumps({**fields, **stamp}), flush=True)
+
+    emit(event="start", compile_cache=enable_compile_cache(),
+         jax=jax.__version__)
+    # Keep every compiled program, not only those that took over a
+    # second: the tool's machine is cold on every call and even a tiny
+    # program costs the TPU compiler ~0.1 s, so a second run in the same
+    # call shows what the cache can save.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    meter = CompileMeter()
+    cfg = TOY if args.rehearse else FULL
+    failed = []
+    for name, fn in PHASES:
+        t0 = time.perf_counter()
+        # The boundary that keeps the other phases running: a failure is
+        # printed with its traceback, recorded in the phase line, and
+        # makes the exit code non-zero — it is never swallowed.
+        try:
+            detail = {"ok": True, **fn(cfg, dev, args.rehearse)}
+        except Exception as e:
+            traceback.print_exc()
+            failed.append(name)
+            detail = {"ok": False, "error": f"{type(e).__name__}: {e}"[:2000]}
+        emit(event="phase", phase=name,
+             wall_s=round(time.perf_counter() - t0, 2),
+             **meter.take(), **detail)
+
+    # The last line, to the driver's contract: on a passing chip run it
+    # is exactly {"ok": true, "device": {platform, kind, count}}. A
+    # rehearsal also carries the stamp every other line does.
+    summary = {"ok": not failed,
+               "device": {"platform": dev["platform"],
+                          "kind": dev["device_kind"],
+                          "count": dev["device_count"]}}
+    if failed:
+        summary["failed"] = failed
+    if args.rehearse:
+        summary.update(stamp)
+    print(json.dumps(summary), flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

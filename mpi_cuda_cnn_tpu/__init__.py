@@ -28,5 +28,4 @@ explicit message passing.
 
 __version__ = "0.1.0"
 
-from .utils import compat as _compat  # noqa: F401  (jax API aliases first)
 from . import data, models, obs, ops, parallel, train, utils  # noqa: F401

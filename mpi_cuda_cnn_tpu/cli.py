@@ -35,23 +35,21 @@ from .models.presets import get_model
 from .obs.metrics import MetricsRegistry
 from .parallel.distributed import initialize_distributed
 from .train.trainer import Trainer
+from .utils.backend import DeviceError, claim_device, device_stamp
 from .utils.config import Config, parse_args
 from .utils.logging import MetricsLogger, get_logger
 
 
-def _select_device(cfg: Config, log) -> bool:
-    """Honor --device (the north star's `--device=cpu|tpu` switch,
-    BASELINE.json). 'auto' takes whatever JAX picked."""
-    import jax
-
-    if cfg.device == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-    elif cfg.device == "tpu":
-        if all(d.platform == "cpu" for d in jax.devices()):
-            log.error("--device=tpu requested but no accelerator is visible")
-            return False
-    elif cfg.device != "auto":
-        log.error("unknown --device %r (want auto|tpu|cpu)", cfg.device)
+def _select_device(cfg, log) -> bool:
+    """Honor --device through the one helper (utils/backend), which
+    also places the compile cache and logs what JAX found as the first
+    log line; False (exit 2) when the requested device is not there.
+    The mesh follows with the first JSONL record, once a trainer has
+    built it."""
+    try:
+        claim_device(cfg.device, log.info)
+    except DeviceError as e:
+        log.error("%s", e)
         return False
     return True
 
@@ -158,6 +156,7 @@ def run(cfg: Config) -> int:
         except ValueError as e:
             log.error("trainer setup failed: %s", e)
             return 2
+        metrics.log("device", **device_stamp(first.mesh))
         try:
             result, _ = _supervised(cfg, log, metrics, first, make_trainer,
                                     registry=registry)
@@ -207,6 +206,10 @@ def run_lm(argv: list[str]) -> int:
         except (OSError, ValueError) as e:
             log.error("lm setup failed: %s", e)
             return 2
+        # What ran includes what "auto" resolved to: the fused kernel
+        # or the XLA oracle (train/lm.pick_attn_impl).
+        metrics.log("device", **device_stamp(first.mesh),
+                    attn=first.attn_impl)
         log.info(
             "lm model=d%dx%d h%d seq=%d vocab=%d moe=%d mesh=%s attn=%s",
             cfg.dim, cfg.depth, cfg.heads, cfg.seq_len, first.model.vocab,

@@ -46,13 +46,14 @@ from .transformer import TransformerLM, _layernorm
 # THE auto-dtype routing table (ISSUE 12 satellite: one place for every
 # "auto" storage-dtype decision), keyed by surface -> (GQA/MQA pick,
 # MHA pick). Cache row: measurement-driven (PERF.md int8 decode table,
-# one v5e) — int8 wins +27-32% under GQA/MQA and LOSES MHA by ~9%,
-# where bfloat16 wins outright. Weights row: under GQA/MQA the weight
-# stream is the dominant byte mover once the cache is int8-shrunk, so
-# int8 follows the same byte-dominance argument (chip rows banked by
-# tpu_capture's bench_decode --weights-dtype steps); at MHA the cache
-# dominates and the measured bf16-weights cast was NOT a win
-# (PERF.md round-5 note), so weights stay f32 there.
+# one v5e, 2026-07-31, the contiguous cache at d=512) — int8 wins
+# +27-32% under GQA/MQA and LOSES MHA by ~9%, where bfloat16 wins
+# outright. Weights row: under GQA/MQA the weight stream is the
+# dominant byte mover once the cache is int8-shrunk, so int8 follows the
+# same byte-dominance argument — argued, not measured: the int8 GEMV
+# first compiled for the v5e in PR 21 and has no timing (ROADMAP S4
+# re-derives this table); at MHA the cache dominates and the measured
+# bf16-weights cast was NOT a win (PERF.md), so weights stay f32 there.
 _AUTO_DTYPE_ROUTING: dict[str, tuple[str, str]] = {
     "cache": ("int8", "bfloat16"),
     "weights": ("int8", "float32"),
@@ -276,36 +277,11 @@ def attend_kv(q, ck, cv, mask, cks=None, cvs=None):
     g = h // hkv
     qg = q.reshape(b, kk, hkv, g, hd)
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    # The single-query gemv cell (g*kk == 1: MHA one-token decode) uses
-    # sum-product contractions instead of einsums when accumulating in
-    # f32 OFF-TPU: XLA CPU's batched-gemv emitter orders its
-    # accumulation differently from any per-(b,h) dot a fused kernel
-    # can express, so einsums there are unreproducible to the bit. The
-    # sum-product is the one formulation XLA CPU emits identically
-    # inside and outside a Pallas kernel — ops/pallas_paged_attention
-    # mirrors it (same backend switch), which is what makes the paged
-    # kernel's f32 parity gate BITWISE across MHA too, exactly where it
-    # is tested (interpret mode on CPU). On TPU both sides keep the
-    # batched einsum/dot — the MXU path the banked MHA decode rows
-    # measure; the kernel-vs-gather contract there is the bf16/int8
-    # band, not bitwise f32 (nothing serving-shaped runs f32 MHA on
-    # chip, and the CPU gate pins the kernel's indexing either way).
-    # bf16 keeps the einsums everywhere (the kernel's bf16 dots already
-    # land bitwise inside bf16 rounding).
-    sumprod = (kk * g == 1 and (int8 or ck.dtype == jnp.float32)
-               and jax.default_backend() != "tpu")
-    if sumprod:
-        qv = qg[:, 0, :, 0, :]                # (B, Hkv, hd)
-        ckf = ck.astype(jnp.float32) if int8 else ck
-        logits = (jnp.sum(
-            qv[:, :, :, None] * jnp.transpose(ckf, (0, 2, 3, 1)), axis=2,
-        ) * scale)[:, :, None, None, :]       # (B, Hkv, 1, 1, L)
-    else:
-        logits = jnp.einsum(
-            "bqhgd,bkhd->bhgqk", qg,
-            ck.astype(jnp.float32) if int8 else ck,
-            preferred_element_type=jnp.float32,
-        ) * scale                             # (B, Hkv, g, k, L)
+    logits = jnp.einsum(
+        "bqhgd,bkhd->bhgqk", qg,
+        ck.astype(jnp.float32) if int8 else ck,
+        preferred_element_type=jnp.float32,
+    ) * scale                                 # (B, Hkv, g, k, L)
     if int8:
         logits = logits * jnp.transpose(cks, (0, 2, 3, 1))[:, :, None, :, :]
     if mask.ndim == 2:
@@ -313,24 +289,11 @@ def attend_kv(q, ck, cv, mask, cks=None, cvs=None):
     logits = jnp.where(mask[:, None, None, :, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     if int8:
-        if sumprod:
-            pq = probs[:, :, 0, 0, :] * cvs[:, :, :, 0].transpose(0, 2, 1)
-            o = jnp.sum(
-                pq[:, :, :, None]
-                * jnp.transpose(cv.astype(jnp.float32), (0, 2, 1, 3)),
-                axis=2,
-            )[:, None]                          # (B, 1, Hkv, hd)
-        else:
-            pv = probs * jnp.transpose(cvs, (0, 2, 3, 1))[:, :, None, :, :]
-            o = jnp.einsum(
-                "bhgqk,bkhd->bqhgd", pv, cv.astype(jnp.float32),
-                preferred_element_type=jnp.float32,
-            )
-    elif sumprod:
-        o = jnp.sum(
-            probs[:, :, 0, 0, :, None] * jnp.transpose(cv, (0, 2, 1, 3)),
-            axis=2,
-        )[:, None]                              # (B, 1, Hkv, hd)
+        pv = probs * jnp.transpose(cvs, (0, 2, 3, 1))[:, :, None, :, :]
+        o = jnp.einsum(
+            "bhgqk,bkhd->bqhgd", pv, cv.astype(jnp.float32),
+            preferred_element_type=jnp.float32,
+        )
     else:
         o = jnp.einsum(
             "bhgqk,bkhd->bqhgd", probs.astype(cv.dtype), cv,

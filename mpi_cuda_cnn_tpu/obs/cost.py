@@ -39,13 +39,16 @@ import re
 
 import jax
 
-# Peak dense-matmul throughput per (backend, dtype) — the MFU
-# denominator. The ONE table (scripts/bench_lm.py imports it); extend as
-# chips appear. CPU has no meaningful MXU peak: peak_flops returns None
-# there and MFU reports null rather than a number against a fake peak.
-PEAK_TFLOPS: dict[str, float] = {
-    "tpu_v5e_bf16": 197.0,
-    "tpu_v5e_f32": 49.0,
+# Peak dense-matmul TFLOP/s per chip — the MFU denominator — keyed by
+# the `device_kind` string JAX reports for the chip (taken from the chip
+# run of PR 21, not typed from memory). The ONE table; a chip that is
+# not in it is an error (`peak_flops`), never a default, so an MFU can
+# never be computed against another chip's peak.
+#   "TPU v5 lite": bfloat16 from Google Cloud documentation, "TPU v5e"
+#   (197 TFLOP/s bf16 per chip); float32 is bf16 / 4, the MXU's
+#   multi-pass f32 path (no published figure).
+PEAK_TFLOPS: dict[str, dict[str, float]] = {
+    "TPU v5 lite": {"bfloat16": 197.0, "float32": 49.25},
 }
 
 # Jaxpr primitive names that are cross-device collectives.
@@ -97,20 +100,28 @@ class ProgramCosts:
         }
 
 
-def peak_flops(dtype: str = "bfloat16", *, backend: str | None = None,
+def peak_flops(dtype: str = "bfloat16", *, device_kind: str | None = None,
                override_tflops: float | None = None) -> float | None:
-    """Peak FLOP/s for the MFU denominator, or None when the backend has
-    no registered peak. An override names the chip's bf16 peak; the f32
-    peak scales by the same ratio as v5e (the MXU's f32 path)."""
+    """Peak FLOP/s for the MFU denominator. `device_kind` defaults to
+    the running device's. CPU has no meaningful MXU peak: None, and MFU
+    reports null rather than a number against a fake one. Any other
+    kind must be in PEAK_TFLOPS — an unknown chip raises. An override
+    names the chip's bf16 peak; its f32 peak is a quarter of that (the
+    MXU's multi-pass f32 path)."""
+    f32 = dtype not in ("bfloat16", "bf16")
     if override_tflops is not None:
-        bf16 = override_tflops
-    elif (backend or jax.default_backend()) == "tpu":
-        bf16 = PEAK_TFLOPS["tpu_v5e_bf16"]
-    else:
+        return override_tflops * 1e12 / (4 if f32 else 1)
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind
+    if device_kind == "cpu":
         return None
-    if dtype in ("bfloat16", "bf16"):
-        return bf16 * 1e12
-    return bf16 * 1e12 * PEAK_TFLOPS["tpu_v5e_f32"] / PEAK_TFLOPS["tpu_v5e_bf16"]
+    if device_kind not in PEAK_TFLOPS:
+        raise ValueError(
+            f"no peak FLOP/s registered for device kind {device_kind!r}; "
+            "add it to obs.cost.PEAK_TFLOPS with its source (known: "
+            f"{sorted(PEAK_TFLOPS)})"
+        )
+    return PEAK_TFLOPS[device_kind]["float32" if f32 else "bfloat16"] * 1e12
 
 
 def mfu(flops: float | None, seconds: float, peak: float | None) -> float | None:
@@ -118,16 +129,6 @@ def mfu(flops: float | None, seconds: float, peak: float | None) -> float | None
     if not flops or not peak or seconds <= 0:
         return None
     return flops / seconds / peak
-
-
-def _normalize_cost_analysis(ca) -> dict:
-    """cost_analysis() returns a dict on some backends/versions and a
-    one-element list of dicts on others; normalize to one dict."""
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
 
 
 def hlo_collective_counts(hlo_text: str) -> dict[str, int]:
@@ -203,7 +204,7 @@ def analyze(fn, *args, **kwargs) -> ProgramCosts:
     paths that must never fail for telemetry's sake.
     """
     compiled = fn.lower(*args, **kwargs).compile()
-    costs = _normalize_cost_analysis(compiled.cost_analysis())
+    costs = compiled.cost_analysis() or {}
     try:
         hlo = compiled.as_text()
     except Exception:
@@ -311,6 +312,7 @@ def log_program(metrics, label: str, fn, *args,
     metrics.log(
         "program", label=label, steps_per_dispatch=steps_per_dispatch,
         counting=counting, backend=jax.default_backend(),
+        device_kind=jax.devices()[0].device_kind,
         compute_dtype=compute_dtype, **costs.to_fields(),
     )
     return True

@@ -1,6 +1,6 @@
 """Perf-regression gate: `mctpu compare A B [--gate thresholds.json]`.
 
-The banked BENCH_r*.json files were compared by eye — a tokens/s
+Benchmark captures used to be compared by eye — a tokens/s
 regression would merge silently. This module makes the comparison a
 program with an exit code:
 
@@ -8,15 +8,16 @@ program with an exit code:
   BOTH shapes in the repo: a metrics JSONL run file (obs.schema — the
   `serve`/`train`/`epoch`/`bench`/`metrics` events become
   "serve.continuous.tokens_per_s"-style names, last run of the file),
-  and a driver capture JSON (BENCH_r*.json: one object whose "parsed"
-  field holds {metric, value}).
+  and a driver capture JSON (one object — cmd, rc, tail — whose
+  "parsed" field holds {metric, value}; fixture: tests/data/
+  driver_capture_*.json).
 - `compare(base, cand)` evaluates each gated metric directionally
   (tokens/s up is good, ticks/ms down is good) against a per-metric
   tolerance; anything worse than tolerance is a REGRESSION and the CLI
   exits 1 — wired into CI against a committed baseline, so the gate
   runs on every PR instead of at PERF.md-assembly time.
-- With more than two files (`mctpu compare BENCH_r*.json`) the LAST
-  file is the candidate and the directional BEST of the earlier files
+- With more than two files (`mctpu compare a.json b.json c.json`) the
+  LAST file is the candidate and the directional BEST of the earlier files
   is the baseline — "did the newest capture regress the trajectory".
 
 Thresholds JSON:
@@ -267,7 +268,7 @@ def metrics_from_records(records: list[dict]) -> dict[str, float]:
 def extract_metrics(path: str | Path) -> dict[str, float]:
     """Metrics from a file of either shape (driver JSON / run JSONL).
 
-    A driver capture (BENCH_r*.json) is ONE json object spanning
+    A driver capture is ONE json object spanning
     multiple lines — detected by parsing the whole file first. A run
     JSONL yields its LAST non-empty run (append-mode files accumulate;
     the newest run is the one being compared).
@@ -454,8 +455,8 @@ def _print_diverge_hint(paths: list[str], rows: list[dict],
 def compare_main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(
         prog="mctpu compare",
-        description="Compare run files (metrics JSONL or BENCH_r*.json "
-                    "driver captures) on named metrics; exit 1 on "
+        description="Compare run files (metrics JSONL or driver-capture "
+                    "JSON) on named metrics; exit 1 on "
                     "regression past per-metric tolerance.",
     )
     ap.add_argument("paths", nargs="+",

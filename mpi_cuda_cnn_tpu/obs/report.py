@@ -155,10 +155,13 @@ def summarize(records: Iterable[dict], *,
             }
             flops, n = p["flops"], p["steps_per_dispatch"] or 1
             p["flops_per_step"] = flops / n if flops else None
+            # The record names its chip (device_kind, PR 21); older
+            # records do not, and get an MFU only under --peak-tflops.
+            kind = r.get("device_kind")
             peak = peak_flops(
                 r.get("compute_dtype", "bfloat16"),
-                backend=p["backend"], override_tflops=peak_tflops,
-            ) if (p["backend"] == "tpu" or peak_tflops) else None
+                device_kind=kind, override_tflops=peak_tflops,
+            ) if (kind or peak_tflops) else None
             sp = summary.get("step_phases", {}).get("per_step_ms", {})
             step_s = sum(sp.values()) / 1e3 if sp else None
             p["mfu"] = (mfu(p["flops_per_step"], step_s, peak)

@@ -2,17 +2,17 @@
 
 Before this module, each emitter invented its own dialect: MetricsLogger
 wrote {"event", "t", ...}, bench.py printed a one-off benchmark object,
-scripts/profile_*.py printed ad-hoc rows, and PERF_capture.jsonl mixed
-all three plus `# comment` lines. PERF.md tables were then assembled by
-hand from the union. One schema ends that: every record carries a
+scripts/profile_*.py printed ad-hoc rows, and a hand-appended capture
+file mixed all three plus `# comment` lines. PERF.md tables were then
+assembled by hand from the union. One schema ends that: every record carries a
 version stamp and an event name, event families declare their required
 keys, and `iter_records`/`validate_record` are the single read/check
 path used by the `mctpu report` aggregator, the tests, and any future
 consumer.
 
 Records are one JSON object per line. Lines starting with '#' are
-comments (PERF_capture.jsonl's capture markers) and are skipped by the
-reader, so existing capture files stay parseable.
+comments (the run-boundary marker MetricsLogger writes) and are skipped
+by the reader.
 """
 
 from __future__ import annotations
@@ -32,6 +32,10 @@ REQUIRED_KEYS = ("schema", "event", "t")
 # here are free-form — the schema constrains what the report aggregator
 # depends on, not what producers may add.
 EVENT_KEYS: dict[str, tuple[str, ...]] = {
+    # What ran (utils/backend.device_stamp): the first record of every
+    # jax-using entry point, so a CPU run can never be read as a chip
+    # run. "mesh" is {axis: size} for trainers, null for serving.
+    "device": ("platform", "device_kind", "device_count", "mesh"),
     # Training progress (per log interval). "step" is the in-run step.
     "train": ("step", "loss"),
     # Epoch wall-clock (CNN trainer).
@@ -239,7 +243,7 @@ def iter_records(path: str | Path, *, strict: bool = False) -> Iterator[dict]:
     """Yield records from a JSONL file, skipping blank and '#' lines.
 
     Pre-schema records (no "schema" key) are passed through unvalidated
-    unless strict=True — report must keep reading old PERF_capture.jsonl
+    unless strict=True — report must keep reading pre-schema capture
     files.
     """
     for _, rec in _iter_lines(path, strict=strict):

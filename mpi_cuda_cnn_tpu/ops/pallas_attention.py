@@ -44,6 +44,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..obs.trace import annotate
+from ..utils.backend import pallas_interpret
 from .attention import NEG_INF
 
 # Tuned on v5e (s=8192, d=64): large blocks amortize per-grid-step
@@ -62,10 +63,6 @@ def _blocks(dtype) -> tuple[int, int]:
     if dtype == jnp.bfloat16:
         return BLK_Q_BF16, BLK_K_BF16
     return BLK_Q, BLK_K
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _dot(a, b, dims, hi: bool):
@@ -267,7 +264,7 @@ def _flash_forward(q, k, v, causal: bool, *, with_lse: bool = False,
             pltpu.VMEM((blk_q, 128), jnp.float32),  # running max (col 0)
             pltpu.VMEM((blk_q, 128), jnp.float32),  # running denom (col 0)
         ],
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(qr, kr, vr)
     out = _from_rows(out, b, h, s, d)
     if not out_f32:
@@ -439,7 +436,7 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, *, grads_f32: bool = False
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(qr, kr, vr, gr, lse_col, dvec_col)
 
     # dk/dv: k-rows outer, q-blocks streamed innermost. The grid stays
@@ -472,7 +469,7 @@ def _flash_backward(q, k, v, o, lse, g, causal: bool, *, grads_f32: bool = False
             pltpu.VMEM((blk_k, d), jnp.float32),
             pltpu.VMEM((blk_k, d), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(qr, kr, vr, gr, lse_row, dvec_row)
 
     dq = _from_rows(dq, b, h, s, d)

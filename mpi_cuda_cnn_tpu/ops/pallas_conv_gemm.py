@@ -37,11 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_ops import (
-    _conv_bwd,
-    _flatten_pixels,
-    _interpret,
-)
+from ..utils.backend import pallas_interpret
+from .pallas_ops import _conv_bwd, _flatten_pixels
 
 
 def _conv1_gemm_kernel(x_ref, w_ref, o_ref, *, kh, kw, oh, ow):
@@ -138,7 +135,7 @@ def _conv1_gemm(x: jnp.ndarray, w: jnp.ndarray, oh: int, ow: int):
             memory_space=pltpu.VMEM,
         ),
         out_shape=jax.ShapeDtypeStruct((n, oh, ow, cout), x.dtype),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(x, w_flat)
 
 

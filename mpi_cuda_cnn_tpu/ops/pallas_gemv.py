@@ -28,22 +28,32 @@ of test_generate's int8-cache pin). MoE expert banks and the embedding
 tables are left in f32: experts route through moe_mlp_inference's own
 einsums (a separate lever), and tok_emb/pos_emb are gathers, not GEMVs.
 
-The kernel tiles dout (the only axis with reuse to exploit at T=1) and
-keeps x resident: grid (dout/TILE,), each step one
-(B, din) x (din, TILE) MXU contraction with the int8 tile dequantized
-on load and the f32 scale row applied to the output tile. Interpret
-mode (non-TPU backends) runs the same kernel body — the tier-1 suite
-pins `int8_gemv` == the jnp dequantized form on CPU.
+The kernel tiles dout and din and keeps x resident: grid
+(dout/TILE_N, din/TILE_K) with din innermost, each step one
+(B, TILE_K) x (TILE_K, TILE_N) MXU contraction of the int8 tile
+converted on load, accumulated into the resident f32 output tile; the
+f32 scale row multiplies the finished tile on the last din step. din is
+tiled because a whole-din weight block does not fit: at din = 16384
+(the 4*d MLP contraction of a d = 4096 model) a (16384, 512) int8 block
+is 8 MiB, 16 MiB double-buffered, against v5e's 16 MiB scoped-VMEM
+limit — before the f32 convert. The dot runs at HIGHEST precision: x
+is f32, and the MXU's default would round it to bf16 (the int8 weights
+are exact either way). Interpret mode (platform cpu) runs the same
+kernel body — the tier-1 suite pins `int8_gemv` against the jnp
+dequantized form on CPU.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..utils.backend import pallas_interpret
 
 
 @dataclasses.dataclass
@@ -117,44 +127,64 @@ def quantize_decode_params(params: dict, dtype: str) -> dict:
     return out
 
 
-def _gemv_tile(dout: int) -> int:
-    """Largest multiple of 128 dividing dout, capped at 512; a dout the
+# Weight tile caps: (2048, 512) int8 is 1 MiB (2 MiB double-buffered)
+# and 4 MiB once converted to f32 — inside the 16 MiB scoped-VMEM limit
+# with the (N, 2048) x block and the (N, 512) output tile resident.
+_TILE_N = 512
+_TILE_K = 2048
+
+
+def _tile(dim: int, cap: int) -> int:
+    """Largest multiple of 128 dividing dim, capped at `cap`; a dim the
     lane width doesn't divide runs as one tile (interpret-mode shapes —
     on TPU, model dims are 128-multiples)."""
-    if dout % 128:
-        return dout
-    t = min(512, dout)
-    while dout % t:
+    if dim % 128:
+        return dim
+    t = min(cap, dim)
+    while dim % t:
         t -= 128
     return t
 
 
-def _gemv_kernel(x_ref, w_ref, s_ref, o_ref):
-    o_ref[:] = jax.lax.dot_general(
+def _gemv_kernel(x_ref, w_ref, s_ref, o_ref, *, nk):
+    k = pl.program_id(1)
+
+    @pl.when(k == 0)
+    def _():
+        o_ref[:] = jnp.zeros_like(o_ref)
+
+    o_ref[:] += jax.lax.dot_general(
         x_ref[:], w_ref[:].astype(jnp.float32),
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) * s_ref[:]
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+    @pl.when(k == nk - 1)
+    def _():
+        o_ref[:] *= s_ref[:]
 
 
-def _run_gemv(n, din, dout, tile, operands):
+def _run_gemv(n, din, dout, operands):
     """The one pallas_call site — the MCT007 producer declared for this
     module in the lint manifest."""
+    tn, tk = _tile(dout, _TILE_N), _tile(din, _TILE_K)
+    nk = din // tk
     return pl.pallas_call(
-        _gemv_kernel,
-        grid=(dout // tile,),
+        functools.partial(_gemv_kernel, nk=nk),
+        grid=(dout // tn, nk),
         in_specs=[
-            pl.BlockSpec((n, din), lambda i: (0, 0),
+            pl.BlockSpec((n, tk), lambda j, k: (0, k),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((din, tile), lambda i: (0, i),
+            pl.BlockSpec((tk, tn), lambda j, k: (k, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tile), lambda i: (0, i),
+            pl.BlockSpec((1, tn), lambda j, k: (0, j),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((n, tile), lambda i: (0, i),
+        out_specs=pl.BlockSpec((n, tn), lambda j, k: (0, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n, dout), jnp.float32),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(*operands)
 
 
@@ -166,9 +196,7 @@ def int8_gemv(x: jnp.ndarray, w: QuantW) -> jnp.ndarray:
     absmax contract; equal to x @ dequant(w) up to one reassociated
     multiply)."""
     n, din = x.shape
-    dout = w.q.shape[1]
-    tile = _gemv_tile(dout)
-    return _run_gemv(n, din, dout, tile,
+    return _run_gemv(n, din, w.q.shape[1],
                      [x.astype(jnp.float32), w.q, w.s])
 
 

@@ -24,8 +24,9 @@ TPU way:
 - everything is wired into jax.custom_vjp, so `jax.grad` of a model using
   backend="pallas" differentiates through these kernels.
 
-On non-TPU backends the kernels run in Pallas interpreter mode, so the
-whole suite is testable on the CPU mesh (tests/test_pallas.py checks
+On platform cpu the kernels run in Pallas interpreter mode
+(utils/backend.pallas_interpret), so the whole suite is testable on the
+CPU mesh (tests/test_pallas.py checks
 parity against the XLA oracle ops).
 """
 
@@ -39,9 +40,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from ..utils.backend import pallas_interpret
 
 
 def _round_up(x: int, m: int) -> int:
@@ -85,7 +84,7 @@ def _matmul(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
             (_BM, _BN), lambda i, j: (i, j), memory_space=pltpu.VMEM
         ),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(xp, wp)
     return out[:m, :n]
 
@@ -222,7 +221,7 @@ def _conv1(x: jnp.ndarray, w: jnp.ndarray, oh: int, ow: int) -> jnp.ndarray:
         ),
         out_shape=jax.ShapeDtypeStruct((n, oh, ow, cout), x.dtype),
         scratch_shapes=[pltpu.VMEM((bn * oh * ow, cout), jnp.float32)],
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(x, w)
 
 
@@ -314,7 +313,7 @@ def _conv1_dw(x, g, kh: int, kw: int):
             memory_space=pltpu.VMEM,
         ),
         out_shape=jax.ShapeDtypeStruct((kh, kw, cin, cout), jnp.float32),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(x, g)
     return dw
 

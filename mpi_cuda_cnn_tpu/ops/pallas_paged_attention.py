@@ -2,68 +2,58 @@
 
 The XLA formulation of the paged read (serve/paged_cache.py's gather
 path) materializes every slot's gathered (B, L, Hkv, hd) cache rows in
-HBM before `attend_kv` touches them — per layer, per tick. On a
-bandwidth-bound decode tick (PERF.md decode table: tokens/s tracks
-cache bytes almost linearly) that round-trip is pure waste: the pages
-already hold the rows; only their ORDER is indirect. This kernel is the
-FlashAttention discipline (ops/pallas_attention.py) applied to the
-PagedAttention layout (Kwon et al., SOSP '23): consume the page pool +
-block tables directly, stream each page HBM -> VMEM, and keep the
-gathered rows on-chip until the attention output is done.
+HBM before `attend_kv` touches them — per layer, per tick. This kernel
+is the FlashAttention discipline (ops/pallas_attention.py) applied to
+the PagedAttention layout (Kwon et al., SOSP '23): consume the page pool
++ block tables directly, stream each page HBM -> VMEM, and fold it into
+an online-softmax carry, so the gathered rows never exist outside VMEM.
 
 Shape contract (the one `paged_update_attend` already speaks):
 
 - q: (B, kk, H, hd) — kk = 1 is the decode tick, kk = chunk the
-  prefill chunk; H % Hkv == 0 (GQA/MQA served by the same head
-  mapping as `attend_kv`'s reshape: query head h serves kv head
-  h // (H // Hkv)).
+  prefill chunk or speculative verify; H % Hkv == 0 (GQA/MQA served by
+  the same head mapping as `attend_kv`'s reshape: query head h serves
+  kv head h // (H // Hkv)).
 - pages: per-layer dicts {k, v} of (num_pages, page_size, Hkv, hd)
   (+ f32 absmax scales {ks, vs} of (num_pages, page_size, Hkv, 1) for
-  the int8 form — the cache's quantization contract, dequantized
-  IN-KERNEL exactly as attend_kv applies it: a k-row's scale multiplies
-  the logits after the QK dot, a v-row's folds into the probabilities
-  before the PV dot).
-- block_table: (B, npages) int32; positions: (B, kk) int32 — both ride
-  as SCALAR PREFETCH (PrefetchScalarGridSpec), so the page index for
-  every grid step is known before the kernel body runs and the Pallas
-  pipeline emitter double-buffers the per-page VMEM copies: page i+1's
-  DMA is in flight while page i folds. That pipeline IS the per-page
-  async-copy/double-buffer structure — hand-rolled semaphores would
-  re-implement what the grid already provides.
+  the int8 form — the cache's quantization contract, applied exactly
+  as attend_kv applies it: a k-row's scale multiplies the logits after
+  the QK dot, a v-row's folds into the probabilities before the PV
+  dot).
+- block_table: (B, npages) int32, SCALAR PREFETCH: the page index of
+  every grid step is known before the body runs, so the Pallas pipeline
+  double-buffers the per-page copies (page i+1's DMA is in flight while
+  page i folds). positions: (B, kk) int32.
 
-Grid: (B, Hkv, npages) with the page axis innermost/sequential; each
-(slot, kv head) program accumulates its pages' QK logits into a VMEM
-scratch strip ((g*kk, L) f32, L = npages * page_size) and the v rows
-into a (L, hd) VMEM buffer, then computes the EXACT softmax + PV on the
-final page step. Exact-not-online is deliberate: the parity gate is
-BITWISE against the gather path in f32, and the online-softmax
-rescaling form (exp(m_i - m_new) carries) is 1-2 ulp off a single
-softmax by construction. A decode slot's extent is bounded by the block
-table (engine max_len), so the strip + v buffer fit VMEM at serving
-shapes ((g*kk + hd) * L * 4 bytes ~ 1.1 MB at L=2048, hd=128, kk=1);
-the online form only pays off past VMEM extents the serving engine
-never allocates.
+Grid: (B, npages), pages innermost/sequential. One step DMAs one whole
+page — all kv heads, a (page_size, Hkv, hd) block whose last two
+dimensions are the pool's own (the only page block Mosaic accepts for
+every Hkv: a one-head (ps, 1, hd) block of an Hkv > 1 pool is neither
+(8, 128)-divisible nor whole) — and folds each kv head's (ps, hd) rows
+into that head's (m, l, acc) carry in VMEM scratch. Online, not exact,
+softmax: a (g*kk, L) logits strip would need page-granular writes along
+the lane axis, which Mosaic only takes at 128-lane alignment.
 
-Parity discipline (pinned by tests/test_paged_kernel.py, interpret
-mode on CPU): f32 BITWISE vs the gather path across MHA/GQA/MQA and
-kk in {1, chunk} — every contraction mirrors attend_kv's dimension
-structure (the g*kk == 1 gemv cell uses the same sum-product form
-attend_kv uses off-TPU, the one formulation XLA CPU emits identically
-in both contexts); bf16/int8 within 1e-5 (same elementwise math,
-reduction order differs by at most the page split). ON TPU that gemv
-cell keeps the MXU dot on BOTH sides (attend_kv's backend switch
-matches), so the banked MHA decode hot path never trades its batched
-gemv for a VPU sum-product — the bitwise contract is scoped to where
-it is tested, and the serving configurations (GQA/MQA, and any kk > 1)
-never enter the cell at all.
+What the kernel reads besides pages is laid out for the vector unit,
+not taken from SMEM: positions arrive as (B, g*kk, 128) int32 rows
+(Mosaic loads scalars, not vectors, from SMEM), and the int8 scales —
+3% of the cache bytes — are gathered by XLA into (B, npages, Hkv, ps)
+so a head's scales are one lane-dense row beside its logits (the pool's
+trailing-1 scale layout would DMA one lane in 128).
 
-TPU compile notes: blocks are (page_size, hd) slabs, so page_size >= 8
-(f32) / 16 (bf16) / 32 (int8) avoids sublane padding; the scratch strip
-is allocated at the table's full L regardless of a slot's live extent —
-the gather baseline reads those same bytes, so kernel-on/off A/B is
-byte-fair. Interpret mode (any non-TPU backend) runs the same kernel
-through the Pallas interpreter — the tier-1 CPU suite executes exactly
-this code path.
+Precision: pages convert to f32 on load and both dots run at HIGHEST,
+so the f32 cache is f32-accurate on the MXU (its default precision
+rounds operands to bf16) and bf16/int8 caches lose nothing beyond their
+storage rounding — the same contract as attend_kv under
+jax.default_matmul_precision("highest"), which is what chip_smoke.py
+compares against on the chip. tests/test_paged_kernel.py pins the kernel
+to the gather path in interpret mode on CPU within a few f32 ulp (the
+online carry reorders the softmax reduction).
+
+Not tuned: one page per grid step makes B * npages small steps per
+layer per tick. Whether it beats the gather anywhere is ROADMAP S4's
+question; this file's job is to compile for every head layout and be
+right.
 """
 
 from __future__ import annotations
@@ -76,11 +66,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..obs.trace import annotate
+from ..utils.backend import pallas_interpret
 from .attention import NEG_INF
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+_LANES = 128
 
 
 def _run_kernel(kern, grid_spec, out_shape, operands):
@@ -88,86 +77,64 @@ def _run_kernel(kern, grid_spec, out_shape, operands):
     manifest declares for this module's hot driver (`paged_attend`)."""
     return pl.pallas_call(
         kern, grid_spec=grid_spec, out_shape=out_shape,
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(*operands)
 
 
-def _paged_kernel(tbl_ref, pos_ref, *refs, npages, page_size, gkk, kk,
-                  int8):
-    """One (slot, kv head, page) grid step.
+def _dot(a, b, dims):
+    return jax.lax.dot_general(
+        a, b, (dims, ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
 
-    Pages stream innermost: step i folds page block_table[b, i]'s QK
-    logits into the s_buf strip (columns [i*ps, (i+1)*ps)) and parks
-    its v rows in v_buf; the last step masks, softmaxes, and contracts
-    — the gathered rows never exist outside VMEM.
-    """
+
+def _paged_kernel(tbl_ref, q_ref, pos_ref, k_ref, v_ref, *refs, npages,
+                  page_size, hkv, int8):
+    """One (slot, page) grid step: fold page block_table[b, i] into
+    every kv head's online-softmax carry; normalize on the last page."""
     if int8:
-        q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, s_buf, v_buf, vs_buf \
-            = refs
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
     else:
-        q_ref, k_ref, v_ref, o_ref, s_buf, v_buf = refs
-        vs_buf = None
-    b = pl.program_id(0)
-    i = pl.program_id(2)
+        o_ref, m_ref, l_ref, acc_ref = refs
+    i = pl.program_id(1)
     ps = page_size
+    gkk, hd = q_ref.shape[2], q_ref.shape[3]
 
-    q = q_ref[0, 0]                                  # (g*kk, hd)
-    hd = q.shape[1]
-    kp = k_ref[0, :, 0, :]                           # (ps, hd)
+    @pl.when(i == 0)
+    def _():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    kpf = kp.astype(jnp.float32) if int8 else kp
-    if gkk == 1 and kpf.dtype == jnp.float32 and _interpret():
-        # The single-query gemv cell OFF-TPU: mirror attend_kv's
-        # sum-product QK — the one formulation XLA CPU emits
-        # identically inside and outside a kernel (a dot here would
-        # take the gemv emitter's accumulation order and land 1 ulp off
-        # the gather path; the f32 gate is bitwise). On TPU both sides
-        # keep the MXU dot (attend_kv's backend switch matches).
-        s = (jnp.sum(q[0][:, None] * kpf.T, axis=0)
-             * scale)[None, :]                       # (1, ps)
-    else:
-        s = jax.lax.dot_general(
-            q, kpf, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                    # (g*kk, ps)
-    if int8:
-        # attend_kv's contract: the k-scale is constant along the
-        # contracted head_dim, so it multiplies the LOGITS — same
-        # elementwise order as the gather path (scale, then absmax).
-        s = s * ks_ref[0, :, 0, :].reshape(1, ps)
-        vs_buf[0, pl.ds(i * ps, ps)] = vs_ref[0, :, 0, :].reshape(ps)
-    s_buf[:, pl.ds(i * ps, ps)] = s
-    v_buf[pl.ds(i * ps, ps), :] = v_ref[0, :, 0, :]
+    # Row r attends key positions <= its own; the page's keys sit at
+    # [i*ps, (i+1)*ps). Key 0 is visible to every row (positions >= 0),
+    # so m is finite after page 0 and a fully masked later page folds
+    # as p == 0.
+    key = i * ps + jax.lax.broadcasted_iota(jnp.int32, (gkk, ps), 1)
+    mask = key <= pos_ref[0][:, :1]
+    for h in range(hkv):
+        kp = k_ref[0, :, h, :].astype(jnp.float32)       # (ps, hd)
+        vp = v_ref[0, :, h, :].astype(jnp.float32)
+        s = _dot(q_ref[0, h], kp, ((1,), (1,))) * scale  # (gkk, ps)
+        if int8:
+            s = s * ks_ref[0, 0, h:h + 1, :]
+        s = jnp.where(mask, s, NEG_INF)
+        m = m_ref[h][:, :1]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l_new = l_ref[h][:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[h] = jnp.broadcast_to(m_new, (gkk, _LANES))
+        l_ref[h] = jnp.broadcast_to(l_new, (gkk, _LANES))
+        if int8:
+            p = p * vs_ref[0, 0, h:h + 1, :]
+        acc_ref[h] = acc_ref[h] * alpha + _dot(p, vp, ((1,), (0,)))
 
     @pl.when(i == npages - 1)
     def _():
-        L = npages * ps
-        pos = pos_ref[b]                             # (kk,)
-        key_idx = jax.lax.broadcasted_iota(jnp.int32, (kk, L), 1)
-        mask = key_idx <= pos[:, None]               # (kk, L)
-        g = gkk // kk
-        mask_full = jnp.broadcast_to(
-            mask[None], (g, kk, L)).reshape(gkk, L)
-        logits = jnp.where(mask_full, s_buf[:], NEG_INF)
-        probs = jax.nn.softmax(logits, axis=-1)
-        vb = v_buf[:]
-        if int8:
-            pv = probs * vs_buf[0, :][None, :]
-            vv = vb.astype(jnp.float32)
-        else:
-            pv = probs.astype(vb.dtype)
-            vv = vb
-        if gkk == 1 and vv.dtype == jnp.float32 and _interpret():
-            # The single-query gemv cell OFF-TPU: mirror attend_kv's
-            # sum-product PV (same backend switch — TPU keeps the MXU
-            # dot on both sides; see attend_kv).
-            o = jnp.sum(pv[0][:, None] * vv, axis=0)[None, :]
-        else:
-            o = jax.lax.dot_general(
-                pv, vv, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        o_ref[0, 0] = o
+        for h in range(hkv):
+            o_ref[0, h] = acc_ref[h] / l_ref[h][:, :1]
 
 
 def paged_attend(q, c, positions, block_table, page_size: int):
@@ -190,46 +157,54 @@ def paged_attend(q, c, positions, block_table, page_size: int):
     npages = block_table.shape[1]
     ps = page_size
     int8 = c["k"].dtype == jnp.int8
+    block_table = block_table.astype(jnp.int32)
     # Head-group layout: (B, Hkv, g*kk, hd), rows g-major within a kv
-    # head — the same (hkv, g) split attend_kv's reshape uses, so the
-    # index maps stay pure picks (no div/mod: the Mosaic constraint
-    # _gqa_maps documents).
-    qg = q.reshape(b, kk, hkv, g, hd).transpose(0, 2, 3, 1, 4).reshape(
-        b, hkv, gkk, hd)
+    # head — the same (hkv, g) split attend_kv's reshape uses.
+    qg = q.astype(jnp.float32).reshape(b, kk, hkv, g, hd).transpose(
+        0, 2, 3, 1, 4).reshape(b, hkv, gkk, hd)
+    # Row r = gi*kk + j sits at positions[b, j]; lane-replicated so the
+    # kernel reads a (gkk, 1) column with a plain vector load.
+    pos_rows = jnp.broadcast_to(
+        positions.astype(jnp.int32)[:, None, :, None],
+        (b, g, kk, _LANES)).reshape(b, gkk, _LANES)
 
-    def q_map(b_, h_, i_, tbl, pos):
-        return b_, h_, 0, 0
+    def slot_map(b_, i_, tbl):
+        return b_, 0, 0, 0
 
-    def page_map(b_, h_, i_, tbl, pos):
-        return tbl[b_, i_], 0, h_, 0
+    def page_map(b_, i_, tbl):
+        return tbl[b_, i_], 0, 0, 0
 
+    q_spec = pl.BlockSpec((1, hkv, gkk, hd), slot_map)
+    page_spec = pl.BlockSpec((1, ps, hkv, hd), page_map)
     in_specs = [
-        pl.BlockSpec((1, 1, gkk, hd), q_map),
-        pl.BlockSpec((1, ps, 1, hd), page_map),
-        pl.BlockSpec((1, ps, 1, hd), page_map),
+        q_spec,
+        pl.BlockSpec((1, gkk, _LANES), lambda b_, i_, tbl: (b_, 0, 0)),
+        page_spec,
+        page_spec,
     ]
-    operands = [block_table.astype(jnp.int32),
-                positions.astype(jnp.int32), qg, c["k"], c["v"]]
-    scratch = [
-        pltpu.VMEM((gkk, npages * ps), jnp.float32),   # logits strip
-        pltpu.VMEM((npages * ps, hd), c["v"].dtype),   # gathered v rows
-    ]
+    operands = [block_table, qg, pos_rows, c["k"], c["v"]]
     if int8:
-        in_specs.append(pl.BlockSpec((1, ps, 1, 1), page_map))
-        in_specs.append(pl.BlockSpec((1, ps, 1, 1), page_map))
-        operands += [c["ks"], c["vs"]]
-        scratch.append(pltpu.VMEM((1, npages * ps), jnp.float32))
+        scale_spec = pl.BlockSpec((1, 1, hkv, ps),
+                                  lambda b_, i_, tbl: (b_, i_, 0, 0))
+        in_specs += [scale_spec, scale_spec]
+        operands += [
+            c[name][block_table][..., 0].transpose(0, 1, 3, 2)
+            for name in ("ks", "vs")
+        ]
 
     kern = functools.partial(
-        _paged_kernel, npages=npages, page_size=ps, gkk=gkk, kk=kk,
-        int8=int8,
+        _paged_kernel, npages=npages, page_size=ps, hkv=hkv, int8=int8,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, npages),
+        num_scalar_prefetch=1,
+        grid=(b, npages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, gkk, hd), q_map),
-        scratch_shapes=scratch,
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((hkv, gkk, _LANES), jnp.float32),   # running max
+            pltpu.VMEM((hkv, gkk, _LANES), jnp.float32),   # running denom
+            pltpu.VMEM((hkv, gkk, hd), jnp.float32),       # acc
+        ],
     )
     with annotate("ops.paged_attention"):
         out = _run_kernel(

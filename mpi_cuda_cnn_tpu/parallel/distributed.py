@@ -7,8 +7,8 @@ The reference's process management is `mpirun -np 8` + MPI_Init
 over ICI within a slice and DCN across slices — user training code is
 unchanged (SURVEY.md §5.8).
 
-On a single host (this environment, and the reference's own test setup)
-initialization is a no-op.
+On a single host (one chip or one four-chip host, and the reference's
+own test setup) initialization is a no-op.
 """
 
 from __future__ import annotations
@@ -16,8 +16,6 @@ from __future__ import annotations
 import dataclasses
 
 import jax
-
-from ..utils.logging import get_logger
 
 
 @dataclasses.dataclass(frozen=True)
@@ -35,20 +33,22 @@ def initialize_distributed(
 ) -> ProcessInfo:
     """Join the multi-host runtime when launched as one process per host.
 
-    With no arguments, relies on the TPU environment's auto-detection
-    (e.g. GCE metadata) and silently stays single-process elsewhere —
-    so the same entry point covers laptop CPU, one TPU VM, and a pod.
+    With no arguments and no coordinator in the environment this is a
+    single-process run and nothing is initialized — the same entry
+    point covers a laptop CPU, one TPU host and a pod. Once a
+    coordinator IS named (argument or COORDINATOR_ADDRESS /
+    JAX_COORDINATOR_ADDRESS), failing to join raises: a process that
+    carried on alone would train on 1/N of the data and report it as
+    the whole job.
     """
-    log = get_logger()
     if coordinator_address is not None or _looks_multiprocess():
-        try:
-            jax.distributed.initialize(
-                coordinator_address=coordinator_address,
-                num_processes=num_processes,
-                process_id=process_id,
-            )
-        except Exception as e:  # already initialized or single-process env
-            log.debug("jax.distributed.initialize skipped: %s", e)
+        if jax.distributed.is_initialized():
+            return process_info()
+        jax.distributed.initialize(
+            coordinator_address=coordinator_address,
+            num_processes=num_processes,
+            process_id=process_id,
+        )
     return process_info()
 
 

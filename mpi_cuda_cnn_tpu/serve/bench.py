@@ -590,11 +590,12 @@ def serve_bench_main(argv: list[str] | None = None) -> int:
 
     import jax
 
-    if args.device == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-    elif args.device == "tpu" and jax.default_backend() != "tpu":
-        print("--device=tpu requested but the backend is "
-              f"{jax.default_backend()}", file=sys.stderr)
+    from ..utils.backend import DeviceError, claim_device
+
+    try:
+        stamp = claim_device(args.device)
+    except DeviceError as e:
+        print(f"error: {e}", file=sys.stderr)
         return 1
 
     from ..models.transformer import TransformerLM
@@ -750,6 +751,7 @@ def serve_bench_main(argv: list[str] | None = None) -> int:
         return rc
     summaries = {}
     with MetricsLogger(path=args.metrics_jsonl, echo=False) as metrics:
+        metrics.log("device", **stamp)
         if alert_engine is not None:
             # Live alerting folds EXACTLY the records the file gets
             # (MetricsLogger observer): replaying the finished JSONL
@@ -1247,14 +1249,16 @@ def fleet_bench_main(argv: list[str] | None = None) -> int:
     max_len = args.prompt_max + args.out_max
     pages = args.pages or args.slots * pages_for(max_len, args.page_size) + 1
     host_pages = (args.host_pages or pages) if args.spill else 0
+    stamp = None  # SimCompute fleets are jax-free: no device to name
     if args.compute == "engine":
         import jax
 
-        if args.device == "cpu":
-            jax.config.update("jax_platforms", "cpu")
-        elif args.device == "tpu" and jax.default_backend() != "tpu":
-            print("--device=tpu requested but the backend is "
-                  f"{jax.default_backend()}", file=sys.stderr)
+        from ..utils.backend import DeviceError, claim_device
+
+        try:
+            stamp = claim_device(args.device)
+        except DeviceError as e:
+            print(f"error: {e}", file=sys.stderr)
             return 1
         from ..models.transformer import TransformerLM
         from .engine import PagedEngine
@@ -1344,6 +1348,8 @@ def fleet_bench_main(argv: list[str] | None = None) -> int:
     registry = MetricsRegistry(clock=clock)
     faults = FaultInjector(args.fault_plan) if args.fault_plan else None
     with MetricsLogger(path=args.metrics_jsonl, echo=False) as metrics:
+        if stamp is not None:
+            metrics.log("device", **stamp)
         if alert_engine is not None:
             # Everything that goes through metrics.log (registry
             # snapshots, replica/fault/request/serve records — and, at

@@ -33,15 +33,17 @@ Host-side page accounting (alloc/free/ownership) is `PagePool`; policy
 (who gets pages when) lives in scheduler.py.
 
 TPU note: the gather materializes (B, L, Hkv, hd) rows per layer — the
-XLA formulation of the paged read. The fused form SHIPPED as
-ops/pallas_paged_attention.paged_attend (ISSUE 12): pages stream
-HBM -> VMEM behind scalar-prefetched block tables with the Pallas
-pipeline double-buffering the per-page copies, and the gathered rows
-never exist outside VMEM. `paged_update_attend(kernel="pallas")`
-dispatches to it (the write stays shared); PagedKVCache carries the
-choice as static metadata so one engine never mixes layouts. Parity is
-bitwise vs this gather in f32, <= 1e-5 in bf16/int8
-(tests/test_paged_kernel.py, interpret mode on CPU).
+XLA formulation of the paged read. The fused form is
+ops/pallas_paged_attention.paged_attend (ISSUE 12; first compiled for
+the v5e in PR 21): pages stream HBM -> VMEM behind scalar-prefetched
+block tables with the Pallas pipeline double-buffering the per-page
+copies, and the gathered rows never exist outside VMEM.
+`paged_update_attend(kernel="pallas")` dispatches to it (the write
+stays shared); PagedKVCache carries the choice as static metadata so
+one engine never mixes layouts. Parity vs this gather: a few f32 ulp
+in f32 and int8, bf16's probability rounding in bf16
+(tests/test_paged_kernel.py in interpret mode on CPU; chip_smoke.py on
+the chip). Which read is faster is not measured (ROADMAP S4).
 """
 
 from __future__ import annotations
@@ -145,8 +147,8 @@ def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
     rows <= i through the read), then the read runs per `kernel`:
     "gather" materializes each slot's pages into (B, L, Hkv, hd) rows
     for the shared attend_kv read; "pallas" streams the same pages
-    HBM -> VMEM inside ops/pallas_paged_attention.paged_attend (bitwise
-    vs the gather in f32, <= 1e-5 in bf16/int8). Either way the read is
+    HBM -> VMEM inside ops/pallas_paged_attention.paged_attend (equal
+    to the gather within rounding). Either way the read is
     masked to key positions <= the row's own position; positions beyond
     a slot's written extent read whatever the (possibly scratch/stale)
     rows hold — the mask keeps them out of the softmax.

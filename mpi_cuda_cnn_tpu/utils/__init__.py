@@ -1,6 +1,5 @@
 """Utilities: config/flags, logging/metrics, profiling."""
 
-from . import compat  # noqa: F401  (installs the jax.shard_map alias)
 from .config import Config, parse_args
 from .logging import MetricsLogger, get_logger
 from .profiling import StepTimer, profile_trace

@@ -33,7 +33,7 @@ class StepTimer:
         timer.start()
         with timer.phase("data"):     bx, by = make_batch()
         with timer.phase("dispatch"): state, m = step(state, bx, by)
-        with timer.phase("device"):   hard_block(state)
+        with timer.phase("device"):   jax.block_until_ready(state)
         timer.stop(n_steps)
 
     Phases nest with the start/stop envelope, not with each other.

@@ -38,7 +38,8 @@ from mpi_cuda_cnn_tpu.models.transformer import TransformerLM
 from mpi_cuda_cnn_tpu.obs.schema import make_record
 from mpi_cuda_cnn_tpu.train.lm import make_lm_state, make_lm_train_step
 from mpi_cuda_cnn_tpu.train.optimizer import make_optimizer
-from mpi_cuda_cnn_tpu.utils.sync import hard_block, two_point
+from mpi_cuda_cnn_tpu.utils.backend import claim_device
+from mpi_cuda_cnn_tpu.utils.sync import two_point
 
 _T0 = time.perf_counter()
 
@@ -84,13 +85,13 @@ def timed_tokens(fn, n, attempts=3, floor=0.0):
     two-point core: fn(m) must produce m tokens and force completion.
     A backend transient can push even the median-of-3 slope NEGATIVE
     (observed: a banked -0.095 ms/tok row) or impossibly FAST (observed
-    round 5: a lookup-k8 slope reading 85x speedup, ~7x above every
-    healthy window's measurement) — a value at or below `floor` is
+    2026-07-31: a lookup-k8 slope reading 85x speedup, ~7x above every
+    other run's measurement) — a value at or below `floor` is
     re-measured up to `attempts` times. Callers pass plain/(k*4) for
     speculative modes (per-round emit <= k tokens; banked legitimate
     rows reach ~2x k because the verify block + while_loop amortize far
     better than one plain step per round, and a first 3x-k margin was
-    itself outrun by a healthy window). If every attempt stays at or
+    itself outrun by a legitimate run). If every attempt stays at or
     below the floor the LAST positive sample is returned with
     suspect=True — the row is emitted flagged, never silently dropped
     and never allowed to kill the remaining bench rows (a raise here
@@ -99,7 +100,7 @@ def timed_tokens(fn, n, attempts=3, floor=0.0):
 
     def run(m):
         t0 = time.perf_counter()
-        hard_block(fn(m))
+        jax.block_until_ready(fn(m))
         return time.perf_counter() - t0
 
     run(n), run(2 * n)  # warm both program sizes
@@ -152,12 +153,7 @@ def main():
     ap.add_argument("--device", default="auto", choices=["auto", "tpu", "cpu"])
     args = ap.parse_args()
 
-    if args.device == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-    elif args.device == "tpu" and jax.default_backend() != "tpu":
-        print("--device=tpu requested but the backend is "
-              f"{jax.default_backend()}", file=sys.stderr)
-        raise SystemExit(1)
+    claim_device(args.device)  # utils/backend: DeviceError off-chip
 
     target = TransformerLM(vocab=args.vocab, dim=args.dim,
                            heads=args.heads, depth=args.depth,

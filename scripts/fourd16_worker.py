@@ -56,6 +56,7 @@ from mpi_cuda_cnn_tpu.parallel.tp_pp_lm import (  # noqa: E402
     unstack_tp_blocks,
 )
 from mpi_cuda_cnn_tpu.train.lm import make_lm_state, make_lm_train_step  # noqa: E402
+from mpi_cuda_cnn_tpu.utils.backend import enable_compile_cache  # noqa: E402
 
 
 def main(fast: bool = False) -> None:
@@ -68,15 +69,13 @@ def main(fast: bool = False) -> None:
     # data:2 x 2 microbatches -> batch 4), so the flagship 4D program
     # cannot regress between --runslow runs while the spawn stays in
     # the fast suite's time budget. XLA compile dominates the spawn
-    # (~12 s of its ~16 s cold); the persistent compilation cache under
-    # .cache/ brings the steady-state run to < 8 s (measured), and only
-    # the first run on a fresh checkout pays the compile.
+    # (~12 s of its ~16 s cold); the persistent compilation cache
+    # (utils/backend.enable_compile_cache: JAX_COMPILATION_CACHE_DIR if
+    # set, else <checkout>/.cache/jax) brings the steady-state run to
+    # < 8 s (measured), and only the first run on a fresh checkout pays
+    # the compile.
     if fast:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), ".cache", "jax_4d_canary"),
-        )
+        enable_compile_cache()
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         model = TransformerLM(vocab=16, dim=16, heads=2, depth=2,
                               max_seq=32)

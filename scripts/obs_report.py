@@ -7,7 +7,7 @@ two entry points:
                                            [--peak-tflops 197]
 
 Reads any file of obs.schema records; '#' comment lines and pre-schema
-rows (old PERF_capture.jsonl) pass through without validation.
+rows (pre-schema capture files) pass through without validation.
 """
 
 from __future__ import annotations

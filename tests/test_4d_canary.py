@@ -6,7 +6,8 @@ regress silently between --runslow runs.
 
 Same spawned-worker pattern as tests/test_4d_full.py (16 virtual
 devices need their own process), but at the smallest shapes every axis
-admits plus a persistent XLA compile cache (.cache/jax_4d_canary):
+admits plus the persistent XLA compile cache (.cache/jax unless
+JAX_COMPILATION_CACHE_DIR says otherwise — utils/backend.py):
 steady-state wall-clock < 8 s measured; only the first run on a fresh
 checkout pays the ~16 s compile.
 """

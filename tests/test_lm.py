@@ -102,6 +102,28 @@ def test_pick_attn_impl(monkeypatch):
         get_attn_fn("nope")
 
 
+def test_flash_attn_fn_on_a_data_mesh_matches_single_device(eight_devices):
+    """GSPMD cannot partition a Mosaic kernel (the first four-chip run,
+    PR 21), so on a multi-device mesh get_attn_fn wraps the flash kernel
+    in a shard_map over the batch. Rows are independent, so the wrapped
+    kernel must equal the bare one — with the batch split over 'data',
+    and replicated when 'data' does not divide it or is absent."""
+    from mpi_cuda_cnn_tpu.parallel.mesh import make_mesh
+
+    rng = np.random.default_rng(0)
+    bare = get_attn_fn("flash")
+    for axes, batch in (({"data": 2}, 2), ({"data": 2}, 3),
+                        ({"model": 2}, 2)):
+        mesh = make_mesh(axes, devices=eight_devices[:2])
+        q, k, v = (jnp.asarray(rng.normal(size=(batch, 128, 2, 16)),
+                               jnp.float32) for _ in range(3))
+        got = jax.jit(get_attn_fn("flash", mesh))(q, k, v)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(bare(q, k, v)))
+    assert get_attn_fn("flash", make_mesh(
+        {"data": 1}, devices=eight_devices[:1])).__name__ == "flash"
+
+
 def test_pick_attn_impl_routing_table(monkeypatch):
     """Pin "auto" to the measured crossovers (one v5e): bf16 -> flash at
     any 128-aligned s (wins 2.2x at s=2048, round-4 capture: 56.4 vs

@@ -1,9 +1,15 @@
 """Fused Pallas paged-attention kernel + int8 decode-weight GEMVs
-(ISSUE 12): parity with the gather path — BITWISE in f32, within the
+(ISSUE 12): parity with the gather path — a few f32 ulp in f32, the
 1e-5 band in bf16/int8 — across MHA/GQA/MQA and decode/prefill query
 widths, the paged-layout edge cases the gather hides, and the
 quantized-weight error bound. Everything runs the real kernels in
-Pallas interpret mode on CPU (tier-1 scope)."""
+Pallas interpret mode on CPU (tier-1 scope); chip_smoke.py compares the
+same kernels with the same twins on the TPU.
+
+Bitwise equality is kept for what it can promise: the same program run
+twice (the masked-row poison checks below, the engine's greedy token
+streams). Two different formulations of one sum get a band.
+"""
 
 import dataclasses
 
@@ -44,6 +50,23 @@ MQA = TransformerLM(vocab=13, dim=32, heads=4, depth=2, max_seq=48,
                     kv_heads=1, pos="rope")
 
 HEAD_CONFIGS = {"mha": 4, "gqa": 2, "mqa": 1}
+
+# The cross-formulation band (ROADMAP D8). The kernel folds pages into
+# an online-softmax carry; the gather path runs one softmax over einsums
+# whose reduction order is XLA:CPU's to choose (it changes between jax
+# versions, which is how the former bitwise gate went red with no code
+# change). Both compute in f32, so they agree to accumulated rounding:
+# 32 ulp of the output's scale (3.8e-6) is several times the drift seen
+# here and three orders tighter than a bf16 computation of the same
+# case, whose operand rounding alone is 2^-9 = 2e-3.
+F32_ULPS = 32
+
+
+def _assert_f32_close(got, want, err_msg=""):
+    scale = max(1.0, float(np.max(np.abs(want))))
+    np.testing.assert_allclose(
+        got, want, rtol=0,
+        atol=F32_ULPS * np.finfo(np.float32).eps * scale, err_msg=err_msg)
 
 
 def _rand_case(dtype, hkv, kk, seed, *, b=3, h=4, hd=8, ps=4, per=5,
@@ -86,31 +109,39 @@ def _both(q, k, v, c, table, positions, ps):
 
 @pytest.mark.parametrize("kk", [1, 4], ids=["decode", "chunk"])
 @pytest.mark.parametrize("head", ["mha", "gqa", "mqa"])
-def test_kernel_matches_gather_f32_bitwise(head, kk):
+def test_kernel_matches_gather_f32(head, kk):
     """THE f32 gate: the fused kernel's output equals the gather path's
-    BITWISE — every contraction mirrors attend_kv's formulation, so any
-    drift is a layout/indexing bug, not rounding. Covers the decode
-    tick (kk=1) and the chunked-prefill query width (kk=4) at every
-    head mapping."""
+    within the few-ulp band (F32_ULPS) — a layout/indexing bug reads a
+    wrong row and lands orders of magnitude outside it. Covers the
+    decode tick (kk=1) and the chunked-prefill query width (kk=4) at
+    every head mapping."""
     for seed in range(3):
         want, got = _both(*_rand_case("float32", HEAD_CONFIGS[head], kk,
                                       seed))
-        np.testing.assert_array_equal(got, want,
-                                      err_msg=f"{head} kk={kk} seed={seed}")
+        _assert_f32_close(got, want, f"{head} kk={kk} seed={seed}")
 
 
 @pytest.mark.parametrize("kk", [1, 4], ids=["decode", "chunk"])
 @pytest.mark.parametrize("head", ["mha", "gqa", "mqa"])
 @pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
 def test_kernel_matches_gather_quantized(dtype, head, kk):
-    """bf16/int8 pages: identical elementwise math (same absmax
-    contract, scales applied outside the dots), reduction order differs
-    by at most the page split — the 1e-5 band of the existing
-    quantized paged-vs-contiguous parity."""
+    """int8 pages: identical values and scales on both sides, scales
+    applied outside the dots, everything after the convert in f32 — the
+    1e-5 band of the existing quantized paged-vs-contiguous parity.
+    bf16 pages: the gather path (attend_kv) additionally rounds the
+    PROBABILITIES to bf16 for its PV contraction and the kernel keeps
+    them f32, so the two differ by that rounding — at most 2^-8
+    relative per term, i.e. 2^-8 of the value scale on the output; the
+    band is twice that (a wrong row is O(1) off)."""
     for seed in range(3):
         want, got = _both(*_rand_case(dtype, HEAD_CONFIGS[head], kk, seed))
+        if dtype == "int8":
+            tol = dict(rtol=1e-5, atol=1e-5)
+        else:
+            tol = dict(rtol=0, atol=2 * 2.0 ** -8
+                       * max(1.0, float(np.max(np.abs(want)))))
         np.testing.assert_allclose(
-            got, want, rtol=1e-5, atol=1e-5,
+            got, want, **tol,
             err_msg=f"{dtype} {head} kk={kk} seed={seed}")
 
 
@@ -127,23 +158,26 @@ def _identity_paged_cache(model, batch, page_size, dtype=jnp.float32,
 
 @pytest.mark.parametrize("model", [MODEL, GQA], ids=["mha", "gqa_rope"])
 def test_paged_kernel_decode_step_matches_contiguous_f32(model):
-    """Transitivity of the layout contracts: kernel == gather (this
-    file's bitwise gate) and gather == contiguous (test_serve's), so
-    decode_step over a kernel="pallas" cache must equal the contiguous
-    cache BITWISE through a 20-step decode, page boundaries crossed
-    mid-sequence."""
+    """Transitivity of the layout contracts: kernel ~ gather (this
+    file's f32 gate) and gather == contiguous (test_serve's), so
+    decode_step over a kernel="pallas" cache must match the contiguous
+    cache through a 20-step decode, page boundaries crossed
+    mid-sequence — logits within the same band (the per-layer drift
+    passes through two blocks and the head, all f32)."""
     params = model.init(jax.random.key(0))
     toks = jnp.asarray(
         np.random.default_rng(1).integers(0, 13, (3, 20)), jnp.int32
     )
     cc = init_cache(model, 3)
     pc = _identity_paged_cache(model, 3, page_size=8, kernel="pallas")
+    # One jitted program per layout, traced once: un-jitted, every step
+    # re-traces and recompiles the interpreted kernel.
+    step = jax.jit(lambda tok, pos, cache: decode_step(
+        model, params, tok, pos, cache))
     for i in range(20):
-        want, cc = decode_step(model, params, toks[:, i], i, cc)
-        got, pc = decode_step(model, params, toks[:, i],
-                              jnp.full((3,), i, jnp.int32), pc)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
-                                      err_msg=f"step {i}")
+        want, cc = step(toks[:, i], jnp.int32(i), cc)
+        got, pc = step(toks[:, i], jnp.full((3,), i, jnp.int32), pc)
+        _assert_f32_close(np.asarray(got), np.asarray(want), f"step {i}")
 
 
 def test_slot_extent_ending_mid_page():
@@ -159,7 +193,7 @@ def test_slot_extent_ending_mid_page():
         1 + np.arange(3 * 5, dtype=np.int32).reshape(3, 5) % 15)
     positions = jnp.asarray([[ps + 1], [2 * ps + 2], [1]], jnp.int32)
     want, got = _both(q, k, v, c, table, positions, ps)
-    np.testing.assert_array_equal(got, want)
+    _assert_f32_close(got, want)
     # Poison the offsets just past each slot's position, inside the
     # same (mid-extent) page — outputs must not move.
     poisoned = dict(c)
@@ -214,7 +248,7 @@ def test_cow_private_page_read_after_copy():
     tab2[0, 0] = dst
     want_before, got_before = _both(q, k, v, copied, jnp.asarray(tab2),
                                     positions, ps)
-    np.testing.assert_array_equal(got_before, want_before)
+    _assert_f32_close(got_before, want_before)
     # Diverge the source AFTER the copy: the dst reader sees nothing.
     diverged = {n: (copied[n].at[src].set(-7.0) if n in ("k", "v")
                     else copied[n]) for n in copied}
@@ -249,7 +283,7 @@ def test_randomized_block_table_fuzz_kernel_equals_gather():
     """Seeded fuzz over the block-table space: random pool sizes, page
     sizes, table permutations (slots may SHARE pages — the prefix-
     sharing read pattern), ragged per-slot depths, MHA/GQA/MQA — kernel
-    == gather bitwise in f32, every draw."""
+    vs gather inside the f32 band (F32_ULPS), every draw."""
     rng = np.random.default_rng(1234)
     for trial in range(12):
         hkv = int(rng.choice([1, 2, 4]))
@@ -274,9 +308,8 @@ def test_randomized_block_table_fuzz_kernel_equals_gather():
             rng.integers(0, L - kk + 1, (b, 1))
             + np.arange(kk)[None, :], jnp.int32)
         want, got = _both(q, k, v, c, table, positions, ps)
-        np.testing.assert_array_equal(
-            got, want, err_msg=f"trial {trial}: hkv={hkv} ps={ps} "
-                               f"per={per} b={b} kk={kk}")
+        _assert_f32_close(got, want, f"trial {trial}: hkv={hkv} ps={ps} "
+                                     f"per={per} b={b} kk={kk}")
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
@@ -353,9 +386,13 @@ def test_int8_weights_logit_error_bound():
     )
     c32 = init_cache(MODEL, 2)
     c8 = init_cache(MODEL, 2)
+    # Jitted once per weight format (un-jitted, every step re-traces
+    # and recompiles the interpreted GEMV).
+    step = jax.jit(lambda p, tok, pos, cache: decode_step(
+        MODEL, p, tok, pos, cache))
     for i in range(12):
-        l32, c32 = decode_step(MODEL, params, toks[:, i], i, c32)
-        l8, c8 = decode_step(MODEL, qparams, toks[:, i], i, c8)
+        l32, c32 = step(params, toks[:, i], jnp.int32(i), c32)
+        l8, c8 = step(qparams, toks[:, i], jnp.int32(i), c8)
         np.testing.assert_allclose(np.asarray(l8), np.asarray(l32),
                                    rtol=5e-2, atol=5e-2,
                                    err_msg=f"step {i}")
@@ -365,22 +402,24 @@ def test_int8_gemv_matches_dequantized_matmul():
     """The fused GEMV's contract is (x @ q) * s — the scale stays
     OUTSIDE the contraction (the absmax discipline; it is constant
     along the contracted din). Pin it against the same jnp formulation
-    to float rounding, and against the scale-inside dequantized matmul
-    within the reassociation band, across tile counts (dout both
-    128-divisible and not)."""
+    within the f32 band (F32_ULPS: the kernel's din-tiled accumulation
+    and XLA:CPU's dot order the same sum differently), and against the
+    scale-inside dequantized matmul within the reassociation band,
+    across tile counts on both axes (dout 128-divisible and not; din
+    one tile and several)."""
     rng = np.random.default_rng(0)
-    for n, din, dout in ((8, 64, 256), (3, 32, 48), (1, 128, 128)):
+    for n, din, dout in ((8, 64, 256), (3, 32, 48), (1, 128, 128),
+                         (4, 4096, 640)):
         x = jnp.asarray(rng.normal(size=(n, din)), jnp.float32)
         w = quantize_weight(jnp.asarray(rng.normal(size=(din, dout)),
                                         jnp.float32))
         got = np.asarray(int8_gemv(x, w))
         want = np.asarray((x @ w.q.astype(jnp.float32)) * w.s)
-        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+        _assert_f32_close(got, want, f"{n}x{din}x{dout}")
         # Scale-inside (x @ dequant) reassociates one multiply — same
         # value to ~1 ulp of the accumulated dot.
-        np.testing.assert_allclose(got,
-                                   np.asarray(x @ dequantize_weight(w)),
-                                   rtol=1e-5, atol=1e-5)
+        _assert_f32_close(got, np.asarray(x @ dequantize_weight(w)),
+                          f"{n}x{din}x{dout} scale-inside")
         # qmatmul dispatch: QuantW routes to the kernel, arrays to @.
         np.testing.assert_allclose(np.asarray(qmatmul(x, w)), got,
                                    rtol=0, atol=0)
