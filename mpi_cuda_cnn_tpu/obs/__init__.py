@@ -59,4 +59,4 @@ from .schema import (  # noqa: F401
     make_record,
     validate_record,
 )
-from .trace import annotate, current_path, span  # noqa: F401
+from .trace import PhaseSpans, annotate, current_path, span  # noqa: F401

@@ -185,6 +185,7 @@ def _run_gemv(n, din, dout, operands):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n, dout), jnp.float32),
         interpret=pallas_interpret(),
+        name="int8_gemv",
     )(*operands)
 
 

@@ -50,6 +50,7 @@ every iteration.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 import zlib
 
@@ -57,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.transformer import TransformerLM
+from ..obs.trace import PhaseSpans
 from ..utils.donation import donate_jit
 from .host_tier import TIER_SPILL_SITE, HostTier
 from .paged_cache import (
@@ -545,6 +547,23 @@ class PagedDraftProposer:
         return [np.asarray(o, np.int32) for o in outs]
 
 
+def _closes_spans(run):
+    """PagedEngine.run, with its phase recorder taken off the engine
+    and its open phase closed however the run ends — a failed pool
+    check, the idle RuntimeError and an injected fault leave mid-phase."""
+
+    @functools.wraps(run)
+    def wrapper(self, *args, **kwargs):
+        try:
+            return run(self, *args, **kwargs)
+        finally:
+            spans, self._spans = self._spans, None
+            if spans is not None:
+                spans.close()
+
+    return wrapper
+
+
 class PagedEngine:
     """Greedy serving engine over a paged KV cache.
 
@@ -684,6 +703,10 @@ class PagedEngine:
         # engine keeps exactly its two programs.
         self._spec = None
         self._draft_proposer = None
+        # run()'s phase recorder for the length of a run that records
+        # spans (obs.trace.PhaseSpans); None otherwise, and always for
+        # the fleet, which drives the device-path methods itself.
+        self._spans = None
         if spec != "off":
             kk = spec_k
 
@@ -720,6 +743,21 @@ class PagedEngine:
         row = np.zeros((1, self._table_width), np.int32)
         row[0, : len(slot.pages)] = slot.pages
         return row
+
+    def compiled_programs(self) -> int:
+        """Compiled forms held by the engine's jitted programs, summed
+        (the tick record's `compiled`): it steps up when a dispatch
+        meets a shape or dtype it has not seen and compiles."""
+        programs = [self._tick, self._prefill, self._copy, self._adopt,
+                    self._restore]
+        if self._spec is not None:
+            programs.append(self._spec)
+        draft = self._draft_proposer
+        if draft is not None:
+            programs.append(draft._step)
+            if isinstance(draft, PagedDraftProposer):
+                programs.append(draft._catchup)
+        return sum(p._cache_size() for p in programs)
 
     def _emit(self, slot, tok: int, now: float) -> None:
         req = slot.req
@@ -805,24 +843,30 @@ class PagedEngine:
         (int()) only on the COMPLETING chunk, where it is emitted.
         Scheduler bookkeeping (slot.cached, emission) is the caller's:
         run() and the fleet's EngineCompute (ISSUE 7) share this one
-        device path."""
+        device path. Inside a run() that records spans, its recorder
+        (already in `prefill.build`) is told where building the inputs
+        ends and the dispatch begins."""
         ctx = np.concatenate(
             [slot.req.prompt, np.asarray(slot.req.out, np.int32)]
         )
         n = min(self.prefill_chunk, slot.target - slot.cached)
         toks = np.zeros((1, self.prefill_chunk), np.int32)
         toks[0, :n] = ctx[slot.cached : slot.cached + n]
-        cache, nxt = self._prefill(
-            self._cache_view(self._slot_table(slot)), self.params,
-            jnp.asarray(toks), jnp.int32(slot.cached), jnp.int32(n),
-        )
+        view = self._cache_view(self._slot_table(slot))
+        inputs = (jnp.asarray(toks), jnp.int32(slot.cached), jnp.int32(n))
+        if self._spans is not None:
+            self._spans.enter("prefill.dispatch")
+        cache, nxt = self._prefill(view, self.params, *inputs)
         self._pages = cache.pages
         return n, nxt
 
     def run_decode_tick(self, dslots) -> np.ndarray:
         """One batched decode tick over `dslots` (every other engine
         row rides along dead). Returns the per-row sampled tokens
-        (index by slot.idx); cached/emit bookkeeping is the caller's."""
+        (index by slot.idx); cached/emit bookkeeping is the caller's.
+        Inside a run() that records spans, its recorder (already in
+        `tick.build`) is told where the dispatch and the wait for the
+        tokens begin."""
         toks = np.zeros((self.slots,), np.int32)
         pos = np.zeros((self.slots,), np.int32)
         live = np.zeros((self.slots,), bool)
@@ -832,11 +876,18 @@ class PagedEngine:
             pos[s.idx] = s.cached
             live[s.idx] = True
             table[s.idx, : len(s.pages)] = s.pages
-        cache, nxt = self._tick(
-            self._cache_view(table), self.params, jnp.asarray(toks),
-            jnp.asarray(pos), jnp.asarray(live),
-        )
+        view = self._cache_view(table)
+        inputs = (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(live))
+        if self._spans is not None:
+            self._spans.enter("tick.dispatch")
+        cache, nxt = self._tick(view, self.params, *inputs)
         self._pages = cache.pages
+        # The donated pools' old handles (a few per layer) go now, under
+        # the device's work — not after the read below, where freeing
+        # them is time the device stands idle (0.4 ms at 42 layers).
+        del view, inputs
+        if self._spans is not None:
+            self._spans.enter("tick.wait")
         # THE sanctioned sync: one host transfer per BATCHED tick
         # (every live slot's token in one array), not per sequence.
         # mctpu: disable=MCT007
@@ -849,7 +900,8 @@ class PagedEngine:
         positions [cached, cached+width), rows past a slot's width (and
         every dead slot) ride along valid=False with their writes
         routed to the scratch page. Returns each slot's per-row greedy
-        picks (the verify_fn contract run_round consumes)."""
+        picks (the verify_fn contract run_round consumes). Spans as in
+        run_decode_tick."""
         kk = self.spec_k
         toks = np.zeros((self.slots, kk), np.int32)
         pos = np.zeros((self.slots,), np.int32)
@@ -860,17 +912,22 @@ class PagedEngine:
             pos[s.idx] = s.cached
             valid[s.idx, :w] = True
             table[s.idx, : len(s.pages)] = s.pages
-        cache, picks = self._spec(
-            self._cache_view(table), self.params, jnp.asarray(toks),
-            jnp.asarray(pos), jnp.asarray(valid),
-        )
+        view = self._cache_view(table)
+        inputs = (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(valid))
+        if self._spans is not None:
+            self._spans.enter("tick.dispatch")
+        cache, picks = self._spec(view, self.params, *inputs)
         self._pages = cache.pages
+        del view, inputs    # as in run_decode_tick: before the read
+        if self._spans is not None:
+            self._spans.enter("tick.wait")
         # The sanctioned sync: one host transfer per BATCHED verify
         # round (every slot's picks in one array), not per sequence.
         # mctpu: disable=MCT007
         picks = np.asarray(picks)
         return [picks[s.idx, :w] for s, _, w in rounds]
 
+    @_closes_spans
     def run(self, requests: list[Request], *, mode: str = "continuous",
             time_fn=time.perf_counter, faults=None, max_queue: int | None = None,
             watchdog_s: float = 0.0, sleep_fn=time.sleep,
@@ -896,6 +953,24 @@ class PagedEngine:
         it happens (serve/bench.py points it at the metrics JSONL, which
         is what makes `mctpu top` live-tailable mid-run). Both default
         to off: the hot loop pays nothing unless asked.
+
+        Phase spans (ISSUE 25): when either asked, every iteration's
+        host time is split by what the host was doing — `schedule`
+        (faults, sweep, admit, queue bound), `prefill.build` /
+        `prefill.dispatch` / `prefill.wait` (the wait only on a
+        completing chunk, whose token the host reads), `grow`,
+        `tick.build` (under speculation: the proposal too) /
+        `tick.dispatch` / `tick.wait`, `emit` (after either program),
+        `idle` (the sleep), `bookkeep` (drains, state digest, pool
+        check: paid in every run), `record` (this record's scans; it
+        ends before the sink is called). They tile the iteration on
+        the record's clock as `spans` ([phase, start, end], seconds
+        since run start) and sit on the profiler's host track as
+        `serve.iter/<phase>` (obs.trace.PhaseSpans); `now` and the
+        watchdog's window are two of the same stamps. `compiled` counts
+        the forms the engine's programs have compiled since the run
+        began (before its first dispatch), so a compile inside a run
+        shows as a step and a warmed run reads 0 throughout.
 
         Prefix sharing + SLO policy (ISSUE 9): `prefix=True` puts a
         PrefixCache over the run's pool — a request whose prompt shares
@@ -1015,8 +1090,16 @@ class PagedEngine:
         # tick's terminal set — no instrumentation at the call sites.
         n_fin_seen = n_drop_seen = 0
         t0 = time_fn()
+        # Stamps below are seconds since t0. The recorder is told the
+        # ones the loop reads anyway and reads the clock itself at the
+        # other phase boundaries; without a consumer there is none.
+        spans = self._spans = (PhaseSpans("serve.iter", time_fn, t0)
+                               if want_ticks else None)
+        compiled0 = self.compiled_programs() if want_ticks else 0
         while sched.unfinished:
-            iter_t0 = time_fn()
+            iter_t0 = time_fn() - t0
+            if spans is not None:
+                spans.begin(tick_idx, "schedule", iter_t0)
             if faults is not None:
                 for f in faults.fire("serve.tick", tick_idx):
                     if f.kind == "squeeze":
@@ -1041,7 +1124,7 @@ class PagedEngine:
                 if sq["pages"]:
                     sched.pool.free(sq["pages"], sq["owner"])
                 squeezes.remove(sq)
-            now = time_fn() - t0
+            now = sched_now = time_fn() - t0
             for r in sched.sweep(now):
                 events.append({"kind": f"request_{r.status}", "id": r.rid,
                                "mode": mode, "t_rel": round(now, 4)})
@@ -1056,6 +1139,8 @@ class PagedEngine:
 
             # At most ONE prefill chunk per iteration: long prompts
             # advance without starving in-flight decodes.
+            if spans is not None:
+                spans.enter("prefill.build")
             slot = sched.prefill_slot()
             if slot is not None:
                 if slot.cow is not None:
@@ -1078,19 +1163,30 @@ class PagedEngine:
                     # static holds every reservation until the batch
                     # drains (the occupancy discipline the comparison
                     # measures).
+                    if spans is not None:
+                        # The chunk runs on the device from here to
+                        # the read: the adoption below is hidden by it.
+                        spans.enter("prefill.wait")
                     sched.note_prefill_complete(slot)
                     # Sanctioned sync: int() ONLY on the completing
                     # chunk, where the token is emitted — mid-prompt
                     # chunks pipeline the device array untouched.
                     # mctpu: disable=MCT007
-                    self._emit(slot, int(nxt), time_fn() - t0)
+                    first = int(nxt)
+                    now = time_fn() - t0
+                    if spans is not None:
+                        spans.enter("emit", now)
+                    self._emit(slot, first, now)
                     prefill_rec.append("emit")  # first token at completion
                     if slot.req.done and isinstance(sched,
                                                     ContinuousScheduler):
                         sched.finish(slot, time_fn() - t0)
 
+            now = time_fn() - t0
+            if spans is not None:
+                spans.enter("grow", now)
             dslots = sched.grow_for_decode(
-                time_fn() - t0, spec_k=self.spec_k if spec else 1)
+                now, spec_k=self.spec_k if spec else 1)
             decoded = [[s.idx, s.req.rid] for s in dslots]
             for r in sched.dropped:
                 # admit/grow_for_decode may have failed a livelocked
@@ -1101,6 +1197,8 @@ class PagedEngine:
                                    "mode": mode, "reason": r.fail_reason})
             spec_rec = None
             emitted_decode = 0
+            if dslots and spans is not None:
+                spans.enter("tick.build")
             if dslots and spec:
                 # Speculative round (ISSUE 14): propose per slot, ONE
                 # batched verify block, greedy acceptance — each slot
@@ -1111,6 +1209,8 @@ class PagedEngine:
                                     self.run_spec_tick)
                 decode_ticks += 1
                 now = time_fn() - t0
+                if spans is not None:
+                    spans.enter("emit", now)
                 spec_rec = []
                 for s, w, j, toks_out in results:
                     sched.commit_spec(s, j)
@@ -1130,6 +1230,8 @@ class PagedEngine:
                 nxt = self.run_decode_tick(dslots)
                 decode_ticks += 1
                 now = time_fn() - t0
+                if spans is not None:
+                    spans.enter("emit", now)
                 for s in dslots:
                     s.cached += 1
                     self._emit(s, int(nxt[s.idx]), now)
@@ -1146,11 +1248,17 @@ class PagedEngine:
             # on purpose (waiting for the next arrival / a squeeze to
             # lift), and counting that wait would turn every sparse
             # workload into a stream of false slow-tick alarms.
-            busy_s = time_fn() - iter_t0
+            now = time_fn() - t0
+            busy_s = now - iter_t0
 
             if not progressed and sched.unfinished:
+                if spans is not None:
+                    spans.enter("idle", now)
                 nxt_arrival = sched.next_arrival()
-                now = time_fn() - t0
+                # What is due is judged by the stamp admit() judged it
+                # by: a request falling due after that read has not
+                # been refused, it has not been looked at yet.
+                now = sched_now
                 if squeezes:
                     # An injected squeeze holds the pages the next step
                     # needs (admission or decode growth): idle one tick
@@ -1167,6 +1275,10 @@ class PagedEngine:
                     )
                 else:
                     sleep_fn(min(nxt_arrival - now, 0.05))
+                if spans is not None:
+                    spans.enter("bookkeep")
+            elif spans is not None:
+                spans.enter("bookkeep", now)
             if watchdog_s > 0 and busy_s > watchdog_s:
                 watchdog_slow += 1
                 if registry is not None:
@@ -1205,14 +1317,25 @@ class PagedEngine:
             state_crc = scheduler_digest(sched, extra=spec_extra)
             state_chain = zlib.crc32(state_crc.to_bytes(4, "little"),
                                      state_chain)
-            if not want_ticks:
+            # The pool check is timed in `bookkeep`, but where records
+            # were asked for its failure is raised only once this
+            # iteration's record has reached the sink: the record of
+            # the iteration that broke the pool is the one to have.
+            check_failed = None
+            try:
                 sched.check()
+            except AssertionError as e:
+                if not want_ticks:
+                    raise
+                check_failed = e
+            if not want_ticks:
                 tick_idx += 1
                 continue
             new_fin = sched.finished[n_fin_seen:]
             new_drop = sched.dropped[n_drop_seen:]
             n_fin_seen, n_drop_seen = len(sched.finished), len(sched.dropped)
             now = time_fn() - t0
+            spans.enter("record", now)
             arrived_now = []
             while arr_cursor < len(arrivals) and \
                     arrivals[arr_cursor][0] <= now:
@@ -1251,6 +1374,7 @@ class PagedEngine:
                 # host-side state after this iteration — `mctpu replay`
                 # recomputes it from the events above at every tick.
                 "state_crc": state_crc,
+                "compiled": self.compiled_programs() - compiled0,
             }
             if squeezes:
                 # Pages an injected squeeze currently holds: the replay
@@ -1285,6 +1409,10 @@ class PagedEngine:
                     tick_rec["prefix"].update(tier.stats)
                     tick_rec["prefix"]["host_used"] = tier.host_used
                     tick_rec["prefix_readmits"] = prefix_tick["readmits"]
+            # `record` ends here, before the sink: what a sink costs
+            # (the profiler's start among them) lies between two
+            # records' spans and inside none.
+            tick_rec["spans"] = spans.end()
             if tick_sink is not None:
                 tick_sink(tick_rec)
             if registry is not None:
@@ -1333,7 +1461,8 @@ class PagedEngine:
                                      tier.host_used)
                 for r in new_fin + new_drop:
                     _observe_request(registry, r)
-            sched.check()
+            if check_failed is not None:
+                raise check_failed
             tick_idx += 1
 
         # Release any squeeze that outlived the workload, evict every

@@ -1,0 +1,404 @@
+"""Phase spans of `PagedEngine.run` (ISSUE 25): every iteration's host
+time by what the host was doing, on the tick record and on the
+profiler's host track; the `compiled` counter; the idle branch's clock
+repair; and the benchmark's nine readers that turn the spans into
+per-layer metrics, each on hand-written records with known answers."""
+
+import re
+import sys
+from pathlib import Path
+
+import jax
+import numpy as np
+import pytest
+
+from mpi_cuda_cnn_tpu.faults import FakeClock
+from mpi_cuda_cnn_tpu.models.transformer import TransformerLM
+from mpi_cuda_cnn_tpu.obs.schema import make_record, validate_record
+from mpi_cuda_cnn_tpu.obs.trace import PhaseSpans
+from mpi_cuda_cnn_tpu.serve import engine as engine_mod
+from mpi_cuda_cnn_tpu.serve.engine import PagedEngine
+from mpi_cuda_cnn_tpu.serve.scheduler import Request
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))       # `benchmarks` is a package of the checkout
+
+from benchmarks.run import load_by_path  # noqa: E402
+
+MODEL = TransformerLM(vocab=13, dim=32, heads=4, depth=2, max_seq=48)
+
+
+class TickingClock:
+    """Advances 1 ms on every read, and counts the reads: no two
+    stamps coincide, so a span boundary in the wrong place shows."""
+
+    def __init__(self):
+        self.reads, self.now = 0, 0.0
+
+    def __call__(self) -> float:
+        self.reads += 1
+        self.now += 0.001
+        return self.now
+
+    def advance(self, seconds: float) -> None:
+        self.now += seconds
+
+
+@pytest.fixture(scope="module")
+def params():
+    return MODEL.init(jax.random.key(0))
+
+
+def make_engine(params, **kw):
+    return PagedEngine(MODEL, params, slots=2, num_pages=16, page_size=8,
+                       prefill_chunk=8, **kw)
+
+
+def requests(gap: float = 0.0, n: int = 3):
+    """Prompts of 12 tokens = a mid-prompt chunk and a completing one."""
+    rng = np.random.default_rng(0)
+    return [Request(rid=i, prompt=rng.integers(0, 13, 12).astype(np.int32),
+                    max_new_tokens=4, arrival=gap * i) for i in range(n)]
+
+
+def serve(engine, reqs, clock, **kw):
+    ticks = []
+    res = engine.run(reqs, time_fn=clock, sleep_fn=clock.advance,
+                     tick_sink=ticks.append, **kw)
+    return res, ticks
+
+
+# The order an iteration runs its phases in (engine.run's docstring).
+ORDER = re.compile(
+    r"schedule prefill\.build( prefill\.dispatch( prefill\.wait emit)?)? grow"
+    r"( tick\.build tick\.dispatch tick\.wait emit)?( idle)? bookkeep record")
+
+
+@pytest.mark.parametrize("mode,gap", [("continuous", 0.0), ("static", 0.0),
+                                      ("continuous", 0.2)])
+def test_spans_tile_every_iteration(params, mode, gap):
+    _, ticks = serve(make_engine(params), requests(gap), TickingClock(),
+                     mode=mode)
+    assert ticks
+    before = 0.0
+    for t in ticks:
+        spans = t["spans"]
+        assert ORDER.fullmatch(" ".join(n for n, _, _ in spans)), spans
+        for (_, a, b), (_, a2, _) in zip(spans, spans[1:]):
+            assert a < b == a2          # no overlap, no hole
+        # Inside [the record before's now, this record's now] — but
+        # for `record`, which begins at `now` and ends before the sink.
+        assert spans[0][1] >= before
+        assert spans[-1][0] == "record" and spans[-1][1] == t["now"]
+        before = t["now"]
+    if gap:
+        assert any(n == "idle" for t in ticks for n, _, _ in t["spans"])
+
+
+def test_wait_on_a_completing_chunk_only(params):
+    _, ticks = serve(make_engine(params), requests(), TickingClock())
+    kinds = set()
+    for t in ticks:
+        names = [n for n, _, _ in t["spans"]]
+        if not t["prefill"]:
+            assert "prefill.dispatch" not in names
+            continue
+        completing = t["prefill"][-1] == "emit"
+        kinds.add(completing)
+        assert "prefill.dispatch" in names
+        assert ("prefill.wait" in names) == completing
+    assert kinds == {True, False}
+
+
+# Clock reads of the parent commit's loop (341d98c) on `requests()`
+# with no sink and no registry, counted there with TickingClock.
+PARENT_CLOCK_READS = {"continuous": 44, "static": 55}
+
+
+@pytest.mark.parametrize("mode", ["continuous", "static"])
+def test_bare_run_records_nothing_and_reads_the_clock_as_the_parent(
+        params, mode, monkeypatch):
+    def no_recorder(*a, **kw):
+        raise AssertionError("a run nobody listens to built a recorder")
+
+    monkeypatch.setattr(engine_mod, "PhaseSpans", no_recorder)
+    clock = TickingClock()
+    res = make_engine(params).run(requests(), mode=mode, time_fn=clock,
+                                  sleep_fn=clock.advance)
+    assert all(r.status == "finished" for r in res.requests)
+    assert clock.reads == PARENT_CLOCK_READS[mode]
+
+
+def test_same_tokens_and_state_crc_with_and_without_a_sink(params):
+    engine = make_engine(params)
+    bare = engine.run(requests(0.2), time_fn=(c := FakeClock()),
+                      sleep_fn=c.advance)
+    heard, ticks = serve(engine, requests(0.2), FakeClock())
+    assert heard.state_crc == bare.state_crc
+    assert ([r.out for r in heard.requests]
+            == [r.out for r in bare.requests])
+    assert heard.decode_ticks == bare.decode_ticks == sum(
+        bool(t["decoded"]) for t in ticks)
+
+
+def test_spans_deterministic_and_schema_valid_under_fake_clock(params):
+    engine = make_engine(params)
+    _, first = serve(engine, requests(0.2), FakeClock())
+    _, again = serve(engine, requests(0.2), FakeClock())
+    assert [t["spans"] for t in first] == [t["spans"] for t in again]
+    for t in first:
+        validate_record(make_record("tick", t["now"], **t))
+
+
+def test_idle_branch_judges_what_is_due_by_admits_stamp(params):
+    """The parent re-read the clock in the idle branch: a request that
+    fell due between admit()'s read and that one had been refused by
+    nobody, and raised "cannot be admitted into an idle engine"."""
+    clock = TickingClock()
+    req = Request(rid=0, prompt=np.arange(12, dtype=np.int32) % 13,
+                  max_new_tokens=2, arrival=0.0045)
+    res = make_engine(params).run([req], time_fn=clock,
+                                  sleep_fn=lambda s: None)
+    assert res.requests[0].status == "finished"
+
+
+def test_watchdog_window_comes_from_the_spans_stamps(params):
+    res, ticks = serve(make_engine(params), requests(), TickingClock(),
+                       watchdog_s=1e-9)
+    slow = {e["tick"]: e["seconds"] for e in res.events
+            if e["kind"] == "watchdog_slow_tick"}
+    assert len(slow) == len(ticks) == res.watchdog_slow_ticks
+    for t in ticks:
+        start = t["spans"][0][1]
+        end = next(a for n, a, _ in t["spans"] if n in ("idle", "bookkeep"))
+        assert slow[t["tick"]] == pytest.approx(end - start, abs=1e-4)
+
+
+def test_compiled_counter_steps_when_a_program_compiles(params):
+    engine = make_engine(params)      # fresh: nothing compiled yet
+    _, ticks = serve(engine, requests(), FakeClock())
+    counts = [t["compiled"] for t in ticks]
+    # Counted from before the run's first dispatch: the first
+    # iteration's own compile (the prefill program) is a step too.
+    assert counts == sorted(counts) and counts[0] >= 1
+    assert counts[-1] == engine.compiled_programs() == 2   # prefill, tick
+    _, warm = serve(engine, requests(), FakeClock())
+    assert {t["compiled"] for t in warm} == {0}
+
+
+def test_stand_ins_for_the_device_path_keep_their_one_argument_form(
+        params, monkeypatch):
+    """A test's fault wrapper (benchmarks/tests/test_correct.py) takes
+    the slots and nothing else, traced or not: the recorder is the
+    engine's for the length of run(), not an argument."""
+    engine = make_engine(params)
+    real = engine.run_decode_tick
+    monkeypatch.setattr(engine, "run_decode_tick", lambda dslots: real(dslots))
+    res, ticks = serve(engine, requests(), TickingClock())
+    assert all(r.status == "finished" for r in res.requests)
+    assert any("tick.wait" in [n for n, _, _ in t["spans"]] for t in ticks)
+    assert engine._spans is None        # and gone once run() returns
+
+
+def test_a_run_that_raises_closes_its_open_phase(params, monkeypatch):
+    made = []
+
+    class Kept(PhaseSpans):
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            made.append(self)
+
+    def broken_tick(dslots):
+        raise RuntimeError("injected")
+
+    monkeypatch.setattr(engine_mod, "PhaseSpans", Kept)
+    engine = make_engine(params)
+    monkeypatch.setattr(engine, "run_decode_tick", broken_tick)
+    with pytest.raises(RuntimeError, match="injected"):
+        serve(engine, requests(), TickingClock())
+    (rec,) = made
+    assert rec._ann is None and rec._spans[-1][0] == "tick.build"
+    assert rec._spans[-1][2] is not None
+    assert engine._spans is None
+
+
+def test_a_failed_pool_check_still_delivers_the_iterations_record(
+        params, monkeypatch):
+    from mpi_cuda_cnn_tpu.serve.scheduler import ContinuousScheduler
+
+    calls = []
+
+    def check(self):
+        calls.append(1)
+        assert len(calls) < 3, "pool broken"
+
+    monkeypatch.setattr(ContinuousScheduler, "check", check)
+    ticks = []
+    clock = TickingClock()
+    with pytest.raises(AssertionError, match="pool broken"):
+        make_engine(params).run(requests(), time_fn=clock,
+                                sleep_fn=clock.advance,
+                                tick_sink=ticks.append)
+    assert [t["tick"] for t in ticks] == [0, 1, 2]
+    assert ticks[-1]["spans"][-1][0] == "record"
+
+
+def test_speculative_round_has_the_ticks_three_spans(params):
+    engine = make_engine(params, spec="lookup", spec_k=3)
+    res, ticks = serve(engine, requests(), TickingClock(), spec=True)
+    assert all(r.status == "finished" for r in res.requests)
+    rounds = [t for t in ticks if t.get("spec")]
+    assert rounds
+    for t in rounds:
+        names = " ".join(n for n, _, _ in t["spans"])
+        assert "tick.build tick.dispatch tick.wait emit" in names
+    assert ticks[-1]["compiled"] == 2     # prefill and the verify block
+
+
+def test_phases_reach_the_profilers_host_track(tmp_path):
+    """Each span lies under a TraceAnnotation `<prefix>/<phase>` whose
+    argument is the iteration's index — the same span, on the clock
+    the device trace is on."""
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level, opts.host_tracer_level = 0, 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        rec = PhaseSpans("serve.iter")
+        rec.begin(7, "schedule")
+        rec.enter("tick.wait")
+        spans = rec.end()
+    finally:
+        jax.profiler.stop_trace()
+    assert [n for n, _, _ in spans] == ["schedule", "tick.wait"]
+    assert spans[0][2] == spans[1][1]
+    data = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    seen = {e.name: dict(e.stats) for p in data.planes for line in p.lines
+            for e in line.events if e.name.startswith("serve.iter/")}
+    assert seen == {"serve.iter/schedule": {"tick": 7},
+                    "serve.iter/tick.wait": {"tick": 7}}
+
+
+# -- the benchmark's readers ----------------------------------------------
+
+def reader(name: str):
+    """benchmarks/layer_metrics/<name>.py, loaded by path as run.py does."""
+    return load_by_path(ROOT / "benchmarks" / "layer_metrics" / f"{name}.py")
+
+
+def iteration(t0: float, *, tick_ms: float = 20.0, host_ms: float = 1.0,
+              chunk: bool = True, compiled: int = 0) -> dict:
+    """One hand-written record starting at t0: schedule 0.2 ms, build
+    `host_ms` in all, dispatches 0.1 ms each, a wait of `tick_ms`,
+    emit + bookkeep 0.3 ms, record 0.1 ms."""
+    at, spans = t0, []
+
+    def add(name, ms):
+        nonlocal at
+        spans.append([name, round(at, 6), round(at + ms / 1e3, 6)])
+        at += ms / 1e3
+
+    add("schedule", 0.2)
+    add("prefill.build", host_ms / 2)
+    if chunk:
+        add("prefill.dispatch", 0.1)
+    add("grow", 0.1)
+    add("tick.build", host_ms / 2)
+    add("tick.dispatch", 0.1)
+    add("tick.wait", tick_ms)
+    add("emit", 0.1)
+    add("bookkeep", 0.2)
+    add("record", 0.1)
+    return {"spans": spans, "compiled": compiled, "now": spans[-1][1]}
+
+
+def window(special=None, first_tick: int = 0) -> list[dict]:
+    """Ten iterations, 0.2 ms of sink between records; `special` maps
+    an index to iteration() keywords."""
+    ticks, at = [], 0.0
+    for i in range(10):
+        ticks.append(iteration(at, **(special or {}).get(i, {})))
+        ticks[-1]["tick"] = first_tick + i
+        at = ticks[-1]["spans"][-1][2] + 0.0002
+    return ticks
+
+
+class FakeTrace:
+    def __init__(self, window_s, busy_s):
+        self.window_s, self.busy_s = window_s, busy_s
+
+
+def ctx_of(ticks, **kw):
+    end = ticks[-1]["spans"][-1][2] if "spans" in ticks[-1] else 1.0
+    return {"ticks": ticks, "first_traced": 4, "window_s": end, **kw}
+
+
+QUIET = window()
+# An iteration of QUIET: 0.2 + 1.0 + 0.2 + 0.1 = 1.5 ms before the
+# tick's dispatch ends, a 20 ms wait, 0.4 ms after it, 0.2 ms of sink.
+# Exposed, from a wait's end to the next PREFILL dispatch's start: 0.4
+# + 0.2 + 0.2 + 0.5 = 1.3 ms (the call itself runs under the device it
+# launches, and the tick's dispatch queues behind the chunk).
+READINGS = [
+    ("host_exposed_ms", QUIET, {}, 1.3),
+    # without a chunk the stretch runs on to the tick's dispatch:
+    # 1.3 + grow 0.1 + the tick's build 0.5
+    ("host_exposed_ms", window({i: {"chunk": False} for i in range(10)}),
+     {}, 1.9),
+    ("host_schedule_ms", QUIET, {}, 0.2),
+    ("host_build_ms", QUIET, {}, 1.3),        # builds 1.0, dispatches 0.2, grow 0.1
+    ("host_bookkeep_ms", QUIET, {}, 0.3),
+    # ten waits of 20 ms over ten iterations of 22.1 ms less the last sink
+    ("device_wait_share", QUIET, {}, 100 * 200 / (221 - 0.2)),
+    ("host_stall_ms", QUIET, {}, 0.0),
+    # iteration 6's host stood still for 50 ms while building: its own
+    # time is 52.1 ms against a median of 2.1 (the first: 1.9, no sink)
+    ("host_stall_ms", window({6: {"host_ms": 51.0}}), {}, 52.1 - 3 * 2.1),
+    # the profiler's start (the sink before record `first_traced`) is
+    # nobody's stall: 300 ms there read as nothing
+    ("host_stall_ms", [dict(t, spans=[[n, a + (0.3 if i >= 4 else 0.0),
+                                       b + (0.3 if i >= 4 else 0.0)]
+                                      for n, a, b in t["spans"]])
+                       for i, t in enumerate(QUIET)], {}, 0.0),
+    ("device_stall_ms", QUIET, {}, 0.0),
+    # iteration 3 waited 100 ms where its like wait 20: 100 - 60; one
+    # without a chunk (another program mix) is judged by its own median
+    ("device_stall_ms", window({3: {"tick_ms": 100.0},
+                                5: {"chunk": False, "tick_ms": 70.0}}),
+     {}, 40.0),
+    # traced records 4..9: five stretches of 1.3 ms begin and end there;
+    # a slice that was idle 5 x 1.9 ms leaves 0.6 ms each unexplained
+    ("idle_unexplained_ms", QUIET,
+     {"trace": FakeTrace(0.1300, 0.1300 - 5 * 0.0019)}, 0.6),
+    ("programs_compiled_in_window", QUIET, {}, 0.0),
+    ("programs_compiled_in_window", window({i: {"compiled": 1}
+                                            for i in range(7, 10)}), {}, 1.0),
+    # a compile in the window's FIRST iteration (a prefill bucket the
+    # warm-up missed) counts: the count starts before the first dispatch
+    ("programs_compiled_in_window", window({i: {"compiled": 1}
+                                            for i in range(10)}), {}, 1.0),
+    # records that begin mid-run: the first is all there is to go by
+    ("programs_compiled_in_window",
+     window({i: {"compiled": 1 + (i >= 5)} for i in range(10)},
+            first_tick=40), {}, 1.0),
+]
+
+
+@pytest.mark.parametrize("name,ticks,extra,want", READINGS,
+                         ids=[f"{r[0]}-{i}" for i, r in enumerate(READINGS)])
+def test_reader_on_hand_written_records(name, ticks, extra, want):
+    got = reader(name).read(ctx_of(ticks, **extra))
+    assert got == pytest.approx(want, abs=1e-6)
+
+
+@pytest.mark.parametrize("name", sorted({r[0] for r in READINGS}))
+def test_reader_reads_nothing_from_records_without_spans(name):
+    """An engine from before the spans (the parent commit under this
+    benchmark): None, never a made-up value, and no exception."""
+    old = [{k: v for k, v in t.items() if k not in ("spans", "compiled")}
+           for t in QUIET]
+    ctx = ctx_of(old, trace=FakeTrace(0.13, 0.12))
+    assert reader(name).read(ctx) is None
+    assert reader(name).read({**ctx, "ticks": []}) is None
