@@ -178,10 +178,11 @@ def phase_kernels(cfg, dev, rehearsal):
     # this chip: a default-precision f32 dot, which rounds its operands
     # to bf16, is 2.5e-3 off; at HIGHEST it is 3e-7 — PERF.md).
     #
-    # f32 paged attention and int8 GEMV: both sides compute in f32 (the
-    # kernels at HIGHEST, the twin under "highest"), so only reduction
-    # order differs — a few 1e-7 over <= 16k-term sums. 2e-5 leaves two
-    # orders of margin.
+    # f32 paged attention and int8 GEMV: both sides compute to f32
+    # accuracy (the paged kernel at HIGHEST, the GEMV with x's three
+    # exact bf16 terms against the exact bf16 weight tile, the twin
+    # under "highest"), so only reduction order differs — a few 1e-7
+    # over <= 16k-term sums. 2e-5 leaves two orders of margin.
     F32_TOL = 2e-5
     # f32 flash: the kernels rebuild p = exp(s - lse) from HIGHEST-
     # precision logits, and exp turns an absolute logit error (~1e-6 at

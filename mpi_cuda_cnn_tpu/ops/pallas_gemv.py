@@ -28,19 +28,29 @@ of test_generate's int8-cache pin). MoE expert banks and the embedding
 tables are left in f32: experts route through moe_mlp_inference's own
 einsums (a separate lever), and tok_emb/pos_emb are gathers, not GEMVs.
 
-The kernel tiles dout and din and keeps x resident: grid
-(dout/TILE_N, din/TILE_K) with din innermost, each step one
-(B, TILE_K) x (TILE_K, TILE_N) MXU contraction of the int8 tile
-converted on load, accumulated into the resident f32 output tile; the
-f32 scale row multiplies the finished tile on the last din step. din is
-tiled because a whole-din weight block does not fit: at din = 16384
-(the 4*d MLP contraction of a d = 4096 model) a (16384, 512) int8 block
-is 8 MiB, 16 MiB double-buffered, against v5e's 16 MiB scoped-VMEM
-limit — before the f32 convert. The dot runs at HIGHEST precision: x
-is f32, and the MXU's default would round it to bf16 (the int8 weights
-are exact either way). Interpret mode (platform cpu) runs the same
-kernel body — the tier-1 suite pins `int8_gemv` against the jnp
-dequantized form on CPU.
+The kernel tiles dout and din: grid (dout/TILE_N, din/TILE_K) with din
+innermost, each step one MXU contraction of the (TILE_K, TILE_N) int8
+tile with the (N, TILE_K) block of x, accumulated into the resident f32
+output tile; the f32 scale row multiplies the finished tile on the last
+din step. din is tiled because a whole-din weight block does not fit
+the 16 MiB of scoped VMEM at din = 16384 (the 4*d MLP contraction of a
+d = 4096 model).
+
+The contraction is ONE bf16 pass over the weights. An int8 value
+(|q| <= 127) is exact in bf16, so the tile converts to bf16 and enters
+the MXU once, at default precision with f32 accumulation. x is f32 and
+must not be rounded (a default-precision f32 dot rounds it to bf16 and
+is 2e-3 off), so it is split into three bf16 terms, hi = bf16(x),
+mid = bf16(x - hi), lo = bf16(x - hi - mid): 3 x 8 mantissa bits hold
+f32's 24, each subtraction is exact, hence hi + mid + lo == x bit for
+bit. The terms are stacked as one (3N, TILE_K) left operand, meet the
+one weight tile in one dot, and the three row groups of the f32 product
+are summed: the same mathematics as an f32 dot at HIGHEST (which splits
+BOTH operands three ways and pushes the weights through the MXU three
+times, two of them zeros), measured as close to an f64 reference
+(PERF.md section 6, PR 26). Dropping mid or lo is a lower precision,
+a different result; tests/test_paged_kernel.py holds that line.
+Interpret mode (platform cpu) runs the same kernel body.
 """
 
 from __future__ import annotations
@@ -127,11 +137,16 @@ def quantize_decode_params(params: dict, dtype: str) -> dict:
     return out
 
 
-# Weight tile caps: (2048, 512) int8 is 1 MiB (2 MiB double-buffered)
-# and 4 MiB once converted to f32 — inside the 16 MiB scoped-VMEM limit
-# with the (N, 2048) x block and the (N, 512) output tile resident.
-_TILE_N = 512
-_TILE_K = 2048
+# Weight tile caps: (512, 4096) int8 is 2 MiB — 4 MiB double-buffered
+# and 4 MiB more as bf16, inside the 16 MiB of scoped VMEM up to 256
+# rows of x. The kernel is bound by the tile's DMA, so the tile is wide
+# before it is deep: 4096 contiguous bytes a row (a whole row where
+# dout = 4096), 32 grid steps for a 64 MiB matrix, and x's (N, 512)
+# block, fetched again at every step, is 1.5% of the step's bytes at 16
+# rows. A narrow dout deepens the tile to the same bytes (_run_gemv).
+# Measured on the v5e against (2048, 512) and others: PERF.md section 6.
+_TILE_N = 4096
+_TILE_K = 512
 
 
 def _tile(dim: int, cap: int) -> int:
@@ -146,32 +161,57 @@ def _tile(dim: int, cap: int) -> int:
     return t
 
 
-def _gemv_kernel(x_ref, w_ref, s_ref, o_ref, *, nk):
+def _bf16_terms(x):
+    """f32 (n, k) -> bf16 (3n, k), rows [hi; mid; lo] with hi + mid +
+    lo == x exactly. Stacked in f32, whose 8-row tiles take any n that
+    Mosaic takes, then cast: each term is a bf16 value already."""
+    hi = x.astype(jnp.bfloat16).astype(jnp.float32)
+    mid = (x - hi).astype(jnp.bfloat16).astype(jnp.float32)
+    lo = x - hi - mid
+    return jnp.concatenate([hi, mid, lo], axis=0).astype(jnp.bfloat16)
+
+
+def _gemv_kernel(x_ref, w_ref, s_ref, o_ref, *, n, nk):
     k = pl.program_id(1)
 
     @pl.when(k == 0)
     def _():
         o_ref[:] = jnp.zeros_like(o_ref)
 
-    o_ref[:] += jax.lax.dot_general(
-        x_ref[:], w_ref[:].astype(jnp.float32),
+    p = jax.lax.dot_general(
+        _bf16_terms(x_ref[:]), w_ref[:].astype(jnp.bfloat16),
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
     )
+    o_ref[:] += p[2 * n:] + p[n:2 * n] + p[:n]
 
     @pl.when(k == nk - 1)
     def _():
         o_ref[:] *= s_ref[:]
 
 
-def _run_gemv(n, din, dout, operands):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _run_gemv(x, q, s, *, interpret):
     """The one pallas_call site — the MCT007 producer declared for this
-    module in the lint manifest."""
-    tn, tk = _tile(dout, _TILE_N), _tile(din, _TILE_K)
+    module in the lint manifest. Jitted because the memory-space
+    constraint exists only under a trace (`interpret` is static, so the
+    trace cache never hands an interpreted body to the chip).
+
+    The weights are held to HBM: the kernel streams them at what the
+    memory allows. Left to itself XLA's memory-space assignment
+    prefetches whole matrices into VMEM ahead of the call (16-64 MiB
+    copy-start/copy-done pairs); that competes with the running
+    kernel's own DMA for the same HBM bandwidth, the copy-done waits,
+    and the bytes move where `int8_gemv_roofline` does not see them
+    (it read 108%; PERF.md section 6, PR 26)."""
+    (n, din), dout = x.shape, q.shape[1]
+    if not interpret:    # the interpreter knows no memory spaces
+        q = pltpu.with_memory_space_constraint(q, pltpu.HBM)
+    tn = _tile(dout, _TILE_N)
+    tk = _tile(din, _TILE_K * max(1, _TILE_N // tn))
     nk = din // tk
     return pl.pallas_call(
-        functools.partial(_gemv_kernel, nk=nk),
+        functools.partial(_gemv_kernel, n=n, nk=nk),
         grid=(dout // tn, nk),
         in_specs=[
             pl.BlockSpec((n, tk), lambda j, k: (0, k),
@@ -184,21 +224,21 @@ def _run_gemv(n, din, dout, operands):
         out_specs=pl.BlockSpec((n, tn), lambda j, k: (0, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n, dout), jnp.float32),
-        interpret=pallas_interpret(),
+        interpret=interpret,
         name="int8_gemv",
-    )(*operands)
+    )(x, q, s)
 
 
 def int8_gemv(x: jnp.ndarray, w: QuantW) -> jnp.ndarray:
     """y = (x @ w.q) * w.s: (N, din) f32 x QuantW(din, dout) ->
-    (N, dout) f32. The int8 tile converts on load inside the kernel;
-    the per-channel scale row multiplies the OUTPUT tile — constant
-    along the contracted din, it never enters the MXU contraction (the
-    absmax contract; equal to x @ dequant(w) up to one reassociated
-    multiply)."""
-    n, din = x.shape
-    return _run_gemv(n, din, w.q.shape[1],
-                     [x.astype(jnp.float32), w.q, w.s])
+    (N, dout) f32. The int8 tile converts to bf16 (exactly) inside the
+    kernel and meets x's three bf16 terms in one MXU pass (module
+    docstring); the per-channel scale row multiplies the OUTPUT tile —
+    constant along the contracted din, it never enters the MXU
+    contraction (the absmax contract; equal to x @ dequant(w) up to one
+    reassociated multiply)."""
+    return _run_gemv(x.astype(jnp.float32), w.q, w.s,
+                     interpret=pallas_interpret())
 
 
 def qmatmul(x, w):

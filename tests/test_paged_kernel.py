@@ -405,11 +405,11 @@ def test_int8_gemv_matches_dequantized_matmul():
     within the f32 band (F32_ULPS: the kernel's din-tiled accumulation
     and XLA:CPU's dot order the same sum differently), and against the
     scale-inside dequantized matmul within the reassociation band,
-    across tile counts on both axes (dout 128-divisible and not; din
-    one tile and several)."""
+    across tile counts on both axes (dout 128-divisible and not, the
+    latter also wider than the tile cap; din one tile and several)."""
     rng = np.random.default_rng(0)
     for n, din, dout in ((8, 64, 256), (3, 32, 48), (1, 128, 128),
-                         (4, 4096, 640)):
+                         (4, 4096, 640), (8, 1024, 8192), (2, 256, 5000)):
         x = jnp.asarray(rng.normal(size=(n, din)), jnp.float32)
         w = quantize_weight(jnp.asarray(rng.normal(size=(din, dout)),
                                         jnp.float32))
@@ -426,6 +426,34 @@ def test_int8_gemv_matches_dequantized_matmul():
         plain = jnp.asarray(rng.normal(size=(din, dout)), jnp.float32)
         np.testing.assert_array_equal(np.asarray(qmatmul(x, plain)),
                                       np.asarray(x @ plain))
+
+
+@pytest.mark.parametrize("din", [384, 8192], ids=["din1tile", "din4tiles"])
+@pytest.mark.parametrize("n", [1, 3, 8, 16, 24, 32, 48])
+def test_int8_gemv_keeps_all_three_bf16_terms_of_x(n, din):
+    """The kernel contracts bf16 operands in one MXU pass, and is still
+    an f32 GEMV: against an f64 (x @ q) * s it stays inside the f32
+    band (F32_ULPS) for an x with a wide range of exponents, at every
+    row count the engine can send (slots, the prefill chunk, a
+    verify's rows) and with din one tile and several. bf16(x) alone —
+    what is left if a later change drops `mid` and `lo` — is OUTSIDE
+    the band through the same reference, so that change fails here and
+    not in the benchmark's `correct`."""
+    rng = np.random.default_rng(1000 * n + din)
+    dout = 640
+    x = (rng.normal(size=(n, din))
+         * np.exp(2 * rng.normal(size=(n, din)))).astype(np.float32)
+    w = QuantW(
+        q=jnp.asarray(rng.integers(-127, 128, size=(din, dout)), jnp.int8),
+        s=jnp.asarray(rng.uniform(0.5, 2.0, size=(1, dout)), jnp.float32))
+    q64 = np.asarray(w.q, np.float64)
+    s64 = np.asarray(w.s, np.float64)
+    want = (x.astype(np.float64) @ q64) * s64
+    _assert_f32_close(np.asarray(int8_gemv(jnp.asarray(x), w)), want,
+                      f"{n}x{din}")
+    hi = np.asarray(jnp.asarray(x).astype(jnp.bfloat16), np.float64)
+    with pytest.raises(AssertionError):
+        _assert_f32_close((hi @ q64) * s64, want)
 
 
 def test_quantize_weight_error_bound():
