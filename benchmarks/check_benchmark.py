@@ -12,9 +12,12 @@
    `serve/bench.make_workload(len_dist="lognormal")` for the same seed
    and ranges where a cell gives a range alone; for every cell, the
    lognormal goes through the median and mean its source publishes
-   and no request outgrows the deployment's `max_len`.
-3. work.py against a count written out by hand at one small shape.
-4. BENCHMARK.json: every name has its file, every reader loads.
+   and no request outgrows the deployment's `max_len`; the vocabulary
+   is the one the configuration's family gives (`dims(cfg)["vocab"]`).
+3. work.py's kernel counts, and every family's own `work.check()`,
+   against counts written out by hand at one small shape.
+4. BENCHMARK.json: every name has its file, every reader loads, every
+   configuration names a family whose four modules load.
 """
 
 from __future__ import annotations
@@ -28,7 +31,13 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
 from benchmarks import reduce_trace, work  # noqa: E402
-from benchmarks.run import load_by_path, load_cell  # noqa: E402
+from benchmarks.run import (  # noqa: E402
+    families_in,
+    family_dir,
+    load_by_path,
+    load_cell,
+    load_family,
+)
 
 HERE = Path(__file__).resolve().parent
 
@@ -105,7 +114,8 @@ def check_traffic():
     for cell in bench["workloads"]:
         _, cfg, wl, _ = load_cell(cell["name"], ROOT / "BENCHMARK.json")
         gen = load_by_path(HERE / "traffic" / f"{wl['generator']}.py")
-        p, vocab = wl["params"], int(cfg["vocab_size"])
+        family = load_family(family_dir(cfg, HERE))
+        p, vocab = wl["params"], family.weights.dims(cfg)["vocab"]
         for length in (p["prompt"], p["out"]):
             mu, sigma = gen.mu_sigma(length)
             if "median" in length:
@@ -127,24 +137,26 @@ def check_traffic():
 
 
 def check_work():
-    dm = {"d": 8, "heads": 2, "hd": 4, "n_kv": 1, "depth": 3, "ffn": 32,
-          "vocab": 10}
-    # wq 8x8, wkv 8x8, wo 8x8, w1 8x32, w2 32x8 per layer; head 8x10.
-    assert work.matmul_params(dm) == 3 * (64 + 64 + 64 + 256 + 256) + 80
-    # 2 tokens at positions 5, 6: contexts 6 and 7.
-    want = 2 * 2 * work.matmul_params(dm) + 4 * 3 * 2 * 4 * (6 + 7)
-    assert work.span_flops(dm, 5, 2) == want
-    assert work.token_flops(dm, 6) + work.token_flops(dm, 7) == want
     assert work.int8_gemv_work(4, 8, 16) == (2 * 4 * 8 * 16,
                                              128 + 64 + 128 + 256)
+    # One call a forward of each shape: the sum of the calls' own floors.
+    peaks = {"bf16_flops": 1e3, "hbm_bytes_per_s": 1e2}
+    assert close(work.int8_gemv_least_seconds(4, [(8, 16, 2)], peaks),
+                 2 * max(1024 / 1e3, 576 / 1e2))
     print("work: ok")
+    families = {path for bench_dir in (HERE, HERE / "tests/tiny")
+                for path in families_in(bench_dir).values()}
+    for path in sorted(families):
+        load_family(path).work.check()
+        print(f"work of family {path.relative_to(ROOT)}: ok")
 
 
 def check_files():
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     cells = [w["name"] for w in bench["workloads"]]
     for name in cells:
-        load_cell(name, ROOT / "BENCHMARK.json")
+        cfg = load_cell(name, ROOT / "BENCHMARK.json")[1]
+        load_family(family_dir(cfg, HERE))
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     for m in bench["per_layer"]:
         reader = load_by_path(HERE / "layer_metrics" / f"{m['name']}.py")

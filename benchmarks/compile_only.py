@@ -3,9 +3,12 @@ size for a v5e that is described, not attached (no chip time):
 
     JAX_PLATFORMS=cpu python benchmarks/compile_only.py <config>
 
-Prints the bytes of the serving weights and of the paged cache, and
-for `tick` and `prefill` the compile seconds and XLA's memory analysis
-on one chip. What the TPU's compiler refuses (a kernel's tiling, fast
+The engine is the one the configuration's family builds
+(`families/<family>/build.py` over its `weights.dims`; the first line
+printed names the family), handed shapes in place of weights. Prints
+the bytes of the serving weights and of the paged cache, and for `tick`
+and `prefill` the compile seconds and XLA's memory analysis on one
+chip. What the TPU's compiler refuses (a kernel's tiling, fast
 memory, a program too large for 16 GB) it refuses here. Nothing runs:
 no time, no result.
 """
@@ -30,7 +33,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from benchmarks import build, weights  # noqa: E402
+from benchmarks.run import HERE, family_dir, load_family  # noqa: E402
 from mpi_cuda_cnn_tpu.ops import pallas_gemv  # noqa: E402
 from mpi_cuda_cnn_tpu.serve.paged_cache import PagedKVCache  # noqa: E402
 
@@ -42,9 +45,10 @@ def tree_bytes(tree) -> int:
 
 def main(config: str) -> None:
     jax.config.update("jax_enable_compilation_cache", False)
-    cfg = json.loads(
-        (ROOT / "benchmarks/configs" / f"{config}.json").read_text())
-    dm = weights.dims(cfg)
+    cfg = json.loads((HERE / "configs" / f"{config}.json").read_text())
+    family = load_family(family_dir(cfg, HERE))
+    build, dm = family.build, family.weights.dims(cfg)
+    print(f"{config}: family {family.name}", flush=True)
     chip = SingleDeviceSharding(topologies.get_topology_desc(
         topology_name="v5e:2x2", platform="tpu").devices[0])
 
@@ -52,7 +56,7 @@ def main(config: str) -> None:
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
 
     params = jax.eval_shape(
-        lambda: build.serving_params(dm, 0, cfg["weights_dtype"]))
+        lambda: build.serving_params(dm, 0, cfg))
     engine = build.engine_of(cfg, dm, params)
     print(f"weights {tree_bytes(params) / 1e9:.3f} GB, cache "
           f"{tree_bytes(engine._pages) / 1e9:.3f} GB in {engine.num_pages} "

@@ -3,8 +3,10 @@ against the plain reference.
 
 Once the window has closed and the engine's memory is freed, a sample
 of the requests that were served tokens (drawn from the seed, the one
-with the most served tokens always in it) is run once through
-reference.py: prompt and served tokens together, teacher-forced. At
+with the most served tokens always in it) is run once through the
+plain reference of the configuration's family
+(`families/<family>/reference.py`, handed in as `forward_logits`):
+prompt and served tokens together, teacher-forced. At
 every served position the reference has a best logit and a logit for
 the token the engine served; their difference is that token's GAP
 (0 where the engine served the reference's own first choice). Greedy
@@ -24,8 +26,6 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
-
-from . import reference
 
 # Sample until this many served tokens are in it, within these counts.
 MIN_TOKENS, MIN_REQUESTS, MAX_REQUESTS = 300, 4, 8
@@ -56,13 +56,15 @@ def _pad_to(n: int, step: int) -> int:
     return -(-n // step) * step
 
 
-def gaps(dm: dict, seed: int, sample, max_len: int,
+def gaps(forward_logits, dm: dict, seed: int, sample, max_len: int,
          lower: str | None = None):
     """Per-token gaps of the served tokens (and of the control's picks
-    where `lower` is given) for a sample from pick_sample. Sequences
+    where `lower` is given) for a sample from pick_sample, by the
+    family's `forward_logits(dm, seed, seqs, rows, lowers)`. Sequences
     are padded to a third, two thirds or the whole of the deployment's
     `max_len`: three shapes to compile in a checkout, whatever the
-    seeds draw."""
+    seeds draw. Of `dm` only `max_seq` is read, which every family's
+    `dims` gives."""
     step = _pad_to(-(-max_len // 3), 128)
     tmax = min(_pad_to(max(len(p) + len(o) for _, p, o in sample), step),
                dm["max_seq"])
@@ -79,7 +81,7 @@ def gaps(dm: dict, seed: int, sample, max_len: int,
         r[: len(out)] = np.arange(len(prompt) - 1, n - 1)
         rows.append(r)
     lowers = (None, lower) if lower else (None,)
-    logits = reference.forward_logits(dm, seed, seqs, rows, lowers)
+    logits = forward_logits(dm, seed, seqs, rows, lowers)
     served_gaps, lower_gaps = [], []
     for i, (_, _, out) in enumerate(sample):
         n = len(out)

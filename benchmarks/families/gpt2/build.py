@@ -1,6 +1,7 @@
-"""The system under test, built from a configuration file: the
-program's `TransformerLM`, seeded weights converted block by block with
-the program's own quantizer, and a `PagedEngine` over them.
+"""The system under test for a configuration of the GPT-2 family: the
+program's `TransformerLM` (learned positions), seeded weights converted
+block by block with the program's own quantizer, and a `PagedEngine`
+over them.
 
 The whole-model f32 tree never exists (StarCoderBase-7B's would be
 28 GB): each block is drawn in f32 inside one jitted call
@@ -41,9 +42,11 @@ def model_of(dm: dict) -> TransformerLM:
     )
 
 
-def serving_params(dm: dict, seed: int, weights_dtype: str) -> dict:
-    """The params tree the engine serves from, in `weights_dtype`."""
+def serving_params(dm: dict, seed: int, cfg: dict) -> dict:
+    """The params tree the engine serves from, in the configuration's
+    `weights_dtype`."""
     key = weights.root_key(seed)
+    weights_dtype = cfg["weights_dtype"]
 
     @jax.jit
     def top(key):
@@ -52,8 +55,12 @@ def serving_params(dm: dict, seed: int, weights_dtype: str) -> dict:
 
     @jax.jit
     def block(key, i):
-        # A one-block tree through the program's own conversion; the
-        # placeholder head is what its int8 branch insists on finding.
+        # A one-block tree through the program's own conversion. Its
+        # int8 branch (`ops/pallas_gemv.quantize_decode_params`)
+        # quantizes `params["head"]` without asking whether there is
+        # one, so a block alone needs some head to be let through: a
+        # few zeros, quantized and dropped with the rest of this tree.
+        # The real head is converted with the top, above.
         tree = {"head": jnp.zeros((8, 128), jnp.float32),
                 "blocks": [weights.block_f32(dm, key, i)]}
         return quantize_decode_params(tree, weights_dtype)["blocks"][0]

@@ -1,9 +1,10 @@
-"""The plain reference: this block's forward in float32 `jax.numpy`
-under "highest" matmul precision — no cache, no paging, no kernels, no
-batching, nothing imported from the program. Pre-LN LayerNorm with
-bias, MHA or multi-query attention over learned positions, tanh-GELU
-4x MLP, no linear biases, untied head (the block `TransformerLM` is;
-the departures from each published model are in its configuration file).
+"""The plain reference of the GPT-2 family: this block's forward in
+float32 `jax.numpy` under "highest" matmul precision — no cache, no
+paging, no kernels, no batching, nothing imported from the program.
+Pre-LN LayerNorm with bias, MHA or multi-query attention over learned
+positions, tanh-GELU 4x MLP, no linear biases, untied head (the block
+`TransformerLM` is; the departures from each published model are in its
+configuration file).
 
 It runs layer by layer so that it fits beside nothing else on a 16 GB
 chip: one f32 block is drawn from the seed (weights.block_f32), applied
@@ -11,10 +12,9 @@ to every sampled sequence, and dropped.
 
 `lower` names a precision below the configuration's own, for the
 control that has to come out not correct (README, "correct"): the
-matmul weights and the keys and values are rounded to it, everything
-else stays f32. "int4": absmax per output channel (weights) or per
-position and head (keys, values) over 7 levels a side. "fp8": the same
-scaling, to e4m3's 3 bits of mantissa.
+matmul weights (per output channel) and the keys and values (per
+position and head) go through the benchmark's shared
+`rounding.round_to`, everything else stays f32.
 """
 
 from __future__ import annotations
@@ -25,28 +25,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmarks.rounding import round_to
+
 from . import weights
 
 _MATS = ("wqkv", "wq", "wkv", "wo", "w1", "w2")
-
-
-def _round_to(x, lower: str, axis: int):
-    """x rounded to the lower precision, back in f32."""
-    if lower == "int4":
-        s = jnp.maximum(jnp.max(jnp.abs(x), axis=axis, keepdims=True) / 7.0,
-                        1e-10)
-        return jnp.clip(jnp.round(x / s), -7, 7) * s
-    if lower == "fp8":
-        # 4 exponent bits, 3 of mantissa (largest finite 240, as IEEE
-        # would have e4m3), scaled by absmax like the int forms.
-        # reduce_precision is an operation of its own in the HLO; a cast
-        # there and back is one XLA may drop ("excess precision"), and
-        # on the chip it did (PR 24).
-        s = jnp.maximum(jnp.max(jnp.abs(x), axis=axis, keepdims=True) / 240.0,
-                        1e-10)
-        return jax.lax.reduce_precision(x / s, exponent_bits=4,
-                                        mantissa_bits=3) * s
-    raise ValueError(f"lower precision {lower!r}: want int4 or fp8")
 
 
 def _layernorm(x, p, eps):
@@ -65,7 +48,7 @@ def _block(dm, lower, x, blk):
     t = x.shape[0]
     h, hd, n_kv = dm["heads"], dm["hd"], dm["n_kv"]
     if lower:
-        blk = {**blk, **{m: _round_to(blk[m], lower, 0)
+        blk = {**blk, **{m: round_to(blk[m], lower, 0)
                          for m in _MATS if m in blk}}
     y = _layernorm(x, blk["ln1"], dm["eps"])
     if "wqkv" in blk:
@@ -77,7 +60,7 @@ def _block(dm, lower, x, blk):
     k = k.reshape(t, n_kv, hd)
     v = v.reshape(t, n_kv, hd)
     if lower:
-        k, v = _round_to(k, lower, -1), _round_to(v, lower, -1)
+        k, v = round_to(k, lower, -1), round_to(v, lower, -1)
     if n_kv != h:                       # each kv head serves h/n_kv queries
         k = jnp.repeat(k, h // n_kv, axis=1)
         v = jnp.repeat(v, h // n_kv, axis=1)
@@ -100,7 +83,7 @@ def _jitted(dm_items, lower):
         return top_p["tok_emb"][toks] + top_p["pos_emb"][: toks.shape[0]]
 
     def logits(top_p, x, rows):
-        head = _round_to(top_p["head"], lower, 0) if lower else top_p["head"]
+        head = round_to(top_p["head"], lower, 0) if lower else top_p["head"]
         return _layernorm(x[rows], top_p["ln_f"], dm["eps"]) @ head
 
     return {

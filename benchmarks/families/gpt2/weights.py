@@ -1,8 +1,8 @@
-"""Seeded f32 weights, one block at a time, in the layout
-`TransformerLM.init` gives (models/transformer.py) — the benchmark's
-own generator: run.py converts each block with the program's
-quantizer and drops the f32 form; reference.py draws the same block
-again from the same key and keeps it f32. Nothing the program made
+"""The GPT-2 family's sizes and seeded f32 weights, one block at a
+time, in the layout `TransformerLM.init` gives (models/transformer.py)
+— the benchmark's own generator: build.py converts each block with the
+program's quantizer and drops the f32 form; reference.py draws the same
+block again from the same key and keeps it f32. Nothing the program made
 (int8 values, scales, casts) ever reaches the reference.
 
 Matrices are normal / sqrt(fan_in) as in `init`; the embeddings
@@ -21,7 +21,9 @@ import jax.numpy as jnp
 
 def dims(cfg: dict) -> dict:
     """The sizes the block needs, from a configuration file's
-    published keys (GPT-2 / GPTBigCode names)."""
+    published keys (GPT-2 / GPTBigCode names). What the harness reads
+    of them: `vocab` (the ids traffic draws from and `short` checks)
+    and `max_seq` (no sampled sequence is padded past it)."""
     d, h = int(cfg["n_embd"]), int(cfg["n_head"])
     if d % h:
         raise ValueError(f"n_embd {d} not divisible by n_head {h}")

@@ -5,9 +5,9 @@ traced slice (work.py: per call the larger of operations over the bf16
 peak and bytes over the HBM peak; at these row counts the bytes bound
 every call), over the summed device time of the kernel's custom-call
 events. The calls are counted from the module runs: each `tick` run
-makes one forward's calls at `slots` rows, each `prefill` run at
-`prefill_chunk` rows. Nothing to read where the configuration's
-weights are not int8.
+makes one forward's calls (the family's `work.matmul_shapes`) at
+`slots` rows, each `prefill` run at `prefill_chunk` rows. Nothing to
+read where the configuration's weights are not int8.
 """
 
 from benchmarks import work
@@ -25,10 +25,11 @@ def read(ctx):
     spent, calls = trace.op_seconds(KERNEL)
     if not calls:
         return None
+    shapes = ctx["family"].work.matmul_shapes(ctx["dims"])
     least = 0.0
     for module, rows in (("jit_tick", ctx["slots"]),
                          ("jit_prefill", ctx["prefill_chunk"])):
         runs = len(trace.module_durations(module)) / len(trace.chips)
         least += runs * work.int8_gemv_least_seconds(
-            rows, ctx["dims"], ctx["peaks"])
+            rows, shapes, ctx["peaks"])
     return 100.0 * least / spent

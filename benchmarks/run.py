@@ -3,15 +3,18 @@
     python benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything about a cell is data that this file finds by name from
-`BENCHMARK.json` (README.md): the configuration's file, the cell's
-file under workloads/, the traffic generator it names under traffic/,
-and one reader per per-layer metric under layer_metrics/.
+`BENCHMARK.json` (README.md): the configuration's file, the family it
+names under families/ (everything that knows the shape of a block: its
+sizes and seeded weights, the engine built over them, the plain
+reference, the operations it needs), the cell's file under workloads/,
+the traffic generator it names under traffic/, and one reader per
+per-layer metric under layer_metrics/.
 
 The run: claim the chip (a TPU whose kind is in peaks.json, or exit 2
 with nothing on stdout), place JAX's persistent compilation cache,
-build the model, its seeded weights and the program's `PagedEngine`,
-serve one throwaway request (that compiles, or loads, the cell's two
-programs) — all of that is `setup_s` — then hand the engine the cell's
+have the family build the model, its seeded weights and the program's
+engine, serve one throwaway request (that compiles, or loads, the cell's
+two programs) — all of that is `setup_s` — then hand the engine the cell's
 requests in ONE call of `PagedEngine.run(requests, mode="continuous")`.
 That call is the window. It lasts `--seconds`: every request carries
 the window's close as its deadline, so what is still queued or decoding
@@ -33,11 +36,15 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
+import importlib.machinery  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
+import types  # noqa: E402
+import zlib  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -94,6 +101,55 @@ def load_by_path(path: Path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+# -- a configuration's family ---------------------------------------------
+
+FAMILY_MODULES = ("weights", "build", "reference", "work")
+
+
+def families_in(bench_dir: Path) -> dict:
+    """name -> directory of every family a configuration of the
+    benchmark at `bench_dir` can name: this directory's families/, and
+    before them its own (tests/tiny borrows these and brings one more)."""
+    found = {}
+    for base in (HERE, Path(bench_dir)):
+        for path in sorted((base / "families").glob("*")):
+            if all((path / f"{m}.py").exists() for m in FAMILY_MODULES):
+                found[path.name] = path
+    return found
+
+
+def family_dir(cfg: dict, bench_dir: Path) -> Path:
+    """Where the family that a configuration names lives. There is no
+    default family: a configuration that names none, or none that is
+    there, ends the run here, before anything touches the chip."""
+    there = families_in(bench_dir)
+    name = cfg.get("family")
+    if name not in there:
+        raise SystemExit(
+            f"configuration {cfg.get('name')!r} names the family {name!r}; "
+            f"there are {sorted(there)} (each a directory families/<family>/ "
+            f"with {', '.join(m + '.py' for m in FAMILY_MODULES)})")
+    return there[name]
+
+
+def load_family(path: Path):
+    """The family's four modules (README, "families"), imported as one
+    package found by its path, so that they import each other
+    (`from . import weights`) wherever the directory lies."""
+    path = Path(path).resolve()
+    # The package's name carries the path, so that two benchmarks in one
+    # process (the tests') may each bring a family of one name.
+    pkg = (f"bench_family_{zlib.crc32(str(path).encode()):08x}_"
+           + re.sub(r"\W", "_", path.name))
+    if pkg not in sys.modules:
+        spec = importlib.machinery.ModuleSpec(pkg, None, is_package=True)
+        spec.submodule_search_locations = [str(path)]
+        sys.modules[pkg] = importlib.util.module_from_spec(spec)
+    return types.SimpleNamespace(
+        name=path.name, dir=path,
+        **{m: importlib.import_module(f"{pkg}.{m}") for m in FAMILY_MODULES})
 
 
 # -- the chip ---------------------------------------------------------------
@@ -299,27 +355,29 @@ def label_gaps(trace, ticks, first_traced: int):
 
 def prepare(cell_name: str, *, seed: int, bench_file: Path,
             require_chip: bool) -> dict:
-    """Set-up: the chip, the cache, the weights, the engine, warm."""
+    """Set-up: the family, the chip, the cache, the weights, the
+    engine, warm."""
     cell, cfg, wl, bench = load_cell(cell_name, bench_file)
+    bench_dir = Path(bench_file).resolve().parent / bench["paths"][0]
+    family_at = family_dir(cfg, bench_dir)
     device, peaks = claim_chip(int(cell["chips"]), require_chip)
     place_compile_cache()
 
     import jax
 
-    from benchmarks import build, weights
-
+    family = load_family(family_at)
     phases = {"reach_chip": time.perf_counter() - T_START}
-    dm = weights.dims(cfg)
-    params = build.serving_params(dm, seed, cfg["weights_dtype"])
+    dm = family.weights.dims(cfg)
+    params = family.build.serving_params(dm, seed, cfg)
     jax.block_until_ready(params)
     phases["weights"] = time.perf_counter() - T_START
-    engine = build.engine_of(cfg, dm, params)
+    engine = family.build.engine_of(cfg, dm, params)
     warm_up(engine, dm["vocab"])
     phases["warm"] = time.perf_counter() - T_START
     return {
         "name": cell_name, "cell": cell, "config": cfg, "workload": wl,
         "bench": bench, "device": device, "peaks": peaks, "dims": dm,
-        "bench_dir": Path(bench_file).resolve().parent / bench["paths"][0],
+        "family": family, "bench_dir": bench_dir,
         "engine": engine, "seed": seed, "phases": phases,
         "setup_s": phases["warm"],
     }
@@ -363,7 +421,8 @@ def measure(cell: dict, *, seconds: float, trace: bool,
         device["busy_s"], device["window_s"] = reduced.busy_s, reduced.window_s
         metrics = per_layer(bench, name, cell["bench_dir"], {
             "cell": name, "config": cell["config"], "dims": dm,
-            "peaks": cell["peaks"], "chips": int(cell["cell"]["chips"]),
+            "family": cell["family"], "peaks": cell["peaks"],
+            "chips": int(cell["cell"]["chips"]),
             "trace": reduced, "ticks": slicer.ticks,
             "first_traced": slicer.first_traced,
             "requests": result.requests, "window_s": result.duration_s,
@@ -403,7 +462,8 @@ def check(cell: dict, result, lower: str | None = None):
     control = None
     sample = correct.pick_sample(served, cell["seed"])
     if sample:
-        gaps = correct.gaps(dm, cell["seed"], sample,
+        gaps = correct.gaps(cell["family"].reference.forward_logits, dm,
+                            cell["seed"], sample,
                             int(cell["config"]["max_len"]), lower)
         compared.update(correct.numbers(gaps["served"]))
         compared["tokens_compared"] = len(gaps["served"])

@@ -13,7 +13,9 @@
 
 The sizes are tests/tiny's, small enough for the CPU; the limits in
 its cell files were set there as the real cells' were on the chip
-(program readings over seeds below, control above).
+(program readings over seeds below, control above; tiny-gqa.mix, PR 27,
+13 seeds on the CPU: program `gap_max` <= 0.036, `gap_mean` <= 0.00053,
+the fp8 control >= 0.139 and >= 0.0075; limits 0.08 and 0.002).
 """
 
 import json
@@ -29,7 +31,10 @@ sys.path.insert(0, str(ROOT))
 from benchmarks import run  # noqa: E402
 
 TINY = Path(__file__).resolve().parent / "tiny" / "bench.json"
-CELLS = ["tiny-mqa.mix", "tiny-mha.mix"]
+# The third cell's configuration names a family that tests/tiny brings
+# itself (tiny/families/rope_gqa): its control and faults are judged
+# through a reference the GPT-2 family did not write.
+CELLS = ["tiny-mqa.mix", "tiny-mha.mix", "tiny-gqa.mix"]
 
 
 def run_tiny(cell, seed=2**31 + 11, **kw):
