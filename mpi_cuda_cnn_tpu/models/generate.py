@@ -41,7 +41,7 @@ from jax import lax
 
 from ..ops.attention import NEG_INF, attention
 from ..ops.pallas_gemv import qmatmul
-from .transformer import TransformerLM, _layernorm
+from .transformer import TransformerLM, norm
 
 # THE auto-dtype routing table (ISSUE 12 satellite: one place for every
 # "auto" storage-dtype decision), keyed by surface -> (GQA/MQA pick,
@@ -111,6 +111,10 @@ def init_cache(model: TransformerLM, batch: int,
     at the MHA shape (PERF.md round-5 decode table: int8 wins +27-32%
     at GQA/MQA, loses ~9% at MHA where the convert spans 8x the
     bytes)."""
+    if model.attn is not None:
+        raise ValueError(
+            "the contiguous cache holds K/V heads; a model with latent "
+            "attention is served from the paged cache (serve.paged_cache)")
     shape = (batch, model.max_seq, model.n_kv, model.head_dim)
     if jnp.dtype(dtype) == jnp.int8:
         sshape = shape[:-1] + (1,)
@@ -209,52 +213,56 @@ def decode_step(model: TransformerLM, params, tok, pos, cache):
     return logits[:, 0, :], new_cache
 
 
-def token_forward(model: TransformerLM, params, toks, positions, attend):
+def token_forward(model: TransformerLM, params, toks, positions, attend,
+                  valid=None):
     """THE cached-decode forward skeleton: k tokens per row at explicit
     absolute positions, with the attention/cache behavior injected per
-    layer. Everything around attention — embedding, layernorms, QKV
+    layer. Everything around attention — embedding, norms, QKV
     projections + rotary (transformer.project_qkv, shared with the
-    training forward), MoE/dense MLP, final head — has exactly one
+    training forward), the feed-forward, final head — has exactly one
     implementation; the contiguous decode_block and serve/'s paged
     continuous-batching path differ ONLY in their `attend`.
+
+    The block is data: what a layer computes is read off its params
+    (transformer.norm: a gain alone is RMSNorm; TransformerLM.mlp: GELU
+    `w1`/`w2`, gated `wg`/`wu`/`wd` at any width, or an `experts` bank;
+    `pos_emb` or none) and off `model.attn` (K/V heads or one latent
+    row), so layers of different kinds ride one loop.
 
     toks: (B, k) int32; positions: (k,) shared across rows, or (B, k)
     PER-ROW absolute positions (the serving form — each slot sits at
     its own depth). attend(i, q, k, v) -> (B, k, H*hd) f32 performs
     layer i's cache update + masked attention read (closing over its
     cache; layers are traced in order, so append-style capture works —
-    the same idiom as prefill's attn_fn).
+    the same idiom as prefill's attn_fn). valid: (B, k) bool, the rows
+    that are tokens of a request (None = all); only an expert layer
+    reads it, to route nothing else.
 
     Every weight matmul routes through ops.pallas_gemv.qmatmul, so
     params may carry int8 QuantW leaves (quantize_decode_params,
     --decode-weights-dtype int8) — the decode-weight bandwidth lever
     rides the SAME forward, not a second one.
-    Returns (B, k, vocab) f32 logits.
+    Returns ((B, k, vocab) f32 logits, counts): counts the expert
+    layers' int32 [pairs computed (summed), held experts hit (summed),
+    largest load (max)], None for a model without any.
     """
-    b, kk = toks.shape
     x = params["tok_emb"][toks]                           # (B, k, dim)
     if model.pos == "learned":
         # (k, dim) broadcasts over rows; (B, k, dim) indexes per row.
         x = x + params["pos_emb"][positions]
+    eps, counts = model.norm_eps, None
     for i, blk in enumerate(params["blocks"]):
-        y = _layernorm(x, blk["ln1"]["g"], blk["ln1"]["b"])
+        y = norm(x, blk["ln1"], eps)
         q, k, v = model.project_qkv(blk, y, positions=positions)
         o = attend(i, q, k, v)
         x = x + qmatmul(o.astype(x.dtype), blk["wo"])
-        y = _layernorm(x, blk["ln2"]["g"], blk["ln2"]["b"])
-        if model.moe_experts:
-            from ..parallel.ep import moe_mlp_inference
-
-            m = moe_mlp_inference(
-                y.reshape(b * kk, model.dim), blk["moe"],
-                n_experts=model.moe_experts, top_k=model.moe_top_k,
-            )
-            x = x + m.reshape(b, kk, model.dim)
-        else:
-            x = x + qmatmul(jax.nn.gelu(qmatmul(y, blk["w1"])),
-                            blk["w2"])
-    x = _layernorm(x, params["ln_f"]["g"], params["ln_f"]["b"])
-    return qmatmul(x, params["head"]).astype(jnp.float32)
+        m, c = model.mlp(blk, norm(x, blk["ln2"], eps), valid)
+        x = x + m
+        if c is not None:
+            counts = c if counts is None else jnp.concatenate(
+                [counts[:2] + c[:2], jnp.maximum(counts[2:], c[2:])])
+    x = norm(x, params["ln_f"], eps)
+    return qmatmul(x, params["head"]).astype(jnp.float32), counts
 
 
 def attend_kv(q, ck, cv, mask, cks=None, cvs=None):
@@ -300,6 +308,50 @@ def attend_kv(q, ck, cv, mask, cks=None, cvs=None):
             preferred_element_type=jnp.float32,
         )
     return o.reshape(b, kk, h * hd)
+
+
+def attend_latent(q, rows, mask, wuk, wuv, a):
+    """The masked read over LATENT cache rows (transformer.LatentAttn):
+    rows (B, L, >= kv_rank + rope) hold, a token, the compressed latent
+    c, the one rotated key k_r all heads share, and zero lanes after
+    them where the pool pads a row; q (B, k, H, nope +
+    rope), rotated part last; wuk (H, nope, kv_rank), wuv (H, kv_rank,
+    v) the per-head up-projections of keys and values, head first: a
+    batch of small matrices as they lie.
+
+    The ABSORBED form: q~_h = wuk_h q_n,h reads the rows as they lie,
+    score = q~_h . c + q_r,h . k_r, and the values are the rows' c
+    again, up-projected once per QUERY. It equals up-projecting every
+    row to per-head keys and values first (the materialized form, which
+    the benchmark's reference computes and tests/test_mla_moe.py holds
+    this against) up to rounding, at far fewer operations while a
+    slot's queries are few: a decode tick has one, a prefill chunk 32,
+    and the two cross near 150 (batched prefill, ROADMAP S2, brings the
+    other form back with a trace of both). mask: (k, L) or (B, k, L),
+    True = attend; scores and softmax f32. Returns (B, k, H*v) f32."""
+    b, kk, h, _ = q.shape
+    f32 = dict(preferred_element_type=jnp.float32)
+    c = rows[..., :a.kv_rank]
+    if mask.ndim == 2:
+        mask = mask[None]
+    # q~ in the weights' type: the rows' type is where it is read.
+    qt = jnp.einsum("bqhn,hnr->bqhr", q[..., :a.nope].astype(wuk.dtype), wuk)
+    qrow = jnp.concatenate(
+        [qt.astype(rows.dtype), q[..., a.nope:].astype(rows.dtype),
+         jnp.zeros((b, kk, h, rows.shape[-1] - a.row), rows.dtype)],
+        axis=-1)                                # (B, k, H, stored row)
+    # Heads and queries are one axis of H*k rows against the slot's
+    # L cache rows: every head reads the same row, whole.
+    qrow = qrow.transpose(0, 2, 1, 3).reshape(b, h * kk, -1)
+    logits = jnp.einsum("bmc,bkc->bmk", qrow, rows, **f32).reshape(
+        b, h, kk, -1)
+    logits = jnp.where(mask[:, None], logits * a.softmax_scale, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1).astype(rows.dtype)
+    ot = jnp.einsum("bmk,bkr->bmr", probs.reshape(b, h * kk, -1), c,
+                    **f32).reshape(b, h, kk, a.kv_rank)
+    o = jnp.einsum("bhqr,hrv->bqhv", ot.astype(wuv.dtype), wuv).astype(
+        jnp.float32)
+    return o.reshape(b, kk, h * a.v)
 
 
 def attend_contiguous(c, q, k, v, pos, positions):
@@ -372,7 +424,7 @@ def decode_block(model: TransformerLM, params, toks, pos, cache):
         new_cache.append(new_c)
         return o
 
-    logits = token_forward(model, params, toks, positions, attend)
+    logits, _ = token_forward(model, params, toks, positions, attend)
     return logits, new_cache
 
 

@@ -29,8 +29,8 @@ from collections.abc import Callable
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import attention, rope
-from ..ops.pallas_gemv import QuantW, qmatmul
+from ..ops.attention import attention, rope, yarn_inv_freq
+from ..ops.pallas_gemv import QuantW, qmatmul, swiglu
 
 
 def _weight_cast(cd):
@@ -52,12 +52,97 @@ def _layernorm(x, g, b, eps=1e-5):
     return y.astype(x.dtype)
 
 
+def _rmsnorm(x, g, eps):
+    """x * rsqrt(mean x^2 + eps) * g, statistics in f32 like
+    _layernorm; no mean is taken off and there is no bias."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * g).astype(x.dtype)
+
+
+def norm(x, p: dict, eps: float):
+    """A block's normalisation, by what its params hold: gain and bias
+    is LayerNorm, a gain alone is RMSNorm."""
+    if "b" in p:
+        return _layernorm(x, p["g"], p["b"], eps)
+    return _rmsnorm(x, p["g"], eps)
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttn:
+    """Multi-head latent attention (MLA; DeepSeek-V2, arXiv:2405.04434):
+    queries through a `q_rank` bottleneck, keys and values through one
+    shared `kv_rank` latent a token plus ONE rotary key of `rope` dims
+    for all heads. The cache holds that row (kv_rank + rope values) and
+    no per-head K or V; each head has `nope` unrotated and `rope`
+    rotated query dims and `v` value dims. `yarn`: (factor, original
+    length, beta_fast, beta_slow, mscale, mscale_all_dim) or None."""
+
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+    rope_theta: float = 10000.0
+    yarn: tuple | None = None
+
+    @property
+    def row(self) -> int:
+        return self.kv_rank + self.rope
+
+    def _mscale(self, m: float) -> float:
+        factor = self.yarn[0]
+        return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    @property
+    def softmax_scale(self) -> float:
+        """1/sqrt(nope + rope), times YaRN's mscale(mscale_all_dim)^2."""
+        scale = 1.0 / math.sqrt(self.nope + self.rope)
+        if self.yarn is None:
+            return scale
+        return scale * self._mscale(self.yarn[5]) ** 2
+
+    def rotate(self, x, positions):
+        """x (B, S, H, rope) rotated at `positions`, rotate-half pairs."""
+        if self.yarn is None:
+            return rope(x, positions, base=self.rope_theta)
+        factor, original, fast, slow, mscale, mscale_all = self.yarn
+        if self._mscale(mscale) != self._mscale(mscale_all):
+            raise ValueError("YaRN with mscale != mscale_all_dim scales "
+                             "cos/sin; not implemented")
+        return rope(x, positions, inv_freq=yarn_inv_freq(
+            self.rope, base=self.rope_theta, factor=factor,
+            original_len=original, beta_fast=fast, beta_slow=slow))
+
+
+@dataclasses.dataclass(frozen=True)
+class RoutedExperts:
+    """The expert layer of one chip (parallel/ep.moe_held_inference):
+    the router scores all `experts` of the layer (sigmoid, a bias that
+    only chooses, `groups` groups of which `top_groups` stay, `top_k`
+    experts a token, weights normalised and times `scale`); `held` are
+    the ids whose weights are here, and only they are computed."""
+
+    experts: int
+    held: tuple[int, ...]
+    top_k: int
+    groups: int = 1
+    top_groups: int = 1
+    scale: float = 1.0
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerLM:
     """Decoder-only LM: vocab -> dim, `depth` pre-LN blocks, tied LN head.
 
-    Sizes are kept explicit; heads must divide dim. The MLP expansion is
-    the standard 4x.
+    Sizes are kept explicit; heads must divide dim. `init` and `apply`
+    (the trainers) build and run one block: LayerNorm, K/V heads, the
+    standard 4x GELU MLP. The cached decode forward
+    (models/generate.token_forward) reads each layer's kind off its
+    params instead (`norm`, `mlp`), and `attn` / `experts` below
+    describe what the params alone cannot: latent attention's sizes and
+    an expert layer's routing. Such a model brings its own params tree
+    and is served through serve.PagedEngine.
 
     TPU sizing note (measured, PERF.md round-4 MFU ladder): prefer
     head_dim = dim/heads = 128 — the flash kernel's QK^T and PV dots
@@ -80,6 +165,10 @@ class TransformerLM:
     moe_experts: int = 0   # 0 = dense MLP; >0 = Switch-MoE MLP per block
                            # (parallel/ep.py), EP-shardable over a mesh axis
     moe_top_k: int = 1     # experts per token: 1 = Switch, 2 = GShard-style
+    norm_eps: float = 1e-5
+    attn: LatentAttn | None = None         # None = K/V heads as above
+    experts: RoutedExperts | None = None   # routing of a block that
+                           # holds an "experts" bank (serving only)
     name: str = "transformer_lm"
 
     @property
@@ -100,7 +189,16 @@ class TransformerLM:
             )
         return hkv
 
+    def _gpt2_block_only(self, what: str) -> None:
+        if self.attn is not None or self.experts is not None:
+            raise ValueError(
+                f"TransformerLM.{what} knows the LayerNorm/GELU block with "
+                "K/V heads; a model with latent attention or held experts "
+                "brings its params tree and is served through "
+                "serve.PagedEngine (models/generate.token_forward)")
+
     def init(self, key) -> dict:
+        self._gpt2_block_only("init")
         d, v, hd = self.dim, self.vocab, self.head_dim
         # Key budget is fixed regardless of config so the default
         # (learned-pos MHA) consumes keys exactly as in round 1 — golden
@@ -172,8 +270,10 @@ class TransformerLM:
         bandwidth lever, same forward.
         Returns q: (B, S, H, hd); k, v: (B, S, Hkv, hd)."""
         b, s, _ = y.shape
-        h, hd, hkv = self.heads, self.head_dim, self.n_kv
         w = _weight_cast(compute_dtype)
+        if self.attn is not None:
+            return self._project_latent(blk, y, positions, w)
+        h, hd, hkv = self.heads, self.head_dim, self.n_kv
         if hkv == h:
             qkv = qmatmul(y, w(blk["wqkv"]))        # (B, S, 3*dim)
             q, k, v = jnp.split(qkv, 3, axis=-1)
@@ -188,6 +288,57 @@ class TransformerLM:
             q = rope(q, positions)
             k = rope(k, positions)
         return q, k, v
+
+    def _project_latent(self, blk, y, positions, w):
+        """project_qkv under latent attention. Returns q: (B, S, H,
+        nope + rope) with the rope part rotated; the token's cache row
+        [RMS(c) ; rope(k_r)] as k: (B, S, 1, kv_rank + rope) — one row
+        for all heads; v: None (values are read out of the same row)."""
+        a, b, s = self.attn, y.shape[0], y.shape[1]
+        cq = _rmsnorm(qmatmul(y, w(blk["wdq"])), blk["q_norm"]["g"],
+                      self.norm_eps)
+        # The query up-projection is held head first, the heads' nope
+        # part and their rope part apart, (H, q_rank, nope | rope): one
+        # (q_rank, H x 192) matrix has a head stride no lane tile
+        # divides, and the compiler then re-lays the whole matrix in
+        # every program (PERF.md section 6, PR 28).
+        qn = jnp.einsum("bsr,hrn->bshn", cq, w(blk["wuq_n"]))
+        qr = jnp.einsum("bsr,hrn->bshn", cq, w(blk["wuq_r"]))
+        q = jnp.concatenate([qn, a.rotate(qr, positions)], axis=-1)
+        ckr = qmatmul(y, w(blk["wdkv"]))                   # (B, S, row)
+        c = _rmsnorm(ckr[..., :a.kv_rank], blk["kv_norm"]["g"],
+                     self.norm_eps)
+        kr = a.rotate(ckr[..., None, a.kv_rank:], positions)
+        return q, jnp.concatenate([c[..., None, :], kr], axis=-1), None
+
+    def mlp(self, blk: dict, y: jnp.ndarray, valid=None):
+        """The block's feed-forward on y (B, S, dim), by what the block
+        holds: `w1`/`w2` the GELU MLP, `wg`/`wu`/`wd` a gated (SwiGLU)
+        one at whatever width the matrices have, `experts` this chip's
+        share of a routed expert layer (only rows in `valid` are
+        routed). Returns (out, counts): counts the expert layer's
+        int32 [token-expert pairs computed, held experts hit, largest
+        load], None elsewhere."""
+        if "experts" in blk:
+            from ..parallel.ep import moe_held_inference
+
+            b, s, d = y.shape
+            m, counts = moe_held_inference(
+                y.reshape(b * s, d), blk, self.experts,
+                valid=None if valid is None else valid.reshape(b * s))
+            return m.reshape(b, s, d), counts
+        if self.moe_experts:
+            from ..parallel.ep import moe_mlp_inference
+
+            b, s, d = y.shape
+            m = moe_mlp_inference(
+                y.reshape(b * s, d), blk["moe"],
+                n_experts=self.moe_experts, top_k=self.moe_top_k,
+            )
+            return m.reshape(b, s, d), None
+        if "wg" in blk:
+            return swiglu(y, blk), None
+        return qmatmul(jax.nn.gelu(qmatmul(y, blk["w1"])), blk["w2"]), None
 
     def apply_block(
         self,
@@ -209,6 +360,7 @@ class TransformerLM:
         params — one implementation of the block math for every layout.
         Returns (x, aux) with aux the MoE balance loss (0 for dense).
         """
+        self._gpt2_block_only("apply")
         b, s, _ = x.shape
         h, hd = self.heads, self.head_dim
         cd = compute_dtype

@@ -18,8 +18,11 @@ exact, not approximate.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..obs.trace import annotate
 
@@ -69,7 +72,29 @@ def repeat_kv(kv, n_heads: int):
     return jnp.repeat(kv, n_heads // hkv, axis=2)
 
 
-def rope(x, positions, *, base: float = 10000.0):
+def yarn_inv_freq(dim: int, *, base: float, factor: float,
+                  original_len: int, beta_fast: float, beta_slow: float):
+    """The dim/2 rotary frequencies under YaRN scaling (Peng et al.,
+    arXiv:2309.00071, as DeepSeek-V3's config spells it): pair i turns
+    at f_i = base ** (-2i/dim); pairs that complete more than
+    `beta_fast` turns over the original length keep f_i, pairs that
+    complete fewer than `beta_slow` are slowed by `factor`, and a
+    linear ramp between the two pair indices blends them. Static
+    numbers (numpy): they fold into the program as a constant."""
+    half = dim // 2
+    f = base ** (-np.arange(half, dtype=np.float64) / half)
+
+    def pair_of(turns):     # the pair that completes `turns` turns
+        return (dim * math.log(original_len / (turns * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    return (f / factor * ramp + f * (1 - ramp)).astype(np.float32)
+
+
+def rope(x, positions, *, base: float = 10000.0, inv_freq=None):
     """Rotary position embedding (rotate-half form) for x: (B, S, H, D).
 
     positions: (S,) absolute token positions — explicit, so sequence
@@ -79,13 +104,19 @@ def rope(x, positions, *, base: float = 10000.0):
     sits at its own depth, so one batched forward spans many absolute
     positions; serve/engine.py). Angles are computed in f32 regardless
     of x.dtype (bf16 loses position precision past ~256); output
-    returns in x.dtype. D must be even.
+    returns in x.dtype. D must be even. `inv_freq` (half,) replaces
+    base ** (-i/half): the YaRN-scaled frequencies of a long-context
+    model (yarn_inv_freq). Pair i is entries i and i + half ("halves",
+    not interleaved) in either case.
     """
     d = x.shape[-1]
     if d % 2:
         raise ValueError(f"rope needs an even head dim, got {d}")
     half = d // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)  # (half,)
+    if inv_freq is None:
+        freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:       # scaled frequencies (yarn_inv_freq), (half,)
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = positions.astype(jnp.float32)[..., None] * freqs
     # (S, 1, half) broadcasts over batch AND heads; (B, S, 1, half)
     # broadcasts over heads only — one expand serves both rank forms.

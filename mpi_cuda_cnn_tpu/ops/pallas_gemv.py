@@ -250,3 +250,11 @@ def qmatmul(x, w):
     lead = x.shape[:-1]
     y = int8_gemv(x.reshape(-1, x.shape[-1]), w)
     return y.reshape(*lead, w.q.shape[1])
+
+
+def swiglu(y, p: dict):
+    """down(silu(gate y) * up y): the gated MLP, at whatever width the
+    three matrices `wg`, `wu`, `wd` of `p` have (models/transformer's
+    dense layers, parallel/ep's shared expert)."""
+    return qmatmul(jax.nn.silu(qmatmul(y, p["wg"])) * qmatmul(y, p["wu"]),
+                   p["wd"])

@@ -32,10 +32,12 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..obs.trace import annotate
+from ..ops.pallas_gemv import swiglu
 from ..utils.donation import donate_jit
 
 EXPERT_AXIS = "expert"
@@ -359,6 +361,106 @@ def moe_mlp_inference(x, params: dict, *, n_experts: int, top_k: int = 1):
     )                                                          # (T, E)
     y = jnp.einsum("ted,te->td", y_all, weight.astype(y_all.dtype))
     return y.astype(x.dtype)
+
+
+# Sorted (token, expert) pairs a call of the grouped products
+# (moe_held_inference): a decode tick of 64 rows sends about 30 pairs
+# here, a 32-row chunk about 16.
+_PAIR_CHUNK = 128
+
+
+def route_grouped(x, router: dict, spec):
+    """The grouped, bias-corrected router of DeepSeek-V3
+    (`scoring_func` sigmoid, `topk_method` noaux_tc), in f32 whatever
+    x is: s = sigmoid(x W_g) over ALL `spec.experts`; s' = s + bias
+    chooses and never weighs; the experts lie in `spec.groups` groups,
+    a group scores the sum of its two largest s', the `spec.top_groups`
+    best groups stay, and among their experts the `spec.top_k` largest
+    s' are taken; the weights are the chosen experts' s, normalised to
+    sum 1 and times `spec.scale`. router: `gate` (dim, experts) and
+    `bias` (experts,), f32. Returns (ids (T, k) int32, weights (T, k)
+    f32)."""
+    t = x.shape[0]
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router["gate"].astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    chooser = (s + router["bias"]).reshape(t, spec.groups, -1)
+    group_score = jnp.sum(lax.top_k(chooser, 2)[0], axis=-1)
+    _, best = lax.top_k(group_score, spec.top_groups)       # (T, top_groups)
+    kept = jnp.any(best[:, :, None] == jnp.arange(spec.groups), axis=1)
+    chooser = jnp.where(kept[:, :, None], chooser, -jnp.inf)
+    _, ids = lax.top_k(chooser.reshape(t, -1), spec.top_k)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * spec.scale
+    return ids.astype(jnp.int32), w
+
+
+def moe_held_inference(x, blk: dict, spec, valid=None):
+    """One chip's share of a routed expert layer, for INFERENCE: route
+    every token over all the layer's experts (route_grouped), compute
+    the chosen experts whose weights are HERE (`spec.held`, the ids of
+    the rows of the banks) and nothing else, add the shared expert.
+    What the absent experts would add is another chip's to compute and
+    is left out; nothing stands in for it or for the exchange.
+
+    Only chosen experts are computed: the (token, choice) pairs are
+    sorted by held expert — pairs that chose an absent expert, or
+    belong to a row outside `valid`, sort last and into no group — and
+    the three products of the gated expert MLP run as grouped matmuls
+    over the sorted rows (lax.ragged_dot: XLA's own grouped kernel on
+    the TPU), one group a held expert. Shapes are static at tokens x
+    top_k rows, the most that can land here, walked in chunks of which
+    only those that hold a pair are computed: nothing is dropped and
+    no capacity exists. A token's output depends on that token alone.
+
+    x: (T, dim); blk: `router` {gate, bias}, `experts` {wg, wu:
+    (held, dim, width), wd: (held, width, dim)}, `shared` {wg, wu, wd}
+    (ops/pallas_gemv.swiglu). Returns (y (T, dim), counts int32 [pairs
+    computed here, held experts with at least one, largest load])."""
+    t, k, bank = x.shape[0], spec.top_k, blk["experts"]
+    n = len(spec.held)
+    ids, w = route_grouped(x, blk["router"], spec)
+    local_of = np.full(spec.experts, n, np.int32)   # n = "not here"
+    local_of[list(spec.held)] = np.arange(n)
+    local = jnp.asarray(local_of)[ids]                         # (T, k)
+    if valid is not None:
+        local = jnp.where(valid[:, None], local, n)
+    # The sorted pairs are taken `chunk` rows at a time, and only the
+    # chunks that hold a pair are computed: the grouped kernel pays for
+    # every row of its tile in every group, so 512 rows of which 30 are
+    # pairs cost what 512 pairs cost (PERF.md section 6, PR 28).
+    chunk = min(t * k, _PAIR_CHUNK)
+    rows = -(-t * k // chunk) * chunk
+    flat = jnp.pad(local.reshape(t * k), (0, rows - t * k), constant_values=n)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.sum(flat[:, None] == jnp.arange(n), axis=0,
+                    dtype=jnp.int32)                           # (held,)
+    ends = jnp.cumsum(sizes)
+    xs = x[jnp.minimum(order // k, t - 1)]                     # (rows, dim)
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    def one_chunk(j, ys):
+        lo = j * chunk
+        size = (jnp.clip(ends - lo, 0, chunk)
+                - jnp.clip(ends - sizes - lo, 0, chunk))
+        xc = lax.dynamic_slice_in_dim(xs, lo, chunk)
+        h = (jax.nn.silu(lax.ragged_dot(xc, bank["wg"], size, **f32))
+             * lax.ragged_dot(xc, bank["wu"], size, **f32))
+        yc = lax.ragged_dot(h.astype(x.dtype), bank["wd"], size, **f32)
+        return lax.dynamic_update_slice_in_dim(ys, yc, lo, 0)
+
+    with annotate("ep.held_experts"):
+        ys = lax.fori_loop(0, -(-ends[-1] // chunk), one_chunk,
+                           jnp.zeros((rows, x.shape[1]), jnp.float32))
+    # Rows past the groups' end belong to no expert: whatever the
+    # grouped product left there is not read.
+    ys = jnp.where((flat[order] < n)[:, None],
+                   ys * jnp.pad(w.reshape(t * k), (0, rows - t * k))[
+                       order][:, None], 0.0)
+    y = jnp.sum(ys[jnp.argsort(order)[:t * k]].reshape(t, k, -1), axis=1)
+    y = y.astype(x.dtype) + swiglu(x, blk["shared"])
+    counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes)])
+    return y, counts
 
 
 def make_moe_layer(mesh, *, n_experts, capacity_factor=1.25, axis=EXPERT_AXIS,

@@ -87,6 +87,14 @@ from .scheduler import (
 )
 
 
+# The tick record's names for PagedKVCache.counts, in its order: token-
+# expert pairs the held experts computed and held experts with at least
+# one token (summed over the expert layers), the largest load of one
+# expert, latent cache rows the read touched (summed over the layers).
+TICK_COUNTS = ("moe_assignments", "moe_experts_hit", "moe_load_max",
+               "latent_rows_read")
+
+
 def request_record(r: Request, mode: str) -> dict:
     """One request as an obs `request` field dict — THE record shape
     report/trace consume, shared by ServeResult and FleetResult so the
@@ -707,6 +715,10 @@ class PagedEngine:
         # spans (obs.trace.PhaseSpans); None otherwise, and always for
         # the fleet, which drives the device-path methods itself.
         self._spans = None
+        # The last decode tick's counters (PagedKVCache.counts), still
+        # on the device: run() fetches them inside `record`, and only
+        # there. None for a model that counts nothing.
+        self._tick_counts = None
         if spec != "off":
             kk = spec_k
 
@@ -881,7 +893,7 @@ class PagedEngine:
         if self._spans is not None:
             self._spans.enter("tick.dispatch")
         cache, nxt = self._tick(view, self.params, *inputs)
-        self._pages = cache.pages
+        self._pages, self._tick_counts = cache.pages, cache.counts
         # The donated pools' old handles (a few per layer) go now, under
         # the device's work — not after the read below, where freeing
         # them is time the device stands idle (0.4 ms at 42 layers).
@@ -917,7 +929,7 @@ class PagedEngine:
         if self._spans is not None:
             self._spans.enter("tick.dispatch")
         cache, picks = self._spec(view, self.params, *inputs)
-        self._pages = cache.pages
+        self._pages, self._tick_counts = cache.pages, cache.counts
         del view, inputs    # as in run_decode_tick: before the read
         if self._spans is not None:
             self._spans.enter("tick.wait")
@@ -1376,6 +1388,14 @@ class PagedEngine:
                 "state_crc": state_crc,
                 "compiled": self.compiled_programs() - compiled0,
             }
+            if decoded and self._tick_counts is not None:
+                # What this tick's forward counted, all layers together
+                # (paged_cache.paged_forward): one small array that left
+                # the device beside the tokens, read here and nowhere
+                # else.
+                # mctpu: disable=MCT007
+                counted = np.asarray(self._tick_counts).tolist()
+                tick_rec.update(zip(TICK_COUNTS, counted))
             if squeezes:
                 # Pages an injected squeeze currently holds: the replay
                 # reconstruction needs it to account the pool's free
@@ -1430,6 +1450,9 @@ class PagedEngine:
                                             else 0)
                 if emitted:
                     registry.inc("serve.tokens_emitted", emitted)
+                for name in TICK_COUNTS:
+                    if name in tick_rec:
+                        registry.set(f"serve.{name}", tick_rec[name])
                 if spec_rec:
                     registry.inc("serve.spec.rounds", len(spec_rec))
                     registry.inc("serve.spec.proposed",

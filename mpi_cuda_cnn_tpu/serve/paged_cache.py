@@ -54,7 +54,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.generate import _quant_kv, attend_kv, token_forward
+from ..models.generate import (
+    _quant_kv,
+    attend_kv,
+    attend_latent,
+    token_forward,
+)
 from ..models.transformer import TransformerLM
 
 # Host-side page accounting lives in pool.py (jax-free — the policy
@@ -77,10 +82,15 @@ class PagedKVCache:
     block_table: jnp.ndarray      # (slots, pages_per_slot) int32
     page_size: int
     kernel: str = "gather"
+    # What the forward that produced this cache counted, for the tick
+    # record: int32 [expert pairs computed, held experts hit, largest
+    # expert load, latent rows the read touched]; None for a model
+    # with neither experts nor latent rows (paged_forward).
+    counts: jnp.ndarray | None = None
 
     @property
     def num_pages(self) -> int:
-        return self.pages[0]["k"].shape[0]
+        return next(iter(self.pages[0].values())).shape[0]
 
     @property
     def slots(self) -> int:
@@ -88,7 +98,7 @@ class PagedKVCache:
 
 
 jax.tree_util.register_dataclass(
-    PagedKVCache, data_fields=["pages", "block_table"],
+    PagedKVCache, data_fields=["pages", "block_table", "counts"],
     meta_fields=["page_size", "kernel"],
 )
 
@@ -115,6 +125,25 @@ def init_paged_cache(model: TransformerLM, *, slots: int, num_pages: int,
     if kernel not in _KERNELS:
         raise ValueError(f"kernel {kernel!r}: want one of {_KERNELS}")
     max_len = max_len or model.max_seq
+    table = jnp.zeros((slots, pages_for(max_len, page_size)), jnp.int32)
+    if model.attn is not None:
+        # The LATENT layout (transformer.LatentAttn): one pool a layer,
+        # one row a token — the compressed latent and the shared rotary
+        # key, written once, zero lanes up to a whole lane tile
+        # (latent_row_lanes) — and no V pool. The model chooses it;
+        # every page operation that copies pools by name (copy-on-
+        # write, handoff, spill, readmit) moves these rows as it moves
+        # K/V pages.
+        if jnp.dtype(dtype) == jnp.int8 or kernel != "gather":
+            raise ValueError(
+                "a latent-attention model's page pool is float32 or "
+                "bfloat16 rows read by the 'gather' formulation; got "
+                f"cache dtype {jnp.dtype(dtype).name}, kernel {kernel!r}")
+        pages = [{"c": jnp.zeros(
+            (num_pages, page_size, latent_row_lanes(model.attn)), dtype)}
+            for _ in range(model.depth)]
+        return PagedKVCache(pages=pages, block_table=table,
+                            page_size=page_size, kernel=kernel)
     shape = (num_pages, page_size, model.n_kv, model.head_dim)
     int8 = jnp.dtype(dtype) == jnp.int8
     sshape = shape[:-1] + (1,)
@@ -130,9 +159,54 @@ def init_paged_cache(model: TransformerLM, *, slots: int, num_pages: int,
         else:
             pages.append({"k": jnp.zeros(shape, dtype),
                           "v": jnp.zeros(shape, dtype)})
-    table = jnp.zeros((slots, pages_for(max_len, page_size)), jnp.int32)
     return PagedKVCache(pages=pages, block_table=table,
                         page_size=page_size, kernel=kernel)
+
+
+_LANES = 128    # the TPU's lane tile: a row's stride in a pool
+
+
+def latent_row_lanes(attn) -> int:
+    """The stored width of a latent row: its kv_rank + rope values and
+    zero lanes up to a multiple of the lane tile. A row-major pool is
+    padded to that stride on the TPU whatever its logical width; a
+    logical width that is NOT a multiple (576) makes the runtime's
+    default layout put the PAGE axis on the lanes instead, and every
+    program then transposes the whole pool in and out around its
+    scatter (measured: 12 of 56 ms an iteration, PERF.md section 6,
+    PR 28). Spelling the padding out keeps the pool row-major."""
+    return -(-attn.row // _LANES) * _LANES
+
+
+def _write_index(positions, valid, block_table, page_size: int):
+    """Flat (page, offset) of every token's cache row; an invalid token
+    (padding, a dead slot) is sent to scratch page 0, offset 0."""
+    page_idx = jnp.take_along_axis(block_table, positions // page_size,
+                                   axis=1)                  # (B, kk)
+    page_idx = jnp.where(valid, page_idx, 0)
+    off = jnp.where(valid, positions % page_size, 0)
+    return page_idx.reshape(-1), off.reshape(-1)
+
+
+def paged_update_attend_latent(c: dict, q, row, positions, valid,
+                               block_table, page_size: int, blk: dict,
+                               attn):
+    """paged_update_attend for the latent layout: the token's one row
+    (B, kk, 1, kv_rank + rope) is written to pool `c` (zero lanes
+    after it, latent_row_lanes), then every
+    slot's pages are gathered into (B, L, row) and read by
+    generate.attend_latent with the block's up-projections `wuk`/`wuv`.
+    Returns (o: (B, kk, H*v) f32, new_c, rows the read touched)."""
+    b, kk = positions.shape
+    pi, of = _write_index(positions, valid, block_table, page_size)
+    row = row.astype(c["c"].dtype).reshape(b * kk, -1)
+    pool = c["c"].at[pi, of].set(
+        jnp.pad(row, ((0, 0), (0, c["c"].shape[-1] - row.shape[-1]))))
+    length = block_table.shape[1] * page_size
+    rows = pool[block_table].reshape(b, length, -1)
+    mask = jnp.arange(length)[None, None, :] <= positions[:, :, None]
+    o = attend_latent(q, rows, mask, blk["wuk"], blk["wuv"], attn)
+    return o, {"c": pool}, b * length
 
 
 def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
@@ -156,12 +230,7 @@ def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
     """
     b, kk = positions.shape
     hkv, hd = k.shape[2], k.shape[3]
-    page_idx = jnp.take_along_axis(block_table, positions // page_size,
-                                   axis=1)                  # (B, kk)
-    off = positions % page_size
-    page_idx = jnp.where(valid, page_idx, 0)
-    off = jnp.where(valid, off, 0)
-    pi, of = page_idx.reshape(-1), off.reshape(-1)
+    pi, of = _write_index(positions, valid, block_table, page_size)
     int8 = c["k"].dtype == jnp.int8
     if int8:
         qk8, sk8 = _quant_kv(k)
@@ -207,20 +276,37 @@ def paged_forward(model: TransformerLM, params, toks, positions, valid,
                   cache: PagedKVCache):
     """toks (B, kk) through the model against the paged cache — the
     paged twin of decode_block's contiguous path, same token_forward
-    skeleton, attend swapped. positions/valid: (B, kk).
-    Returns (logits (B, kk, vocab) f32, new PagedKVCache)."""
+    skeleton, attend swapped (by the pool's layout: K/V heads, or
+    latent rows where the model has latent attention).
+    positions/valid: (B, kk).
+    Returns (logits (B, kk, vocab) f32, new PagedKVCache); the new
+    cache carries this forward's `counts` where the model has expert
+    layers or latent rows."""
     new_pages: list[dict] = []
+    rows_read = 0
 
     def attend(i, q, k, v):
-        o, new_c = paged_update_attend(
-            cache.pages[i], q, k, v, positions, valid,
-            cache.block_table, cache.page_size, kernel=cache.kernel,
-        )
+        nonlocal rows_read
+        if model.attn is not None:
+            o, new_c, n = paged_update_attend_latent(
+                cache.pages[i], q, k, positions, valid, cache.block_table,
+                cache.page_size, params["blocks"][i], model.attn)
+            rows_read += n
+        else:
+            o, new_c = paged_update_attend(
+                cache.pages[i], q, k, v, positions, valid,
+                cache.block_table, cache.page_size, kernel=cache.kernel,
+            )
         new_pages.append(new_c)
         return o
 
-    logits = token_forward(model, params, toks, positions, attend)
-    return logits, dataclasses.replace(cache, pages=new_pages)
+    logits, counts = token_forward(model, params, toks, positions, attend,
+                                   valid)
+    if counts is not None or rows_read:
+        counts = jnp.concatenate([
+            jnp.zeros((3,), jnp.int32) if counts is None else counts,
+            jnp.full((1,), rows_read, jnp.int32)])
+    return logits, dataclasses.replace(cache, pages=new_pages, counts=counts)
 
 
 def paged_decode_block(model: TransformerLM, params, toks, pos,

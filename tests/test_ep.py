@@ -315,23 +315,22 @@ def test_ep_dp_lm_trains(eight_devices):
 def test_dispatch_chunk_matches_unchunked_when_nothing_drops(top_k):
     """With capacity ample enough that no token drops, per-chunk routing
     assigns every token to the same expert with the same gate as
-    whole-batch routing — identical outputs (routing is per-token;
+    whole-batch routing — the same outputs (routing is per-token;
     capacity boundaries are the ONLY coupling between tokens, and the
     fused router's gate reassociation is exact — each token's expert
-    rows hold one occupied slot each). Top-1 is BITWISE (one product per
-    token); top-2 sums two products inside reductions of different
-    capacity extents, so the contraction order may differ by 1 ulp."""
+    rows hold one occupied slot each). Equal to an ulp or two, not
+    bitwise, for top-1 as for top-2: the expert matmuls and the combine
+    contract over capacity extents of 64 rows in one case and 16 in the
+    other, and the backend tiles and orders the two differently (on
+    this CPU backend 731 of 1024 top-1 elements differ, by <= 2.4e-7)."""
     p = _params()
     x = _tokens(64)
     want, want_aux = moe_mlp(x, p, n_experts=E, capacity_factor=8.0,
                              axis=None, top_k=top_k)
     got, got_aux = moe_mlp(x, p, n_experts=E, capacity_factor=8.0,
                            axis=None, top_k=top_k, dispatch_chunk=16)
-    if top_k == 1:
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    else:
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=3e-7, atol=3e-7)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=3e-7, atol=3e-7)
     # aux is the GLOBAL balance loss formed once from count/prob sums
     # accumulated across the chunk scan — the same objective as
     # unchunked routing, agreeing to float summation-order rounding
