@@ -8,16 +8,18 @@ calls (`mpi_cuda_cnn_tpu.cli.main`, `--device tpu`) and checks what
 comes out by the repo's own records (`--metrics-jsonl`):
 
 - kernels: every Pallas kernel the other phases reach (flash forward
-  and both backward kernels, paged attention, int8 GEMV), called once
-  at the smoke's shapes and compared on the device with its XLA twin
-  under `jax.default_matmul_precision("highest")`; on the chip the
-  lowered program must hold a Mosaic custom call (nothing interpreted).
+  and both backward kernels, int8 GEMV), called once at the smoke's
+  shapes and compared on the device with its XLA twin under
+  `jax.default_matmul_precision("highest")`; on the chip the lowered
+  program must hold a Mosaic custom call (nothing interpreted). And
+  the bounded paged read against the whole-table gather, at the
+  benchmark's two head layouts and pool types.
 - cnn: the source paper's path — 4 IDX files, `reference_cnn`, 60,000
   samples, batch 32 per chip, 2 scanned epochs + eval.
 - lm: `lm --dim 4096 --depth 3 --heads 32 --seq-len 2048`, bf16, 6 steps.
 - serve: `serve-bench --mode continuous` at the same width, twice: the
-  defaults (gather read, f32 cache) and the serving configuration
-  (GQA-8, auto cache/weights dtypes, Pallas paged read).
+  defaults (f32 cache) and the serving configuration (GQA-8, auto
+  cache/weights dtypes).
 
 With no arguments it needs a TPU and fails before compiling anything
 without one; `--rehearse` is the only way it runs on a CPU, at toy
@@ -51,7 +53,13 @@ FULL = dict(
             steps=6),
     serve=dict(dim=4096, depth=3, heads=32, kv_heads=8, max_seq=2048,
                slots=8, page_size=16, prompt_max=512, out_max=64,
-               requests=8, prefill_chunk=32),
+               requests=8, prefill_chunk=32,
+               # The benchmark's two cache layouts (PERF.md section 4).
+               paged=dict(
+                   chat=dict(kv_heads=32, cache_dtype="bfloat16", slots=8,
+                             max_len=2048),
+                   generation=dict(kv_heads=1, cache_dtype="int8", slots=16,
+                                   max_len=1024))),
 )
 # Same phases, same code paths, sizes a CPU finishes in seconds.
 TOY = dict(
@@ -59,7 +67,12 @@ TOY = dict(
     lm=dict(dim=32, depth=1, heads=2, seq=128, per_chip_batch=2, steps=6),
     serve=dict(dim=32, depth=1, heads=4, kv_heads=2, max_seq=64,
                slots=2, page_size=8, prompt_max=16, out_max=8,
-               requests=3, prefill_chunk=8),
+               requests=3, prefill_chunk=8,
+               paged=dict(
+                   chat=dict(kv_heads=4, cache_dtype="bfloat16", slots=4,
+                             max_len=64),
+                   generation=dict(kv_heads=1, cache_dtype="int8", slots=5,
+                                   max_len=48))),
 )
 # A tick slower than this is a compile (or a stall) inside the serving
 # window: warm-up is supposed to have compiled every program.
@@ -158,6 +171,8 @@ def phase_kernels(cfg, dev, rehearsal):
     """Each Pallas kernel vs its XLA twin, on the device. The error
     measure is max|got - want| / max|want| — absolute error normalized
     by the output's scale, so near-zero entries don't dominate."""
+    from unittest import mock
+
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -170,7 +185,7 @@ def phase_kernels(cfg, dev, rehearsal):
         int8_gemv,
         quantize_weight,
     )
-    from mpi_cuda_cnn_tpu.serve.paged_cache import paged_update_attend
+    from mpi_cuda_cnn_tpu.serve import paged_cache
 
     # Tolerances on max|got - want| / max|want|, each with its reason.
     # Every f32 bound must still fail a bf16 computation of the same
@@ -178,11 +193,11 @@ def phase_kernels(cfg, dev, rehearsal):
     # this chip: a default-precision f32 dot, which rounds its operands
     # to bf16, is 2.5e-3 off; at HIGHEST it is 3e-7 — PERF.md).
     #
-    # f32 paged attention and int8 GEMV: both sides compute to f32
-    # accuracy (the paged kernel at HIGHEST, the GEMV with x's three
-    # exact bf16 terms against the exact bf16 weight tile, the twin
-    # under "highest"), so only reduction order differs — a few 1e-7
-    # over <= 16k-term sums. 2e-5 leaves two orders of margin.
+    # the bounded paged read over int8 rows and the int8 GEMV: both
+    # sides compute to f32 accuracy (the read and its twin both under
+    # "highest", the GEMV with x's three exact bf16 terms against the
+    # exact bf16 weight tile), so only reduction order differs — a few
+    # 1e-7 over <= 16k-term sums. 2e-5 leaves two orders of margin.
     F32_TOL = 2e-5
     # f32 flash: the kernels rebuild p = exp(s - lse) from HIGHEST-
     # precision logits, and exp turns an absolute logit error (~1e-6 at
@@ -251,14 +266,21 @@ def phase_kernels(cfg, dev, rehearsal):
         for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
             compare(f"flash_{dtype}_{name}", g, w, tol)
 
-    # Paged attention at the serving engine's two program shapes (decode
-    # tick, prefill chunk), GQA, int8 pages (the serving configuration)
-    # and f32 pages (the case a hidden bf16 computation would fail).
-    h, hkv, ps = sv["heads"], sv["kv_heads"], sv["page_size"]
-    hd = sv["dim"] // h
-    per = -(-(sv["prompt_max"] + sv["out_max"]) // ps)
-    pool = sv["slots"] * per + 1
-    for dtype in ("float32", "int8"):
+    # The bounded paged read (serve/paged_cache.bounded_read, its loop
+    # forced where the table is so small that the code reads it whole)
+    # against the whole-table gather + attend_kv, at the benchmark's
+    # two head layouts and pool types (chat: MHA rows in bf16;
+    # generation: MQA rows in int8) and the engine's two program shapes
+    # (decode tick with dead slots between live ones, prefill chunk).
+    # Both under "highest", where only the order of the softmax's sums
+    # differs; then the read as the engine runs it (default precision:
+    # the MXU rounds an f32 operand to bf16) against the same twin.
+    for name, lay in sv["paged"].items():
+        h, hkv, ps, dtype = sv["heads"], lay["kv_heads"], sv["page_size"], \
+            lay["cache_dtype"]
+        hd = sv["dim"] // h
+        per = -(-lay["max_len"] // ps)
+        pool = lay["slots"] * per + 1
         rows = jnp.asarray(rng.normal(size=(2, 1, pool * ps, hkv, hd)),
                            jnp.float32)
         if dtype == "int8":
@@ -268,28 +290,44 @@ def phase_kernels(cfg, dev, rehearsal):
                  "v": qv.reshape(pool, ps, hkv, hd),
                  "vs": sv_.reshape(pool, ps, hkv, 1)}
         else:
-            c = {"k": rows[0].reshape(pool, ps, hkv, hd),
-                 "v": rows[1].reshape(pool, ps, hkv, hd)}
-        for b, kk in ((sv["slots"], 1), (1, sv["prefill_chunk"])):
+            c = {"k": rows[0].reshape(pool, ps, hkv, hd).astype(dtype),
+                 "v": rows[1].reshape(pool, ps, hkv, hd).astype(dtype)}
+        del rows
+        loop = (max(1, min(per // 4, 128 // ps)), 4)
+        for b, kk in ((lay["slots"], 1), (1, sv["prefill_chunk"])):
             q = jnp.asarray(rng.normal(size=(b, kk, h, hd)), jnp.float32)
             k, v = (jnp.asarray(rng.normal(size=(b, kk, hkv, hd)),
                                 jnp.float32) for _ in range(2))
-            table = jnp.asarray(np.stack([
+            # Every third row of the tick is dead: position 0, not
+            # valid, an all-scratch table.
+            live = np.arange(b) % 3 != 1
+            table = np.where(live[:, None], np.stack([
                 rng.choice(np.arange(1, pool), per, replace=False)
-                for _ in range(b)]), jnp.int32)
-            pos0 = rng.integers(0, per * ps - kk + 1, (b, 1))
+                for _ in range(b)]), 0).astype(np.int32)
+            pos0 = rng.integers(0, per * ps - kk + 1, (b, 1)) * live[:, None]
             positions = jnp.asarray(pos0 + np.arange(kk), jnp.int32)
-            valid = jnp.ones((b, kk), bool)
+            valid = jnp.asarray(np.broadcast_to(live[:, None], (b, kk)))
+            table = jnp.asarray(table)
 
-            def read(kernel):
-                return lambda c, q, k, v: paged_update_attend(
-                    c, q, k, v, positions, valid, table, ps,
-                    kernel=kernel)[0]
+            def read(step):
+                def f(c, q, k, v):
+                    with mock.patch.object(paged_cache, "read_step",
+                                           lambda *a: step):
+                        return paged_cache.paged_update_attend(
+                            c, q, k, v, positions, valid, table, ps)[0]
+                return f
 
-            mosaic(read("pallas"), c, q, k, v)
-            compare(f"paged_{dtype}_b{b}_kk{kk}",
-                    jax.jit(read("pallas"))(c, q, k, v),
-                    twin(read("gather"), c, q, k, v), F32_TOL)
+            want = twin(read((per, b)), c, q, k, v)
+            # bf16 rows: the loop rounds exp(s - block max) to bf16 for
+            # the second product where the gather rounds the normalised
+            # probabilities: 2^-8 a term, twice that on the output.
+            tol = 2 * 2.0 ** -8 if dtype == "bfloat16" else F32_TOL
+            compare(f"paged_{name}_b{b}_kk{kk}",
+                    twin(read(loop), c, q, k, v), want, tol)
+            # As served: one MXU pass rounds f32 queries and
+            # probabilities to bf16 (2^-9 each) on either form.
+            compare(f"paged_{name}_b{b}_kk{kk}_as_served",
+                    jax.jit(read(loop))(c, q, k, v), want, 2e-2)
 
     # int8 GEMV at the decode tick's widest matrices: the MLP pair
     # (w2's din = 4*dim is the contraction that overflowed VMEM untiled).
@@ -389,19 +427,18 @@ def phase_serve(cfg, dev, rehearsal, extra=(), tag="default"):
     check(s["watchdog_slow_ticks"] == 0,
           f"{s['watchdog_slow_ticks']} ticks slower than {WATCHDOG_MS} ms "
           "(a compile inside the serving window?)")
-    return {k: s[k] for k in ("cache_dtype", "attn_kernel", "weights_dtype",
+    return {k: s[k] for k in ("cache_dtype", "weights_dtype",
                               "output_tokens", "decode_ticks",
                               "prefill_chunks", "duration_s")}
 
 
 def phase_serve_config(cfg, dev, rehearsal):
     """README's serving configuration: GQA, auto cache/weights dtypes
-    (int8 both under GQA), the Pallas paged read."""
+    (int8 both under GQA)."""
     return phase_serve(
         cfg, dev, rehearsal, tag="serving_config",
         extra=["--kv-heads", str(cfg["serve"]["kv_heads"]),
-               "--cache-dtype", "auto", "--decode-weights-dtype", "auto",
-               "--attn-kernel", "pallas"])
+               "--cache-dtype", "auto", "--decode-weights-dtype", "auto"])
 
 
 PHASES = (

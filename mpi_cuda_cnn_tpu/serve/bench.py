@@ -411,13 +411,6 @@ def serve_bench_main(argv: list[str] | None = None) -> int:
                     help="auto routes from the banked int8 table "
                          "(VERDICT 7): int8 for GQA/MQA, bfloat16 "
                          "for MHA (models/generate.pick_cache_dtype)")
-    ap.add_argument("--attn-kernel", default="gather",
-                    choices=["gather", "pallas"],
-                    help="paged-attention read (ISSUE 12): gather = "
-                         "the XLA formulation; pallas = the fused "
-                         "ops/pallas_paged_attention kernel (pages "
-                         "stream HBM->VMEM; bitwise vs gather in f32, "
-                         "<=1e-5 in bf16/int8; interpret mode on CPU)")
     ap.add_argument("--decode-weights-dtype", default="float32",
                     choices=["float32", "bfloat16", "int8", "auto"],
                     help="decode GEMV weights storage (ISSUE 12): int8 "
@@ -682,7 +675,6 @@ def serve_bench_main(argv: list[str] | None = None) -> int:
         model, params, slots=args.slots, num_pages=pages,
         page_size=args.page_size, prefill_chunk=args.prefill_chunk,
         cache_dtype=cache_dtype, max_len=max_len,
-        attn_kernel=args.attn_kernel,
         weights_dtype=args.decode_weights_dtype,
         spec=args.spec, spec_k=args.spec_k, spec_ngram=args.spec_ngram,
         draft_model=draft_model, draft_params=draft_params,
@@ -842,7 +834,6 @@ def serve_bench_main(argv: list[str] | None = None) -> int:
             metrics.log("serve", **{
                 "bench": "serve", "backend": jax.default_backend(),
                 "cache_dtype": cache_dtype, "rate": args.rate,
-                "attn_kernel": args.attn_kernel,
                 "weights_dtype": engine.weights_dtype,
                 "spec": args.spec, "spec_k": args.spec_k,
                 "slots": args.slots, "page_size": args.page_size,
@@ -863,8 +854,7 @@ def serve_bench_main(argv: list[str] | None = None) -> int:
             print(json.dumps({"bench": "serve", "backend":
                               jax.default_backend(),
                               "cache_dtype": cache_dtype,
-                              "attn_kernel": args.attn_kernel,
-                              "weights_dtype": engine.weights_dtype,
+                                            "weights_dtype": engine.weights_dtype,
                               "spec": args.spec, "spec_k": args.spec_k,
                               **s}))
     if alert_engine is not None:
@@ -1120,11 +1110,6 @@ def fleet_bench_main(argv: list[str] | None = None) -> int:
                     choices=["float32", "bfloat16", "int8", "auto"],
                     help="auto routes int8 for GQA/MQA, bfloat16 for "
                          "MHA (models/generate.pick_cache_dtype)")
-    ap.add_argument("--attn-kernel", default="gather",
-                    choices=["gather", "pallas"],
-                    help="paged-attention read per engine replica "
-                         "(ISSUE 12; engine compute only): gather = "
-                         "XLA, pallas = the fused kernel")
     ap.add_argument("--decode-weights-dtype", default="float32",
                     choices=["float32", "bfloat16", "int8", "auto"],
                     help="decode GEMV weights per engine replica "
@@ -1278,8 +1263,7 @@ def fleet_bench_main(argv: list[str] | None = None) -> int:
                 model, params, slots=args.slots, num_pages=pages,
                 page_size=args.page_size, prefill_chunk=args.prefill_chunk,
                 cache_dtype=args.cache_dtype, max_len=max_len,
-                attn_kernel=args.attn_kernel,
-                weights_dtype=args.decode_weights_dtype,
+                        weights_dtype=args.decode_weights_dtype,
                 spec=args.spec, spec_k=args.spec_k,
                 spec_ngram=args.spec_ngram,
             ))

@@ -87,12 +87,14 @@ from .scheduler import (
 )
 
 
-# The tick record's names for PagedKVCache.counts, in its order: token-
-# expert pairs the held experts computed and held experts with at least
-# one token (summed over the expert layers), the largest load of one
-# expert, latent cache rows the read touched (summed over the layers).
+# The tick record's names for PagedKVCache.counts: token-expert pairs
+# the held experts computed and held experts with at least one token
+# (summed over the expert layers) and the largest load of one expert,
+# where the model has expert layers; then the cache rows the read
+# touched (summed over the layers), under the name of the pool's
+# layout: latent rows, or K/V rows.
 TICK_COUNTS = ("moe_assignments", "moe_experts_hit", "moe_load_max",
-               "latent_rows_read")
+               "latent_rows_read", "kv_rows_read")
 
 
 def request_record(r: Request, mode: str) -> dict:
@@ -384,7 +386,7 @@ class PagedDraftProposer:
 
     def __init__(self, model: TransformerLM, params, *, slots: int,
                  page_size: int, max_len: int, cache_dtype=jnp.float32,
-                 chunk: int = 32, attn_kernel: str = "gather"):
+                 chunk: int = 32):
         self.model = model
         self.params = params
         self.slots = slots
@@ -392,14 +394,13 @@ class PagedDraftProposer:
         self.max_len = min(max_len, model.max_seq)
         self.table_width = pages_for(self.max_len, page_size)
         self.chunk = chunk
-        self.attn_kernel = attn_kernel
         # +1 for the reserved scratch page: full per-slot coverage, so
         # draft paging changes FLOPs, never the serving schedule.
         self.pool = PagePool(slots * self.table_width + 1)
         tmpl = init_paged_cache(model, slots=slots,
                                 num_pages=slots * self.table_width + 1,
                                 page_size=page_size, dtype=cache_dtype,
-                                max_len=self.max_len, kernel=attn_kernel)
+                                max_len=self.max_len)
         self._pages = tmpl.pages
         # Per-slot draft state, indexed by ENGINE slot idx: the rid the
         # cache rows belong to, committed rows held, physical pages.
@@ -460,8 +461,7 @@ class PagedDraftProposer:
     def _cache_view(self, table: np.ndarray) -> PagedKVCache:
         return PagedKVCache(pages=self._pages,
                             block_table=jnp.asarray(table),
-                            page_size=self.page_size,
-                            kernel=self.attn_kernel)
+                            page_size=self.page_size)
 
     def end_run(self) -> None:
         """Release every slot's draft pages and prove the draft pool
@@ -581,12 +581,10 @@ class PagedEngine:
     sizes the block table. cache_dtype composes with the shipped
     --decode-cache-dtype forms (float32 / bfloat16 / int8).
 
-    ISSUE 12 levers, both behind the ONE shared decode implementation:
-    `attn_kernel` picks the paged read — "gather" (XLA) or "pallas"
-    (the fused ops/pallas_paged_attention kernel; bitwise in f32,
-    <= 1e-5 in bf16/int8) — carried as PagedKVCache metadata so both
-    jitted programs (run_prefill_chunk / run_decode_tick) compile the
-    same choice; `weights_dtype` quantizes the decode GEMV weights ONCE
+    The paged read is chosen by the code (paged_cache.bounded_read: by
+    each slot's depth, its step from the bytes the table moves), in
+    both jitted programs (run_prefill_chunk / run_decode_tick) alike.
+    `weights_dtype` quantizes the decode GEMV weights ONCE
     at construction (ops/pallas_gemv.quantize_decode_params — int8
     per-channel absmax, bf16 cast, or f32 pass-through; "auto" routes
     via generate.pick_weights_dtype, the pick_cache_dtype twin).
@@ -595,7 +593,7 @@ class PagedEngine:
     def __init__(self, model: TransformerLM, params, *, slots: int = 4,
                  num_pages: int = 64, page_size: int = 16,
                  prefill_chunk: int = 32, cache_dtype="float32",
-                 max_len: int | None = None, attn_kernel: str = "gather",
+                 max_len: int | None = None,
                  weights_dtype: str = "float32", spec: str = "off",
                  spec_k: int = 8, spec_ngram: int = 2,
                  draft_model: TransformerLM | None = None,
@@ -632,7 +630,9 @@ class PagedEngine:
             weights_dtype, heads=model.heads, kv_heads=model.n_kv)
         # One-time conversion: the hot loop only ever reads this form.
         self.params = quantize_decode_params(params, self.weights_dtype)
-        self.attn_kernel = attn_kernel
+        # Chooses nothing: read by benchmarks/compile_only.py, and goes
+        # with that line (PagedKVCache.kernel).
+        self.attn_kernel = "gather"
         if isinstance(cache_dtype, str) and cache_dtype == "auto":
             # VERDICT item 7: route the storage dtype from the banked
             # measurements — int8 for GQA/MQA, bfloat16 for MHA.
@@ -642,7 +642,7 @@ class PagedEngine:
         self.max_len = min(max_len or model.max_seq, model.max_seq)
         tmpl = init_paged_cache(model, slots=slots, num_pages=num_pages,
                                 page_size=page_size, dtype=self.cache_dtype,
-                                max_len=self.max_len, kernel=attn_kernel)
+                                max_len=self.max_len)
         self._pages = tmpl.pages
         self._table_width = tmpl.block_table.shape[1]
 
@@ -717,7 +717,7 @@ class PagedEngine:
         self._spans = None
         # The last decode tick's counters (PagedKVCache.counts), still
         # on the device: run() fetches them inside `record`, and only
-        # there. None for a model that counts nothing.
+        # there.
         self._tick_counts = None
         if spec != "off":
             kk = spec_k
@@ -738,7 +738,7 @@ class PagedEngine:
                         draft_model, dparams, slots=slots,
                         page_size=page_size, max_len=self.max_len,
                         cache_dtype=self.cache_dtype,
-                        chunk=prefill_chunk, attn_kernel=attn_kernel)
+                        chunk=prefill_chunk)
                 else:
                     self._draft_proposer = DraftProposer(
                         draft_model, dparams, batch=slots)
@@ -748,8 +748,7 @@ class PagedEngine:
     def _cache_view(self, table: np.ndarray) -> PagedKVCache:
         return PagedKVCache(pages=self._pages,
                             block_table=jnp.asarray(table),
-                            page_size=self.page_size,
-                            kernel=self.attn_kernel)
+                            page_size=self.page_size)
 
     def _slot_table(self, slot) -> np.ndarray:
         row = np.zeros((1, self._table_width), np.int32)
@@ -1395,7 +1394,10 @@ class PagedEngine:
                 # else.
                 # mctpu: disable=MCT007
                 counted = np.asarray(self._tick_counts).tolist()
-                tick_rec.update(zip(TICK_COUNTS, counted))
+                names = (*TICK_COUNTS[:3],
+                         "latent_rows_read" if self.model.attn is not None
+                         else "kv_rows_read")
+                tick_rec.update(zip(names[-len(counted):], counted))
             if squeezes:
                 # Pages an injected squeeze currently holds: the replay
                 # reconstruction needs it to account the pool's free

@@ -23,32 +23,48 @@ in one global pool:
   be re-issued to another sequence without a stale writer corrupting it.
 
 The device-side ops are pure functions of (pages, block_table): the
-scatter write + gathered read (`paged_update_attend`) and the
-generate-compatible forward (`paged_decode_block` — models/generate's
-decode_step/decode_block accept a PagedKVCache and land here). The
-attention read itself is models/generate.attend_kv, shared with the
-contiguous path — the parity tests rest on the two layouts differing
-only in how cache rows are materialized, never in the attention math.
+scatter write + the read (`paged_update_attend`) and the
+generate-compatible forward (`paged_decode_block` -- models/generate's
+decode_step/decode_block accept a PagedKVCache and land here).
 Host-side page accounting (alloc/free/ownership) is `PagePool`; policy
 (who gets pages when) lives in scheduler.py.
 
-TPU note: the gather materializes (B, L, Hkv, hd) rows per layer — the
-XLA formulation of the paged read. The fused form is
-ops/pallas_paged_attention.paged_attend (ISSUE 12; first compiled for
-the v5e in PR 21): pages stream HBM -> VMEM behind scalar-prefetched
-block tables with the Pallas pipeline double-buffering the per-page
-copies, and the gathered rows never exist outside VMEM.
-`paged_update_attend(kernel="pallas")` dispatches to it (the write
-stays shared); PagedKVCache carries the choice as static metadata so
-one engine never mixes layouts. Parity vs this gather: a few f32 ulp
-in f32 and int8, bf16's probability rounding in bf16
-(tests/test_paged_kernel.py in interpret mode on CPU; chip_smoke.py on
-the chip). Which read is faster is not measured (ROADMAP S4).
+THE READ IS BOUNDED BY WHAT EACH SLOT HOLDS (`bounded_read`, PR 29). A
+block table is as wide as `max_len`, and a gather of every slot's whole
+table costs the table's bytes whatever the slots hold: in the
+benchmark's chat cell (8 slots x 2,048 positions of bf16 MHA rows, 22%
+live, 3 of 8 slots decoding) that gather and its conversion to f32
+were two thirds of the device's busy time. So the K/V read walks a flat
+list of (slot, block of pages) items that it builds on the device from
+`positions` and `valid`, a few items a step of a `fori_loop` whose trip
+count is the list's length: a dead slot costs one block, a live one its
+depth rounded up to a block, and because the bound is a value inside
+the program the engine still compiles ONE tick and ONE prefill. Each
+item leaves its softmax statistics and the items of a slot are folded
+as an online softmax folds them; per item the arithmetic is
+models/generate.attend_kv's, the read of the contiguous cache. The
+step comes from the bytes the table moves (`read_step`): a table that
+one step covers (the benchmark's int8 MQA cell: 2 MB a pool a layer) is
+gathered whole and read by attend_kv itself, as before -- there a loop
+costs more than the rows it skips. Parity of the two forms: a few f32
+ulp in f32 and int8, the probabilities' bf16 rounding in bf16
+(tests/test_paged_kernel.py on the CPU, chip_smoke.py on the chip).
+
+What was measured on the v5e (PERF.md section 6, PR 29; one tick's
+reads at chat's shapes, 8 layers): whole-table gather 20.6 ms, bounded
+2.1 ms; the former Pallas kernel (one page a grid step over the whole
+table) 71.6 ms, slower than the gather at every shape of both cells,
+so it and the option that chose it are gone (ROADMAP D9). A step of
+the loop runs its gathers, converts and products one after the other
+at ~310 GB/s of cache bytes; a kernel that overlaps the page fetches
+with the products is what is left (ROADMAP S3). The latent layout's
+read (`paged_update_attend_latent`) still gathers whole tables.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +77,7 @@ from ..models.generate import (
     token_forward,
 )
 from ..models.transformer import TransformerLM
+from ..ops.attention import NEG_INF
 
 # Host-side page accounting lives in pool.py (jax-free — the policy
 # layer imports it without pulling this module's device stack);
@@ -72,11 +89,10 @@ from .pool import PagePool, pages_for  # noqa: F401
 class PagedKVCache:
     """Device-side paged cache state: per-layer page pools + the block
     table mapping each slot's logical positions to physical pages.
-    `page_size` is static metadata (it shapes the compiled program), as
-    is `kernel` — "gather" (the XLA formulation) or "pallas" (the fused
-    ops/pallas_paged_attention read); carrying the choice on the cache
-    keeps ONE decode implementation with a leaf-level dispatch, the
-    QuantW pattern applied to the attention read."""
+    `page_size` is static metadata (it shapes the compiled program).
+    `kernel` names the one read there is and chooses nothing: it stays
+    because benchmarks/compile_only.py passes it, and goes with that
+    line in a `benchmark` PR (PERF.md section 7)."""
 
     pages: list[dict]
     block_table: jnp.ndarray      # (slots, pages_per_slot) int32
@@ -84,8 +100,8 @@ class PagedKVCache:
     kernel: str = "gather"
     # What the forward that produced this cache counted, for the tick
     # record: int32 [expert pairs computed, held experts hit, largest
-    # expert load, latent rows the read touched]; None for a model
-    # with neither experts nor latent rows (paged_forward).
+    # expert load] where the model has expert layers, then the cache
+    # rows the read touched (paged_forward); None before any forward.
     counts: jnp.ndarray | None = None
 
     @property
@@ -102,13 +118,9 @@ jax.tree_util.register_dataclass(
     meta_fields=["page_size", "kernel"],
 )
 
-_KERNELS = ("gather", "pallas")
-
-
 def init_paged_cache(model: TransformerLM, *, slots: int, num_pages: int,
                      page_size: int, dtype=jnp.float32,
-                     max_len: int | None = None,
-                     kernel: str = "gather") -> PagedKVCache:
+                     max_len: int | None = None) -> PagedKVCache:
     """Empty page pools + an all-scratch block table.
 
     num_pages INCLUDES the reserved scratch page 0, so num_pages - 1
@@ -122,8 +134,6 @@ def init_paged_cache(model: TransformerLM, *, slots: int, num_pages: int,
         raise ValueError(f"num_pages {num_pages} < 2 (page 0 is scratch)")
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
-    if kernel not in _KERNELS:
-        raise ValueError(f"kernel {kernel!r}: want one of {_KERNELS}")
     max_len = max_len or model.max_seq
     table = jnp.zeros((slots, pages_for(max_len, page_size)), jnp.int32)
     if model.attn is not None:
@@ -134,16 +144,15 @@ def init_paged_cache(model: TransformerLM, *, slots: int, num_pages: int,
         # every page operation that copies pools by name (copy-on-
         # write, handoff, spill, readmit) moves these rows as it moves
         # K/V pages.
-        if jnp.dtype(dtype) == jnp.int8 or kernel != "gather":
+        if jnp.dtype(dtype) == jnp.int8:
             raise ValueError(
                 "a latent-attention model's page pool is float32 or "
-                "bfloat16 rows read by the 'gather' formulation; got "
-                f"cache dtype {jnp.dtype(dtype).name}, kernel {kernel!r}")
+                "bfloat16 rows; got cache dtype int8")
         pages = [{"c": jnp.zeros(
             (num_pages, page_size, latent_row_lanes(model.attn)), dtype)}
             for _ in range(model.depth)]
         return PagedKVCache(pages=pages, block_table=table,
-                            page_size=page_size, kernel=kernel)
+                            page_size=page_size)
     shape = (num_pages, page_size, model.n_kv, model.head_dim)
     int8 = jnp.dtype(dtype) == jnp.int8
     sshape = shape[:-1] + (1,)
@@ -160,7 +169,7 @@ def init_paged_cache(model: TransformerLM, *, slots: int, num_pages: int,
             pages.append({"k": jnp.zeros(shape, dtype),
                           "v": jnp.zeros(shape, dtype)})
     return PagedKVCache(pages=pages, block_table=table,
-                        page_size=page_size, kernel=kernel)
+                        page_size=page_size)
 
 
 _LANES = 128    # the TPU's lane tile: a row's stride in a pool
@@ -210,23 +219,20 @@ def paged_update_attend_latent(c: dict, q, row, positions, valid,
 
 
 def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
-                        page_size: int, kernel: str = "gather"):
+                        page_size: int):
     """One layer's paged write + attention read.
 
     q: (B, kk, H, hd); k/v: (B, kk, Hkv, hd); positions: (B, kk)
-    absolute positions; valid: (B, kk) bool — invalid tokens (padding
+    absolute positions; valid: (B, kk) bool -- invalid tokens (padding
     beyond a prompt's length, dead slots) write to scratch page 0 at
     offset 0 instead, so they can never touch a page owned by a live
     sequence. Writes land FIRST (in-chunk causality: row i then reads
-    rows <= i through the read), then the read runs per `kernel`:
-    "gather" materializes each slot's pages into (B, L, Hkv, hd) rows
-    for the shared attend_kv read; "pallas" streams the same pages
-    HBM -> VMEM inside ops/pallas_paged_attention.paged_attend (equal
-    to the gather within rounding). Either way the read is
-    masked to key positions <= the row's own position; positions beyond
-    a slot's written extent read whatever the (possibly scratch/stale)
-    rows hold — the mask keeps them out of the softmax.
-    Returns (o: (B, kk, H*hd) f32, new_c).
+    rows <= i through the read), then `bounded_read` reads each slot's
+    pages up to its deepest valid position, masked to key positions <=
+    the row's own; rows of a page past a slot's written extent hold
+    whatever they hold -- the mask keeps them out of the softmax, and
+    pages past the slot's last block are not touched at all.
+    Returns (o: (B, kk, H*hd) f32, new_c, cache rows the read touched).
     """
     b, kk = positions.shape
     hkv, hd = k.shape[2], k.shape[3]
@@ -249,27 +255,154 @@ def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
             "v": c["v"].at[pi, of].set(
                 v.astype(cdt).reshape(b * kk, hkv, hd)),
         }
-    if kernel == "pallas":
-        from ..ops.pallas_paged_attention import paged_attend
+    key_bytes = sum(int(np.prod(a.shape[2:])) * a.dtype.itemsize
+                    for a in new_c.values())
+    o, rows = bounded_read(
+        q, new_c, positions, valid, block_table, page_size=page_size,
+        step=read_step(b, block_table.shape[1], page_size, key_bytes))
+    return o, new_c, rows
 
-        o = paged_attend(q, new_c, positions, block_table, page_size)
-        return o, new_c
-    # Gather this slot's pages into contiguous logical rows. L =
-    # pages_per_slot * page_size — the engine sizes the table to the
-    # serving max_len, not to the pool (reads scale with the SEQUENCE
-    # bound; pool size only bounds total residency).
+
+# What one (slot, block) item of the bounded read moves at least, and
+# what one step of its loop moves at least, K and V together. A step of
+# the loop costs some microseconds whatever it moves (a gather, two
+# products, the carry's update: PERF.md section 6, PR 29), so it has to
+# move megabytes to cover them; a block is what a slot's read is
+# rounded up to, so it is as small as a lane tile of keys allows.
+_BLOCK_BYTES = 1 << 20
+_STEP_BYTES = 8 << 20
+
+
+def read_step(slots: int, npages: int, page_size: int,
+              key_bytes: int) -> tuple[int, int]:
+    """(pages a block, blocks a step) of the bounded read, from what
+    the table moves: `key_bytes` is one cache row, K and V and their
+    scales. A block is at least a lane tile of keys and _BLOCK_BYTES; a
+    step is as many blocks as make _STEP_BYTES, at most every block of
+    every slot -- a table so small is read whole in one step."""
+    keys = max(_LANES, -(-_BLOCK_BYTES // key_bytes))
+    per_block = min(npages, -(-keys // page_size))
+    blocks = slots * -(-npages // per_block)
+    per_step = -(-_STEP_BYTES // (per_block * page_size * key_bytes))
+    return per_block, min(blocks, per_step)
+
+
+@functools.partial(jax.jit, static_argnames=("page_size", "step"))
+def bounded_read(q, c: dict, positions, valid, block_table, *,
+                 page_size: int, step: tuple[int, int]):
+    """The attention read over one layer's page pools `c`, bounded by
+    what each slot holds; `step` = (per_block, per_step) is read_step's.
+    Jitted, so a program of many layers traces it once and not once a
+    layer (set-up time: 0.7 s of chat's two programs otherwise).
+
+    A slot's keys are read in BLOCKS of `per_block` pages, and only the
+    blocks up to its deepest valid position (a dead row, position 0:
+    one block). The (slot, block) items of all slots form one flat
+    list, built here from `positions` and `valid`; a `fori_loop` takes
+    `per_step` items a step -- gathers their pages, scores them against
+    their slot's queries, and leaves each item's softmax statistics
+    (row maximum, denominator, unnormalised output) in a buffer -- and
+    its trip count is the list's length over `per_step`: a value inside
+    the program, so one compiled program serves every depth. The items
+    of a slot are then folded as an online softmax folds them.
+    The arithmetic per item is attend_kv's: scores of `q` against keys
+    in the cache's type, f32 statistics, probabilities in the values'
+    type for the second product, int8 scales outside the products; only
+    the order of the softmax's sums differs.
+
+    Where one step covers every block of every slot (a small table),
+    the loop would run once over the whole table, and the read IS the
+    gather of the table and attend_kv.
+
+    Returns (o: (B, kk, H*hd) f32, cache rows the read touched: steps
+    taken x rows a step, int32)."""
+    b, kk, h, hd = q.shape
+    hkv = c["k"].shape[2]
+    g = h // hkv
     npages = block_table.shape[1]
-    gathered = {
-        name: new_c[name][block_table].reshape(
-            b, npages * page_size, *new_c[name].shape[2:]
-        )
-        for name in new_c
-    }
-    mask = (jnp.arange(npages * page_size)[None, None, :]
-            <= positions[:, :, None])         # (B, kk, L)
-    o = attend_kv(q, gathered["k"], gathered["v"], mask,
-                  cks=gathered.get("ks"), cvs=gathered.get("vs"))
-    return o, new_c
+    int8 = c["k"].dtype == jnp.int8
+    per_block, per_step = step
+    nblk = -(-npages // per_block)
+    if per_step >= b * nblk:
+        length = npages * page_size
+        rows = {n: c[n][block_table].reshape(b, length, *c[n].shape[2:])
+                for n in c}
+        mask = jnp.arange(length)[None, None, :] <= positions[:, :, None]
+        o = attend_kv(q, rows["k"], rows["v"], mask,
+                      cks=rows.get("ks"), cvs=rows.get("vs"))
+        return o, jnp.int32(b * length)
+
+    width = per_block * page_size                 # keys a block
+    # Each slot's blocks; its table, padded with scratch to whole blocks.
+    depth = jnp.max(jnp.where(valid, positions, 0), axis=1)
+    need = jnp.minimum(depth // width + 1, nblk)              # (B,)
+    ends = jnp.cumsum(need)
+    steps = -(-ends[-1] // per_step)
+    blocks = jnp.pad(block_table, ((0, 0), (0, nblk * per_block - npages))
+                     ).reshape(b * nblk, per_block)
+    # The flat list: item i is block i - (ends - need)[slot] of the
+    # slot whose run of items holds i. Past the list's end there is no
+    # item: what the last step computes there (scratch pages) is never
+    # folded.
+    item = jnp.arange(-(-b * nblk // per_step) * per_step)
+    slot = jnp.minimum(jnp.sum(item[:, None] >= ends[None, :], axis=1), b - 1)
+    blk = item - (ends - need)[slot]
+    live = item < ends[-1]
+    item_pages = jnp.where(live[:, None],
+                           blocks[slot * nblk + jnp.where(live, blk, 0)], 0)
+    first_key = blk * width
+    qg = q.reshape(b, kk, hkv, g, hd)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    stat = (item.shape[0], hkv, g, kk)
+
+    def take(i, carry):
+        m_buf, l_buf, o_buf = carry
+        at = i * per_step
+        sl = jax.lax.dynamic_slice_in_dim(slot, at, per_step)
+        pages = jax.lax.dynamic_slice_in_dim(item_pages, at, per_step)
+        key0 = jax.lax.dynamic_slice_in_dim(first_key, at, per_step)
+        rows = {n: c[n][pages.reshape(-1)].reshape(
+            per_step, width, *c[n].shape[2:]) for n in c}
+        logits = jnp.einsum(
+            "iqhgd,ikhd->ihgqk", qg[sl],
+            rows["k"].astype(jnp.float32) if int8 else rows["k"],
+            preferred_element_type=jnp.float32) * scale
+        if int8:
+            logits = logits * jnp.transpose(
+                rows["ks"], (0, 2, 3, 1))[:, :, None, :, :]
+        mask = ((key0[:, None, None] + jnp.arange(width)[None, None, :])
+                <= positions[sl][:, :, None])[:, None, None, :, :]
+        logits = jnp.where(mask, logits, NEG_INF)
+        m = jnp.max(logits, axis=-1)
+        p = jnp.where(mask, jnp.exp(logits - m[..., None]), 0.0)
+        if int8:
+            pv = p * jnp.transpose(rows["vs"], (0, 2, 3, 1))[:, :, None, :, :]
+            o = jnp.einsum("ihgqk,ikhd->ihgqd", pv,
+                           rows["v"].astype(jnp.float32),
+                           preferred_element_type=jnp.float32)
+        else:
+            o = jnp.einsum("ihgqk,ikhd->ihgqd", p.astype(rows["v"].dtype),
+                           rows["v"], preferred_element_type=jnp.float32)
+        put = jax.lax.dynamic_update_slice_in_dim
+        return (put(m_buf, m, at, 0), put(l_buf, jnp.sum(p, axis=-1), at, 0),
+                put(o_buf, o, at, 0))
+
+    m_buf, l_buf, o_buf = jax.lax.fori_loop(
+        0, steps, take,
+        (jnp.full(stat, NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32),
+         jnp.zeros(stat + (hd,), jnp.float32)))
+    # Fold each slot's items: block j of slot s is item (ends - need)[s]
+    # + j while j < need[s]; a block not read weighs nothing.
+    j = jnp.arange(nblk)[None, :]
+    mine = jnp.where(j < need[:, None], (ends - need)[:, None] + j, 0)
+    read = (j < need[:, None])[:, :, None, None, None]
+    m = jnp.where(read, m_buf[mine], NEG_INF)        # (B, nblk, Hkv, g, kk)
+    top = jnp.max(m, axis=1, keepdims=True)
+    w = jnp.where(read, jnp.exp(m - top), 0.0)
+    denom = jnp.sum(w * l_buf[mine], axis=1)
+    o = jnp.sum(w[..., None] * o_buf[mine], axis=1) / denom[..., None]
+    o = jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(b, kk, h * hd)
+    return o, (steps * (per_step * width)).astype(jnp.int32)
 
 
 def paged_forward(model: TransformerLM, params, toks, positions, valid,
@@ -280,8 +413,9 @@ def paged_forward(model: TransformerLM, params, toks, positions, valid,
     latent rows where the model has latent attention).
     positions/valid: (B, kk).
     Returns (logits (B, kk, vocab) f32, new PagedKVCache); the new
-    cache carries this forward's `counts` where the model has expert
-    layers or latent rows."""
+    cache carries this forward's `counts`: the expert layers' three
+    where the model has any (and zeros for a latent model without),
+    then the cache rows the read touched, all layers together."""
     new_pages: list[dict] = []
     rows_read = 0
 
@@ -291,21 +425,21 @@ def paged_forward(model: TransformerLM, params, toks, positions, valid,
             o, new_c, n = paged_update_attend_latent(
                 cache.pages[i], q, k, positions, valid, cache.block_table,
                 cache.page_size, params["blocks"][i], model.attn)
-            rows_read += n
         else:
-            o, new_c = paged_update_attend(
+            o, new_c, n = paged_update_attend(
                 cache.pages[i], q, k, v, positions, valid,
-                cache.block_table, cache.page_size, kernel=cache.kernel,
-            )
+                cache.block_table, cache.page_size)
+        rows_read += n
         new_pages.append(new_c)
         return o
 
     logits, counts = token_forward(model, params, toks, positions, attend,
                                    valid)
-    if counts is not None or rows_read:
-        counts = jnp.concatenate([
-            jnp.zeros((3,), jnp.int32) if counts is None else counts,
-            jnp.full((1,), rows_read, jnp.int32)])
+    rows_read = jnp.reshape(jnp.asarray(rows_read, jnp.int32), (1,))
+    if model.attn is not None and counts is None:
+        counts = jnp.zeros((3,), jnp.int32)
+    counts = rows_read if counts is None else jnp.concatenate(
+        [counts, rows_read])
     return logits, dataclasses.replace(cache, pages=new_pages, counts=counts)
 
 

@@ -8,15 +8,11 @@ is (B, max_seq, Hkv, D), so kv_heads < heads cuts cache reads by
 heads/kv_heads — the reason serving stacks use GQA (generate.init_cache).
 
 ISSUE 12 axes: `--paged` switches to the PAGED cache (identity block
-tables over a page pool — the serving layout) and `--kernel
-{gather,pallas}` picks the read (XLA gather vs the fused
-ops/pallas_paged_attention kernel), so kernel-on vs kernel-off is an
-A/B on an identical seeded workload; `--weights-dtype int8` turns on
-the per-channel quantized decode GEMVs (ops/pallas_gemv, quantized once
-before timing). Every paged row carries the greedy token CRC — in f32
-the kernel is bitwise vs the gather, so `mctpu compare` gates the CRCs
-at exact equality (ci/decode_gate.json, run in CI on the CPU interpret
-path).
+tables over a page pool — the serving layout; its read is
+serve/paged_cache.bounded_read); `--weights-dtype int8` turns on the
+per-channel quantized decode GEMVs (ops/pallas_gemv, quantized once
+before timing). Every paged row carries the greedy token CRC of its
+seeded workload.
 
 Timing: a generate(num_tokens=N) run costs fixed dispatch + prefill +
 N * per_token; timing N and 2N and reporting (T2N - TN)/N cancels the
@@ -107,7 +103,7 @@ def bench_decode_config(model, *, batch, prompt_len, gen_tokens,
 
 @functools.lru_cache(maxsize=16)
 def _compiled_paged_run(model, s0: int, num_tokens: int, batch: int,
-                        cache_dtype: str, kernel: str, page_size: int):
+                        cache_dtype: str, page_size: int):
     """One jitted paged prefill-block + greedy decode scan per config:
     the paged twin of generate()'s program, driven through the SAME
     decode_step dispatch the engine uses (PagedKVCache with per-slot
@@ -131,7 +127,6 @@ def _compiled_paged_run(model, s0: int, num_tokens: int, batch: int,
         cache = init_paged_cache(
             model, slots=batch, num_pages=batch * per + 1,
             page_size=page_size, dtype=cdt, max_len=max_len,
-            kernel=kernel,
         )
         cache = dataclasses.replace(cache, block_table=jnp.asarray(table))
         # Paged prefill: the whole prompt as one cached block forward
@@ -161,11 +156,9 @@ def _compiled_paged_run(model, s0: int, num_tokens: int, batch: int,
 
 
 def bench_paged_config(model, *, batch, prompt_len, gen_tokens,
-                       cache_dtype, weights_dtype, kernel, page_size,
-                       seed=0):
-    """Two-point paged decode timing + the greedy token CRC the A/B
-    gate pins (identical seeded workload across --kernel values; f32
-    kernel parity is bitwise, so the CRCs must be EQUAL)."""
+                       cache_dtype, weights_dtype, page_size, seed=0):
+    """Two-point paged decode timing + the greedy token CRC of the
+    seeded workload."""
     params = quantize_decode_params(
         model.init(jax.random.key(seed)), weights_dtype)
     rng = np.random.default_rng(seed)
@@ -175,7 +168,7 @@ def bench_paged_config(model, *, batch, prompt_len, gen_tokens,
 
     def timed(n):
         run = _compiled_paged_run(model, prompt_len, n, batch,
-                                  cache_dtype, kernel, page_size)
+                                  cache_dtype, page_size)
         t0 = time.perf_counter()
         toks = run(params, prompt)
         jax.block_until_ready(toks)
@@ -185,7 +178,7 @@ def bench_paged_config(model, *, batch, prompt_len, gen_tokens,
     # (greedy decode is deterministic — a ninth decode purely for the
     # CRC would be wasted wall-clock on the interpret path).
     run = _compiled_paged_run(model, prompt_len, gen_tokens, batch,
-                              cache_dtype, kernel, page_size)
+                              cache_dtype, page_size)
     toks = np.asarray(run(params, prompt), np.int32)
     timed(2 * gen_tokens)
     per_tok = two_point(timed, gen_tokens, warmup=0)
@@ -223,11 +216,6 @@ def main():
                     help="bench the PAGED cache (serving layout: "
                          "identity block tables over a page pool) "
                          "instead of the contiguous one")
-    ap.add_argument("--kernel", default="gather",
-                    choices=["gather", "pallas"],
-                    help="paged read (with --paged): gather = XLA, "
-                         "pallas = the fused paged-attention kernel "
-                         "(ops/pallas_paged_attention)")
     ap.add_argument("--page-size", type=int, default=16,
                     help="tokens per KV page (with --paged)")
     ap.add_argument("--device", default="auto", choices=["auto", "tpu", "cpu"])
@@ -267,11 +255,11 @@ def main():
             params=count_params(model.init(jax.random.key(0))),
         )
         if args.paged:
-            label = f"paged/{args.kernel}/" + label
+            label = "paged/" + label
             per_tok, crc, toks = bench_paged_config(
                 model, batch=args.batch, prompt_len=args.prompt,
                 gen_tokens=args.tokens, cache_dtype=args.cache_dtype,
-                weights_dtype=args.weights_dtype, kernel=args.kernel,
+                weights_dtype=args.weights_dtype,
                 page_size=args.page_size,
             )
             ok = per_tok > 0
@@ -283,7 +271,7 @@ def main():
             }
             _emit("paged_decode_tokens_per_s",
                   results[label]["decode_tokens_per_s"], "tokens/s",
-                  kernel=args.kernel, page_size=args.page_size,
+                  page_size=args.page_size,
                   decode_ms_per_tok=results[label]["decode_ms_per_tok"],
                   config=label, **common)
             # Per-config CRC row (metric name carries the kv count:
@@ -292,7 +280,7 @@ def main():
             # gateable) + the cross-config accumulator for the combined
             # headline row below.
             _emit(f"paged_greedy_crc_kv{hkv}", int(crc), "crc32",
-                  kernel=args.kernel, tokens=int(toks.size),
+                  tokens=int(toks.size),
                   batch=args.batch, gen_tokens=args.tokens,
                   page_size=args.page_size, **common)
             paged_crcs.append((hkv, toks))
@@ -331,19 +319,15 @@ def main():
               })
 
     if paged_crcs:
-        # The structural A/B row `mctpu compare` gates at exact
-        # equality (ci/decode_gate.json): ONE combined CRC over every
-        # config's greedy tokens, in kv order — a kernel divergence in
-        # ANY config changes it, so a multi-config run is as gated as a
-        # single-config one. In f32 the pallas kernel is BITWISE vs the
-        # gather, so kernel-on vs kernel-off runs must agree exactly.
+        # ONE combined CRC over every config's greedy tokens, in kv
+        # order: a divergence in ANY config changes it.
         combined = 0
         total = 0
         for _, toks in sorted(paged_crcs, key=lambda kv_: kv_[0]):
             combined = zlib.crc32(toks.tobytes(), combined)
             total += int(toks.size)
         _emit("paged_greedy_crc", int(combined), "crc32",
-              kernel=args.kernel, tokens=total,
+              tokens=total,
               configs=len(paged_crcs), batch=args.batch,
               gen_tokens=args.tokens, page_size=args.page_size,
               backend=jax.default_backend())

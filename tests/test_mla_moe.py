@@ -362,12 +362,10 @@ def test_handoff_moves_latent_rows_between_engines(served):
     assert disagg.outputs() == unified.outputs()
 
 
-@pytest.mark.parametrize("kw", [{"cache_dtype": "int8"},
-                                {"attn_kernel": "pallas"}])
-def test_what_cannot_hold_latent_rows_refuses_at_construction(served, kw):
+def test_what_cannot_hold_latent_rows_refuses_at_construction(served):
     _, _, model, params = served
     with pytest.raises(ValueError, match="latent-attention model's page"):
-        PagedEngine(model, params, **{"cache_dtype": "float32", **kw})
+        PagedEngine(model, params, cache_dtype="int8")
 
 
 def test_the_tick_record_carries_the_counters(served):
@@ -375,7 +373,9 @@ def test_the_tick_record_carries_the_counters(served):
     ticks = []
     engine(served).run(requests(dm), tick_sink=ticks.append)
     decoded = [t for t in ticks if t["decoded"]]
-    assert decoded and all(set(TICK_COUNTS) <= set(t) for t in decoded)
+    latent = set(TICK_COUNTS) - {"kv_rows_read"}
+    assert decoded and all(set(TICK_COUNTS) & set(t) == latent
+                           for t in decoded)
     assert not any(set(TICK_COUNTS) & set(t) for t in ticks
                    if not t["decoded"])
     # Dead slots route nothing: pairs <= live rows x top_k x layers.
@@ -386,7 +386,7 @@ def test_the_tick_record_carries_the_counters(served):
     assert sum(t["moe_assignments"] for t in decoded) > 0
 
 
-def test_a_kv_model_counts_nothing():
+def test_a_kv_model_counts_its_rows_and_nothing_else():
     from mpi_cuda_cnn_tpu.models.transformer import TransformerLM
 
     model = TransformerLM(vocab=64, dim=32, heads=4, depth=2, max_seq=64)
@@ -395,8 +395,13 @@ def test_a_kv_model_counts_nothing():
     ticks = []
     eng.run([Request(rid=0, prompt=np.arange(9, dtype=np.int32),
                      max_new_tokens=4)], tick_sink=ticks.append)
-    assert eng._tick_counts is None
-    assert not any(set(TICK_COUNTS) & set(t) for t in ticks)
+    decoded = [t for t in ticks if t["decoded"]]
+    # A table this small is read whole: 2 layers x 2 slots x 64 rows.
+    assert decoded and all(
+        set(TICK_COUNTS) & set(t) == {"kv_rows_read"}
+        and t["kv_rows_read"] == 2 * 2 * 64 for t in decoded)
+    assert not any(set(TICK_COUNTS) & set(t) for t in ticks
+                   if not t["decoded"])
 
 
 def test_a_latent_model_is_not_for_the_trainers_or_the_contiguous_cache(

@@ -1,10 +1,14 @@
-"""Fused Pallas paged-attention kernel + int8 decode-weight GEMVs
-(ISSUE 12): parity with the gather path — a few f32 ulp in f32, the
-1e-5 band in bf16/int8 — across MHA/GQA/MQA and decode/prefill query
-widths, the paged-layout edge cases the gather hides, and the
-quantized-weight error bound. Everything runs the real kernels in
-Pallas interpret mode on CPU (tier-1 scope); chip_smoke.py compares the
-same kernels with the same twins on the TPU.
+"""The depth-bounded paged read (serve/paged_cache.bounded_read, PR 29)
++ int8 decode-weight GEMVs (ISSUE 12): parity of the bounded read, its
+loop forced on at tiny sizes, with the whole-table gather + attend_kv —
+a few f32 ulp in f32 and int8, the probabilities' rounding in bf16 —
+across MHA/GQA/MQA and decode/prefill query widths, the paged-layout
+edge cases the gather hides, depths on every boundary, and the
+quantized-weight error bound. The GEMV runs in Pallas interpret mode on
+CPU (tier-1 scope); chip_smoke.py makes the same comparisons on the
+TPU. Until PR 29 these cases held the Pallas paged kernel to the
+gather; the kernel lost to the gather at every shape on the chip and
+went (ROADMAP D9), and each case now holds the read that replaced it.
 
 Bitwise equality is kept for what it can promise: the same program run
 twice (the masked-row poison checks below, the engine's greedy token
@@ -35,11 +39,14 @@ from mpi_cuda_cnn_tpu.ops.pallas_gemv import (
     quantize_decode_params,
     quantize_weight,
 )
+from mpi_cuda_cnn_tpu.serve import paged_cache
 from mpi_cuda_cnn_tpu.serve.engine import PagedEngine
 from mpi_cuda_cnn_tpu.serve.paged_cache import (
     init_paged_cache,
+    paged_forward,
     paged_update_attend,
     pages_for,
+    read_step,
 )
 from mpi_cuda_cnn_tpu.serve.scheduler import Request
 
@@ -51,11 +58,12 @@ MQA = TransformerLM(vocab=13, dim=32, heads=4, depth=2, max_seq=48,
 
 HEAD_CONFIGS = {"mha": 4, "gqa": 2, "mqa": 1}
 
-# The cross-formulation band (ROADMAP D8). The kernel folds pages into
-# an online-softmax carry; the gather path runs one softmax over einsums
-# whose reduction order is XLA:CPU's to choose (it changes between jax
-# versions, which is how the former bitwise gate went red with no code
-# change). Both compute in f32, so they agree to accumulated rounding:
+# The cross-formulation band (ROADMAP D8). The bounded read folds blocks
+# of pages as an online softmax does; the whole-table read runs one
+# softmax over einsums whose reduction order is XLA:CPU's to choose (it
+# changes between jax versions, which is how the former bitwise gate
+# went red with no code change). Both compute in f32, so they agree to
+# accumulated rounding:
 # 32 ulp of the output's scale (3.8e-6) is several times the drift seen
 # here and three orders tighter than a bf16 computation of the same
 # case, whose operand rounding alone is 2^-9 = 2e-3.
@@ -98,20 +106,42 @@ def _rand_case(dtype, hkv, kk, seed, *, b=3, h=4, hd=8, ps=4, per=5,
     return q, k, v, c, jnp.asarray(table), positions, ps
 
 
+# The loop at tiny sizes: blocks of 2 pages, 3 (slot, block) items a
+# step -- paged_cache.read_step itself reads tables this small whole.
+LOOP = (2, 3)
+
+
+def _whole(slots, npages, page_size, key_bytes):
+    return npages, slots
+
+
+@pytest.fixture
+def loop(monkeypatch):
+    """Every paged read traced in the test takes the loop."""
+    monkeypatch.setattr(paged_cache, "read_step", lambda *a: LOOP)
+
+
+def _read(step, c, q, k, v, positions, valid, table, ps):
+    """(output, rows read) of one layer's write + read under `step`."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(paged_cache, "read_step", step)
+        o, _, n = paged_update_attend(dict(c), q, k, v, positions, valid,
+                                      table, ps)
+    return np.asarray(o), int(n)
+
+
 def _both(q, k, v, c, table, positions, ps):
+    """(whole-table gather + attend_kv, bounded read with its loop on)."""
     valid = jnp.ones(positions.shape, bool)
-    og, _ = paged_update_attend(dict(c), q, k, v, positions, valid,
-                                table, ps, kernel="gather")
-    op, _ = paged_update_attend(dict(c), q, k, v, positions, valid,
-                                table, ps, kernel="pallas")
-    return np.asarray(og), np.asarray(op)
+    args = (c, q, k, v, positions, valid, table, ps)
+    return _read(_whole, *args)[0], _read(lambda *a: LOOP, *args)[0]
 
 
 @pytest.mark.parametrize("kk", [1, 4], ids=["decode", "chunk"])
 @pytest.mark.parametrize("head", ["mha", "gqa", "mqa"])
-def test_kernel_matches_gather_f32(head, kk):
-    """THE f32 gate: the fused kernel's output equals the gather path's
-    within the few-ulp band (F32_ULPS) — a layout/indexing bug reads a
+def test_bounded_matches_full_f32(head, kk):
+    """THE f32 gate: the bounded read's output equals the whole-table
+    read's within the few-ulp band (F32_ULPS) — an indexing bug reads a
     wrong row and lands orders of magnitude outside it. Covers the
     decode tick (kk=1) and the chunked-prefill query width (kk=4) at
     every head mapping."""
@@ -124,15 +154,16 @@ def test_kernel_matches_gather_f32(head, kk):
 @pytest.mark.parametrize("kk", [1, 4], ids=["decode", "chunk"])
 @pytest.mark.parametrize("head", ["mha", "gqa", "mqa"])
 @pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
-def test_kernel_matches_gather_quantized(dtype, head, kk):
+def test_bounded_matches_full_quantized(dtype, head, kk):
     """int8 pages: identical values and scales on both sides, scales
     applied outside the dots, everything after the convert in f32 — the
     1e-5 band of the existing quantized paged-vs-contiguous parity.
-    bf16 pages: the gather path (attend_kv) additionally rounds the
-    PROBABILITIES to bf16 for its PV contraction and the kernel keeps
-    them f32, so the two differ by that rounding — at most 2^-8
-    relative per term, i.e. 2^-8 of the value scale on the output; the
-    band is twice that (a wrong row is O(1) off)."""
+    bf16 pages: both reads round the PROBABILITIES to bf16 for the PV
+    contraction, the whole-table read after normalising them and the
+    bounded read before (exp(s - block max), folded afterwards), so
+    the two differ by that rounding — at most 2^-8 relative per term,
+    i.e. 2^-8 of the value scale on the output; the band is twice that
+    (a wrong row is O(1) off)."""
     for seed in range(3):
         want, got = _both(*_rand_case(dtype, HEAD_CONFIGS[head], kk, seed))
         if dtype == "int8":
@@ -145,33 +176,30 @@ def test_kernel_matches_gather_quantized(dtype, head, kk):
             err_msg=f"{dtype} {head} kk={kk} seed={seed}")
 
 
-def _identity_paged_cache(model, batch, page_size, dtype=jnp.float32,
-                          kernel="gather"):
+def _identity_paged_cache(model, batch, page_size, dtype=jnp.float32):
     per = pages_for(model.max_seq, page_size)
     cache = init_paged_cache(model, slots=batch,
                              num_pages=batch * per + 1,
-                             page_size=page_size, dtype=dtype,
-                             kernel=kernel)
+                             page_size=page_size, dtype=dtype)
     table = 1 + np.arange(batch * per, dtype=np.int32).reshape(batch, per)
     return dataclasses.replace(cache, block_table=jnp.asarray(table))
 
 
 @pytest.mark.parametrize("model", [MODEL, GQA], ids=["mha", "gqa_rope"])
-def test_paged_kernel_decode_step_matches_contiguous_f32(model):
-    """Transitivity of the layout contracts: kernel ~ gather (this
-    file's f32 gate) and gather == contiguous (test_serve's), so
-    decode_step over a kernel="pallas" cache must match the contiguous
-    cache through a 20-step decode, page boundaries crossed
-    mid-sequence — logits within the same band (the per-layer drift
-    passes through two blocks and the head, all f32)."""
+def test_bounded_decode_step_matches_contiguous_f32(model, loop):
+    """Transitivity of the layout contracts: bounded ~ whole-table (this
+    file's f32 gate) and whole-table == contiguous (test_serve's), so
+    decode_step over a paged cache read by the loop must match the
+    contiguous cache through a 20-step decode, page, block and step
+    boundaries crossed mid-sequence — logits within the same band (the
+    per-layer drift passes through two blocks and the head, all f32)."""
     params = model.init(jax.random.key(0))
     toks = jnp.asarray(
         np.random.default_rng(1).integers(0, 13, (3, 20)), jnp.int32
     )
     cc = init_cache(model, 3)
-    pc = _identity_paged_cache(model, 3, page_size=8, kernel="pallas")
-    # One jitted program per layout, traced once: un-jitted, every step
-    # re-traces and recompiles the interpreted kernel.
+    pc = _identity_paged_cache(model, 3, page_size=8)
+    # One jitted program per layout, traced once.
     step = jax.jit(lambda tok, pos, cache: decode_step(
         model, params, tok, pos, cache))
     for i in range(20):
@@ -184,8 +212,8 @@ def test_slot_extent_ending_mid_page():
     """A slot whose extent ends mid-page must mask the page's written
     tail out of the softmax: corrupting rows BEYOND the slot's position
     (same page, later offsets) changes nothing; corrupting the position
-    row itself does. The gather hides this case behind XLA's masked
-    reads — the kernel's VMEM strip must reproduce it."""
+    row itself does, in the whole-table read and in a block of the
+    bounded one alike."""
     q, k, v, c, table, _, ps = _rand_case("float32", 2, 1, 7)
     # DISJOINT tables for this test: the poison targets one slot's page
     # tail, so no other slot may share that physical page.
@@ -216,7 +244,7 @@ def test_scratch_page_never_read():
     """Block-table columns beyond a slot's live pages hold 0 — the
     scratch page. Its contents are masked out of every softmax, so
     poisoning page 0 with huge finite values must not move any output
-    (kernel and gather alike). This is the page-0 contract the pool
+    (both reads alike). This is the page-0 contract the pool
     invariants assume."""
     q, k, v, c, table, _, ps = _rand_case("float32", 2, 1, 11)
     # Short extents: positions inside page 1 of 5, so table columns
@@ -233,9 +261,9 @@ def test_scratch_page_never_read():
 
 
 def test_cow_private_page_read_after_copy():
-    """The COW discipline (ISSUE 9) on the kernel path: after a page is
-    copied src -> dst and the slot's table repointed at dst, the kernel
-    must read the COPY — later writes to the shared source must not
+    """The COW discipline (ISSUE 9) on both reads: after a page is
+    copied src -> dst and the slot's table repointed at dst, the read
+    must see the COPY — later writes to the shared source must not
     leak into the reader. Mirrors engine.copy_page's per-layer
     .at[dst].set(c[src]) exactly."""
     q, k, v, c, table, positions, ps = _rand_case("float32", 2, 1, 13)
@@ -258,15 +286,15 @@ def test_cow_private_page_read_after_copy():
     np.testing.assert_array_equal(want_after, want_before)
 
 
-def test_preempted_then_resumed_slot_kernel_on():
-    """Recompute preemption under a starved pool, with the fused kernel
-    serving every read: the resumed slot re-prefills into DIFFERENT
+def test_preempted_then_resumed_slot_loop_on(loop):
+    """Recompute preemption under a starved pool, with the bounded
+    read's loop serving every read: the resumed slot re-prefills into DIFFERENT
     physical pages, and its greedy stream must still equal generate()'s
     — the block-table indirection is the only thing that changed."""
     params = MODEL.init(jax.random.key(1))
     rng = np.random.default_rng(5)
     engine = PagedEngine(MODEL, params, slots=3, num_pages=10, page_size=4,
-                         prefill_chunk=8, max_len=40, attn_kernel="pallas")
+                         prefill_chunk=8, max_len=40)
     prompts = [rng.integers(0, 13, (6,)).astype(np.int32) for _ in range(5)]
     want = [np.asarray(generate(MODEL, params, jnp.asarray(p[None, :]),
                                 18))[0] for p in prompts]
@@ -279,11 +307,12 @@ def test_preempted_then_resumed_slot_kernel_on():
                                       err_msg=f"request {r.rid}")
 
 
-def test_randomized_block_table_fuzz_kernel_equals_gather():
+def test_randomized_block_table_fuzz_bounded_equals_full():
     """Seeded fuzz over the block-table space: random pool sizes, page
     sizes, table permutations (slots may SHARE pages — the prefix-
-    sharing read pattern), ragged per-slot depths, MHA/GQA/MQA — kernel
-    vs gather inside the f32 band (F32_ULPS), every draw."""
+    sharing read pattern), ragged per-slot depths, MHA/GQA/MQA — the
+    bounded read vs the whole-table one inside the f32 band (F32_ULPS),
+    every draw."""
     rng = np.random.default_rng(1234)
     for trial in range(12):
         hkv = int(rng.choice([1, 2, 4]))
@@ -312,12 +341,195 @@ def test_randomized_block_table_fuzz_kernel_equals_gather():
                                      f"per={per} b={b} kk={kk}")
 
 
+def _boundary_case(dtype, hkv, kk, *, ps=4, per=24, h=4, hd=8, seed=3):
+    """Slots whose depths sit on every boundary of the LOOP geometry
+    (blocks of 2 pages = 8 keys, 3 items a step), dead rows between
+    live ones, one slot at max_len. Returns the call's inputs over a
+    CLEAN pool and the same pool with garbage wherever no read may
+    look: huge finite values in the rows of a slot's last block past
+    its depth (read and masked), NaN in every page past that block
+    (never read: a NaN row times a zero probability is NaN)."""
+    rng = np.random.default_rng(seed)
+    width = LOOP[0] * ps
+    length = per * ps
+    # first position of each slot's rows; None = a dead row
+    starts = [0, None, ps - 1, ps, ps + 1, None, width - 1, width,
+              width + 1, None, None, 3 * width - 1, 3 * width,
+              length - kk - ps, length - kk]
+    starts = [None if p is None else max(0, min(p, length - kk))
+              for p in starts]
+    b = len(starts)
+    pool = b * per + 1
+    live = np.array([p is not None for p in starts])
+    pos0 = np.array([p or 0 for p in starts])
+    positions = pos0[:, None] + np.arange(kk)[None, :]
+    table = np.where(live[:, None],
+                     1 + np.arange(b * per).reshape(b, per), 0)
+    q = jnp.asarray(rng.normal(size=(b, kk, h, hd)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(b, kk, hkv, hd)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(b, kk, hkv, hd)), jnp.float32)
+    kv = rng.normal(size=(2, pool * ps, hkv, hd)).astype(np.float32)
+    dirty = kv.copy()
+    for i in np.flatnonzero(live):
+        depth = positions[i, -1]
+        rows = table[i, :, None] * ps + np.arange(ps)[None, :]
+        rows = rows.reshape(-1)                 # the slot's rows in order
+        last = (depth // width + 1) * width
+        dirty[:, rows[depth + 1:last]] = 1e30
+        dirty[:, rows[last:]] = np.nan
+
+    def pools(rows):
+        if dtype == "int8":
+            out = {}
+            for name, r in zip("kv", rows):
+                bad = ~np.isfinite(r) | (np.abs(r) > 1e20)
+                qr, sr = _quant_kv(jnp.asarray(np.where(bad, 0.0, r))[None])
+                mark = jnp.asarray(bad.any(axis=-1, keepdims=True))[None]
+                nan = jnp.asarray(np.isnan(r).any(axis=-1, keepdims=True))
+                sr = jnp.where(mark, jnp.where(nan[None], jnp.nan, 1e30), sr)
+                qr = jnp.where(mark, jnp.int8(127), qr)
+                out[name] = qr.reshape(pool, ps, hkv, hd)
+                out[name + "s"] = sr.reshape(pool, ps, hkv, 1)
+            return out
+        return {name: jnp.asarray(r, dtype).reshape(pool, ps, hkv, hd)
+                for name, r in zip("kv", rows)}
+
+    args = (q, k, v, jnp.asarray(positions, jnp.int32),
+            jnp.asarray(np.broadcast_to(live[:, None], (b, kk))),
+            jnp.asarray(table, jnp.int32), ps)
+    return pools(kv), pools(dirty), args, live, positions
+
+
+@pytest.mark.parametrize("kk", [1, 32], ids=["decode", "chunk32"])
+@pytest.mark.parametrize("head", ["mha", "gqa", "mqa"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
-def test_engine_greedy_matches_generate_kernel_on(dtype):
-    """End-to-end engine-vs-generate greedy equality with the fused
-    kernel serving both jitted programs (prefill chunks AND decode
+def test_bounded_read_on_every_boundary_ignores_what_it_may_not_touch(
+        dtype, head, kk):
+    """Depths at 0, one under / at / one over a page, a block and a
+    step boundary, a slot at max_len, dead rows between live ones: the
+    bounded read over a pool full of garbage past every slot's depth
+    equals the whole-table read over the clean pool, in the band of its
+    type (F32_ULPS in f32; int8's 1e-5; bf16's probability rounding,
+    2 x 2^-8 of the scale), and it touched no block past a slot's
+    depth: rows read = steps x rows a step, by hand."""
+    clean, dirty, args, live, positions = _boundary_case(
+        dtype, HEAD_CONFIGS[head], kk)
+    want, n_whole = _read(_whole, clean, *args)
+    got, n = _read(lambda *a: LOOP, dirty, *args)
+    per_block, per_step = LOOP
+    ps, width = args[-1], per_block * args[-1]
+    assert n_whole == len(live) * args[-2].shape[1] * ps
+    items = sum(int(positions[i, -1]) // width + 1 if live[i] else 1
+                for i in range(len(live)))
+    assert n == -(-items // per_step) * per_step * width
+    assert n < n_whole / 2
+    want, got = want[live], got[live]           # a dead row's output is
+    assert np.isfinite(got).all()               # nobody's
+    if dtype == "float32":
+        _assert_f32_close(got, want)
+    elif dtype == "int8":
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    else:
+        np.testing.assert_allclose(
+            got, want, rtol=0,
+            atol=2 * 2.0 ** -8 * max(1.0, float(np.max(np.abs(want)))))
+
+
+def test_the_step_comes_from_the_bytes_the_table_moves():
+    """read_step at the benchmark's two tables (PERF.md section 4):
+    chat's bf16 MHA rows (16 KB a key, 268 MB a layer) are read in
+    blocks of 128 keys, 4 a step; generation's int8 MQA rows (264 B a
+    key, 4 MB a layer) in one step, which is the whole-table read. A
+    longer table of the same rows takes the loop."""
+    chat = read_step(8, 128, 16, 2 * 32 * 128 * 2)
+    assert chat == (8, 4) and chat[1] < 8 * (128 // chat[0])
+    per_block, per_step = read_step(16, 64, 16, 2 * (128 + 4))
+    assert per_block == 64 and per_step >= 16         # every block at once
+    per_block, per_step = read_step(16, 512, 16, 2 * (128 + 4))
+    assert per_step < 16 * -(-512 // per_block)
+
+
+def test_kv_rows_read_is_the_hand_count_on_three_slots(loop):
+    """paged_forward's count over a three-slot tick: slot 0 at position
+    17 (3 blocks of 8 keys), slot 1 dead (1), slot 2 at 8 (2): 6 items
+    = 2 steps of 3, 8 keys each, in each of the model's 2 layers."""
+    params = MODEL.init(jax.random.key(0))
+    cache = init_paged_cache(MODEL, slots=3, num_pages=3 * 12 + 1,
+                             page_size=4)
+    table = 1 + np.arange(36, dtype=np.int32).reshape(3, 12)
+    table[1] = 0
+    cache = dataclasses.replace(cache, block_table=jnp.asarray(table))
+    _, cache = paged_forward(
+        MODEL, params, jnp.zeros((3, 1), jnp.int32),
+        jnp.asarray([[17], [0], [8]], jnp.int32),
+        jnp.asarray([[True], [False], [True]]), cache)
+    assert np.asarray(cache.counts).tolist() == [2 * 2 * 3 * 8]
+
+
+def _mixed_requests(rng, lens, new):
+    return [Request(rid=i, prompt=rng.integers(0, 13, (n,)).astype(np.int32),
+                    max_new_tokens=m) for i, (n, m) in enumerate(zip(lens, new))]
+
+
+def test_depths_cross_every_step_boundary_in_one_tick_and_one_prefill(loop):
+    """The bound is a value inside the program: a run whose slots grow
+    from 0 to max_len, across every page, block and step boundary,
+    compiles nothing after the engine's first tick and first chunk,
+    and its tick records carry `kv_rows_read` where a tick decoded --
+    never more than the whole table, and less while slots are short."""
+    params = MODEL.init(jax.random.key(2))
+    eng = PagedEngine(MODEL, params, slots=3, num_pages=3 * 12 + 1,
+                      page_size=4, prefill_chunk=8, max_len=48)
+    rng = np.random.default_rng(11)
+    eng.run(_mixed_requests(rng, [3], [2]))              # compiles both
+    warm = eng.compiled_programs()
+    assert warm == 2
+    ticks = []
+    res = eng.run(_mixed_requests(rng, [1, 9, 30, 17, 5], [47, 39, 18, 8, 3]),
+                  tick_sink=ticks.append)
+    assert res.status_counts() == {"finished": 5}
+    assert eng.compiled_programs() == warm
+    assert all(t["compiled"] == 0 for t in ticks)
+    rows = [t["kv_rows_read"] for t in ticks if t["decoded"]]
+    assert rows and all("kv_rows_read" not in t for t in ticks
+                        if not t["decoded"])
+    whole = MODEL.depth * 3 * 48
+    assert max(rows) <= whole + MODEL.depth * 2 * 8 and min(rows) < whole / 2
+    assert len(set(rows)) > 3                            # it follows depth
+
+
+def test_counts_are_fetched_by_a_sink_and_by_nothing_else(loop):
+    """With no sink and no registry the tick's counts stay on the
+    device: run() never turns them into host values."""
+    class NotForTheHost:
+        def __array__(self, *a, **kw):
+            raise AssertionError("the tick's counts were fetched")
+
+    params = MODEL.init(jax.random.key(2))
+    eng = PagedEngine(MODEL, params, slots=2, num_pages=2 * 12 + 1,
+                      page_size=4, prefill_chunk=8, max_len=48)
+    tick = eng._tick
+
+    def counted(*args):
+        cache, nxt = tick(*args)
+        return dataclasses.replace(cache, counts=NotForTheHost()), nxt
+
+    counted._cache_size = tick._cache_size
+    eng._tick = counted
+    rng = np.random.default_rng(12)
+    res = eng.run(_mixed_requests(rng, [5, 9], [4, 6]))
+    assert res.status_counts() == {"finished": 2}
+    with pytest.raises(AssertionError, match="counts were fetched"):
+        eng.run(_mixed_requests(rng, [5], [4]), tick_sink=lambda t: None)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
+def test_engine_greedy_matches_generate_loop_on(dtype, loop):
+    """End-to-end engine-vs-generate greedy equality with the bounded
+    read's loop in both jitted programs (prefill chunks AND decode
     ticks), across cache dtypes and both scheduler modes — the same
-    acceptance the gather path holds in test_serve.py."""
+    acceptance the whole-table read of a small table holds in
+    test_serve.py."""
     params = MODEL.init(jax.random.key(0))
     rng = np.random.default_rng(7)
     prompts = [rng.integers(0, 13, (n,)).astype(np.int32)
@@ -329,8 +541,7 @@ def test_engine_greedy_matches_generate_kernel_on(dtype):
         for p, n in zip(prompts, new)
     ]
     engine = PagedEngine(MODEL, params, slots=2, num_pages=4 * 6 + 1,
-                         page_size=8, prefill_chunk=4, cache_dtype=dtype,
-                         attn_kernel="pallas")
+                         page_size=8, prefill_chunk=4, cache_dtype=dtype)
     for mode in ("continuous", "static"):
         reqs = [Request(rid=i, prompt=p, max_new_tokens=n)
                 for i, (p, n) in enumerate(zip(prompts, new))]
@@ -341,8 +552,8 @@ def test_engine_greedy_matches_generate_kernel_on(dtype):
                 err_msg=f"{mode} request {r.rid} ({dtype})")
 
 
-def test_engine_vs_generate_with_both_levers_on():
-    """THE both-levers acceptance: Pallas paged read + int8 decode
+def test_engine_vs_generate_with_both_levers_on(loop):
+    """THE both-levers acceptance: the bounded read's loop + int8 decode
     weights in the engine, against generate() running the SAME
     quantized params over the contiguous cache — greedy streams equal
     per request (one forward implementation, two storage formats)."""
@@ -357,7 +568,7 @@ def test_engine_vs_generate_with_both_levers_on():
             for p, n in zip(prompts, new)]
     engine = PagedEngine(GQA, params, slots=2, num_pages=4 * 6 + 1,
                          page_size=8, prefill_chunk=4, cache_dtype="int8",
-                         attn_kernel="pallas", weights_dtype="int8")
+                         weights_dtype="int8")
     assert engine.weights_dtype == "int8"
     assert isinstance(engine.params["head"], QuantW)
     reqs = [Request(rid=i, prompt=p, max_new_tokens=n)
@@ -485,11 +696,8 @@ def test_pick_weights_dtype_routing_shares_table_with_cache():
     assert pick_cache_dtype("auto", heads=8, kv_heads=None) == "bfloat16"
 
 
-def test_bad_kernel_and_weights_dtype_rejected():
+def test_bad_weights_dtype_rejected():
     params = MODEL.init(jax.random.key(0))
-    with pytest.raises(ValueError, match="kernel"):
-        init_paged_cache(MODEL, slots=1, num_pages=4, page_size=4,
-                         kernel="fused")
     with pytest.raises(ValueError, match="decode weights dtype"):
         PagedEngine(MODEL, params, slots=1, num_pages=4, page_size=4,
                     weights_dtype="fp8")

@@ -71,7 +71,6 @@ def test_chip_smoke_rehearsal_passes_on_cpu():
     assert set(phases) == {"kernels", "cnn", "lm", "serve_default",
                            "serve_serving_config"}
     assert all(p["ok"] for p in phases.values())
-    assert phases["serve_serving_config"]["attn_kernel"] == "pallas"
     assert phases["serve_serving_config"]["weights_dtype"] == "int8"
     assert lines[-1]["ok"] is True
     assert lines[-1]["device"] == {"platform": "cpu", "kind": "cpu",
