@@ -2,7 +2,9 @@
 
 The decode-side counterpart of the scanned-epoch training design — see
 paged_cache.py (the memory layout), scheduler.py (the admission /
-preemption policy), engine.py (the jitted ticks), bench.py (the
+preemption policy), core.py (the ONE serving iteration, jax-free, that
+engine.run and the fleet's replicas both drive), engine.py (the jitted
+ticks and the single-engine driver), bench.py (the
 `mctpu serve-bench` / `mctpu fleet-bench` harnesses), router.py (the
 fleet's dispatch/health/fencing policy), fleet.py (N replicas behind
 the router, failure-aware re-dispatch — ISSUE 7), prefix_cache.py (the
@@ -13,9 +15,8 @@ prefill/decode pools' crash-safe page-granular KV transfer protocol —
 ISSUE 13; fleet.py drives it, engine.adopt_pages is the device copy),
 spec.py (batched speculative decoding's jax-free policy half —
 ISSUE 14: prompt-lookup proposal, the greedy acceptance law, the round
-scaffold engine.run and ReplicaCore.step share; the engine compiles
-the batched verify block, the scheduler owns the acceptance-aware page
-accounting).
+scaffold the serving iteration runs; the engine compiles the batched
+verify block, the scheduler owns the acceptance-aware page accounting).
 """
 
 from .engine import PagedEngine, ServeResult

@@ -17,10 +17,9 @@ A speculative engine (ISSUE 14: spec="lookup"/"draft") compiles ONE
 additional program, the batched verify block — every slot's k candidate
 rows at per-slot positions through the same paged_forward, rows past a
 slot's round width riding along valid=False. serve/spec.py owns the
-jax-free policy half (proposal, greedy acceptance, the round scaffold
-shared with the fleet's ReplicaCore); the scheduler owns the
-acceptance-aware page accounting (opportunistic growth toward k,
-rejected-draft page rollback at commit).
+jax-free policy half (proposal, greedy acceptance, the round
+scaffold); the scheduler owns the acceptance-aware page accounting
+(opportunistic growth toward k, rejected-draft page rollback at commit).
 
 Both donate the page pools, so the cache updates in place across ticks
 (utils/donation discipline; the pool is the engine's dominant buffer).
@@ -28,13 +27,16 @@ Sampling is greedy — the serving benches measure schedule/memory
 effects, and greedy keeps static-vs-continuous token streams bitwise
 comparable per request.
 
-The host loop (`run`) is one scheduler iteration per pass: sweep
-deadlines/cancellations -> enforce the queue bound -> admit -> at most
-one prefill chunk -> one decode tick over every decoding slot.
-Interleaving the single chunk between ticks bounds how long a long
-prompt can stall token emission for in-flight sequences (the Orca
-iteration-level property); `decode_ticks`/`prefill_chunks` counts are
-the deterministic cost model the CPU tests compare schedulers on.
+The host loop (`run`) is a driver: each pass it fires faults, runs ONE
+serving iteration — serve/core.py's ServeCore, the body the fleet's
+replicas step too: sweep deadlines/cancellations -> admit -> enforce
+the queue bound -> at most one prefill chunk -> one decode tick over
+every decoding slot — then idles or raises, watches the iteration's
+time, and records it. Interleaving the single chunk between ticks
+bounds how long a long prompt can stall token emission for in-flight
+sequences (the Orca iteration-level property);
+`decode_ticks`/`prefill_chunks` counts are the deterministic cost model
+the CPU tests compare schedulers on.
 
 Failure-awareness (ISSUE 4): `run` accepts a faults.FaultInjector whose
 "serve.tick" site can squeeze the page pool (steal pages for a window
@@ -60,7 +62,8 @@ import numpy as np
 from ..models.transformer import TransformerLM
 from ..obs.trace import PhaseSpans
 from ..utils.donation import donate_jit
-from .host_tier import TIER_SPILL_SITE, HostTier
+from .core import EngineCompute, ServeCore, build_scheduler, observe_tick
+from .host_tier import TIER_SPILL_SITE
 from .paged_cache import (
     PagedKVCache,
     PagePool,
@@ -68,23 +71,9 @@ from .paged_cache import (
     paged_forward,
     pages_for,
 )
-from .prefix_cache import PrefixCache, empty_prefix_fields
-from .spec import (
-    SPEC_MODES,
-    LookupProposer,
-    empty_spec_fields,
-    run_round,
-)
-from .scheduler import (
-    ContinuousScheduler,
-    Request,
-    SLOPolicy,
-    SLOScheduler,
-    StaticScheduler,
-    scheduler_digest,
-    tenant_block,
-    terminal_fields,
-)
+from .prefix_cache import empty_prefix_fields
+from .spec import SPEC_MODES, LookupProposer, empty_spec_fields
+from .scheduler import Request, SLOPolicy, tenant_block, terminal_fields
 
 
 # The tick record's names for PagedKVCache.counts: token-expert pairs
@@ -95,6 +84,17 @@ from .scheduler import (
 # layout: latent rows, or K/V rows.
 TICK_COUNTS = ("moe_assignments", "moe_experts_hit", "moe_load_max",
                "latent_rows_read", "kv_rows_read")
+
+# The key order of run()'s tick record (`spans` closes it): the core's
+# shared fields and run()'s own, laid out as the trail's readers and the
+# checked-in samples have them.
+TICK_LAYOUT = (
+    "tick", "now", "mode", "queue", "running", "prefilling", "free_pages",
+    "backlog", "arrived", "admitted", "prefill", "decoded", "finished",
+    "aborted", "preempted", "blocked", "preempted_for", "terminal",
+    "state_crc", "compiled", *TICK_COUNTS, "squeezed", "spec",
+    "prefix_hits", "prefix", "prefix_readmits",
+)
 
 
 def request_record(r: Request, mode: str) -> dict:
@@ -274,6 +274,61 @@ def _observe_request(registry, r: Request) -> None:
         )
 
 
+def _observe_run_tick(registry, rec: dict, out, core: ServeCore) -> None:
+    """Fold one run() tick record into the registry: the shared gauges
+    and counters (core.observe_tick), then what only run() records."""
+    observe_tick(registry, rec)
+    registry.set("serve.prefilling_slots", rec["prefilling"])
+    registry.set("serve.prefill_backlog", rec["backlog"])
+    if out.emitted:
+        registry.inc("serve.tokens_emitted", out.emitted)
+    for name in TICK_COUNTS:
+        if name in rec:
+            registry.set(f"serve.{name}", rec[name])
+    for _, _, accepted in out.spec or ():
+        registry.observe("serve.spec.accepted", accepted)
+    if core.prefix is not None:
+        for key in ("cow", "evictions", "inserts"):
+            if out.prefix_tick[key]:
+                registry.inc(f"serve.prefix.{key}", out.prefix_tick[key])
+        registry.set("serve.prefix.shared_pages", core.prefix.shared_pages)
+        registry.set("serve.prefix.retained_pages",
+                     rec["prefix"]["retained_pages"])
+        if core.tier is not None:
+            # Cumulative counters are SET, not inc'd: the tier already
+            # accumulates; gauges mirror it.
+            for key, val in core.tier.stats.items():
+                registry.set(f"serve.tier.{key}", val)
+            registry.set("serve.tier.host_used", core.tier.host_used)
+    for r in out.new_fin + out.new_drop:
+        _observe_request(registry, r)
+
+
+def _fire_tick_faults(faults, pool: PagePool, squeezes: list[dict],
+                      tick_idx: int, events: list[dict]) -> None:
+    """The "serve.tick" fault site of iteration `tick_idx`: a `squeeze`
+    steals pages into `squeezes` for a window of ticks, a `slow` stalls
+    the tick; then the squeezes whose window has ended give their pages
+    back."""
+    for f in faults.fire("serve.tick", tick_idx):
+        if f.kind == "squeeze":
+            # Steal up to `pages` pages for `ticks` ticks —
+            # ownership-checked like any sequence's, so the end-of-run
+            # pool invariant still proves zero leaks with faults active.
+            owner = f"_fault_squeeze_{tick_idx}"
+            want = int(f.arg("pages", 1))
+            got = pool.try_alloc(min(want, pool.free_pages), owner) or []
+            squeezes.append({"pages": got, "owner": owner,
+                             "until": tick_idx + int(f.arg("ticks", 1))})
+        elif f.kind == "slow":
+            faults.sleep(float(f.arg("s", 0.05)))
+    events.extend(faults.drain_events())
+    for sq in [s for s in squeezes if s["until"] <= tick_idx]:
+        if sq["pages"]:
+            pool.free(sq["pages"], sq["owner"])
+        squeezes.remove(sq)
+
+
 class DraftProposer:
     """Model-draft proposal behind the LookupProposer interface
     (ISSUE 14): a cheap draft model proposes each slot's k-1 candidate
@@ -433,6 +488,13 @@ class PagedDraftProposer:
         """Slots carrying draft-cache state (the digest's lazy-state
         count — entries persist across slot release until reused)."""
         return sum(1 for r in self._rid if r is not None)
+
+    def digest_state(self) -> tuple:
+        """The draft pool's share of the per-tick state digest (ISSUE
+        17): free draft pages + slots carrying lazy draft state —
+        `mctpu replay` re-derives both from the spec round records (the
+        pages_for page law)."""
+        return (1, self.pool.free_pages, self.tracked)
 
     def _owner(self, idx: int) -> tuple:
         return ("draft", idx)
@@ -711,9 +773,10 @@ class PagedEngine:
         # engine keeps exactly its two programs.
         self._spec = None
         self._draft_proposer = None
-        # run()'s phase recorder for the length of a run that records
-        # spans (obs.trace.PhaseSpans); None otherwise, and always for
-        # the fleet, which drives the device-path methods itself.
+        # The phase recorder (obs.trace.PhaseSpans) of the driver this
+        # engine serves, told here where a dispatch and the wait for
+        # its tokens begin: run()'s for the length of a run that
+        # records spans, None otherwise.
         self._spans = None
         # The last decode tick's counters (PagedKVCache.counts), still
         # on the device: run() fetches them inside `record`, and only
@@ -770,17 +833,11 @@ class PagedEngine:
                 programs.append(draft._catchup)
         return sum(p._cache_size() for p in programs)
 
-    def _emit(self, slot, tok: int, now: float) -> None:
-        req = slot.req
-        req.out.append(tok)
-        if req.first_token_at is None:
-            req.first_token_at = now
-
     def copy_page(self, src: int, dst: int) -> None:
         """Device-side COW: duplicate page `src` into page `dst` in
         every layer's pools (keys and values, plus int8 scales). The
-        caller (engine.run / ReplicaCore.step) releases the shared
-        source's reference via scheduler.cow_complete afterwards."""
+        caller (ServeCore.work) releases the shared source's reference
+        via scheduler.cow_complete afterwards."""
         self._pages = self._copy(self._pages, jnp.int32(src),
                                  jnp.int32(dst))
 
@@ -852,11 +909,12 @@ class PagedEngine:
         the prefill). The token stays a device array so intermediate
         chunks pipeline under async dispatch: the caller converts it
         (int()) only on the COMPLETING chunk, where it is emitted.
-        Scheduler bookkeeping (slot.cached, emission) is the caller's:
-        run() and the fleet's EngineCompute (ISSUE 7) share this one
-        device path. Inside a run() that records spans, its recorder
-        (already in `prefill.build`) is told where building the inputs
-        ends and the dispatch begins."""
+        Scheduler bookkeeping (slot.cached, emission) is the caller's,
+        the serving iteration (serve/core.py) reaching this through
+        EngineCompute for run() and the fleet alike. Inside a run()
+        that records spans, its recorder (already in `prefill.build`)
+        is told where building the inputs ends and the dispatch
+        begins."""
         ctx = np.concatenate(
             [slot.req.prompt, np.asarray(slot.req.out, np.int32)]
         )
@@ -937,6 +995,53 @@ class PagedEngine:
         # mctpu: disable=MCT007
         picks = np.asarray(picks)
         return [picks[s.idx, :w] for s, _, w in rounds]
+
+    def _tick_record(self, core: ServeCore, out, *, tick: int, now: float,
+                     mode: str, arrived: list, squeezes: list[dict],
+                     compiled: int) -> dict:
+        """run()'s tick record (obs `tick` event shape) of a settled
+        step, all but its `spans`: the core's shared fields and what
+        only run() has, in TICK_LAYOUT's order."""
+        sched = core.sched
+        fields = core.tick_fields(out)
+        fields.update({
+            "tick": tick, "now": round(now, 4), "mode": mode,
+            "queue": sum(1 for r in sched.queue if r.arrival <= now),
+            "prefilling": sum(1 for s in sched.slots
+                              if s.prefilling and not s.req.terminal),
+            "backlog": sched.prefill_backlog(),
+            "arrived": arrived,
+            # Terminal detail (ISSUE 8): tenant + latency per request
+            # reaching a terminal status THIS tick — the streaming
+            # good/bad events the SLO burn-rate rules fold, emitted
+            # when they happen instead of at end of run.
+            "terminal": [terminal_fields(r)
+                         for r in out.new_fin + out.new_drop],
+            "compiled": compiled,
+        })
+        if out.decoded and self._tick_counts is not None:
+            # What this tick's forward counted, all layers together
+            # (paged_cache.paged_forward): one small array that left
+            # the device beside the tokens, read here and nowhere else.
+            # mctpu: disable=MCT007
+            counted = np.asarray(self._tick_counts).tolist()
+            names = (*TICK_COUNTS[:3],
+                     "latent_rows_read" if self.model.attn is not None
+                     else "kv_rows_read")
+            fields.update(zip(names[-len(counted):], counted))
+        if squeezes:
+            # Pages an injected squeeze currently holds: the replay
+            # reconstruction needs it to account the pool's free count
+            # (squeeze allocations have no scheduling event).
+            fields["squeezed"] = sum(len(sq["pages"]) for sq in squeezes)
+        if core.prefix is not None:
+            # The `mctpu top` cache panel's LRU gauge, second in the
+            # block: an O(tree) scan a storm's replicas do not pay.
+            shared = fields["prefix"]
+            fields["prefix"] = {
+                "shared_pages": shared["shared_pages"],
+                "retained_pages": core.prefix.retained_pages(), **shared}
+        return {k: fields[k] for k in TICK_LAYOUT if k in fields}
 
     @_closes_spans
     def run(self, requests: list[Request], *, mode: str = "continuous",
@@ -1024,11 +1129,6 @@ class PagedEngine:
                 "batching only (static is the one-token-per-tick "
                 "reservation baseline)"
             )
-        if host_pages > 0 and not prefix:
-            raise ValueError(
-                "host_pages > 0 without prefix=True — the host tier "
-                "spills prefix-cache pages; there is nothing to spill"
-            )
         if host_pages == 0 and faults is not None:
             # Inert-fault contract, tier leg (mirrors Fleet.__init__):
             # without a host tier no spill ever happens, so a tier.spill
@@ -1041,51 +1141,26 @@ class PagedEngine:
                     "host tier (--spill / host_pages > 0) — without one "
                     "they would silently never fire"
                 )
-        pool = PagePool(self.num_pages)
-        tier = None
-        if host_pages > 0:
-            tier = HostTier(
-                host_pages, spill_fn=self.spill_page,
-                readmit_fn=self.readmit_page,
-                fault_poll=((lambda seq: faults.poll(TIER_SPILL_SITE, seq))
-                            if faults is not None else None),
-            )
-        pcache = PrefixCache(pool, self.page_size, tier) if prefix else None
         proposer = None
         if spec:
             proposer = (self._draft_proposer if self.spec_mode == "draft"
                         else LookupProposer(self.spec_ngram))
-        draft_paged = isinstance(proposer, PagedDraftProposer)
-        spec_rounds = spec_proposed = spec_accepted = 0
-        sched_kw = dict(slots=self.slots, pool=pool,
-                        page_size=self.page_size, max_len=self.max_len,
-                        max_queue=max_queue, prefix=pcache)
-        if mode == "continuous":
-            if policy is not None:
-                sched = SLOScheduler(policy=policy, **sched_kw)
-            else:
-                sched = ContinuousScheduler(**sched_kw)
-        elif mode == "static":
-            if prefix or policy is not None:
-                raise ValueError(
-                    "prefix sharing / SLO policy apply to continuous "
-                    "batching only — static is the reservation baseline"
-                )
-            sched = StaticScheduler(**{**sched_kw, "prefix": None})
-        else:
-            raise ValueError(f"mode {mode!r}: want 'continuous' or 'static'")
+        sched = build_scheduler(
+            slots=self.slots, num_pages=self.num_pages,
+            page_size=self.page_size, max_len=self.max_len,
+            max_queue=max_queue, prefix=prefix, policy=policy,
+            host_pages=host_pages, mode=mode, spill_fn=self.spill_page,
+            readmit_fn=self.readmit_page,
+            tier_fault_poll=((lambda seq: faults.poll(TIER_SPILL_SITE, seq))
+                             if faults is not None else None),
+        )
+        core = ServeCore(EngineCompute(self), sched, proposer=proposer,
+                         spec_k=self.spec_k)
         sched.submit(requests)
         n_reqs = sched.unfinished
-        decode_ticks = prefill_chunks = 0
         state_chain = 0
-        # Digest framing: spec-off (0, 0), window-draft/lookup spec
-        # (1, k) — both the ISSUE-14/15 spellings, bit-for-bit. A PAGED
-        # draft (ISSUE 17) extends the tuple with its pool state per
-        # tick below; the longer frame can never alias the shorter one
-        # (state_digest length-frames the extra block).
-        spec_extra = (1, self.spec_k) if spec else (0, 0)
         events: list[dict] = []
-        failed_logged: set[int] = set()  # rids with a request_failed event
+        n_drop_seen = 0     # sched.dropped (append-only) read so far
         watchdog_slow = 0
         squeezes: list[dict] = []  # {"pages": [...], "until": tick}
         tick_idx = 0
@@ -1096,164 +1171,39 @@ class PagedEngine:
         # axis without needing the end-of-run request records.
         arrivals = sorted((r.arrival, r.rid) for r in requests)
         arr_cursor = 0
-        # Terminal-request watermarks: sched.finished / sched.dropped
-        # are append-only, so the new tail since last iteration IS this
-        # tick's terminal set — no instrumentation at the call sites.
-        n_fin_seen = n_drop_seen = 0
         t0 = time_fn()
         # Stamps below are seconds since t0. The recorder is told the
         # ones the loop reads anyway and reads the clock itself at the
         # other phase boundaries; without a consumer there is none.
-        spans = self._spans = (PhaseSpans("serve.iter", time_fn, t0)
-                               if want_ticks else None)
+        core.clock = lambda: time_fn() - t0
+        spans = core.spans = self._spans = (
+            PhaseSpans("serve.iter", time_fn, t0) if want_ticks else None)
         compiled0 = self.compiled_programs() if want_ticks else 0
         while sched.unfinished:
             iter_t0 = time_fn() - t0
             if spans is not None:
                 spans.begin(tick_idx, "schedule", iter_t0)
             if faults is not None:
-                for f in faults.fire("serve.tick", tick_idx):
-                    if f.kind == "squeeze":
-                        # Steal up to `pages` pages for `ticks` ticks —
-                        # ownership-checked like any sequence's, so the
-                        # end-of-run pool invariant still proves zero
-                        # leaks with faults active.
-                        want = int(f.arg("pages", 1))
-                        got = sched.pool.try_alloc(
-                            min(want, sched.pool.free_pages),
-                            f"_fault_squeeze_{tick_idx}",
-                        ) or []
-                        squeezes.append({
-                            "pages": got,
-                            "owner": f"_fault_squeeze_{tick_idx}",
-                            "until": tick_idx + int(f.arg("ticks", 1)),
-                        })
-                    elif f.kind == "slow":
-                        faults.sleep(float(f.arg("s", 0.05)))
-                events.extend(faults.drain_events())
-            for sq in [s for s in squeezes if s["until"] <= tick_idx]:
-                if sq["pages"]:
-                    sched.pool.free(sq["pages"], sq["owner"])
-                squeezes.remove(sq)
-            now = sched_now = time_fn() - t0
-            for r in sched.sweep(now):
+                _fire_tick_faults(faults, sched.pool, squeezes, tick_idx,
+                                  events)
+            sched_now = time_fn() - t0
+            # The engine sweeps EVERY iteration: anyone holding a
+            # Request may cancel it between two of them.
+            out = core.work(sched_now, sweep=True)
+            for r in out.swept:
                 events.append({"kind": f"request_{r.status}", "id": r.rid,
-                               "mode": mode, "t_rel": round(now, 4)})
-            admitted = [[s.idx, s.req.rid] for s in sched.admit(now)]
-            # Backpressure AFTER admission: the bound applies to what
-            # remains waiting once free slots have been filled.
-            for r in sched.enforce_queue_bound(now):
+                               "mode": mode, "t_rel": round(sched_now, 4)})
+            for r in out.rejected:
                 events.append({"kind": "request_rejected", "id": r.rid,
-                               "mode": mode, "t_rel": round(now, 4)})
-            progressed = False
-            prefill_rec = None
-
-            # At most ONE prefill chunk per iteration: long prompts
-            # advance without starving in-flight decodes.
-            if spans is not None:
-                spans.enter("prefill.build")
-            slot = sched.prefill_slot()
-            if slot is not None:
-                if slot.cow is not None:
-                    # Copy-on-write (ISSUE 9): duplicate the partially
-                    # matched shared page into the slot's private page
-                    # BEFORE its first write lands there.
-                    self.copy_page(*slot.cow)
-                    sched.cow_complete(slot)
-                n, nxt = self.run_prefill_chunk(slot)
-                slot.cached += n
-                prefill_chunks += 1
-                prefill_rec = [slot.idx, slot.req.rid, n]
-                progressed = True
-                if slot.cached >= slot.target:
-                    # Prefill complete: the full prompt's pages are now
-                    # adoptable into the prefix tree (ISSUE 9), and the
-                    # chunk's last valid logits give the first generated
-                    # token right now. A request done at its first token
-                    # releases its slot only under continuous batching —
-                    # static holds every reservation until the batch
-                    # drains (the occupancy discipline the comparison
-                    # measures).
-                    if spans is not None:
-                        # The chunk runs on the device from here to
-                        # the read: the adoption below is hidden by it.
-                        spans.enter("prefill.wait")
-                    sched.note_prefill_complete(slot)
-                    # Sanctioned sync: int() ONLY on the completing
-                    # chunk, where the token is emitted — mid-prompt
-                    # chunks pipeline the device array untouched.
-                    # mctpu: disable=MCT007
-                    first = int(nxt)
-                    now = time_fn() - t0
-                    if spans is not None:
-                        spans.enter("emit", now)
-                    self._emit(slot, first, now)
-                    prefill_rec.append("emit")  # first token at completion
-                    if slot.req.done and isinstance(sched,
-                                                    ContinuousScheduler):
-                        sched.finish(slot, time_fn() - t0)
-
-            now = time_fn() - t0
-            if spans is not None:
-                spans.enter("grow", now)
-            dslots = sched.grow_for_decode(
-                now, spec_k=self.spec_k if spec else 1)
-            decoded = [[s.idx, s.req.rid] for s in dslots]
-            for r in sched.dropped:
+                               "mode": mode, "t_rel": round(sched_now, 4)})
+            while n_drop_seen < len(sched.dropped):
                 # admit/grow_for_decode may have failed a livelocked
-                # request; log each rid once.
-                if r.status == "failed" and r.rid not in failed_logged:
-                    failed_logged.add(r.rid)
+                # request.
+                r = sched.dropped[n_drop_seen]
+                n_drop_seen += 1
+                if r.status == "failed":
                     events.append({"kind": "request_failed", "id": r.rid,
                                    "mode": mode, "reason": r.fail_reason})
-            spec_rec = None
-            emitted_decode = 0
-            if dslots and spans is not None:
-                spans.enter("tick.build")
-            if dslots and spec:
-                # Speculative round (ISSUE 14): propose per slot, ONE
-                # batched verify block, greedy acceptance — each slot
-                # commits 1..k tokens; commit_spec rolls rejected-draft
-                # pages back into the pool.
-                widths = [sched.spec_width(s, self.spec_k) for s in dslots]
-                results = run_round(dslots, widths, proposer,
-                                    self.run_spec_tick)
-                decode_ticks += 1
-                now = time_fn() - t0
-                if spans is not None:
-                    spans.enter("emit", now)
-                spec_rec = []
-                for s, w, j, toks_out in results:
-                    sched.commit_spec(s, j)
-                    for t in toks_out:
-                        self._emit(s, t, now)
-                    emitted_decode += j
-                    spec_rec.append([s.req.rid, w - 1, j - 1])
-                    spec_rounds += 1
-                    spec_proposed += w - 1
-                    spec_accepted += j - 1
-                    if registry is not None:
-                        registry.observe("serve.spec.accepted", j - 1)
-                    if s.req.done and isinstance(sched, ContinuousScheduler):
-                        sched.finish(s, now)
-                progressed = True
-            elif dslots:
-                nxt = self.run_decode_tick(dslots)
-                decode_ticks += 1
-                now = time_fn() - t0
-                if spans is not None:
-                    spans.enter("emit", now)
-                for s in dslots:
-                    s.cached += 1
-                    self._emit(s, int(nxt[s.idx]), now)
-                    if s.req.done and isinstance(sched, ContinuousScheduler):
-                        sched.finish(s, now)
-                emitted_decode = len(dslots)
-                progressed = True
-
-            if isinstance(sched, StaticScheduler) and sched.batch_done():
-                sched.drain(time_fn() - t0)
-                progressed = True
 
             # Watchdog window closes HERE: the idle branch below sleeps
             # on purpose (waiting for the next arrival / a squeeze to
@@ -1262,14 +1212,13 @@ class PagedEngine:
             now = time_fn() - t0
             busy_s = now - iter_t0
 
-            if not progressed and sched.unfinished:
+            if not out.progressed and sched.unfinished:
                 if spans is not None:
                     spans.enter("idle", now)
                 nxt_arrival = sched.next_arrival()
                 # What is due is judged by the stamp admit() judged it
                 # by: a request falling due after that read has not
                 # been refused, it has not been looked at yet.
-                now = sched_now
                 if squeezes:
                     # An injected squeeze holds the pages the next step
                     # needs (admission or decode growth): idle one tick
@@ -1277,7 +1226,7 @@ class PagedEngine:
                     sleep_fn(0.001)
                 elif nxt_arrival is None:
                     raise RuntimeError("scheduler stalled with no queue")
-                elif nxt_arrival <= now:
+                elif nxt_arrival <= sched_now:
                     raise RuntimeError(
                         f"request {sched.queue[0].rid} cannot be "
                         f"admitted into an idle engine — page pool "
@@ -1285,7 +1234,7 @@ class PagedEngine:
                         " too small"
                     )
                 else:
-                    sleep_fn(min(nxt_arrival - now, 0.05))
+                    sleep_fn(min(nxt_arrival - sched_now, 0.05))
                 if spans is not None:
                     spans.enter("bookkeep")
             elif spans is not None:
@@ -1298,40 +1247,17 @@ class PagedEngine:
                     "kind": "watchdog_slow_tick", "tick": tick_idx,
                     "mode": mode, "seconds": round(busy_s, 4),
                 })
-            # The tick record (obs `tick` event shape): this iteration's
-            # scheduling moments + end-of-iteration gauges. Terminal
-            # requests are the new tails of the append-only finished/
-            # dropped lists since last iteration. Built only when a
-            # telemetry consumer asked for it — the slot/queue scans are
-            # the cost the docstring promises a bare run never pays; the
-            # record itself is streamed, never retained (the JSONL sink
-            # is the tick store — an in-memory list would grow without
-            # bound on a long-lived serve).
-            # (victim, beneficiary) pairs: the rid list keeps the
-            # pre-ISSUE-11 tick shape, the pairs are the causal edges.
-            preempted_pairs = sched.drain_preempted()
-            preempted = [v for v, _ in preempted_pairs]
-            blocked = sched.drain_blocked()
-            prefix_tick = pcache.drain_tick() if pcache is not None else None
-            # Flight recorder (ISSUE 15): the end-of-iteration state
-            # digest, stamped on the tick record and chained into the
-            # summary's state_crc — computed on EVERY run (bare runs
-            # included: the chain is what the determinism gates pin on
-            # summary-only storms). O(slots) per tick.
-            if draft_paged:
-                # Paged-draft pool state rides the digest (ISSUE 17):
-                # free draft pages + slots carrying lazy draft state —
-                # `mctpu replay` re-derives both from the spec round
-                # records (the pages_for page law).
-                spec_extra = (1, self.spec_k, 1,
-                              proposer.pool.free_pages, proposer.tracked)
-            state_crc = scheduler_digest(sched, extra=spec_extra)
-            state_chain = zlib.crc32(state_crc.to_bytes(4, "little"),
+            # The drains and the state digest are paid on EVERY run
+            # (bare runs included: the chain is what the determinism
+            # gates pin on summary-only storms).
+            core.settle(out)
+            state_chain = zlib.crc32(out.state_crc.to_bytes(4, "little"),
                                      state_chain)
-            # The pool check is timed in `bookkeep`, but where records
-            # were asked for its failure is raised only once this
-            # iteration's record has reached the sink: the record of
-            # the iteration that broke the pool is the one to have.
+            # The engine checks the pool every iteration. The check is
+            # timed in `bookkeep`, but where records were asked for its
+            # failure is raised only once this iteration's record has
+            # reached the sink: the record of the iteration that broke
+            # the pool is the one to have.
             check_failed = None
             try:
                 sched.check()
@@ -1342,9 +1268,12 @@ class PagedEngine:
             if not want_ticks:
                 tick_idx += 1
                 continue
-            new_fin = sched.finished[n_fin_seen:]
-            new_drop = sched.dropped[n_drop_seen:]
-            n_fin_seen, n_drop_seen = len(sched.finished), len(sched.dropped)
+            # The tick record (obs `tick` event shape), built only when
+            # a telemetry consumer asked for it — the slot/queue scans
+            # are the cost the docstring promises a bare run never
+            # pays; the record itself is streamed, never retained (the
+            # JSONL sink is the tick store — an in-memory list would
+            # grow without bound on a long-lived serve).
             now = time_fn() - t0
             spans.enter("record", now)
             arrived_now = []
@@ -1352,85 +1281,10 @@ class PagedEngine:
                     arrivals[arr_cursor][0] <= now:
                 arrived_now.append(arrivals[arr_cursor][1])
                 arr_cursor += 1
-            arrived_waiting = sum(1 for r in sched.queue if r.arrival <= now)
-            running = sum(1 for s in sched.slots if not s.free)
-            prefilling = sum(1 for s in sched.slots
-                             if s.prefilling and not s.req.terminal)
-            backlog = sched.prefill_backlog()
-            tick_rec = {
-                "tick": tick_idx, "now": round(now, 4), "mode": mode,
-                "queue": arrived_waiting, "running": running,
-                "prefilling": prefilling,
-                "free_pages": sched.pool.free_pages, "backlog": backlog,
-                "arrived": arrived_now,
-                "admitted": admitted, "prefill": prefill_rec,
-                "decoded": decoded,
-                "finished": [r.rid for r in new_fin],
-                "aborted": [[r.rid, r.status] for r in new_drop],
-                "preempted": preempted,
-                # Causality (ISSUE 11): blocked admission attempts
-                # ([rid, reason, holders]) and preemption beneficiaries
-                # ([victim, for_rid]) — the blocker edges of the blame
-                # DAG `mctpu explain` reconstructs.
-                "blocked": [[rid, reason, holders]
-                            for rid, reason, holders in blocked],
-                "preempted_for": [[v, b] for v, b in preempted_pairs
-                                  if b is not None],
-                # Terminal detail (ISSUE 8): tenant + latency per request
-                # reaching a terminal status THIS tick — the streaming
-                # good/bad events the SLO burn-rate rules fold, emitted
-                # when they happen instead of at end of run.
-                "terminal": [terminal_fields(r) for r in new_fin + new_drop],
-                # Flight recorder (ISSUE 15): crc32 of the canonical
-                # host-side state after this iteration — `mctpu replay`
-                # recomputes it from the events above at every tick.
-                "state_crc": state_crc,
-                "compiled": self.compiled_programs() - compiled0,
-            }
-            if decoded and self._tick_counts is not None:
-                # What this tick's forward counted, all layers together
-                # (paged_cache.paged_forward): one small array that left
-                # the device beside the tokens, read here and nowhere
-                # else.
-                # mctpu: disable=MCT007
-                counted = np.asarray(self._tick_counts).tolist()
-                names = (*TICK_COUNTS[:3],
-                         "latent_rows_read" if self.model.attn is not None
-                         else "kv_rows_read")
-                tick_rec.update(zip(names[-len(counted):], counted))
-            if squeezes:
-                # Pages an injected squeeze currently holds: the replay
-                # reconstruction needs it to account the pool's free
-                # count (squeeze allocations have no scheduling event).
-                tick_rec["squeezed"] = sum(len(sq["pages"])
-                                           for sq in squeezes)
-            if spec_rec is not None:
-                # Speculative round detail (ISSUE 14): [rid, proposed,
-                # accepted] per slot — `mctpu trace` derives the round's
-                # emitted count (1 + accepted) from it, so the token
-                # cross-check survives variable-length commits.
-                tick_rec["spec"] = spec_rec
-            if prefix_tick is not None:
-                # Prefix-cache panel fields (ISSUE 9): this tick's hit
-                # markers ([rid, matched_tokens] — the lifecycle event
-                # `mctpu trace` renders) + cumulative stats and
-                # residency gauges for the `mctpu top` cache panel.
-                tick_rec["prefix_hits"] = prefix_tick["hits"]
-                tick_rec["prefix"] = {
-                    "shared_pages": pcache.shared_pages,
-                    "retained_pages": pcache.retained_pages(),
-                    **pcache.stats,
-                }
-                if tier is not None:
-                    # Host-tier panel fields (ISSUE 17): cumulative
-                    # spill/readmit/refusal/host-eviction counters +
-                    # occupancy in the prefix block (the `mctpu top`
-                    # cache panel / replay mirror source), plus this
-                    # tick's readmit lifecycle markers ([rid, tokens] —
-                    # the `mctpu trace` event).
-                    tick_rec["prefix"].update(tier.stats)
-                    tick_rec["prefix"]["host_used"] = tier.host_used
-                    tick_rec["prefix_readmits"] = prefix_tick["readmits"]
+            tick_rec = self._tick_record(
+                core, out, tick=tick_idx, now=now, mode=mode,
+                arrived=arrived_now, squeezes=squeezes,
+                compiled=self.compiled_programs() - compiled0)
             # `record` ends here, before the sink: what a sink costs
             # (the profiler's start among them) lies between two
             # records' spans and inside none.
@@ -1438,54 +1292,7 @@ class PagedEngine:
             if tick_sink is not None:
                 tick_sink(tick_rec)
             if registry is not None:
-                registry.set("serve.queue_depth", arrived_waiting)
-                registry.set("serve.running_slots", running)
-                registry.set("serve.prefilling_slots", prefilling)
-                registry.set("serve.free_pages", sched.pool.free_pages)
-                registry.set("serve.prefill_backlog", backlog)
-                if decoded:
-                    registry.inc("serve.decode_ticks")
-                if prefill_rec is not None:
-                    registry.inc("serve.prefill_chunks")
-                emitted = emitted_decode + (1 if prefill_rec is not None
-                                            and prefill_rec[-1] == "emit"
-                                            else 0)
-                if emitted:
-                    registry.inc("serve.tokens_emitted", emitted)
-                for name in TICK_COUNTS:
-                    if name in tick_rec:
-                        registry.set(f"serve.{name}", tick_rec[name])
-                if spec_rec:
-                    registry.inc("serve.spec.rounds", len(spec_rec))
-                    registry.inc("serve.spec.proposed",
-                                 sum(p for _, p, _ in spec_rec))
-                    registry.inc("serve.spec.accepted_total",
-                                 sum(a for _, _, a in spec_rec))
-                if preempted:
-                    registry.inc("serve.preemptions", len(preempted))
-                if prefix_tick is not None:
-                    if prefix_tick["hits"]:
-                        registry.inc("serve.prefix.hits",
-                                     len(prefix_tick["hits"]))
-                        registry.inc("serve.prefix.hit_tokens",
-                                     sum(m for _, m in prefix_tick["hits"]))
-                    for key in ("cow", "evictions", "inserts"):
-                        if prefix_tick[key]:
-                            registry.inc(f"serve.prefix.{key}",
-                                         prefix_tick[key])
-                    registry.set("serve.prefix.shared_pages",
-                                 pcache.shared_pages)
-                    registry.set("serve.prefix.retained_pages",
-                                 pcache.retained_pages())
-                    if tier is not None:
-                        # Cumulative counters are SET, not inc'd: the
-                        # tier already accumulates; gauges mirror it.
-                        for key, val in tier.stats.items():
-                            registry.set(f"serve.tier.{key}", val)
-                        registry.set("serve.tier.host_used",
-                                     tier.host_used)
-                for r in new_fin + new_drop:
-                    _observe_request(registry, r)
+                _observe_run_tick(registry, tick_rec, out, core)
             if check_failed is not None:
                 raise check_failed
             tick_idx += 1
@@ -1497,15 +1304,14 @@ class PagedEngine:
         for sq in squeezes:
             if sq["pages"]:
                 sched.pool.free(sq["pages"], sq["owner"])
-        prefix_fields = empty_prefix_fields()
-        if pcache is not None:
-            prefix_fields = pcache.summary_fields()
-            pcache.clear()
+        prefix_fields = core.prefix_stats()
+        if core.prefix is not None:
+            core.prefix.clear()
             # clear() evicts; freeze the counters at pre-flush values
             # (end-of-run teardown is not cache pressure — and it never
             # SPILLS: a run-end spill burst would land after the last
             # tick's digest, leaving tier counters no record covers).
-        if draft_paged:
+        if isinstance(proposer, PagedDraftProposer):
             # Release the draft pool and prove it clean — the draft's
             # twin of the main-pool leak check below.
             proposer.end_run()
@@ -1518,11 +1324,10 @@ class PagedEngine:
             )
         assert sched.pool.free_pages == sched.pool.usable, "pages leaked"
         return ServeResult(
-            mode=mode, requests=terminal, decode_ticks=decode_ticks,
-            prefill_chunks=prefill_chunks, preemptions=sched.preemptions,
-            duration_s=time_fn() - t0, events=events,
-            watchdog_slow_ticks=watchdog_slow, prefix=prefix_fields,
-            spec={"spec_rounds": spec_rounds, "spec_proposed": spec_proposed,
-                  "spec_accepted": spec_accepted},
+            mode=mode, requests=terminal, decode_ticks=core.decode_ticks,
+            prefill_chunks=core.prefill_chunks,
+            preemptions=sched.preemptions, duration_s=time_fn() - t0,
+            events=events, watchdog_slow_ticks=watchdog_slow,
+            prefix=prefix_fields, spec=core.spec_stats,
             state_crc=state_chain,
         )

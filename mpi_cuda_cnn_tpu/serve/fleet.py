@@ -6,8 +6,9 @@ DEATH a scheduled, tested event rather than an outage:
 
 - Each `Replica` wraps its own scheduler + PagePool (the PR-3 policy
   machinery, unchanged) and a pluggable `compute`: `EngineCompute`
-  drives a real PagedEngine's jitted prefill/decode programs (each
-  replica its own page pools — the one-chip-per-replica model), while
+  (serve/core.py) drives a real PagedEngine's jitted prefill/decode
+  programs (each replica its own page pools — the one-chip-per-replica
+  model), while
   `SimCompute` replaces the device math with a pure token function of
   (request, position) so a 10^5-request storm runs on CPU in seconds
   with the SCHEDULING — dispatch, paging, preemption, re-dispatch —
@@ -15,12 +16,13 @@ DEATH a scheduled, tested event rather than an outage:
   are a pure function of (prompt, params|salt), which is what makes
   the crash-vs-crash-free output-equality proof meaningful.
 
-- `ReplicaCore.step` is the PagedEngine.run loop body restructured as
-  one scheduler iteration (sweep -> admit -> one prefill chunk -> one
-  decode tick) so the fleet can interleave N replicas on one clock.
-  The deadline sweep is skipped on ticks where no submitted request
-  carries a deadline and no cancel is pending — the O(queue) scan is
-  what would otherwise dominate a storm.
+- `Replica.step` drives serve/core.py's `ServeCore.step` — the ONE
+  serving iteration (sweep -> admit -> one prefill chunk -> one decode
+  tick), the same body `PagedEngine.run` drives on its wall clock — so
+  the fleet can interleave N replicas on one clock. The deadline sweep
+  is skipped on ticks where no submitted request carries a deadline
+  and no cancel is pending — the O(queue) scan is what would otherwise
+  dominate a storm.
 
 - The `Fleet` loop advances a FakeClock by `tick_s` per tick; every
   decision (router policy, failure detection, backoff, fencing) is
@@ -72,7 +74,8 @@ from collections import deque
 
 from ..faults import FakeClock
 from ..obs.metrics import MetricsRegistry
-from .host_tier import TIER_SPILL_SITE, HostTier
+from .core import EngineCompute, ServeCore, build_scheduler, observe_tick
+from .host_tier import TIER_SPILL_SITE
 from .handoff import (
     Handoff,
     context_crc,
@@ -83,23 +86,20 @@ from .handoff import (
     verify_page_crcs,
 )
 from .pool import PagePool
-from .prefix_cache import PrefixCache, empty_prefix_fields
+from .prefix_cache import empty_prefix_fields
 from .router import CircuitOpen, Router, fleet_state_digest
-from .spec import LookupProposer, empty_spec_fields, run_round
+from .spec import LookupProposer, empty_spec_fields
 from .transport import TRANSPORT_SITE, TransportBus, transport_digest_tuple
 from .scheduler import (
-    ContinuousScheduler,
     Request,
-    SLOScheduler,
-    scheduler_digest,
     tenant_block,
     terminal_fields,
     validate_request,
 )
 
 __all__ = [
-    "EngineCompute", "Fleet", "FleetResult", "Replica", "ReplicaCore",
-    "SimCompute", "parse_pools",
+    "EngineCompute", "Fleet", "FleetResult", "Replica", "SimCompute",
+    "parse_pools",
 ]
 
 
@@ -182,309 +182,24 @@ class SimCompute:
         while the content copy has nothing to move."""
 
 
-class EngineCompute:
-    """Model-backed compute: one PagedEngine (its own page pools) per
-    replica; prefill/decode go through the engine's two jitted
-    programs via the same run_prefill_chunk/run_decode_tick path
-    engine.run uses — one implementation, two drivers."""
-
-    def __init__(self, engine):
-        self.engine = engine
-
-    def prefill_chunk(self, slot) -> tuple[int, int]:
-        return self.engine.run_prefill_chunk(slot)
-
-    def decode(self, dslots):
-        return self.engine.run_decode_tick(dslots)
-
-    def copy_page(self, src: int, dst: int) -> None:
-        self.engine.copy_page(src, dst)
-
-    def adopt_pages(self, src_compute, src_pages, dst_pages) -> None:
-        """Cross-engine KV page transfer (ISSUE 13): copy the sender
-        engine's page rows into this engine's pools at the destination
-        indices — the device half of the prefill->decode handoff."""
-        self.engine.adopt_pages(src_compute.engine, src_pages, dst_pages)
-
-    def verify(self, rounds):
-        """Speculative verify, engine form (ISSUE 14): the batched
-        verify program — the engine must have been constructed with
-        spec="lookup"/"draft" (the fleet bench's compute factory
-        threads --spec through)."""
-        return self.engine.run_spec_tick(rounds)
-
-
-class ReplicaCore:
-    """One replica's steppable engine loop over the PR-3 scheduler.
-
-    `on_emit(req, tok, now)` is the fleet's fenced commit hook, called
-    AFTER the token lands in the replica-local request (the local copy
-    always advances — a zombie replica keeps generating; only the
-    fence decides whether the authoritative output accepts it)."""
-
-    def __init__(self, compute, *, slots: int, num_pages: int,
-                 page_size: int, max_len: int, max_queue: int | None = None,
-                 on_emit=None, check_every: int = 1, prefix: bool = False,
-                 policy=None, spec: str = "off", spec_k: int = 8,
-                 spec_ngram: int = 2, host_pages: int = 0,
-                 tier_fault_poll=None):
-        if spec not in ("off", "lookup"):
-            # Fleet speculation is the draft-free form: a per-replica
-            # draft model is an engine-construction concern (the bench
-            # factory could thread one), and the sim storms have no
-            # draft to run — "lookup" is the serving-fleet contract.
-            raise ValueError(
-                f"fleet spec {spec!r}: want 'off' or 'lookup'")
-        self.spec = spec
-        self.spec_k = spec_k
-        self.proposer = (LookupProposer(spec_ngram) if spec != "off"
-                         else None)
-        self.spec_stats = empty_spec_fields()
-        pool = PagePool(num_pages)
-        if host_pages > 0 and not prefix:
-            raise ValueError(
-                "host_pages > 0 without prefix=True — the host tier "
-                "spills prefix-tree pages; there is nothing to spill "
-                "without the tree"
-            )
-        self.tier = None
-        # Cache-aware routing digest (ISSUE 18): the host-side set of
-        # cumulative prefix keys this replica can serve a hit from —
-        # device-tree paths plus host-tier keys, maintained
-        # incrementally by the cache/tier at their insert/readmit/
-        # evict/spill seams. Router.pick's cache_aware scoring reads it
-        # via Replica.route_keys; it is NEVER digested (replay
-        # re-applies recorded routing decisions, not pick()).
-        self.route_keys: set | None = set() if prefix else None
-        if host_pages > 0:
-            # Per-incarnation tier (ISSUE 17): it dies with the replica
-            # like its PagePool — a cold restart comes back with the
-            # host tier EMPTY, same as the device tree. Under
-            # EngineCompute the tier carries real KV payloads via the
-            # replica engine's spill/readmit programs; the sim tier is
-            # accounting-only (same schedule, no device rows).
-            engine = getattr(compute, "engine", None)
-            self.tier = HostTier(
-                host_pages,
-                spill_fn=engine.spill_page if engine is not None else None,
-                readmit_fn=(engine.readmit_page if engine is not None
-                            else None),
-                fault_poll=tier_fault_poll,
-                route_keys=self.route_keys,
-            )
-        self.prefix = (PrefixCache(pool, page_size, self.tier,
-                                   route_keys=self.route_keys)
-                       if prefix else None)
-        sched_kw = dict(slots=slots, pool=pool, page_size=page_size,
-                        max_len=max_len, max_queue=max_queue,
-                        prefix=self.prefix)
-        if policy is not None:
-            self.sched = SLOScheduler(policy=policy, **sched_kw)
-        else:
-            self.sched = ContinuousScheduler(**sched_kw)
-        self.compute = compute
-        # Precomputed digest config (ISSUE 15): built once — step()
-        # stamps a state digest per tick of a 10^5 storm.
-        self._digest_extra = ((1, spec_k) if spec != "off" else (0, 0))
-        self.on_emit = on_emit
-        # Disaggregated serving hook (ISSUE 13): called when a slot's
-        # prefill completes with decode work remaining; returning True
-        # means the fleet DETACHED the slot for a cross-pool handoff
-        # (prefill was this replica's whole job for the rid).
-        self.on_prefill_done = None
-        self.check_every = check_every
-        self.steps = 0
-        self.decode_ticks = 0
-        self.prefill_chunks = 0
-        self._cancel_pending = False
-        self._n_fin = 0
-        self._n_drop = 0
-
-    def submit(self, req: Request) -> None:
-        self.sched.submit([req])
-
-    def flag_cancel(self) -> None:
-        """A cancel() landed on one of this core's requests: force the
-        sweep on the next step even with no deadlines in play."""
-        self._cancel_pending = True
-
-    @property
-    def unfinished(self) -> int:
-        return self.sched.unfinished
-
-    def _emit(self, req: Request, tok: int, now: float) -> None:
-        req.out.append(tok)
-        if req.first_token_at is None:
-            req.first_token_at = now
-        if self.on_emit is not None:
-            self.on_emit(req, tok, now)
-
-    def step(self, now: float):
-        """One scheduler iteration (the engine.run body, minus the
-        idle/fault/watchdog handling the fleet owns). Returns
-        (tick-record fields, newly finished locals, newly dropped
-        locals) — the fleet syncs terminal statuses from the tails."""
-        sched = self.sched
-        self.steps += 1
-        progressed = False
-        if sched.has_deadlines or self._cancel_pending:
-            progressed = bool(sched.sweep(now))
-            self._cancel_pending = False
-        admitted = [[s.idx, s.req.rid] for s in sched.admit(now)]
-        if sched.max_queue is not None:
-            progressed |= bool(sched.enforce_queue_bound(now))
-        prefill_rec = None
-        slot = sched.prefill_slot()
-        if slot is not None:
-            if slot.cow is not None:
-                # COW (ISSUE 9): duplicate the partially matched shared
-                # page before the slot's first write (engine.run's rule;
-                # SimCompute's copy is accounting-only).
-                self.compute.copy_page(*slot.cow)
-                sched.cow_complete(slot)
-            n, nxt = self.compute.prefill_chunk(slot)
-            slot.cached += n
-            self.prefill_chunks += 1
-            prefill_rec = [slot.idx, slot.req.rid, n]
-            progressed = True
-            if slot.cached >= slot.target:
-                # Prefill complete: adopt the prompt's pages into the
-                # prefix tree (ISSUE 9); the first generated token is
-                # due now (TTFT at prefill completion — engine.run's
-                # rule).
-                sched.note_prefill_complete(slot)
-                # Sanctioned sync (engine.run's rule): int() only on
-                # the completing chunk, where the token is emitted.
-                # mctpu: disable=MCT007
-                self._emit(slot.req, int(nxt), now)
-                prefill_rec.append("emit")
-                if slot.req.done:
-                    sched.finish(slot, now)
-                elif (self.on_prefill_done is not None
-                        and self.on_prefill_done(self, slot, now)):
-                    # Handed off (ISSUE 13): the fleet sealed the page
-                    # set and detached the slot — decode happens on the
-                    # receiving pool's replica.
-                    pass
-        dslots = sched.grow_for_decode(
-            now, spec_k=self.spec_k if self.spec != "off" else 1)
-        decoded = [[s.idx, s.req.rid] for s in dslots]
-        spec_rec = None
-        if dslots and self.spec != "off":
-            # Speculative round (ISSUE 14): the SAME spec.run_round
-            # scaffold engine.run drives — proposal + one batched
-            # verify (compute.verify: jitted block on engine replicas,
-            # the pure token mix on sim) + greedy acceptance, with
-            # commit_spec rolling rejected-draft pages back.
-            widths = [sched.spec_width(s, self.spec_k) for s in dslots]
-            results = run_round(dslots, widths, self.proposer,
-                                self.compute.verify)
-            self.decode_ticks += 1
-            progressed = True
-            spec_rec = []
-            for s, w, j, toks_out in results:
-                sched.commit_spec(s, j)
-                for t in toks_out:
-                    self._emit(s.req, t, now)
-                spec_rec.append([s.req.rid, w - 1, j - 1])
-                self.spec_stats["spec_rounds"] += 1
-                self.spec_stats["spec_proposed"] += w - 1
-                self.spec_stats["spec_accepted"] += j - 1
-                if s.req.done:
-                    sched.finish(s, now)
-        elif dslots:
-            toks = self.compute.decode(dslots)
-            self.decode_ticks += 1
-            progressed = True
-            for s in dslots:
-                s.cached += 1
-                self._emit(s.req, int(toks[s.idx]), now)
-                if s.req.done:
-                    sched.finish(s, now)
-        preempted_pairs = sched.drain_preempted()
-        blocked = sched.drain_blocked()
-        prefix_tick = (self.prefix.drain_tick()
-                       if self.prefix is not None else None)
-        new_fin = sched.finished[self._n_fin:]
-        new_drop = sched.dropped[self._n_drop:]
-        self._n_fin, self._n_drop = len(sched.finished), len(sched.dropped)
-        if self.check_every and self.steps % self.check_every == 0:
-            sched.check()
-        rec = {
-            "queue": len(sched.queue),
-            "running": sum(1 for s in sched.slots if not s.free),
-            "free_pages": sched.pool.free_pages,
-            "admitted": admitted, "prefill": prefill_rec,
-            "decoded": decoded,
-            "preempted": [v for v, _ in preempted_pairs],
-            # Causal edges (ISSUE 11): blocked admission attempts and
-            # preemption beneficiaries, same shape as engine.run's tick
-            # record so `mctpu explain` folds both trails identically.
-            "blocked": [[rid, reason, holders]
-                        for rid, reason, holders in blocked],
-            "preempted_for": [[v, b] for v, b in preempted_pairs
-                              if b is not None],
-            "finished": [r.rid for r in new_fin],
-            "aborted": [[r.rid, r.status] for r in new_drop],
-            "progressed": progressed or bool(admitted or new_fin or new_drop),
-            # Flight recorder (ISSUE 15): this replica's end-of-step
-            # state digest — the ONE scheduler_digest spelling, stamped
-            # on every ReplicaCore tick (zombie steps included while
-            # their records still flow) and chained into the fleet
-            # summary's state_crc.
-            "state_crc": scheduler_digest(sched, extra=self._digest_extra),
-        }
-        if prefix_tick is not None:
-            rec["prefix_hits"] = prefix_tick["hits"]
-            # Cumulative tree stats (ISSUE 15): the replay
-            # reconstruction derives hit/miss counts itself and adopts
-            # the cow/insert/eviction deltas from here (both feed the
-            # digest's prefix tuple and the free-page conservation
-            # audit).
-            rec["prefix"] = {"shared_pages": self.prefix.shared_pages,
-                             **self.prefix.stats}
-            if self.tier is not None:
-                # Host-tier fields (ISSUE 17): cumulative tier counters
-                # + occupancy on the same dict, and the tick's
-                # readmission markers — engine.run's spelling, so the
-                # replay reconstruction and `mctpu trace` fold engine
-                # and fleet trails identically.
-                rec["prefix"].update(self.tier.stats)
-                rec["prefix"]["host_used"] = self.tier.host_used
-                rec["prefix_readmits"] = prefix_tick["readmits"]
-        if spec_rec is not None:
-            rec["spec"] = spec_rec
-        return rec, new_fin, new_drop
-
-    def prefix_stats(self) -> dict:
-        """Cumulative prefix counters in the flat fleet-summary shape
-        (zeros with sharing off — gated metrics exist in every run)."""
-        if self.prefix is None:
-            return empty_prefix_fields()
-        return self.prefix.summary_fields()
-
-    def reset_prefix_stats(self) -> None:
-        """Zero the counters after they were banked (retirement at
-        failover: a zombie's later activity must not re-bank)."""
-        if self.prefix is not None:
-            for k in self.prefix.stats:
-                self.prefix.stats[k] = 0
-        if self.tier is not None:
-            for k in self.tier.stats:
-                self.tier.stats[k] = 0
-
-    def reset_spec_stats(self) -> None:
-        """Spec-counter twin of reset_prefix_stats (retirement at
-        failover — a zombie's later rounds must not re-bank)."""
-        self.spec_stats = empty_spec_fields()
+# The key order of a replica's tick record between `tick`/`now`/`mode`
+# and `terminal`: the core's shared fields and the replica's `queue`,
+# laid out as the trail's readers and the checked-in samples have them.
+REPLICA_TICK_LAYOUT = (
+    "queue", "running", "free_pages", "admitted", "prefill", "decoded",
+    "preempted", "blocked", "preempted_for", "finished", "aborted",
+    "state_crc", "prefix_hits", "prefix", "prefix_readmits", "spec",
+)
 
 
 class Replica:
-    """One fleet member: a named ReplicaCore plus the PR-6 registry its
-    step loop keeps current — `load()` (what least-loaded dispatch
-    reads) is queue depth + running slots FROM THE GAUGES, plus the
-    dispatches routed here since the last step (so a burst arriving
-    within one tick spreads instead of dog-piling the stalest gauge)."""
+    """One fleet member: a named ServeCore (serve/core.py: the one
+    serving iteration) over its own scheduler and pool, plus the PR-6
+    registry its step keeps current — `load()` (what least-loaded
+    dispatch reads) is queue depth + running slots FROM THE GAUGES,
+    plus the dispatches routed here since the last step (so a burst
+    arriving within one tick spreads instead of dog-piling the stalest
+    gauge)."""
 
     def __init__(self, name: str, compute, *, slots: int, num_pages: int,
                  page_size: int, max_len: int, max_queue: int | None = None,
@@ -492,19 +207,48 @@ class Replica:
                  prefix: bool = False, policy=None, phase: str | None = None,
                  spec: str = "off", spec_k: int = 8, spec_ngram: int = 2,
                  host_pages: int = 0, tier_fault_poll=None):
+        if spec not in ("off", "lookup"):
+            # Fleet speculation is the draft-free form: a per-replica
+            # draft model is an engine-construction concern (the bench
+            # factory could thread one), and the sim storms have no
+            # draft to run — "lookup" is the serving-fleet contract.
+            raise ValueError(
+                f"fleet spec {spec!r}: want 'off' or 'lookup'")
         self.name = name
         # Pool membership of a disaggregated fleet (ISSUE 13):
         # "prefill" | "decode" | None (unified). A restarted
         # incarnation keeps its name's phase.
         self.phase = phase
         self.registry = MetricsRegistry(clock=clock)
-        self.core = ReplicaCore(
-            compute, slots=slots, num_pages=num_pages, page_size=page_size,
-            max_len=max_len, max_queue=max_queue, check_every=check_every,
-            on_emit=on_emit, prefix=prefix, policy=policy,
-            spec=spec, spec_k=spec_k, spec_ngram=spec_ngram,
-            host_pages=host_pages, tier_fault_poll=tier_fault_poll,
+        # Cache-aware routing digest (ISSUE 18): the host-side set of
+        # cumulative prefix keys this replica can serve a hit from —
+        # device-tree paths plus host-tier keys, maintained
+        # incrementally by the cache/tier at their insert/readmit/
+        # evict/spill seams. Router.pick's cache_aware scoring reads
+        # it; it is NEVER digested (replay re-applies recorded routing
+        # decisions, not pick()). None with the prefix cache off.
+        self.route_keys: set | None = set() if prefix else None
+        # Pool, tree and tier are per-incarnation (ISSUE 17): they die
+        # with the replica — a cold restart comes back with the host
+        # tier EMPTY, same as the device tree. Under EngineCompute the
+        # tier carries real KV payloads via the replica engine's
+        # spill/readmit programs; the sim tier is accounting-only (same
+        # schedule, no device rows).
+        engine = getattr(compute, "engine", None)
+        sched = build_scheduler(
+            slots=slots, num_pages=num_pages, page_size=page_size,
+            max_len=max_len, max_queue=max_queue, prefix=prefix,
+            policy=policy, host_pages=host_pages,
+            spill_fn=engine.spill_page if engine is not None else None,
+            readmit_fn=engine.readmit_page if engine is not None else None,
+            tier_fault_poll=tier_fault_poll, route_keys=self.route_keys,
         )
+        self.core = ServeCore(
+            compute, sched, spec_k=spec_k, on_emit=on_emit,
+            proposer=LookupProposer(spec_ngram) if spec != "off" else None,
+        )
+        self.check_every = check_every
+        self._cancel_pending = False
         self.alive = True
         self.zombie_until = -1   # fleet tick a partitioned zombie stops at
         self.pending_dispatches = 0
@@ -525,36 +269,33 @@ class Replica:
                 + self._gauge("serve.running_slots")
                 + self.pending_dispatches)
 
-    @property
-    def route_keys(self):
-        """The core's routing digest (ISSUE 18) — what Router.pick's
-        cache_aware scoring reads; None with the prefix cache off."""
-        return self.core.route_keys
+    def flag_cancel(self) -> None:
+        """A cancel() landed on one of this replica's requests: force
+        the sweep on the next step even with no deadlines in play."""
+        self._cancel_pending = True
 
     def step(self, now: float):
-        rec, new_fin, new_drop = self.core.step(now)
-        r = self.registry
-        r.set("serve.queue_depth", rec["queue"])
-        r.set("serve.running_slots", rec["running"])
-        r.set("serve.free_pages", rec["free_pages"])
-        if rec["decoded"]:
-            r.inc("serve.decode_ticks")
-        if rec["prefill"] is not None:
-            r.inc("serve.prefill_chunks")
-        if rec["preempted"]:
-            r.inc("serve.preemptions", len(rec["preempted"]))
-        if rec.get("prefix_hits"):
-            r.inc("serve.prefix.hits", len(rec["prefix_hits"]))
-            r.inc("serve.prefix.hit_tokens",
-                  sum(m for _, m in rec["prefix_hits"]))
-        if rec.get("spec"):
-            r.inc("serve.spec.rounds", len(rec["spec"]))
-            r.inc("serve.spec.proposed",
-                  sum(p for _, p, _ in rec["spec"]))
-            r.inc("serve.spec.accepted_total",
-                  sum(a for _, _, a in rec["spec"]))
+        """One core step on the fleet's clock. Returns (the replica's
+        tick record fields, the step's outcome — the fleet syncs
+        terminal statuses from its new_fin / new_drop tails)."""
+        core = self.core
+        sched = core.sched
+        # The fleet sweeps only when something can be swept: a deadline
+        # exists or a cancel was flagged — the O(queue) scan is what
+        # would otherwise dominate a storm.
+        out = core.step(now, sweep=sched.has_deadlines
+                        or self._cancel_pending)
+        self._cancel_pending = False
+        # The fleet checks the pool every `check_every` steps (0: never
+        # — a 10^5 storm checks once, at the end of the run).
+        if self.check_every and core.steps % self.check_every == 0:
+            sched.check()
+        fields = core.tick_fields(out)
+        fields["queue"] = len(sched.queue)
+        rec = {k: fields[k] for k in REPLICA_TICK_LAYOUT if k in fields}
+        observe_tick(self.registry, rec)
         self.pending_dispatches = 0
-        return rec, new_fin, new_drop
+        return rec, out
 
 
 @dataclasses.dataclass
@@ -1107,7 +848,7 @@ class Fleet:
             if self.bus is not None:
                 # Lease fence, sender side (ISSUE 20): past its lease a
                 # replica refuses its OWN commit — it does not even
-                # send. ReplicaCore._emit appended tok to local.out
+                # send. ServeCore._emit appended tok to local.out
                 # before calling us, so the commit's position is
                 # len-1; the router applies commits in position order
                 # (gap-stashed), so reordered delivery cannot misfile
@@ -1313,7 +1054,7 @@ class Fleet:
                     # A cancel that landed while the dispatch was in
                     # flight re-arms the sweep at delivery (the
                     # send-time flag was consumed by earlier steps).
-                    rep.core.flag_cancel()
+                    rep.flag_cancel()
                 member = self.router.members.get(rep.name)
                 if member is not None and member.replica is rep:
                     # Delivery marker for the replay mirror — CURRENT
@@ -1379,11 +1120,11 @@ class Fleet:
                 self._log_replica(pool, "restored", tick, now, pool=pool)
 
     def _make_prefill_done(self, replica: Replica):
-        def on_done(core: ReplicaCore, slot, now: float) -> bool:
+        def on_done(core: ServeCore, slot, now: float) -> bool:
             return self._begin_handoff(replica, core, slot, now)
         return on_done
 
-    def _begin_handoff(self, replica: Replica, core: ReplicaCore, slot,
+    def _begin_handoff(self, replica: Replica, core: ServeCore, slot,
                        now: float) -> bool:
         """A prefill-pool slot just completed its prefill with decode
         work remaining: seal its page set and open a handoff, or — with
@@ -1623,7 +1364,7 @@ class Fleet:
             self._holder[rid] = (ho.dst_rep, local)
             if auth.cancel_requested:
                 local.cancel()
-                ho.dst_rep.core.flag_cancel()
+                ho.dst_rep.flag_cancel()
             ho.state = "done"
             self.handoffs += 1
             self.handoff_pages += len(ho.pages)
@@ -1735,7 +1476,7 @@ class Fleet:
             # A cancel that landed while the rid awaited (re-)dispatch
             # carries over to the new incarnation.
             local.cancel()
-            member.replica.core.flag_cancel()
+            member.replica.flag_cancel()
         kind = "redispatch" if redispatch else "dispatch"
         self.dispatch_trace.append((tick, req.rid, member.name, epoch, kind))
         self.dispatches += not redispatch
@@ -1767,7 +1508,7 @@ class Fleet:
         if held is not None:
             replica, local = held
             local.cancel()
-            replica.core.flag_cancel()
+            replica.flag_cancel()
 
     # -- failure handling ----------------------------------------------
 
@@ -2272,7 +2013,8 @@ class Fleet:
                 rep = member.replica
                 if not rep.alive:
                     continue
-                rec, new_fin, new_drop = rep.step(now)
+                rec, out = rep.step(now)
+                ended = out.new_fin + out.new_drop
                 # Cumulative live-member step count (ISSUE 18): the
                 # capacity actually spent — what the static-vs-
                 # autoscaled acceptance compares. Zombies excluded
@@ -2280,8 +2022,7 @@ class Fleet:
                 self.replica_ticks += 1
                 if self.bus is None:
                     self.router.beat(member.name, tick)
-                    synced = self._sync_terminal(rep, new_fin + new_drop,
-                                                 now)
+                    synced = self._sync_terminal(rep, ended, now)
                 else:
                     # Heartbeat as a MESSAGE (ISSUE 20): liveness is
                     # now whatever the router can observe over the
@@ -2292,7 +2033,7 @@ class Fleet:
                     self.bus.send("hb", self._endpoint(rep), "router",
                                   {"name": member.name, "gen": rep.gen,
                                    "tick": tick}, tick=tick)
-                    self._send_terminals(rep, new_fin + new_drop, tick)
+                    self._send_terminals(rep, ended, tick)
                     synced = self._drain_synced()
                 n_done += len(synced)
                 if self.autoscaler is not None and synced:
@@ -2303,7 +2044,7 @@ class Fleet:
                     for r in synced:
                         self.autoscaler.observe_terminal(
                             terminal_fields(r), now)
-                any_work = any_work or rec["progressed"] or rep.core.unfinished
+                any_work = any_work or out.moved or rep.core.unfinished
                 self.state_chain = zlib.crc32(
                     rec["state_crc"].to_bytes(4, "little"), self.state_chain)
                 if self.replica_tick_sink is not None:
@@ -2314,19 +2055,7 @@ class Fleet:
                     # refused (ISSUE 8).
                     self.replica_tick_sink({
                         "tick": tick, "now": round(now, 4),
-                        "mode": f"fleet/{member.name}",
-                        **{k: rec[k] for k in
-                           ("queue", "running", "free_pages", "admitted",
-                            "prefill", "decoded", "preempted",
-                            "blocked", "preempted_for", "finished",
-                            "aborted", "state_crc")},
-                        **({"prefix_hits": rec["prefix_hits"],
-                            "prefix": rec["prefix"]}
-                           if "prefix_hits" in rec else {}),
-                        **({"prefix_readmits": rec["prefix_readmits"]}
-                           if "prefix_readmits" in rec else {}),
-                        **({"spec": rec["spec"]}
-                           if "spec" in rec else {}),
+                        "mode": f"fleet/{rep.name}", **rec,
                         "terminal": [terminal_fields(r) for r in synced],
                     })
             for rep in list(self._zombies):
@@ -2341,19 +2070,19 @@ class Fleet:
                             # failover's unregister handles it.)
                             self.bus.unregister(self._endpoint(rep))
                     continue
-                rec, new_fin, new_drop = rep.step(now)
+                rec, out = rep.step(now)
+                ended = out.new_fin + out.new_drop
                 # Terminal claims from a zombie are fenced like tokens:
                 # before failover revokes its fences the zombie's
                 # completions are authoritative commits and must count
                 # toward n_done; after revocation they are discarded.
                 if self.bus is None:
-                    synced = self._sync_terminal(rep, new_fin + new_drop,
-                                                 now)
+                    synced = self._sync_terminal(rep, ended, now)
                 else:
                     # A zombie never heartbeats (alive=False), so its
                     # lease starves and its late claims are first
                     # lease-refused, then fence-refused — both counted.
-                    self._send_terminals(rep, new_fin + new_drop, tick)
+                    self._send_terminals(rep, ended, tick)
                     synced = self._drain_synced()
                 n_done += len(synced)
                 if self.autoscaler is not None and synced:
@@ -2380,19 +2109,7 @@ class Fleet:
                         and self.replica_tick_sink is not None):
                     self.replica_tick_sink({
                         "tick": tick, "now": round(now, 4),
-                        "mode": f"fleet/{rep.name}",
-                        **{k: rec[k] for k in
-                           ("queue", "running", "free_pages", "admitted",
-                            "prefill", "decoded", "preempted",
-                            "blocked", "preempted_for", "finished",
-                            "aborted", "state_crc")},
-                        **({"prefix_hits": rec["prefix_hits"],
-                            "prefix": rec["prefix"]}
-                           if "prefix_hits" in rec else {}),
-                        **({"prefix_readmits": rec["prefix_readmits"]}
-                           if "prefix_readmits" in rec else {}),
-                        **({"spec": rec["spec"]}
-                           if "spec" in rec else {}),
+                        "mode": f"fleet/{rep.name}", **rec,
                         "terminal": [terminal_fields(r) for r in synced],
                     })
             # False-positive failovers (ISSUE 20): an isolated replica
@@ -2411,11 +2128,11 @@ class Fleet:
                     self.bus.unregister(self._endpoint(rep))
                     self._log_replica(name, "isolated_end", tick, now)
                     continue
-                _rec, new_fin, new_drop = rep.step(now)
+                _rec, out = rep.step(now)
                 self.bus.send("hb", self._endpoint(rep), "router",
                               {"name": name, "gen": rep.gen,
                                "tick": tick}, tick=tick)
-                self._send_terminals(rep, new_fin + new_drop, tick)
+                self._send_terminals(rep, out.new_fin + out.new_drop, tick)
                 synced = self._drain_synced()
                 n_done += len(synced)
                 if self.autoscaler is not None and synced:

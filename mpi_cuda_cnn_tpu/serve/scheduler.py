@@ -54,7 +54,7 @@ from .prefix_cache import PrefixCache
 # -- per-tick state digests (ISSUE 15) ----------------------------------
 #
 # The deterministic flight recorder: every producer (engine tick,
-# ReplicaCore tick, fleet/router record) stamps a `state_crc` — a crc32
+# replica tick, fleet/router record) stamps a `state_crc` — a crc32
 # of a canonical, jax-free projection of its full host-side serving
 # state — so a failed 0%/equal determinism gate localizes to the first
 # divergent TICK instead of "trace_crc differs" over a 10^5 storm.
@@ -650,11 +650,16 @@ class _SchedulerBase:
         self._on_terminal(req, now)
         return req
 
-    # Whether sweep() releases an in-flight aborted request's slot and
-    # pages immediately (continuous) or holds the reservation until the
-    # batch drains (static — the reserve-until-drain discipline; the
-    # aborted row just stops decoding).
-    release_on_abort = True
+    # Whether a slot and its pages go back the moment its request ends
+    # — done, or aborted by sweep() — (continuous) or the reservation is
+    # held until the whole batch drains (static — the reserve-until-
+    # drain discipline; an ended row just stops decoding).
+    release_at_once = True
+
+    def batch_done(self) -> bool:
+        """Whether a batch that is held together has run out and is
+        owed its `drain`; never, where every slot is released at once."""
+        return False
 
     def sweep(self, now: float) -> list[Request]:
         """Abort expired and cancelled requests, queued AND in-flight.
@@ -684,7 +689,7 @@ class _SchedulerBase:
             dropped.append(self._drop(r, status, now,
                                       None if status == "cancelled"
                                       else "deadline"))
-            if self.release_on_abort:
+            if self.release_at_once:
                 self._release(slot)
         return dropped
 
@@ -938,7 +943,7 @@ class StaticScheduler(_SchedulerBase):
     (expired/cancelled) in-flight rows keep their reservation until the
     drain — they only stop decoding."""
 
-    release_on_abort = False
+    release_at_once = False
 
     def admit(self, now: float) -> list[Slot]:
         if any(not s.free for s in self.slots):

@@ -13,9 +13,8 @@ Division of labor (the scheduler/engine split, applied again):
 - THIS module is host-side, numpy-only, and deliberately jax-free
   (`mctpu lint` MCT001): proposal (prompt lookup over the request's own
   committed context), the greedy acceptance law, and the round scaffold
-  `run_round` that engine.run and fleet.ReplicaCore.step both drive —
-  one implementation, two drivers, so the engine and the fleet's sim
-  storms can never drift.
+  `run_round` that the serving iteration (core.ServeCore.work) runs
+  for the engine and for the fleet's sim storms alike.
 - The VERIFY forward is the caller's: engine.PagedEngine.run_spec_tick
   (one jitted paged_forward over every slot's k candidate rows — the
   same token_forward/attend_kv stack every other decode surface shares)
@@ -164,8 +163,8 @@ def context_tokens(req) -> np.ndarray:
 
 
 def run_round(dslots, widths, proposer, verify):
-    """One speculative round over the tick's decoding slots — THE
-    scaffold engine.run and fleet.ReplicaCore.step share:
+    """One speculative round over the tick's decoding slots — the
+    scaffold core.ServeCore.work runs for every driver:
 
     1. per slot, propose width-1 draft tokens from its committed
        context and assemble the verify inputs u = [current token,

@@ -591,15 +591,19 @@ def test_fleet_summary_is_json_serializable():
 # ------------------------------------------------- engine-backed fleet
 
 
-def test_single_replica_fleet_matches_paged_engine_run():
-    """ReplicaCore.step is engine.run's continuous-mode tick body with
-    the idle/fault/watchdog handling hoisted into the fleet loop — this
-    pins the two drivers against each other so a rule change in one
-    (emit timing, finish ordering, sweep placement, chunking) cannot
-    silently diverge single-engine and fleet serving: the same workload
-    through PagedEngine.run and through a 1-replica engine-backed fleet
-    must finish every request with identical outputs, statuses, and
-    prefill-chunk counts."""
+@pytest.mark.parametrize("prefix,spec", [(False, "off"), (True, "off"),
+                                         (False, "lookup")],
+                         ids=["plain", "prefix", "spec-lookup"])
+def test_single_replica_fleet_matches_paged_engine_run(prefix, spec):
+    """PagedEngine.run and the fleet's Replica.step drive ONE iteration
+    (serve/core.py's ServeCore); what still differs is the driver —
+    run()'s wall clock, arrival-driven sleeps and every-iteration sweep
+    against the fleet's stepped clock, router and fenced commit. This
+    pins the two drivers against each other: the same workload through
+    PagedEngine.run and through a 1-replica engine-backed fleet must
+    finish every request with identical outputs, statuses, and
+    prefill-chunk counts — with prefix sharing and with speculative
+    rounds as without."""
     import jax
 
     from mpi_cuda_cnn_tpu.models.transformer import TransformerLM
@@ -609,31 +613,39 @@ def test_single_replica_fleet_matches_paged_engine_run():
     model = TransformerLM(vocab=13, dim=32, heads=4, depth=2, max_seq=48)
     params = model.init(jax.random.key(0))
     geom = dict(slots=2, num_pages=13, page_size=8, max_len=48)
+    ekw = dict(prefill_chunk=8, spec=spec, spec_k=4, **geom)
 
     def reqs():
         return make_fleet_workload(n=12, vocab=13, prompt_min=4,
                                    prompt_max=10, out_min=4, out_max=10,
-                                   rate=300.0, seed=3)
+                                   rate=300.0, seed=3,
+                                   prefix_mix=0.7 if prefix else 0.0)
 
-    engine = PagedEngine(model, params, prefill_chunk=8, **geom)
+    engine = PagedEngine(model, params, **ekw)
     clock = FakeClock()
     eng = engine.run(reqs(), mode="continuous", time_fn=clock,
-                     sleep_fn=clock.advance)
+                     sleep_fn=clock.advance, prefix=prefix,
+                     spec=spec != "off")
     fleet = Fleet(
-        lambda name: EngineCompute(PagedEngine(model, params,
-                                               prefill_chunk=8, **geom)),
-        replicas=1, **geom,
+        lambda name: EngineCompute(PagedEngine(model, params, **ekw)),
+        replicas=1, prefix=prefix, spec=spec, spec_k=4, **geom,
     ).run(reqs())
 
     assert {r.status for r in eng.requests} == {"finished"}
     assert fleet.status_counts() == {"finished": 12}
     eng_outs = {r.rid: list(r.out) for r in eng.requests}
     assert fleet.outputs() == eng_outs
-    # Chunk counts are per-request structure (ceil(prompt/chunk) each)
-    # and must agree; decode TICK counts are batching density — a
-    # function of admission cadence (fleet tick clock vs engine.run's
-    # arrival-driven sleeps), legitimately different between drivers.
+    # Chunk counts are per-request structure (ceil(uncached prompt /
+    # chunk) each) and must agree; decode TICK counts are batching
+    # density — a function of admission cadence (fleet tick clock vs
+    # engine.run's arrival-driven sleeps), legitimately different
+    # between drivers.
     assert fleet.prefill_chunks == eng.prefill_chunks
+    if prefix:
+        assert eng.prefix["prefix_hits"] > 0
+        assert fleet.prefix["prefix_hits"] == eng.prefix["prefix_hits"]
+    if spec != "off":
+        assert eng.spec["spec_rounds"] > 0 and fleet.spec["spec_rounds"] > 0
 
 
 @pytest.mark.parametrize("redispatch", ["resume", "discard"])

@@ -240,7 +240,7 @@ def test_pagepool_randomized_op_sequence_invariant(dtype):
                          prefill_chunk=4, max_len=32, cache_dtype=dtype,
                          spec="lookup", spec_k=4)
     # Host pool sized to the engine's device page arrays — the pairing
-    # ReplicaCore uses: page indices from this pool index those arrays.
+    # ServeCore uses: page indices from this pool index those arrays.
     pool = PagePool(10)
     # Host tier on A (ISSUE 17): real engine spill/readmit callbacks —
     # evicted KV rows round-trip through host memory — plus an armable
