@@ -13,7 +13,8 @@ comes out by the repo's own records (`--metrics-jsonl`):
   `jax.default_matmul_precision("highest")`; on the chip the lowered
   program must hold a Mosaic custom call (nothing interpreted). And
   the bounded paged read against the whole-table gather, at the
-  benchmark's two head layouts and pool types.
+  benchmark's two K/V head layouts and pool types and at its latent
+  layout (64 x 2,048 bf16 rows of 640 lanes, 128 heads).
 - cnn: the source paper's path — 4 IDX files, `reference_cnn`, 60,000
   samples, batch 32 per chip, 2 scanned epochs + eval.
 - lm: `lm --dim 4096 --depth 3 --heads 32 --seq-len 2048`, bf16, 6 steps.
@@ -59,7 +60,12 @@ FULL = dict(
                    chat=dict(kv_heads=32, cache_dtype="bfloat16", slots=8,
                              max_len=2048),
                    generation=dict(kv_heads=1, cache_dtype="int8", slots=16,
-                                   max_len=1024))),
+                                   max_len=1024)),
+               # ... and its latent layout (`dots.vlm1.inst`): one row a
+               # token that all heads read, no V pool.
+               latent=dict(heads=128, kv_rank=512, nope=128, rope=64, v=128,
+                           lanes=640, cache_dtype="bfloat16", slots=64,
+                           max_len=2048)),
 )
 # Same phases, same code paths, sizes a CPU finishes in seconds.
 TOY = dict(
@@ -72,7 +78,10 @@ TOY = dict(
                    chat=dict(kv_heads=4, cache_dtype="bfloat16", slots=4,
                              max_len=64),
                    generation=dict(kv_heads=1, cache_dtype="int8", slots=5,
-                                   max_len=48))),
+                                   max_len=48)),
+               latent=dict(heads=4, kv_rank=32, nope=8, rope=8, v=8,
+                           lanes=128, cache_dtype="bfloat16", slots=4,
+                           max_len=64)),
 )
 # A tick slower than this is a compile (or a stall) inside the serving
 # window: warm-up is supposed to have compiled every program.
@@ -312,7 +321,7 @@ def phase_kernels(cfg, dev, rehearsal):
             def read(step):
                 def f(c, q, k, v):
                     with mock.patch.object(paged_cache, "read_step",
-                                           lambda *a: step):
+                                           lambda *a, **k: step):
                         return paged_cache.paged_update_attend(
                             c, q, k, v, positions, valid, table, ps)[0]
                 return f
@@ -328,6 +337,66 @@ def phase_kernels(cfg, dev, rehearsal):
             # probabilities to bf16 (2^-9 each) on either form.
             compare(f"paged_{name}_b{b}_kk{kk}_as_served",
                     jax.jit(read(loop))(c, q, k, v), want, 2e-2)
+
+    # The same for the latent layout (bounded_read_latent against the
+    # whole-table gather + attend_latent) at the `dots` cell's shape:
+    # on the chip the loop at the step the code itself picks there (it
+    # must engage: fewer rows read than the table holds), in rehearsal
+    # forced. bf16 rows and weights: beside the probabilities' rounding
+    # (exp(s - block max) in the loop, normalised in the gather: 2^-8 a
+    # term) both forms round the weighted latents to bf16 before `wuv`,
+    # so the tolerance is twice the K/V one, 4 x 2^-8 = 1.6e-2.
+    from mpi_cuda_cnn_tpu.models.transformer import LatentAttn
+
+    lat, ps = sv["latent"], sv["page_size"]
+    a = LatentAttn(q_rank=lat["kv_rank"], kv_rank=lat["kv_rank"],
+                   nope=lat["nope"], rope=lat["rope"], v=lat["v"])
+    h, dtype = lat["heads"], lat["cache_dtype"]
+    per = -(-lat["max_len"] // ps)
+    pool = lat["slots"] * per + 1
+    rows = np.zeros((pool * ps, lat["lanes"]), np.float32)
+    rows[:, :a.row] = rng.normal(size=(pool * ps, a.row))
+    c = {"c": jnp.asarray(rows, dtype).reshape(pool, ps, -1)}
+    blk = {"wuk": jnp.asarray(rng.normal(size=(h, a.nope, a.kv_rank))
+                              / np.sqrt(a.nope), dtype),
+           "wuv": jnp.asarray(rng.normal(size=(h, a.kv_rank, a.v))
+                              / np.sqrt(a.kv_rank), dtype)}
+    del rows
+    for b, kk in ((lat["slots"], 1), (1, sv["prefill_chunk"])):
+        q = jnp.asarray(rng.normal(size=(b, kk, h, a.nope + a.rope)), dtype)
+        row = jnp.asarray(rng.normal(size=(b, kk, 1, a.row)), dtype)
+        live = np.arange(b) % 3 != 1
+        table = jnp.asarray(np.where(live[:, None], np.stack([
+            rng.choice(np.arange(1, pool), per, replace=False)
+            for _ in range(b)]), 0).astype(np.int32))
+        # The tick's slots at any depth; the chunk mid-prompt.
+        pos0 = (rng.integers(0, per * ps, (b, 1)) * live[:, None] if kk == 1
+                else np.full((b, 1), per * ps // 2 - kk))
+        positions = jnp.asarray(pos0 + np.arange(kk), jnp.int32)
+        valid = jnp.asarray(np.broadcast_to(live[:, None], (b, kk)))
+
+        def read(step):
+            def f(c, q, row, blk):
+                forced = (mock.patch.object(paged_cache, "read_step",
+                                            lambda *a, **k: step)
+                          if step else contextlib.nullcontext())
+                with forced:
+                    o, _, n = paged_cache.paged_update_attend_latent(
+                        c, q, row, positions, valid, table, ps, blk, a)
+                return o, n
+            return f
+
+        loop = (max(1, per // 4), 3) if rehearsal else None
+        want, _ = twin(read((per, b)), c, q, row, blk)
+        got, n = twin(read(loop), c, q, row, blk)
+        check(int(n) < b * per * ps,
+              f"latent b{b} kk{kk}: the read touched {int(n)} rows of a "
+              f"table of {b * per * ps}: the loop did not engage")
+        compare(f"paged_latent_b{b}_kk{kk}", got[live], want[live],
+                4 * 2.0 ** -8)
+        compare(f"paged_latent_b{b}_kk{kk}_as_served",
+                jax.jit(read(loop))(c, q, row, blk)[0][live], want[live],
+                2e-2)
 
     # int8 GEMV at the decode tick's widest matrices: the MLP pair
     # (w2's din = 4*dim is the contraction that overflowed VMEM untiled).

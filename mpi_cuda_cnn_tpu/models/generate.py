@@ -310,6 +310,37 @@ def attend_kv(q, ck, cv, mask, cks=None, cvs=None):
     return o.reshape(b, kk, h * hd)
 
 
+def latent_query_rows(q, wuk, a, like):
+    """The absorbed query of every head, laid out as the cache rows
+    `like` (..., stored lanes) lie: q~_h = wuk_h q_n,h in the weights'
+    type, then [q~_h ; q_r,h ; zero lanes] in the rows' type, heads and
+    queries as ONE axis of H*k rows (head-major) -- every head reads
+    the same cache row, whole. q (B, k, H, nope + rope) ->
+    (B, H*k, stored lanes). Once per query: attend_latent and the paged
+    cache's bounded latent read both start here."""
+    b, kk, h, _ = q.shape
+    # q~ in the weights' type: the rows' type is where it is read.
+    # Head-major from the start: transposing the finished rows is a
+    # copy of all of them (30 us a layer at the benchmark's tick).
+    qt = jnp.einsum("bqhn,hnr->bhqr", q[..., :a.nope].astype(wuk.dtype), wuk)
+    qrow = jnp.concatenate(
+        [qt.astype(like.dtype),
+         q[..., a.nope:].transpose(0, 2, 1, 3).astype(like.dtype),
+         jnp.zeros((b, h, kk, like.shape[-1] - a.row), like.dtype)],
+        axis=-1)                                # (B, H, k, stored row)
+    return qrow.reshape(b, h * kk, -1)
+
+
+def latent_values_up(ot, wuv):
+    """The other end of the absorbed read: ot (B, H, k, kv_rank), the
+    softmax-weighted latents of every head and query, up-projected by
+    wuv (H, kv_rank, v) once per QUERY. Returns (B, k, H*v) f32."""
+    b, h, kk, _ = ot.shape
+    o = jnp.einsum("bhqr,hrv->bqhv", ot.astype(wuv.dtype), wuv).astype(
+        jnp.float32)
+    return o.reshape(b, kk, h * wuv.shape[-1])
+
+
 def attend_latent(q, rows, mask, wuk, wuv, a):
     """The masked read over LATENT cache rows (transformer.LatentAttn):
     rows (B, L, >= kv_rank + rope) hold, a token, the compressed latent
@@ -328,30 +359,27 @@ def attend_latent(q, rows, mask, wuk, wuv, a):
     slot's queries are few: a decode tick has one, a prefill chunk 32,
     and the two cross near 150 (batched prefill, ROADMAP S2, brings the
     other form back with a trace of both). mask: (k, L) or (B, k, L),
-    True = attend; scores and softmax f32. Returns (B, k, H*v) f32."""
+    True = attend; scores and softmax f32. Returns (B, k, H*v) f32.
+
+    This is the read over WHOLE rows in one softmax: the paged cache
+    calls it where a block table is small enough to gather whole
+    (serve/paged_cache.bounded_read_latent: the tiny presets, the
+    tests' tables); a large table is read block by block there, with
+    these same products (latent_query_rows, latent_values_up) around
+    an online softmax."""
     b, kk, h, _ = q.shape
     f32 = dict(preferred_element_type=jnp.float32)
     c = rows[..., :a.kv_rank]
     if mask.ndim == 2:
         mask = mask[None]
-    # q~ in the weights' type: the rows' type is where it is read.
-    qt = jnp.einsum("bqhn,hnr->bqhr", q[..., :a.nope].astype(wuk.dtype), wuk)
-    qrow = jnp.concatenate(
-        [qt.astype(rows.dtype), q[..., a.nope:].astype(rows.dtype),
-         jnp.zeros((b, kk, h, rows.shape[-1] - a.row), rows.dtype)],
-        axis=-1)                                # (B, k, H, stored row)
-    # Heads and queries are one axis of H*k rows against the slot's
-    # L cache rows: every head reads the same row, whole.
-    qrow = qrow.transpose(0, 2, 1, 3).reshape(b, h * kk, -1)
+    qrow = latent_query_rows(q, wuk, a, rows)
     logits = jnp.einsum("bmc,bkc->bmk", qrow, rows, **f32).reshape(
         b, h, kk, -1)
     logits = jnp.where(mask[:, None], logits * a.softmax_scale, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(rows.dtype)
     ot = jnp.einsum("bmk,bkr->bmr", probs.reshape(b, h * kk, -1), c,
                     **f32).reshape(b, h, kk, a.kv_rank)
-    o = jnp.einsum("bhqr,hrv->bqhv", ot.astype(wuv.dtype), wuv).astype(
-        jnp.float32)
-    return o.reshape(b, kk, h * a.v)
+    return latent_values_up(ot, wuv)
 
 
 def attend_contiguous(c, q, k, v, pos, positions):

@@ -50,6 +50,19 @@ costs more than the rows it skips. Parity of the two forms: a few f32
 ulp in f32 and int8, the probabilities' bf16 rounding in bf16
 (tests/test_paged_kernel.py on the CPU, chip_smoke.py on the chip).
 
+BOTH LAYOUTS READ THIS WAY (PR 31). The item list, its trip count as a
+value and the rule that picks the step are one (`_read_items`,
+`read_step`); the step body and the fold are the layout's. K/V heads
+(`bounded_read`): attend_kv's products, every item's statistics kept
+(2 MB in chat) and folded after the loop. Latent rows
+(`bounded_read_latent`): attend_latent's absorbed products over a row
+that all 128 heads share, so an item's f32 output (262 KB) outweighs a
+small block's rows; the loop carries one running softmax a live slot
+and folds a step's items into it, dead slots have no item, and a row
+weighs its operations beside its bytes when `read_step` sizes the
+block (the benchmark's `dots` tick: 27 pages a block, 7 blocks a
+step; its 32-row chunk: 8 pages, 1).
+
 What was measured on the v5e (PERF.md section 6, PR 29; one tick's
 reads at chat's shapes, 8 layers): whole-table gather 20.6 ms, bounded
 2.1 ms; the former Pallas kernel (one page a grid step over the whole
@@ -57,8 +70,10 @@ table) 71.6 ms, slower than the gather at every shape of both cells,
 so it and the option that chose it are gone (ROADMAP D9). A step of
 the loop runs its gathers, converts and products one after the other
 at ~310 GB/s of cache bytes; a kernel that overlaps the page fetches
-with the products is what is left (ROADMAP S3). The latent layout's
-read (`paged_update_attend_latent`) still gathers whole tables.
+with the products is what is left (ROADMAP S3(b)). The latent read at
+the `dots` cell's shapes (six layers, 64 slots, 13.4 k live rows a
+layer in 40 of them; PERF.md section 6, PR 31): whole-table gather
+7.39 ms, bounded 1.73 ms; a 32-row chunk 128 rows deep 0.90 / 0.15.
 """
 
 from __future__ import annotations
@@ -74,6 +89,8 @@ from ..models.generate import (
     _quant_kv,
     attend_kv,
     attend_latent,
+    latent_query_rows,
+    latent_values_up,
     token_forward,
 )
 from ..models.transformer import TransformerLM
@@ -202,20 +219,27 @@ def paged_update_attend_latent(c: dict, q, row, positions, valid,
                                attn):
     """paged_update_attend for the latent layout: the token's one row
     (B, kk, 1, kv_rank + rope) is written to pool `c` (zero lanes
-    after it, latent_row_lanes), then every
-    slot's pages are gathered into (B, L, row) and read by
-    generate.attend_latent with the block's up-projections `wuk`/`wuv`.
+    after it, latent_row_lanes; an invalid token's to scratch page 0),
+    writes FIRST, then `bounded_read_latent` reads each slot's pages up
+    to its deepest valid position with the block's up-projections
+    `wuk`/`wuv`, at the step `read_step` picks from what a row costs
+    here: its bytes, the operations of the H*kk query rows that all
+    read it, and the f32 statistics a (slot, block) item leaves.
     Returns (o: (B, kk, H*v) f32, new_c, rows the read touched)."""
     b, kk = positions.shape
     pi, of = _write_index(positions, valid, block_table, page_size)
     row = row.astype(c["c"].dtype).reshape(b * kk, -1)
     pool = c["c"].at[pi, of].set(
         jnp.pad(row, ((0, 0), (0, c["c"].shape[-1] - row.shape[-1]))))
-    length = block_table.shape[1] * page_size
-    rows = pool[block_table].reshape(b, length, -1)
-    mask = jnp.arange(length)[None, None, :] <= positions[:, :, None]
-    o = attend_latent(q, rows, mask, blk["wuk"], blk["wuv"], attn)
-    return o, {"c": pool}, b * length
+    lanes, queries = pool.shape[-1], q.shape[2] * kk
+    step = read_step(
+        b, block_table.shape[1], page_size, lanes * pool.dtype.itemsize,
+        key_flops=2 * queries * (lanes + attn.kv_rank),
+        stat_bytes=queries * attn.kv_rank * 4)
+    o, rows = bounded_read_latent(
+        q, pool, positions, valid, block_table, blk["wuk"], blk["wuv"],
+        page_size=page_size, step=step, attn=attn)
+    return o, {"c": pool}, rows
 
 
 def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
@@ -271,20 +295,73 @@ def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
 # rounded up to, so it is as small as a lane tile of keys allows.
 _BLOCK_BYTES = 1 << 20
 _STEP_BYTES = 8 << 20
+# Operations that take the chip as long as one byte from its memory
+# (197 TFLOP/s over 819 GB/s on the v5e): a cache row that many query
+# rows read weighs its operations too, in bytes' time.
+_FLOPS_PER_BYTE = 240
 
 
-def read_step(slots: int, npages: int, page_size: int,
-              key_bytes: int) -> tuple[int, int]:
+def read_step(slots: int, npages: int, page_size: int, key_bytes: int,
+              *, key_flops: int = 0, stat_bytes: int = 0) -> tuple[int, int]:
     """(pages a block, blocks a step) of the bounded read, from what
     the table moves: `key_bytes` is one cache row, K and V and their
     scales. A block is at least a lane tile of keys and _BLOCK_BYTES; a
     step is as many blocks as make _STEP_BYTES, at most every block of
-    every slot -- a table so small is read whole in one step."""
-    keys = max(_LANES, -(-_BLOCK_BYTES // key_bytes))
+    every slot -- a table so small is read whole in one step.
+
+    Where a row is shared by many query rows (the latent layout: every
+    head reads the one row, 128 heads x 1 or 32 queries) its operations
+    weigh as much as its bytes or more, and each (slot, block) item
+    leaves f32 statistics larger than a small block's rows: `key_flops`
+    (operations one cache row costs) counts at _FLOPS_PER_BYTE beside
+    the row's bytes, and `stat_bytes` (what an item leaves) beside a
+    block's in the step. The K/V layouts pass neither: there both are
+    a small part of the bytes (a chat tick: 0.4% and 0.8%), and their
+    steps are as PR 29 timed them."""
+    weight = key_bytes + key_flops // _FLOPS_PER_BYTE
+    keys = max(_LANES, -(-_BLOCK_BYTES // weight))
     per_block = min(npages, -(-keys // page_size))
     blocks = slots * -(-npages // per_block)
-    per_step = -(-_STEP_BYTES // (per_block * page_size * key_bytes))
+    per_step = -(-_STEP_BYTES // (per_block * page_size * weight + stat_bytes))
     return per_block, min(blocks, per_step)
+
+
+def _read_items(positions, valid, block_table, page_size: int,
+                step: tuple[int, int], dead_blocks: int):
+    """The flat list of (slot, block of pages) items both bounded reads
+    walk, built on the device from `positions` and `valid`: a live
+    slot's blocks up to its deepest valid position, a dead slot's (no
+    valid token) `dead_blocks` -- 1 for the K/V read, whose dead rows
+    read one block of scratch, 0 for the latent read, which skips them.
+    Returns (need, ends, steps, slot, live, item_pages, first_key):
+    blocks a slot needs (B,), their running total, the loop's trip
+    count (a value), and per item its slot, whether the list holds it,
+    its pages (scratch where it does not) and its first key's
+    position. The list is padded to whole steps."""
+    b, npages = block_table.shape
+    per_block, per_step = step
+    nblk = -(-npages // per_block)
+    width = per_block * page_size                 # keys a block
+    # Each slot's blocks; its table, padded with scratch to whole blocks.
+    depth = jnp.max(jnp.where(valid, positions, 0), axis=1)
+    need = jnp.minimum(depth // width + 1, nblk)              # (B,)
+    if not dead_blocks:
+        need = jnp.where(jnp.any(valid, axis=1), need, 0)
+    ends = jnp.cumsum(need)
+    steps = -(-ends[-1] // per_step)
+    blocks = jnp.pad(block_table, ((0, 0), (0, nblk * per_block - npages))
+                     ).reshape(b * nblk, per_block)
+    # The flat list: item i is block i - (ends - need)[slot] of the
+    # slot whose run of items holds i. Past the list's end there is no
+    # item: what the last step computes there (scratch pages) is never
+    # folded.
+    item = jnp.arange(-(-b * nblk // per_step) * per_step)
+    slot = jnp.minimum(jnp.sum(item[:, None] >= ends[None, :], axis=1), b - 1)
+    blk = item - (ends - need)[slot]
+    live = item < ends[-1]
+    item_pages = jnp.where(live[:, None],
+                           blocks[slot * nblk + jnp.where(live, blk, 0)], 0)
+    return need, ends, steps, slot, live, item_pages, blk * width
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "step"))
@@ -298,7 +375,9 @@ def bounded_read(q, c: dict, positions, valid, block_table, *,
     A slot's keys are read in BLOCKS of `per_block` pages, and only the
     blocks up to its deepest valid position (a dead row, position 0:
     one block). The (slot, block) items of all slots form one flat
-    list, built here from `positions` and `valid`; a `fori_loop` takes
+    list, built from `positions` and `valid` (`_read_items`, shared
+    with the latent layout's bounded_read_latent; what follows -- the
+    step body and the fold -- is this layout's); a `fori_loop` takes
     `per_step` items a step -- gathers their pages, scores them against
     their slot's queries, and leaves each item's softmax statistics
     (row maximum, denominator, unnormalised output) in a buffer -- and
@@ -333,27 +412,11 @@ def bounded_read(q, c: dict, positions, valid, block_table, *,
         return o, jnp.int32(b * length)
 
     width = per_block * page_size                 # keys a block
-    # Each slot's blocks; its table, padded with scratch to whole blocks.
-    depth = jnp.max(jnp.where(valid, positions, 0), axis=1)
-    need = jnp.minimum(depth // width + 1, nblk)              # (B,)
-    ends = jnp.cumsum(need)
-    steps = -(-ends[-1] // per_step)
-    blocks = jnp.pad(block_table, ((0, 0), (0, nblk * per_block - npages))
-                     ).reshape(b * nblk, per_block)
-    # The flat list: item i is block i - (ends - need)[slot] of the
-    # slot whose run of items holds i. Past the list's end there is no
-    # item: what the last step computes there (scratch pages) is never
-    # folded.
-    item = jnp.arange(-(-b * nblk // per_step) * per_step)
-    slot = jnp.minimum(jnp.sum(item[:, None] >= ends[None, :], axis=1), b - 1)
-    blk = item - (ends - need)[slot]
-    live = item < ends[-1]
-    item_pages = jnp.where(live[:, None],
-                           blocks[slot * nblk + jnp.where(live, blk, 0)], 0)
-    first_key = blk * width
+    need, ends, steps, slot, _, item_pages, first_key = _read_items(
+        positions, valid, block_table, page_size, step, dead_blocks=1)
     qg = q.reshape(b, kk, hkv, g, hd)
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    stat = (item.shape[0], hkv, g, kk)
+    stat = (slot.shape[0], hkv, g, kk)
 
     def take(i, carry):
         m_buf, l_buf, o_buf = carry
@@ -403,6 +466,112 @@ def bounded_read(q, c: dict, positions, valid, block_table, *,
     o = jnp.sum(w[..., None] * o_buf[mine], axis=1) / denom[..., None]
     o = jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(b, kk, h * hd)
     return o, (steps * (per_step * width)).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("page_size", "step", "attn"))
+def bounded_read_latent(q, pool, positions, valid, block_table, wuk, wuv, *,
+                        page_size: int, step: tuple[int, int], attn):
+    """bounded_read for the latent layout: one pool of rows
+    (pages, page_size, stored lanes), every head reading the same row.
+    The item list and its trip count are bounded_read's (_read_items;
+    a dead slot has no item here); the step body is attend_latent's
+    absorbed products, and the fold runs INSIDE the loop.
+
+    Once per query, outside the loop: the (H*kk, lanes) query rows
+    q~_h = wuk_h q_n,h (latent_query_rows). Per item: scores of its
+    slot's query rows against the block's rows as they lie (f32
+    accumulation), f32 statistics, probabilities exp(s - block max) in
+    the rows' type for the product with the rows' first kv_rank lanes.
+    An item's unnormalised output is (H*kk, kv_rank) f32 -- 262 KB at
+    128 heads, more than a 128-row block's rows -- so the items are
+    not kept for a fold afterwards (bounded_read's buffers would be
+    268 MB a layer at the benchmark's tick): the loop carries ONE
+    running (maximum, denominator, output) a live slot, 17 MB at 64
+    slots, and merges a step's items into the window of slots they
+    belong to as an online softmax does. Live slots are numbered in
+    order (their RANK), so the slots of a step's consecutive items are
+    consecutive ranks and the window is one dynamic slice; items of one
+    slot in one step are summed by select-and-add, which is exact and
+    leaves the carry's layout slot-major (a one-hot product made XLA
+    lay the carry out head-major, and every window update then cost
+    15 us at an unaligned sublane offset: PERF.md section 6, PR 31).
+    After the loop: normalise, `wuv` once per query
+    (latent_values_up), and each slot takes its rank's row (a dead
+    slot zeros: its output is nobody's). Only the order of the
+    softmax's sums differs from attend_latent over the whole table.
+
+    Where one step covers every block of every slot (a small table)
+    the read IS the gather of the table and attend_latent.
+
+    Returns (o: (B, kk, H*v) f32, cache rows the read touched: steps
+    taken x rows a step, int32)."""
+    b, kk, h, _ = q.shape
+    npages = block_table.shape[1]
+    per_block, per_step = step
+    nblk = -(-npages // per_block)
+    if per_step >= b * nblk:
+        length = npages * page_size
+        rows = pool[block_table].reshape(b, length, -1)
+        mask = jnp.arange(length)[None, None, :] <= positions[:, :, None]
+        return (attend_latent(q, rows, mask, wuk, wuv, attn),
+                jnp.int32(b * length))
+
+    width = per_block * page_size                 # rows a block
+    need, _, steps, slot, live, item_pages, first_key = _read_items(
+        positions, valid, block_table, page_size, step, dead_blocks=0)
+    rank = jnp.cumsum(need > 0) - 1               # (B,), a live slot's
+    dest = rank[slot]
+    qrow = latent_query_rows(q, wuk, attn, pool)              # (B, H*kk, C)
+    win = min(per_step, b)                        # slots a step can touch
+
+    def take(i, carry):
+        sl, pages, key0, here, to = (
+            jax.lax.dynamic_slice_in_dim(x, i * per_step, per_step)
+            for x in (slot, item_pages, first_key, live, dest))
+        rows = pool[pages.reshape(-1)].reshape(per_step, width, -1)
+        logits = jnp.einsum("imc,ikc->imk", qrow[sl], rows,
+                            preferred_element_type=jnp.float32).reshape(
+            per_step, h, kk, width)
+        mask = (((key0[:, None, None] + jnp.arange(width)[None, None, :])
+                 <= positions[sl][:, :, None])
+                & here[:, None, None])[:, None, :, :]
+        logits = jnp.where(mask, logits * attn.softmax_scale, NEG_INF)
+        m = jnp.max(logits, axis=-1)                          # (P, H, kk)
+        p = jnp.where(mask, jnp.exp(logits - m[..., None]), 0.0)
+        o = jnp.einsum("imk,ikr->imr",
+                       p.astype(rows.dtype).reshape(per_step, h * kk, width),
+                       rows[..., :attn.kv_rank],
+                       preferred_element_type=jnp.float32)
+        m = m.reshape(per_step, h * kk)
+        denom = jnp.sum(p, axis=-1).reshape(per_step, h * kk)
+        # Merge into the window of live slots this step's items hold.
+        first = jnp.minimum(to[0], b - win)
+        at_slot = jnp.clip(to - first, 0, win - 1)            # (P,)
+        mine = at_slot[:, None] == jnp.arange(win)[None, :]   # (P, win)
+        old = [jax.lax.dynamic_slice_in_dim(x, first, win) for x in carry]
+        top = jnp.maximum(old[0], jnp.max(
+            jnp.where(mine[:, :, None], m[:, None, :], NEG_INF), axis=0))
+        keep = jnp.exp(old[0] - top)                          # (win, H*kk)
+        w = jnp.exp(m - top[at_slot])                         # (P, H*kk)
+
+        def add(x):     # sum of a slot's items: exact, and no layout's
+            sel = mine.reshape(mine.shape + (1,) * (x.ndim - 1))
+            return jnp.sum(jnp.where(sel, x[:, None], 0.0), axis=0)
+
+        new = (top, keep * old[1] + add(w * denom),
+               keep[..., None] * old[2] + add(w[..., None] * o))
+        return tuple(jax.lax.dynamic_update_slice_in_dim(x, n, first, 0)
+                     for x, n in zip(carry, new))
+
+    stat = (b, h * kk)
+    _, denom, o = jax.lax.fori_loop(
+        0, steps, take,
+        (jnp.full(stat, NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32),
+         jnp.zeros(stat + (attn.kv_rank,), jnp.float32)))
+    ot = o / jnp.where(denom > 0, denom, 1.0)[..., None]
+    out = latent_values_up(ot.reshape(b, h, kk, attn.kv_rank), wuv)
+    out = jnp.where((need > 0)[:, None, None], out[rank], 0.0)
+    return out, (steps * (per_step * width)).astype(jnp.int32)
 
 
 def paged_forward(model: TransformerLM, params, toks, positions, valid,

@@ -20,6 +20,11 @@ nothing of the program. Tiny widths, seeded weights, f32, on the CPU.
 7. The GPT-2 family's golden numbers (benchmarks/testdata) stand.
 8. The family's tiny benchmark (benchmarks/tests/tiny_mla): the program
    correct, the fp8 control not correct.
+9. The bounded latent read (PR 31), its loop forced on at these tiny
+   tables (`loop`): 1 and 2 again through it, `latent_rows_read` by
+   hand, one tick and one prefill program for every depth. Tables this
+   small are read WHOLE by the code's own choice (paged_cache.read_step),
+   which is what every other test here runs.
 """
 
 import dataclasses
@@ -42,8 +47,10 @@ from mpi_cuda_cnn_tpu.parallel.ep import (  # noqa: E402
     moe_held_inference,
     route_grouped,
 )
+from mpi_cuda_cnn_tpu.serve import paged_cache  # noqa: E402
 from mpi_cuda_cnn_tpu.serve.engine import TICK_COUNTS, PagedEngine  # noqa: E402
 from mpi_cuda_cnn_tpu.serve.paged_cache import (  # noqa: E402
+    bounded_read_latent,
     init_paged_cache,
     paged_forward,
 )
@@ -69,8 +76,31 @@ def served():
         dm, SEED, cfg)
 
 
-def test_prefill_then_paged_decode_matches_the_reference(served):
+# The bounded latent read's loop at tiny sizes: blocks of 2 pages, 3
+# (slot, block) items a step (read_step itself reads these tables whole).
+LOOP = (2, 3)
+
+
+@pytest.fixture
+def loop(monkeypatch):
+    """Every latent read traced in the test takes the loop."""
+    monkeypatch.setattr(paged_cache, "read_step", lambda *a, **k: LOOP)
+
+
+def loop_rows(depths, page):
+    """Rows one layer's loop touches for live slots at `depths` (a dead
+    slot has no item): steps taken x rows a step."""
+    width = LOOP[0] * page
+    items = sum(d // width + 1 for d in depths)
+    return -(-items // LOOP[1]) * LOOP[1] * width
+
+
+@pytest.mark.parametrize("read", ["whole", "loop"])
+def test_prefill_then_paged_decode_matches_the_reference(served, read,
+                                                         monkeypatch):
     _, dm, model, params = served
+    if read == "loop":
+        monkeypatch.setattr(paged_cache, "read_step", lambda *a, **k: LOOP)
     page, chunk, n_prompt, n_total = 8, 16, 37, 49
     seq = np.random.default_rng(1).integers(0, dm["vocab"], n_total)
     cache = init_paged_cache(model, slots=2, num_pages=17, page_size=page,
@@ -104,11 +134,14 @@ def test_prefill_then_paged_decode_matches_the_reference(served):
             jnp.asarray([[False], [True]]), cache)
         got[p] = logits[1, 0]
         # One live row: its 4 choices among 16 experts, of which 4 are
-        # held, in 2 expert layers; the read touched every table row.
+        # held, in 2 expert layers; the read touched every table row
+        # where the table is read whole (as the code itself reads one
+        # this small), the live slot's blocks where the loop is forced.
         counts = dict(zip(TICK_COUNTS, np.asarray(cache.counts).tolist()))
         assert 0 <= counts["moe_assignments"] <= 2 * dm["top_k"]
         assert counts["moe_experts_hit"] <= counts["moe_assignments"]
-        assert counts["latent_rows_read"] == dm["layers"] * 2 * 8 * page
+        assert counts["latent_rows_read"] == dm["layers"] * (
+            2 * 8 * page if read == "whole" else loop_rows([p], page))
     pool = np.asarray(cache.pages[0]["c"])
     assert np.all(pool[..., 40:] == 0) and np.any(pool[1:8, :, :40] != 0)
     rows = np.arange(n_total)
@@ -133,8 +166,13 @@ def materialized_read(q, rows, mask, wuk, wuv, a):
     return o.reshape(*q.shape[:2], -1)
 
 
+@pytest.mark.parametrize("form", ["whole", "bounded"])
 @pytest.mark.parametrize("kk", [1, 5])
-def test_absorbed_read_equals_materialized(served, kk):
+def test_absorbed_read_equals_materialized(served, kk, form):
+    """`whole`: attend_latent over the rows. `bounded`: the same rows as
+    two slots' pages of a pool (4 rows a page, tables in another order
+    than the pool's), read by the bounded loop: still the materialized
+    reference's numbers."""
     _, dm, model, _ = served
     a, h = model.attn, dm["heads"]
     ks = jax.random.split(jax.random.key(3), 4)
@@ -146,7 +184,18 @@ def test_absorbed_read_equals_materialized(served, kk):
     wuv = jax.random.normal(ks[3], (h, a.kv_rank, a.v)) / 6
     mask = jnp.arange(24)[None, :] <= (24 - kk + jnp.arange(kk))[:, None]
     with jax.default_matmul_precision("highest"):
-        got = attend_latent(q, rows, mask, wuk, wuv, a)
+        if form == "whole":
+            got = attend_latent(q, rows, mask, wuk, wuv, a)
+        else:
+            table = 1 + np.random.default_rng(4).permutation(12).reshape(2, 6)
+            pool = jnp.zeros((13, 4, rows.shape[-1])).at[table].set(
+                rows.reshape(2, 6, 4, -1))
+            positions = jnp.broadcast_to(24 - kk + jnp.arange(kk), (2, kk))
+            got, n = bounded_read_latent(
+                q, pool, positions, jnp.ones((2, kk), bool),
+                jnp.asarray(table, jnp.int32), wuk, wuv, page_size=4,
+                step=LOOP, attn=a)
+            assert int(n) == loop_rows([23, 23], 4) < 2 * 24 + 8
         want = materialized_read(q, rows, mask, wuk, wuv, a)
     assert got.shape == (2, kk, h * a.v)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
@@ -379,11 +428,62 @@ def test_the_tick_record_carries_the_counters(served):
     assert not any(set(TICK_COUNTS) & set(t) for t in ticks
                    if not t["decoded"])
     # Dead slots route nothing: pairs <= live rows x top_k x layers.
+    # The engine's table here (3 slots x 20 pages of 8) is read whole,
+    # as the code itself chooses: every table row, every tick.
     for t in decoded:
         assert t["moe_assignments"] <= len(t["decoded"]) * dm["top_k"] * 2
         assert t["moe_experts_hit"] <= 2 * dm["held"]
         assert t["latent_rows_read"] == dm["layers"] * 3 * 160
     assert sum(t["moe_assignments"] for t in decoded) > 0
+
+
+def test_latent_rows_read_is_the_hand_count_on_three_slots(served, loop):
+    """paged_forward's count over a three-slot tick with the loop on:
+    slot 0 at position 37 (3 blocks of 16 rows), slot 1 dead (no item:
+    the latent read skips it), slot 2 at 16 (2): 5 items = 2 steps of
+    3, 16 rows each, in each of the model's layers."""
+    _, dm, model, params = served
+    cache = init_paged_cache(model, slots=3, num_pages=3 * 12 + 1,
+                             page_size=8, max_len=96)
+    table = 1 + np.arange(36, dtype=np.int32).reshape(3, 12)
+    table[1] = 0
+    cache = dataclasses.replace(cache, block_table=jnp.asarray(table))
+    _, cache = paged_forward(
+        model, params, jnp.zeros((3, 1), jnp.int32),
+        jnp.asarray([[37], [0], [16]], jnp.int32),
+        jnp.asarray([[True], [False], [True]]), cache)
+    assert loop_rows([37, 16], 8) == 2 * 3 * 16
+    assert np.asarray(cache.counts).tolist()[-1] == dm["layers"] * 2 * 3 * 16
+
+
+def test_latent_depths_cross_every_block_in_one_tick_and_one_prefill(
+        served, loop):
+    """The bound is a value inside the program: with the loop on, slots
+    that grow across every page, block and step boundary compile
+    nothing after the engine's first tick and first chunk, the tick
+    records' `latent_rows_read` follows the depths (never more than the
+    whole table plus a step's rounding), and the tokens are the
+    whole-table read's."""
+    dm = served[1]
+    plain = engine(served)
+    with pytest.MonkeyPatch.context() as mp:    # the same engine, read whole
+        mp.setattr(paged_cache, "read_step",
+                   lambda slots, npages, *a, **k: (npages, slots))
+        want = {r.rid: r.out for r in plain.run(requests(dm)).requests}
+    eng = engine(served)
+    eng.run(requests(dm, n=1))                           # compiles both
+    warm = eng.compiled_programs()
+    assert warm == 2
+    ticks = []
+    res = eng.run(requests(dm), tick_sink=ticks.append)
+    assert eng.compiled_programs() == warm
+    assert all(t["compiled"] == 0 for t in ticks)
+    assert {r.rid: r.out for r in res.requests} == want
+    rows = [t["latent_rows_read"] for t in ticks if t["decoded"]]
+    whole = dm["layers"] * 3 * 160
+    width = LOOP[0] * 8
+    assert max(rows) <= whole + dm["layers"] * LOOP[1] * width
+    assert min(rows) < whole / 2 and len(set(rows)) >= 3    # it follows depth
 
 
 def test_a_kv_model_counts_its_rows_and_nothing_else():

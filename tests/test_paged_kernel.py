@@ -9,6 +9,10 @@ CPU (tier-1 scope); chip_smoke.py makes the same comparisons on the
 TPU. Until PR 29 these cases held the Pallas paged kernel to the
 gather; the kernel lost to the gather at every shape on the chip and
 went (ROADMAP D9), and each case now holds the read that replaced it.
+Since PR 31 the latent layout's read (bounded_read_latent) is bounded
+the same way, and the cases that are the same case take the layout as
+one more head mapping, "latent": one 128-lane row a token that all
+heads read, the absorbed products, f32 and bf16 rows.
 
 Bitwise equality is kept for what it can promise: the same program run
 twice (the masked-row poison checks below, the engine's greedy token
@@ -30,7 +34,7 @@ from mpi_cuda_cnn_tpu.models.generate import (
     pick_cache_dtype,
     pick_weights_dtype,
 )
-from mpi_cuda_cnn_tpu.models.transformer import TransformerLM
+from mpi_cuda_cnn_tpu.models.transformer import LatentAttn, TransformerLM
 from mpi_cuda_cnn_tpu.ops.pallas_gemv import (
     QuantW,
     dequantize_weight,
@@ -45,6 +49,7 @@ from mpi_cuda_cnn_tpu.serve.paged_cache import (
     init_paged_cache,
     paged_forward,
     paged_update_attend,
+    paged_update_attend_latent,
     pages_for,
     read_step,
 )
@@ -56,7 +61,11 @@ GQA = TransformerLM(vocab=13, dim=32, heads=4, depth=2, max_seq=48,
 MQA = TransformerLM(vocab=13, dim=32, heads=4, depth=2, max_seq=48,
                     kv_heads=1, pos="rope")
 
-HEAD_CONFIGS = {"mha": 4, "gqa": 2, "mqa": 1}
+HEAD_CONFIGS = {"mha": 4, "gqa": 2, "mqa": 1, "latent": "latent"}
+# The latent layout at tiny widths: 4 heads over one row of 32 + 8
+# values a token, stored 128 lanes wide (paged_cache.latent_row_lanes).
+LATENT = LatentAttn(q_rank=16, kv_rank=32, nope=8, rope=8, v=8)
+LANES = 128
 
 # The cross-formulation band (ROADMAP D8). The bounded read folds blocks
 # of pages as an online softmax does; the whole-table read runs one
@@ -84,6 +93,17 @@ def _rand_case(dtype, hkv, kk, seed, *, b=3, h=4, hd=8, ps=4, per=5,
     pages, and in-range positions. Returns (inputs..., call kwargs)."""
     rng = np.random.default_rng(seed)
     L = per * ps
+    if hkv == "latent":
+        # The same tuple with the layout's own parts: `k` is the
+        # token's row, `v` the block's up-projections (_read tells the
+        # layout by the pool's name).
+        q, k, v = _latent_inputs(rng, b, kk, h, dtype)
+        c = {"c": _latent_rows(rng, pool * ps, dtype).reshape(pool, ps, LANES)}
+        table = np.stack([rng.choice(np.arange(1, pool), per, replace=False)
+                          for _ in range(b)]).astype(np.int32)
+        pos0 = rng.integers(0, L - kk, (b, 1))
+        positions = jnp.asarray(pos0 + np.arange(kk)[None, :], jnp.int32)
+        return q, k, v, c, jnp.asarray(table), positions, ps
     q = jnp.asarray(rng.normal(size=(b, kk, h, hd)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(b, kk, hkv, hd)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, kk, hkv, hd)), jnp.float32)
@@ -106,27 +126,54 @@ def _rand_case(dtype, hkv, kk, seed, *, b=3, h=4, hd=8, ps=4, per=5,
     return q, k, v, c, jnp.asarray(table), positions, ps
 
 
+def _latent_inputs(rng, b, kk, h, dtype):
+    """(q, the tokens' rows, the block's wuk / wuv) of a latent read."""
+    a = LATENT
+    q = jnp.asarray(rng.normal(size=(b, kk, h, a.nope + a.rope)), jnp.float32)
+    row = jnp.asarray(rng.normal(size=(b, kk, 1, a.row)), jnp.float32)
+    blk = {"wuk": jnp.asarray(rng.normal(size=(h, a.nope, a.kv_rank)) / 3,
+                              dtype),
+           "wuv": jnp.asarray(rng.normal(size=(h, a.kv_rank, a.v)) / 3,
+                              dtype)}
+    return q, row, blk
+
+
+def _latent_rows(rng, n, dtype):
+    """n stored rows: the row's values, zero lanes after them."""
+    rows = np.zeros((n, LANES), np.float32)
+    rows[:, :LATENT.row] = rng.normal(size=(n, LATENT.row))
+    return jnp.asarray(rows, dtype)
+
+
 # The loop at tiny sizes: blocks of 2 pages, 3 (slot, block) items a
 # step -- paged_cache.read_step itself reads tables this small whole.
 LOOP = (2, 3)
 
 
-def _whole(slots, npages, page_size, key_bytes):
+def _whole(slots, npages, page_size, key_bytes, **_):
     return npages, slots
+
+
+def _loop(*a, **k):
+    return LOOP
 
 
 @pytest.fixture
 def loop(monkeypatch):
     """Every paged read traced in the test takes the loop."""
-    monkeypatch.setattr(paged_cache, "read_step", lambda *a: LOOP)
+    monkeypatch.setattr(paged_cache, "read_step", _loop)
 
 
 def _read(step, c, q, k, v, positions, valid, table, ps):
     """(output, rows read) of one layer's write + read under `step`."""
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(paged_cache, "read_step", step)
-        o, _, n = paged_update_attend(dict(c), q, k, v, positions, valid,
-                                      table, ps)
+        if "c" in c:        # the latent layout: k the row, v wuk / wuv
+            o, _, n = paged_update_attend_latent(
+                dict(c), q, k, positions, valid, table, ps, v, LATENT)
+        else:
+            o, _, n = paged_update_attend(dict(c), q, k, v, positions,
+                                          valid, table, ps)
     return np.asarray(o), int(n)
 
 
@@ -134,26 +181,39 @@ def _both(q, k, v, c, table, positions, ps):
     """(whole-table gather + attend_kv, bounded read with its loop on)."""
     valid = jnp.ones(positions.shape, bool)
     args = (c, q, k, v, positions, valid, table, ps)
-    return _read(_whole, *args)[0], _read(lambda *a: LOOP, *args)[0]
+    return _read(_whole, *args)[0], _read(_loop, *args)[0]
 
 
 @pytest.mark.parametrize("kk", [1, 4], ids=["decode", "chunk"])
-@pytest.mark.parametrize("head", ["mha", "gqa", "mqa"])
+@pytest.mark.parametrize("head", ["mha", "gqa", "mqa", "latent"])
 def test_bounded_matches_full_f32(head, kk):
     """THE f32 gate: the bounded read's output equals the whole-table
     read's within the few-ulp band (F32_ULPS) — an indexing bug reads a
     wrong row and lands orders of magnitude outside it. Covers the
     decode tick (kk=1) and the chunked-prefill query width (kk=4) at
-    every head mapping."""
+    every head mapping, and over latent rows (the whole-table read is
+    attend_latent there, the loop folds inside itself)."""
     for seed in range(3):
         want, got = _both(*_rand_case("float32", HEAD_CONFIGS[head], kk,
                                       seed))
         _assert_f32_close(got, want, f"{head} kk={kk} seed={seed}")
 
 
+# bf16 rows: K/V heads round the probabilities once (2 x 2^-8 of the
+# scale covers both orders); the latent read also rounds the weighted
+# latents to the weights' type before `wuv`, on both sides: twice that.
+BF16_TOL = {"latent": 4 * 2.0 ** -8}
+
+
+def _bf16_atol(head, want):
+    return BF16_TOL.get(head, 2 * 2.0 ** -8) * max(
+        1.0, float(np.max(np.abs(want))))
+
+
 @pytest.mark.parametrize("kk", [1, 4], ids=["decode", "chunk"])
-@pytest.mark.parametrize("head", ["mha", "gqa", "mqa"])
-@pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("dtype,head", [
+    *[(d, h) for d in ("bfloat16", "int8") for h in ("mha", "gqa", "mqa")],
+    ("bfloat16", "latent")])            # a latent pool is never int8
 def test_bounded_matches_full_quantized(dtype, head, kk):
     """int8 pages: identical values and scales on both sides, scales
     applied outside the dots, everything after the convert in f32 — the
@@ -169,8 +229,7 @@ def test_bounded_matches_full_quantized(dtype, head, kk):
         if dtype == "int8":
             tol = dict(rtol=1e-5, atol=1e-5)
         else:
-            tol = dict(rtol=0, atol=2 * 2.0 ** -8
-                       * max(1.0, float(np.max(np.abs(want)))))
+            tol = dict(rtol=0, atol=_bf16_atol(head, want))
         np.testing.assert_allclose(
             got, want, **tol,
             err_msg=f"{dtype} {head} kk={kk} seed={seed}")
@@ -365,10 +424,15 @@ def _boundary_case(dtype, hkv, kk, *, ps=4, per=24, h=4, hd=8, seed=3):
     positions = pos0[:, None] + np.arange(kk)[None, :]
     table = np.where(live[:, None],
                      1 + np.arange(b * per).reshape(b, per), 0)
-    q = jnp.asarray(rng.normal(size=(b, kk, h, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(b, kk, hkv, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(b, kk, hkv, hd)), jnp.float32)
-    kv = rng.normal(size=(2, pool * ps, hkv, hd)).astype(np.float32)
+    latent = hkv == "latent"
+    if latent:
+        q, k, v = _latent_inputs(rng, b, kk, h, dtype)
+        kv = np.asarray(_latent_rows(rng, pool * ps, "float32"))[None]
+    else:
+        q = jnp.asarray(rng.normal(size=(b, kk, h, hd)), jnp.float32)
+        k = jnp.asarray(rng.normal(size=(b, kk, hkv, hd)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(b, kk, hkv, hd)), jnp.float32)
+        kv = rng.normal(size=(2, pool * ps, hkv, hd)).astype(np.float32)
     dirty = kv.copy()
     for i in np.flatnonzero(live):
         depth = positions[i, -1]
@@ -377,8 +441,15 @@ def _boundary_case(dtype, hkv, kk, *, ps=4, per=24, h=4, hd=8, seed=3):
         last = (depth // width + 1) * width
         dirty[:, rows[depth + 1:last]] = 1e30
         dirty[:, rows[last:]] = np.nan
+    if latent:
+        # Scratch page 0 too: the latent read gives a dead slot no item,
+        # so nothing the mask admits lies there (the K/V read's dead
+        # rows attend scratch row 0, and their output is nobody's).
+        dirty[:, :ps] = 1e30
 
     def pools(rows):
+        if latent:
+            return {"c": jnp.asarray(rows[0], dtype).reshape(pool, ps, LANES)}
         if dtype == "int8":
             out = {}
             for name, r in zip("kv", rows):
@@ -401,8 +472,10 @@ def _boundary_case(dtype, hkv, kk, *, ps=4, per=24, h=4, hd=8, seed=3):
 
 
 @pytest.mark.parametrize("kk", [1, 32], ids=["decode", "chunk32"])
-@pytest.mark.parametrize("head", ["mha", "gqa", "mqa"])
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("dtype,head", [
+    *[(d, h) for d in ("float32", "bfloat16", "int8")
+      for h in ("mha", "gqa", "mqa")],
+    ("float32", "latent"), ("bfloat16", "latent")])
 def test_bounded_read_on_every_boundary_ignores_what_it_may_not_touch(
         dtype, head, kk):
     """Depths at 0, one under / at / one over a page, a block and a
@@ -411,15 +484,18 @@ def test_bounded_read_on_every_boundary_ignores_what_it_may_not_touch(
     equals the whole-table read over the clean pool, in the band of its
     type (F32_ULPS in f32; int8's 1e-5; bf16's probability rounding,
     2 x 2^-8 of the scale), and it touched no block past a slot's
-    depth: rows read = steps x rows a step, by hand."""
+    depth: rows read = steps x rows a step, by hand. Latent rows: the
+    same depths, scratch page 0 poisoned as well, and a dead slot has
+    no item (K/V: one block)."""
     clean, dirty, args, live, positions = _boundary_case(
         dtype, HEAD_CONFIGS[head], kk)
     want, n_whole = _read(_whole, clean, *args)
-    got, n = _read(lambda *a: LOOP, dirty, *args)
+    got, n = _read(_loop, dirty, *args)
     per_block, per_step = LOOP
     ps, width = args[-1], per_block * args[-1]
     assert n_whole == len(live) * args[-2].shape[1] * ps
-    items = sum(int(positions[i, -1]) // width + 1 if live[i] else 1
+    dead = 0 if head == "latent" else 1
+    items = sum(int(positions[i, -1]) // width + 1 if live[i] else dead
                 for i in range(len(live)))
     assert n == -(-items // per_step) * per_step * width
     assert n < n_whole / 2
@@ -430,9 +506,8 @@ def test_bounded_read_on_every_boundary_ignores_what_it_may_not_touch(
     elif dtype == "int8":
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     else:
-        np.testing.assert_allclose(
-            got, want, rtol=0,
-            atol=2 * 2.0 ** -8 * max(1.0, float(np.max(np.abs(want)))))
+        np.testing.assert_allclose(got, want, rtol=0,
+                                   atol=_bf16_atol(head, want))
 
 
 def test_the_step_comes_from_the_bytes_the_table_moves():
@@ -447,6 +522,39 @@ def test_the_step_comes_from_the_bytes_the_table_moves():
     assert per_block == 64 and per_step >= 16         # every block at once
     per_block, per_step = read_step(16, 512, 16, 2 * (128 + 4))
     assert per_step < 16 * -(-512 // per_block)
+
+
+def test_the_latent_step_weighs_what_a_shared_row_costs():
+    """read_step at the benchmark's latent table (PERF.md section 4:
+    64 slots x 128 pages x 16 rows of 640 bf16 lanes, 128 heads), as
+    paged_update_attend_latent asks it: a row's bytes, the operations
+    of the H*kk query rows that all read it, the f32 output an item
+    leaves. The tick (1 query a slot) takes the loop in blocks of a few
+    hundred rows -- not the 832 the byte rule alone gives a 1,280-byte
+    row -- several items a step; the 32-row chunk (one slot, 4,096
+    query rows: a row's operations are 30 x its bytes, an item leaves
+    8 MB) takes it in lane-tile blocks, one item a step. The K/V
+    tables pass neither weight and keep their steps: chat's (8, 4),
+    generation's whole read."""
+    def latent(slots, kk, heads=128, lanes=640, rank=512):
+        return read_step(slots, 128, 16, lanes * 2,
+                         key_flops=2 * heads * kk * (lanes + rank),
+                         stat_bytes=heads * kk * rank * 4)
+
+    per_block, per_step = latent(64, 1)
+    assert 128 <= per_block * 16 <= 512 and 4 <= per_step <= 16
+    assert per_step < 64 * -(-128 // per_block)          # the loop
+    assert read_step(64, 128, 16, 640 * 2)[0] * 16 == 832
+    assert latent(1, 32) == (8, 1)
+    # A table the tiny presets use is read whole whatever a row weighs.
+    per_block, per_step = read_step(3, 20, 8, 128 * 4, key_flops=2 * 4 * 160,
+                                    stat_bytes=4 * 32 * 4)
+    assert per_step >= 3 * -(-20 // per_block)
+    assert read_step(8, 128, 16, 2 * 32 * 128 * 2) == (8, 4)
+    assert read_step(1, 128, 16, 2 * 32 * 128 * 2) == (8, 4)
+    for slots in (16, 1):
+        per_block, per_step = read_step(slots, 64, 16, 2 * (128 + 4))
+        assert per_block == 64 and per_step >= slots
 
 
 def test_kv_rows_read_is_the_hand_count_on_three_slots(loop):
