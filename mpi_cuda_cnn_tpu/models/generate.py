@@ -226,8 +226,10 @@ def token_forward(model: TransformerLM, params, toks, positions, attend,
     The block is data: what a layer computes is read off its params
     (transformer.norm: a gain alone is RMSNorm; TransformerLM.mlp: GELU
     `w1`/`w2`, gated `wg`/`wu`/`wd` at any width, or an `experts` bank;
-    `pos_emb` or none) and off `model.attn` (K/V heads or one latent
-    row), so layers of different kinds ride one loop.
+    `pos_emb` or none) and off the model where the params cannot say
+    (`attn`: K/V heads or one latent row; `layout`: which layers
+    rotate; `experts`: the router's kind and where it reads), so layers
+    of different kinds ride one loop.
 
     toks: (B, k) int32; positions: (k,) shared across rows, or (B, k)
     PER-ROW absolute positions (the serving form — each slot sits at
@@ -252,17 +254,31 @@ def token_forward(model: TransformerLM, params, toks, positions, attend,
         x = x + params["pos_emb"][positions]
     eps, counts = model.norm_eps, None
     for i, blk in enumerate(params["blocks"]):
+        # A router that reads the layer's input chooses here, before
+        # attention; its experts run on the stream after it.
+        routing = model.route(blk, x)
         y = norm(x, blk["ln1"], eps)
-        q, k, v = model.project_qkv(blk, y, positions=positions)
+        q, k, v = model.project_qkv(blk, y, positions=positions, layer=i)
         o = attend(i, q, k, v)
         x = x + qmatmul(o.astype(x.dtype), blk["wo"])
-        m, c = model.mlp(blk, norm(x, blk["ln2"], eps), valid)
+        m, c = model.mlp(blk, norm(x, blk["ln2"], eps), valid, routing)
         x = x + m
         if c is not None:
             counts = c if counts is None else jnp.concatenate(
                 [counts[:2] + c[:2], jnp.maximum(counts[2:], c[2:])])
     x = norm(x, params["ln_f"], eps)
     return qmatmul(x, params["head"]).astype(jnp.float32), counts
+
+
+def causal_mask(keys, queries, window: int = 0):
+    """True where the key at position `keys` is seen by the query at
+    position `queries` (broadcast against each other): keys <= queries,
+    and under a sliding window of `window` keys, the query itself
+    included, also keys > queries - window."""
+    seen = keys <= queries
+    if window:
+        seen = seen & (keys > queries - window)
+    return seen
 
 
 def attend_kv(q, ck, cv, mask, cks=None, cvs=None):
@@ -382,10 +398,11 @@ def attend_latent(q, rows, mask, wuk, wuv, a):
     return latent_values_up(ot, wuv)
 
 
-def attend_contiguous(c, q, k, v, pos, positions):
+def attend_contiguous(c, q, k, v, pos, positions, window: int = 0):
     """Contiguous-cache attend: write k/v at [pos, pos+k) of the static
     (B, max_seq, Hkv, hd) buffers, then attend each row i over keys at
-    positions <= positions[i] (attend_kv does the masked read).
+    positions <= positions[i] (attend_kv does the masked read); a
+    windowed layer's `window` is a mask here and nothing else.
     Returns (o: (B, k, H*hd) f32, new_c)."""
     int8 = c["k"].dtype == jnp.int8
     if int8:
@@ -406,8 +423,8 @@ def attend_contiguous(c, q, k, v, pos, positions):
         }
     # Rows attend over the cached prefix + the block's causal part:
     # row i sees keys at positions <= pos+i.
-    mask = (jnp.arange(new_c["k"].shape[1])[None, :]
-            <= positions[:, None])            # (k, max_seq)
+    mask = causal_mask(jnp.arange(new_c["k"].shape[1])[None, :],
+                       positions[:, None], window)       # (k, max_seq)
     o = attend_kv(q, new_c["k"], new_c["v"], mask,
                   cks=new_c.get("ks"), cvs=new_c.get("vs"))
     return o, new_c
@@ -434,7 +451,8 @@ def decode_block(model: TransformerLM, params, toks, pos, cache):
     (models/ must not depend on serve/ — serve/ imports THIS module).
     Returns (logits: (B, k, vocab), new_cache).
     """
-    if hasattr(cache, "block_table"):
+    if hasattr(cache[0] if isinstance(cache, tuple) else cache,
+               "block_table"):       # one PagedKVCache, or one a group
         from ..serve.paged_cache import paged_decode_block
 
         return paged_decode_block(model, params, toks, pos, cache)
@@ -448,7 +466,8 @@ def decode_block(model: TransformerLM, params, toks, pos, cache):
     new_cache = []
 
     def attend(i, q, k, v):
-        o, new_c = attend_contiguous(cache[i], q, k, v, pos, positions)
+        o, new_c = attend_contiguous(cache[i], q, k, v, pos, positions,
+                                     model.layer_window(i))
         new_cache.append(new_c)
         return o
 

@@ -118,10 +118,19 @@ class LatentAttn:
 @dataclasses.dataclass(frozen=True)
 class RoutedExperts:
     """The expert layer of one chip (parallel/ep.moe_held_inference):
-    the router scores all `experts` of the layer (sigmoid, a bias that
-    only chooses, `groups` groups of which `top_groups` stay, `top_k`
-    experts a token, weights normalised and times `scale`); `held` are
-    the ids whose weights are here, and only they are computed."""
+    the router scores all `experts` of the layer and takes `top_k` a
+    token, weights normalised and times `scale`; `held` are the ids
+    whose weights are here, and only they are computed.
+
+    `router`: "sigmoid" is DeepSeek-V3's (a bias that only chooses,
+    `groups` groups of which `top_groups` stay: ep.route_grouped),
+    "softmax" a softmax over all experts and its top_k, no bias and no
+    groups (ep.route_softmax). `act` is the gate branch's activation of
+    a held expert: "silu" (SwiGLU) or "relu" (ReGLU). `reads` says
+    which tensor the router scores: "block", the normed stream after
+    attention that the experts themselves read, or "layer_input", the
+    layer's residual input before its first norm — the choice is then
+    made before attention and used after it."""
 
     experts: int
     held: tuple[int, ...]
@@ -129,6 +138,18 @@ class RoutedExperts:
     groups: int = 1
     top_groups: int = 1
     scale: float = 1.0
+    router: str = "sigmoid"
+    act: str = "silu"
+    reads: str = "block"
+
+    def __post_init__(self):
+        for name, value, known in (
+                ("router", self.router, ("sigmoid", "softmax")),
+                ("act", self.act, ("silu", "relu")),
+                ("reads", self.reads, ("block", "layer_input"))):
+            if value not in known:
+                raise ValueError(f"RoutedExperts.{name} {value!r}: want "
+                                 f"one of {known}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,9 +160,11 @@ class TransformerLM:
     (the trainers) build and run one block: LayerNorm, K/V heads, the
     standard 4x GELU MLP. The cached decode forward
     (models/generate.token_forward) reads each layer's kind off its
-    params instead (`norm`, `mlp`), and `attn` / `experts` below
-    describe what the params alone cannot: latent attention's sizes and
-    an expert layer's routing. Such a model brings its own params tree
+    params instead (`norm`, `mlp`), and `attn` / `experts` /
+    `head_width` / `layout` below describe what the params alone
+    cannot: latent attention's sizes, an expert layer's routing, a head
+    width that is not dim / heads, and which layers rotate and which
+    see a sliding window only. Such a model brings its own params tree
     and is served through serve.PagedEngine.
 
     TPU sizing note (measured, PERF.md round-4 MFU ladder): prefer
@@ -169,13 +192,62 @@ class TransformerLM:
     attn: LatentAttn | None = None         # None = K/V heads as above
     experts: RoutedExperts | None = None   # routing of a block that
                            # holds an "experts" bank (serving only)
+    head_width: int = 0    # 0 = dim / heads; else each head's width,
+                           # heads x head_width need not be dim
+    rope_theta: float = 10000.0            # rotary base where pos="rope"
+    window: int = 0        # keys a windowed layer's query sees, itself
+                           # included (0 = the model has no such layer)
+    layout: tuple[tuple[bool, bool], ...] | None = None
+                           # per layer (rotary, windowed); None = every
+                           # layer as `pos` says, none windowed
     name: str = "transformer_lm"
+
+    def __post_init__(self):
+        if self.layout is None:
+            if self.window:
+                raise ValueError("a window needs a layout that says which "
+                                 "layers it applies to")
+            return
+        if self.pos != "rope" or self.attn is not None:
+            raise ValueError("a per-layer layout is of K/V heads with "
+                             "pos='rope' (a layer without rotary has no "
+                             "positional encoding at all)")
+        if len(self.layout) != self.depth:
+            raise ValueError(f"layout of {len(self.layout)} layers for "
+                             f"depth {self.depth}")
+        if any(w for _, w in self.layout) != (self.window > 0):
+            raise ValueError(f"window {self.window} with the windowed "
+                             f"layers {[w for _, w in self.layout]}")
 
     @property
     def head_dim(self) -> int:
+        if self.head_width:
+            return self.head_width
         if self.dim % self.heads:
             raise ValueError(f"dim {self.dim} not divisible by heads {self.heads}")
         return self.dim // self.heads
+
+    def rotary(self, layer: int | None) -> bool:
+        """Whether `layer`'s q and k are rotated."""
+        if self.layout is None or layer is None:
+            return self.pos == "rope"
+        return bool(self.layout[layer][0])
+
+    def layer_window(self, layer: int) -> int:
+        """The sliding window of `layer`, 0 where it sees every key."""
+        if self.layout is None or not self.layout[layer][1]:
+            return 0
+        return self.window
+
+    def cache_groups(self) -> tuple[tuple[int, tuple[int, ...]], ...]:
+        """The layer groups of the paged cache, (window, layers) each:
+        layers that forget at the same distance share a page pool's
+        accounting and a block table. The global layers (window 0)
+        first; a model without windowed layers has the one group."""
+        groups = {}
+        for i in range(self.depth):
+            groups.setdefault(self.layer_window(i), []).append(i)
+        return tuple((w, tuple(groups[w])) for w in sorted(groups))
 
     @property
     def n_kv(self) -> int:
@@ -190,11 +262,13 @@ class TransformerLM:
         return hkv
 
     def _gpt2_block_only(self, what: str) -> None:
-        if self.attn is not None or self.experts is not None:
+        if (self.attn is not None or self.experts is not None
+                or self.head_width or self.layout is not None):
             raise ValueError(
                 f"TransformerLM.{what} knows the LayerNorm/GELU block with "
-                "K/V heads; a model with latent attention or held experts "
-                "brings its params tree and is served through "
+                "K/V heads; a model with latent attention, held experts, "
+                "its own head width or a per-layer layout brings its "
+                "params tree and is served through "
                 "serve.PagedEngine (models/generate.token_forward)")
 
     def init(self, key) -> dict:
@@ -256,6 +330,7 @@ class TransformerLM:
         *,
         positions: jnp.ndarray,        # (S,) or (B, S) absolute positions
         compute_dtype=None,
+        layer: int | None = None,      # which layer (a `layout` is per layer)
     ):
         """QKV projections + head reshape + rotary — THE one
         implementation, shared by the training forward (apply_block) and
@@ -284,9 +359,9 @@ class TransformerLM:
         q = q.reshape(b, s, h, hd)
         k = k.reshape(b, s, hkv, hd)
         v = v.reshape(b, s, hkv, hd)
-        if self.pos == "rope":
-            q = rope(q, positions)
-            k = rope(k, positions)
+        if self.rotary(layer):
+            q = rope(q, positions, base=self.rope_theta)
+            k = rope(k, positions, base=self.rope_theta)
         return q, k, v
 
     def _project_latent(self, blk, y, positions, w):
@@ -311,21 +386,36 @@ class TransformerLM:
         kr = a.rotate(ckr[..., None, a.kv_rank:], positions)
         return q, jnp.concatenate([c[..., None, :], kr], axis=-1), None
 
-    def mlp(self, blk: dict, y: jnp.ndarray, valid=None):
+    def route(self, blk: dict, x: jnp.ndarray):
+        """The routing of an expert layer decided from its INPUT x
+        (B, S, dim), where the configuration says the router reads
+        there (`experts.reads` "layer_input"): (ids, weights) of
+        (B*S, top_k) for `mlp`; None for every other layer, whose
+        router (if any) reads what its experts read."""
+        if ("experts" not in blk or self.experts is None
+                or self.experts.reads != "layer_input"):
+            return None
+        from ..parallel.ep import route
+
+        return route(x.reshape(-1, x.shape[-1]), blk["router"], self.experts)
+
+    def mlp(self, blk: dict, y: jnp.ndarray, valid=None, routing=None):
         """The block's feed-forward on y (B, S, dim), by what the block
         holds: `w1`/`w2` the GELU MLP, `wg`/`wu`/`wd` a gated (SwiGLU)
         one at whatever width the matrices have, `experts` this chip's
         share of a routed expert layer (only rows in `valid` are
-        routed). Returns (out, counts): counts the expert layer's
-        int32 [token-expert pairs computed, held experts hit, largest
-        load], None elsewhere."""
+        routed; `routing` is `route`'s where the choice was made from
+        the layer's input, else the layer routes on y). Returns (out,
+        counts): counts the expert layer's int32 [token-expert pairs
+        computed, held experts hit, largest load], None elsewhere."""
         if "experts" in blk:
             from ..parallel.ep import moe_held_inference
 
             b, s, d = y.shape
             m, counts = moe_held_inference(
                 y.reshape(b * s, d), blk, self.experts,
-                valid=None if valid is None else valid.reshape(b * s))
+                valid=None if valid is None else valid.reshape(b * s),
+                routing=routing)
             return m.reshape(b, s, d), counts
         if self.moe_experts:
             from ..parallel.ep import moe_mlp_inference

@@ -395,11 +395,40 @@ def route_grouped(x, router: dict, spec):
     return ids.astype(jnp.int32), w
 
 
-def moe_held_inference(x, blk: dict, spec, valid=None):
+def route_softmax(x, router: dict, spec):
+    """The plain softmax router (SmallThinker's
+    `moe_primary_router_apply_softmax`, `norm_topk_prob`), in f32
+    whatever x is: p = softmax(x W_g) over ALL `spec.experts`, the
+    `spec.top_k` largest p are taken, and the weights are their p
+    normalised to sum 1 and times `spec.scale`. No bias, no groups.
+    router: `gate` (dim, experts), f32. Returns (ids (T, k) int32,
+    weights (T, k) f32)."""
+    p = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router["gate"].astype(jnp.float32),
+        precision=lax.Precision.HIGHEST), axis=-1)
+    w, ids = lax.top_k(p, spec.top_k)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * spec.scale
+    return ids.astype(jnp.int32), w
+
+
+def route(x, router: dict, spec):
+    """(ids, weights) of x's rows by the router `spec` names."""
+    if spec.router == "softmax":
+        return route_softmax(x, router, spec)
+    return route_grouped(x, router, spec)
+
+
+_GATE_ACT = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def moe_held_inference(x, blk: dict, spec, valid=None, routing=None):
     """One chip's share of a routed expert layer, for INFERENCE: route
-    every token over all the layer's experts (route_grouped), compute
+    every token over all the layer's experts (`route`, by the router
+    `spec` names; or take `routing` = (ids, weights) where the choice
+    was made elsewhere, from the layer's input), compute
     the chosen experts whose weights are HERE (`spec.held`, the ids of
-    the rows of the banks) and nothing else, add the shared expert.
+    the rows of the banks) and nothing else, add the shared expert
+    where the block has one.
     What the absent experts would add is another chip's to compute and
     is left out; nothing stands in for it or for the exchange.
 
@@ -413,13 +442,17 @@ def moe_held_inference(x, blk: dict, spec, valid=None):
     only those that hold a pair are computed: nothing is dropped and
     no capacity exists. A token's output depends on that token alone.
 
-    x: (T, dim); blk: `router` {gate, bias}, `experts` {wg, wu:
-    (held, dim, width), wd: (held, width, dim)}, `shared` {wg, wu, wd}
-    (ops/pallas_gemv.swiglu). Returns (y (T, dim), counts int32 [pairs
-    computed here, held experts with at least one, largest load])."""
+    x: (T, dim); blk: `router` {gate[, bias]}, `experts` {wg, wu:
+    (held, dim, width), wd: (held, width, dim)}, and where the layer
+    has one `shared` {wg, wu, wd} (ops/pallas_gemv.swiglu); the gate
+    branch's activation is `spec.act`. Returns (y (T, dim), counts
+    int32 [pairs computed here, held experts with at least one,
+    largest load])."""
     t, k, bank = x.shape[0], spec.top_k, blk["experts"]
     n = len(spec.held)
-    ids, w = route_grouped(x, blk["router"], spec)
+    ids, w = routing if routing is not None else route(
+        x, blk["router"], spec)
+    act = _GATE_ACT[spec.act]
     local_of = np.full(spec.experts, n, np.int32)   # n = "not here"
     local_of[list(spec.held)] = np.arange(n)
     local = jnp.asarray(local_of)[ids]                         # (T, k)
@@ -444,7 +477,7 @@ def moe_held_inference(x, blk: dict, spec, valid=None):
         size = (jnp.clip(ends - lo, 0, chunk)
                 - jnp.clip(ends - sizes - lo, 0, chunk))
         xc = lax.dynamic_slice_in_dim(xs, lo, chunk)
-        h = (jax.nn.silu(lax.ragged_dot(xc, bank["wg"], size, **f32))
+        h = (act(lax.ragged_dot(xc, bank["wg"], size, **f32))
              * lax.ragged_dot(xc, bank["wu"], size, **f32))
         yc = lax.ragged_dot(h.astype(x.dtype), bank["wd"], size, **f32)
         return lax.dynamic_update_slice_in_dim(ys, yc, lo, 0)
@@ -458,7 +491,9 @@ def moe_held_inference(x, blk: dict, spec, valid=None):
                    ys * jnp.pad(w.reshape(t * k), (0, rows - t * k))[
                        order][:, None], 0.0)
     y = jnp.sum(ys[jnp.argsort(order)[:t * k]].reshape(t, k, -1), axis=1)
-    y = y.astype(x.dtype) + swiglu(x, blk["shared"])
+    y = y.astype(x.dtype)
+    if "shared" in blk:
+        y = y + swiglu(x, blk["shared"])
     counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0), jnp.max(sizes)])
     return y, counts
 

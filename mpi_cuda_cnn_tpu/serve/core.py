@@ -40,7 +40,7 @@ storm) and how often to `sched.check()` the pool.
 from __future__ import annotations
 
 from .host_tier import HostTier
-from .pool import PagePool
+from .pool import PagePool, WindowGroup
 from .prefix_cache import PrefixCache, empty_prefix_fields
 from .scheduler import (
     ContinuousScheduler,
@@ -89,12 +89,30 @@ def build_scheduler(*, slots: int, num_pages: int, page_size: int,
                     prefix: bool = False, policy=None, host_pages: int = 0,
                     mode: str = "continuous", spill_fn=None,
                     readmit_fn=None, tier_fault_poll=None,
-                    route_keys: set | None = None):
+                    route_keys: set | None = None,
+                    window: tuple[int, int] | None = None):
     """A fresh PagePool with the scheduler over it and, where asked,
     the prefix tree and the host tier under it (reached afterwards as
     `sched.pool`, `sched.prefix`, `sched.prefix.tier`). Prefix sharing
     and an SLO policy are iteration-level: static batching is the
-    reservation baseline the comparison measures."""
+    reservation baseline the comparison measures.
+
+    `window` = (window, rows the largest forward writes a slot) gives
+    the scheduler a second, windowed layer group beside that pool
+    (pool.WindowGroup, `sched.window`), sized to full coverage. What
+    cannot yet mean anything for such a group is refused here: a
+    prefix hit (which layers would it be a hit for? a windowed layer
+    forgot the prefix) and with it the host tier's spill of prefix
+    pages."""
+    if window is not None and host_pages > 0:
+        raise ValueError(
+            "spill moves the prefix cache's pages of ONE layer group; a "
+            "model with a windowed group beside the global one has two")
+    if window is not None and prefix:
+        raise ValueError(
+            "prefix sharing shares the pages of ONE layer group; what a "
+            "hit means for a windowed group, which forgot the prefix, is "
+            "not defined yet (ROADMAP R5)")
     if host_pages > 0 and not prefix:
         raise ValueError(
             "host_pages > 0 without prefix=True — the host tier spills "
@@ -117,6 +135,10 @@ def build_scheduler(*, slots: int, num_pages: int, page_size: int,
               if prefix else None)
     kw = dict(slots=slots, pool=pool, page_size=page_size, max_len=max_len,
               max_queue=max_queue, prefix=pcache)
+    if window is not None:
+        kw["window"] = WindowGroup(
+            window=window[0], chunk=window[1], page_size=page_size,
+            slots=slots, max_len=max_len)
     if mode == "static":
         return StaticScheduler(**kw)
     if policy is not None:
@@ -131,7 +153,7 @@ class StepOutcome:
     __slots__ = ("swept", "rejected", "admitted", "prefill", "decoded",
                  "spec", "emitted", "progressed", "preempted_pairs",
                  "blocked", "prefix_tick", "new_fin", "new_drop",
-                 "state_crc")
+                 "state_crc", "window_freed")
 
     @property
     def moved(self) -> bool:
@@ -238,6 +260,7 @@ class ServeCore:
                 # BEFORE its first write lands there.
                 self.compute.copy_page(*slot.cow)
                 sched.cow_complete(slot)
+            sched.window_step(slot)
             n, nxt = self.compute.prefill_chunk(slot)
             slot.cached += n
             self.prefill_chunks += 1
@@ -278,6 +301,8 @@ class ServeCore:
         dslots = sched.grow_for_decode(
             now, spec_k=self.spec_k if speculating else 1)
         out.decoded = [[s.idx, s.req.rid] for s in dslots]
+        for s in dslots:
+            sched.window_step(s)
         spec_rec = None
         if dslots and spans is not None:
             spans.enter("tick.build")
@@ -340,6 +365,8 @@ class ServeCore:
         out.blocked = sched.drain_blocked()
         out.prefix_tick = (self.prefix.drain_tick()
                            if self.prefix is not None else None)
+        out.window_freed = (sched.window.drain_freed()
+                            if sched.window is not None else None)
         out.new_fin = sched.finished[self._n_fin:]
         out.new_drop = sched.dropped[self._n_drop:]
         self._n_fin, self._n_drop = len(sched.finished), len(sched.dropped)
@@ -378,6 +405,14 @@ class ServeCore:
             # recomputes it from the events above at every tick.
             "state_crc": out.state_crc,
         }
+        if sched.window is not None:
+            # A windowed layer group (pool.WindowGroup): the pages
+            # issued at the iteration's end, [global group, windowed
+            # group], and the pages the windowed group gave back
+            # behind windows in this iteration.
+            fields["pages_held"] = [p.usable - p.free_pages
+                                    for p in (sched.pool, sched.window.pool)]
+            fields["window_pages_freed"] = out.window_freed
         if out.spec is not None:
             # Speculative round detail (ISSUE 14): [rid, proposed,
             # accepted] per slot — `mctpu trace` derives the round's

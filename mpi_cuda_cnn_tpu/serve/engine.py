@@ -71,6 +71,7 @@ from .paged_cache import (
     paged_forward,
     pages_for,
 )
+from .pool import window_pages_per_slot
 from .prefix_cache import empty_prefix_fields
 from .spec import SPEC_MODES, LookupProposer, empty_spec_fields
 from .scheduler import Request, SLOPolicy, tenant_block, terminal_fields
@@ -84,6 +85,9 @@ from .scheduler import Request, SLOPolicy, tenant_block, terminal_fields
 # layout: latent rows, or K/V rows.
 TICK_COUNTS = ("moe_assignments", "moe_experts_hit", "moe_load_max",
                "latent_rows_read", "kv_rows_read")
+# ... and after them, where the model has windowed layers, the rows
+# THEIR reads touched (`kv_rows_read` stays all layers).
+WINDOW_COUNT = "kv_rows_read_window"
 
 # The key order of run()'s tick record (`spans` closes it): the core's
 # shared fields and run()'s own, laid out as the trail's readers and the
@@ -92,7 +96,8 @@ TICK_LAYOUT = (
     "tick", "now", "mode", "queue", "running", "prefilling", "free_pages",
     "backlog", "arrived", "admitted", "prefill", "decoded", "finished",
     "aborted", "preempted", "blocked", "preempted_for", "terminal",
-    "state_crc", "compiled", *TICK_COUNTS, "squeezed", "spec",
+    "state_crc", "compiled", *TICK_COUNTS, WINDOW_COUNT, "pages_held",
+    "window_pages_freed", "squeezed", "spec",
     "prefix_hits", "prefix", "prefix_readmits",
 )
 
@@ -282,7 +287,7 @@ def _observe_run_tick(registry, rec: dict, out, core: ServeCore) -> None:
     registry.set("serve.prefill_backlog", rec["backlog"])
     if out.emitted:
         registry.inc("serve.tokens_emitted", out.emitted)
-    for name in TICK_COUNTS:
+    for name in (*TICK_COUNTS, WINDOW_COUNT):
         if name in rec:
             registry.set(f"serve.{name}", rec[name])
     for _, _, accepted in out.spec or ():
@@ -702,11 +707,35 @@ class PagedEngine:
                                            kv_heads=model.n_kv)
         self.cache_dtype = jnp.dtype(cache_dtype)
         self.max_len = min(max_len or model.max_seq, model.max_seq)
-        tmpl = init_paged_cache(model, slots=slots, num_pages=num_pages,
-                                page_size=page_size, dtype=self.cache_dtype,
-                                max_len=self.max_len)
-        self._pages = tmpl.pages
-        self._table_width = tmpl.block_table.shape[1]
+        # Layer groups (TransformerLM.cache_groups): every model has
+        # the global one, `num_pages` is its pool and admission's. A
+        # model with windowed layers too has a second, `_window` =
+        # (window, chunk), sized here to full coverage
+        # (pool.WindowGroup); `_pages` is then one list of pools a
+        # group, and a cache view one PagedKVCache a group.
+        windows = [w for w, _ in model.cache_groups()]
+        if windows[0] != 0 or len(windows) > 2:
+            raise ValueError(
+                f"layer groups with windows {windows}: the engine admits "
+                "by a global group's pool and serves one windowed group "
+                "beside it")
+        self._window = (windows[1], prefill_chunk) if windows[1:] else None
+        if self._window is not None and spec != "off":
+            raise ValueError(
+                "speculation rolls back rejected rows' pages in ONE "
+                "layer group; a windowed group may already have given "
+                "back the pages a rollback would need")
+        tmpl = init_paged_cache(
+            model, slots=slots, num_pages=num_pages, page_size=page_size,
+            dtype=self.cache_dtype, max_len=self.max_len,
+            window_pages=None if self._window is None else 1 + slots
+            * window_pages_per_slot(*self._window, page_size, self.max_len))
+        if self._window is None:
+            self._pages = tmpl.pages
+            self._table_width = tmpl.block_table.shape[1]
+        else:
+            self._pages = tuple(c.pages for c in tmpl)
+            self._table_width = tmpl[0].block_table.shape[1]
 
         def tick(cache: PagedKVCache, params, toks, pos, live):
             logits, cache = paged_forward(
@@ -808,15 +837,47 @@ class PagedEngine:
 
     # -- host-side helpers ------------------------------------------------
 
-    def _cache_view(self, table: np.ndarray) -> PagedKVCache:
-        return PagedKVCache(pages=self._pages,
-                            block_table=jnp.asarray(table),
-                            page_size=self.page_size)
+    def _cache_view(self, table):
+        """The device cache under `table`: a (rows, table width) block
+        table and one PagedKVCache, or for a model with a windowed
+        group the two groups' tables and one PagedKVCache each."""
+        if self._window is None:
+            return PagedKVCache(pages=self._pages,
+                                block_table=jnp.asarray(table),
+                                page_size=self.page_size)
+        return tuple(
+            PagedKVCache(pages=pages, block_table=jnp.asarray(t),
+                         page_size=self.page_size, window=window)
+            for pages, t, window in zip(self._pages, table,
+                                        (0, self._window[0])))
 
-    def _slot_table(self, slot) -> np.ndarray:
-        row = np.zeros((1, self._table_width), np.int32)
-        row[0, : len(slot.pages)] = slot.pages
-        return row
+    def _tables(self, rows: int, slots):
+        """Block tables of `rows` rows for `slots` ((row, slot) pairs):
+        the global group's, and the windowed group's beside it where
+        the model has one (`_cache_view`'s argument)."""
+        table = np.zeros((rows, self._table_width), np.int32)
+        for i, s in slots:
+            table[i, : len(s.pages)] = s.pages
+        if self._window is None:
+            return table
+        wtable = np.zeros((rows, self._table_width), np.int32)
+        for i, s in slots:
+            wtable[i, : len(s.wpages)] = s.wpages
+        return table, wtable
+
+    def _keep(self, cache):
+        """Adopt a program's returned cache; returns its counts."""
+        if self._window is None:
+            self._pages = cache.pages
+            return cache.counts
+        self._pages = tuple(c.pages for c in cache)
+        return cache[0].counts
+
+    def _one_group(self, what: str) -> None:
+        if self._window is not None:
+            raise ValueError(
+                f"{what} moves the pages of ONE layer group; this model "
+                "has a windowed group beside the global one")
 
     def compiled_programs(self) -> int:
         """Compiled forms held by the engine's jitted programs, summed
@@ -838,6 +899,7 @@ class PagedEngine:
         every layer's pools (keys and values, plus int8 scales). The
         caller (ServeCore.work) releases the shared source's reference
         via scheduler.cow_complete afterwards."""
+        self._one_group("copy-on-write")
         self._pages = self._copy(self._pages, jnp.int32(src),
                                  jnp.int32(dst))
 
@@ -847,6 +909,7 @@ class PagedEngine:
         device->host transfer happens HERE, before the pool frees the
         page; the page's content is then owned by the tier entry until
         readmission or host eviction."""
+        self._one_group("spill")
         # Device->host fetch of the evicted page — the spill's one
         # sanctioned sync (an np.asarray per layer pool).
         # mctpu: disable=MCT007
@@ -858,6 +921,7 @@ class PagedEngine:
         page `page` — HostTier.readmit_fn, called only AFTER the CRC
         verify accepted the entry (a refused spill is never restored,
         so garbage rows cannot enter the pools)."""
+        self._one_group("readmission")
         self._pages = self._restore(
             self._pages,
             [{name: jnp.asarray(h[name]) for name in h} for h in payload],
@@ -873,6 +937,8 @@ class PagedEngine:
         must share the cache geometry — the fleet builds every replica
         from one model/config, which is also what makes the handed-off
         decode bitwise-equal to the unified one."""
+        self._one_group("hand-off")
+        src_engine._one_group("hand-off")
         if (src_engine.page_size != self.page_size
                 or src_engine.cache_dtype != self.cache_dtype
                 or len(src_engine._pages) != len(self._pages)):
@@ -921,12 +987,12 @@ class PagedEngine:
         n = min(self.prefill_chunk, slot.target - slot.cached)
         toks = np.zeros((1, self.prefill_chunk), np.int32)
         toks[0, :n] = ctx[slot.cached : slot.cached + n]
-        view = self._cache_view(self._slot_table(slot))
+        view = self._cache_view(self._tables(1, [(0, slot)]))
         inputs = (jnp.asarray(toks), jnp.int32(slot.cached), jnp.int32(n))
         if self._spans is not None:
             self._spans.enter("prefill.dispatch")
         cache, nxt = self._prefill(view, self.params, *inputs)
-        self._pages = cache.pages
+        self._keep(cache)
         return n, nxt
 
     def run_decode_tick(self, dslots) -> np.ndarray:
@@ -939,18 +1005,17 @@ class PagedEngine:
         toks = np.zeros((self.slots,), np.int32)
         pos = np.zeros((self.slots,), np.int32)
         live = np.zeros((self.slots,), bool)
-        table = np.zeros((self.slots, self._table_width), np.int32)
         for s in dslots:
             toks[s.idx] = s.req.out[-1]
             pos[s.idx] = s.cached
             live[s.idx] = True
-            table[s.idx, : len(s.pages)] = s.pages
-        view = self._cache_view(table)
+        view = self._cache_view(
+            self._tables(self.slots, [(s.idx, s) for s in dslots]))
         inputs = (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(live))
         if self._spans is not None:
             self._spans.enter("tick.dispatch")
         cache, nxt = self._tick(view, self.params, *inputs)
-        self._pages, self._tick_counts = cache.pages, cache.counts
+        self._tick_counts = self._keep(cache)
         # The donated pools' old handles (a few per layer) go now, under
         # the device's work — not after the read below, where freeing
         # them is time the device stands idle (0.4 ms at 42 layers).
@@ -975,18 +1040,17 @@ class PagedEngine:
         toks = np.zeros((self.slots, kk), np.int32)
         pos = np.zeros((self.slots,), np.int32)
         valid = np.zeros((self.slots, kk), bool)
-        table = np.zeros((self.slots, self._table_width), np.int32)
         for s, u, w in rounds:
             toks[s.idx, :w] = u
             pos[s.idx] = s.cached
             valid[s.idx, :w] = True
-            table[s.idx, : len(s.pages)] = s.pages
-        view = self._cache_view(table)
+        view = self._cache_view(
+            self._tables(self.slots, [(s.idx, s) for s, _, _ in rounds]))
         inputs = (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(valid))
         if self._spans is not None:
             self._spans.enter("tick.dispatch")
         cache, picks = self._spec(view, self.params, *inputs)
-        self._pages, self._tick_counts = cache.pages, cache.counts
+        self._tick_counts = self._keep(cache)
         del view, inputs    # as in run_decode_tick: before the read
         if self._spans is not None:
             self._spans.enter("tick.wait")
@@ -1025,6 +1089,8 @@ class PagedEngine:
             # the device beside the tokens, read here and nowhere else.
             # mctpu: disable=MCT007
             counted = np.asarray(self._tick_counts).tolist()
+            if self._window is not None:
+                fields[WINDOW_COUNT] = counted.pop()
             names = (*TICK_COUNTS[:3],
                      "latent_rows_read" if self.model.attn is not None
                      else "kv_rows_read")
@@ -1153,6 +1219,7 @@ class PagedEngine:
             readmit_fn=self.readmit_page,
             tier_fault_poll=((lambda seq: faults.poll(TIER_SPILL_SITE, seq))
                              if faults is not None else None),
+            window=self._window,
         )
         core = ServeCore(EngineCompute(self), sched, proposer=proposer,
                          spec_k=self.spec_k)
@@ -1323,6 +1390,9 @@ class PagedEngine:
                 "a terminal status"
             )
         assert sched.pool.free_pages == sched.pool.usable, "pages leaked"
+        if sched.window is not None:
+            wpool = sched.window.pool
+            assert wpool.free_pages == wpool.usable, "windowed pages leaked"
         return ServeResult(
             mode=mode, requests=terminal, decode_ticks=core.decode_ticks,
             prefill_chunks=core.prefill_chunks,

@@ -235,6 +235,11 @@ class Replica:
         # spill/readmit programs; the sim tier is accounting-only (same
         # schedule, no device rows).
         engine = getattr(compute, "engine", None)
+        if getattr(engine, "_window", None) is not None:
+            raise ValueError(
+                "the fleet's hand-off and failover move a slot as the page "
+                "set of ONE layer group; this replica's model has a "
+                "windowed group beside the global one")
         sched = build_scheduler(
             slots=slots, num_pages=num_pages, page_size=page_size,
             max_len=max_len, max_queue=max_queue, prefix=prefix,

@@ -63,6 +63,23 @@ weighs its operations beside its bytes when `read_step` sizes the
 block (the benchmark's `dots` tick: 27 pages a block, 7 blocks a
 step; its 32-row chunk: 8 pages, 1).
 
+LAYER GROUPS (PR 32). A model whose layers do not all see equally far
+back -- sliding-window layers beside global ones -- is served from one
+PagedKVCache a GROUP of layers (TransformerLM.cache_groups): the
+group's layers' pools, its own block table, its `window`. Every layer
+still reads through `bounded_read`; a windowed group's item list
+starts at the block that holds the first key its slot's window still
+sees (`_read_items`), its mask drops what lies behind
+(`generate.causal_mask`), and the host gives the pages wholly behind
+back to the group's own pool (pool.WindowGroup), so their table
+entries read scratch and are never looked at. A slot with MANY query
+rows (`_many_queries`: a 512-row prefill chunk of 28 heads leaves 7.3
+MB of f32 output an item) takes the latent read's way through the same
+loop: the step sized by the row's operations and the item's statistics
+too, one running softmax a slot carried and folded inside the loop. A
+model with one group, and every read of a tick or a 32-row chunk, is
+the code it was.
+
 What was measured on the v5e (PERF.md section 6, PR 29; one tick's
 reads at chat's shapes, 8 layers): whole-table gather 20.6 ms, bounded
 2.1 ms; the former Pallas kernel (one page a grid step over the whole
@@ -89,11 +106,13 @@ from ..models.generate import (
     _quant_kv,
     attend_kv,
     attend_latent,
+    causal_mask,
     latent_query_rows,
     latent_values_up,
     token_forward,
 )
 from ..models.transformer import TransformerLM
+from ..obs.trace import annotate
 from ..ops.attention import NEG_INF
 
 # Host-side page accounting lives in pool.py (jax-free — the policy
@@ -104,8 +123,15 @@ from .pool import PagePool, pages_for  # noqa: F401
 
 @dataclasses.dataclass
 class PagedKVCache:
-    """Device-side paged cache state: per-layer page pools + the block
-    table mapping each slot's logical positions to physical pages.
+    """Device-side paged cache state of one LAYER GROUP: the page
+    pools of its layers + the block table mapping each slot's logical
+    positions to physical pages, and the group's `window` (0: its
+    layers see every key; else they see the last `window`, and the
+    table's entries behind a slot's window may have gone back to
+    scratch). A model whose layers all forget alike (every model but
+    one with windowed layers beside global ones) has one group and is
+    served from one PagedKVCache; one with both kinds from a tuple of
+    them, in `TransformerLM.cache_groups()`'s order.
     `page_size` is static metadata (it shapes the compiled program).
     `kernel` names the one read there is and chooses nothing: it stays
     because benchmarks/compile_only.py passes it, and goes with that
@@ -115,10 +141,13 @@ class PagedKVCache:
     block_table: jnp.ndarray      # (slots, pages_per_slot) int32
     page_size: int
     kernel: str = "gather"
+    window: int = 0
     # What the forward that produced this cache counted, for the tick
     # record: int32 [expert pairs computed, held experts hit, largest
     # expert load] where the model has expert layers, then the cache
-    # rows the read touched (paged_forward); None before any forward.
+    # rows the read touched, and where the model has windowed layers
+    # the rows THEIR reads touched (paged_forward; on the first group's
+    # cache); None before any forward.
     counts: jnp.ndarray | None = None
 
     @property
@@ -132,13 +161,19 @@ class PagedKVCache:
 
 jax.tree_util.register_dataclass(
     PagedKVCache, data_fields=["pages", "block_table", "counts"],
-    meta_fields=["page_size", "kernel"],
+    meta_fields=["page_size", "kernel", "window"],
 )
+
 
 def init_paged_cache(model: TransformerLM, *, slots: int, num_pages: int,
                      page_size: int, dtype=jnp.float32,
-                     max_len: int | None = None) -> PagedKVCache:
-    """Empty page pools + an all-scratch block table.
+                     max_len: int | None = None,
+                     window_pages: int | None = None):
+    """Empty page pools + an all-scratch block table: one PagedKVCache,
+    or for a model with windowed layers beside global ones a tuple of
+    them, one a layer group (TransformerLM.cache_groups), each with the
+    pools of ITS layers and a table of its own; `window_pages` sizes a
+    windowed group's pools (default: as `num_pages`).
 
     num_pages INCLUDES the reserved scratch page 0, so num_pages - 1
     pages are allocatable; max_len (default model.max_seq) bounds any
@@ -170,23 +205,36 @@ def init_paged_cache(model: TransformerLM, *, slots: int, num_pages: int,
             for _ in range(model.depth)]
         return PagedKVCache(pages=pages, block_table=table,
                             page_size=page_size)
-    shape = (num_pages, page_size, model.n_kv, model.head_dim)
     int8 = jnp.dtype(dtype) == jnp.int8
-    sshape = shape[:-1] + (1,)
-    pages = []
-    for _ in range(model.depth):
-        if int8:
-            pages.append({
-                "k": jnp.zeros(shape, jnp.int8),
-                "ks": jnp.zeros(sshape, jnp.float32),
-                "v": jnp.zeros(shape, jnp.int8),
-                "vs": jnp.zeros(sshape, jnp.float32),
-            })
-        else:
-            pages.append({"k": jnp.zeros(shape, dtype),
-                          "v": jnp.zeros(shape, dtype)})
-    return PagedKVCache(pages=pages, block_table=table,
-                        page_size=page_size)
+
+    def pools(layers: int, num_pages: int) -> list[dict]:
+        shape = (num_pages, page_size, model.n_kv, model.head_dim)
+        sshape = shape[:-1] + (1,)
+        pages = []
+        for _ in range(layers):
+            if int8:
+                pages.append({
+                    "k": jnp.zeros(shape, jnp.int8),
+                    "ks": jnp.zeros(sshape, jnp.float32),
+                    "v": jnp.zeros(shape, jnp.int8),
+                    "vs": jnp.zeros(sshape, jnp.float32),
+                })
+            else:
+                pages.append({"k": jnp.zeros(shape, dtype),
+                              "v": jnp.zeros(shape, dtype)})
+        return pages
+
+    groups = model.cache_groups()
+    if len(groups) == 1:
+        return PagedKVCache(pages=pools(model.depth, num_pages),
+                            block_table=table, page_size=page_size,
+                            window=groups[0][0])
+    return tuple(
+        PagedKVCache(
+            pages=pools(len(layers),
+                        (window_pages or num_pages) if window else num_pages),
+            block_table=table, page_size=page_size, window=window)
+        for window, layers in groups)
 
 
 _LANES = 128    # the TPU's lane tile: a row's stride in a pool
@@ -242,8 +290,23 @@ def paged_update_attend_latent(c: dict, q, row, positions, valid,
     return o, {"c": pool}, rows
 
 
+def _many_queries(q) -> bool:
+    """Whether a (slot, block) item's f32 output -- every head of every
+    query row of the slot, (H, kk, hd) -- outweighs a block's rows
+    (_BLOCK_BYTES): a 512-row prefill chunk of 28 heads leaves 7.3 MB an
+    item. The K/V read then sizes its step as the latent read does
+    (the operations of the query rows that share a cache row, and the
+    item's statistics, beside the row's bytes) and folds its items into
+    one running softmax a slot inside the loop, as bounded_read_latent
+    does, instead of keeping every item's for a fold afterwards. A
+    tick's one row and a 32-row chunk are far under it (0.5 MB at 32
+    heads) and read as PR 29 timed them."""
+    _, kk, h, hd = q.shape
+    return h * kk * hd * 4 > _BLOCK_BYTES
+
+
 def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
-                        page_size: int):
+                        page_size: int, window: int = 0):
     """One layer's paged write + attention read.
 
     q: (B, kk, H, hd); k/v: (B, kk, Hkv, hd); positions: (B, kk)
@@ -255,7 +318,11 @@ def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
     pages up to its deepest valid position, masked to key positions <=
     the row's own; rows of a page past a slot's written extent hold
     whatever they hold -- the mask keeps them out of the softmax, and
-    pages past the slot's last block are not touched at all.
+    pages past the slot's last block are not touched at all. Under a
+    sliding `window` a row also sees no key at or before its position
+    - window, and the read starts at the block that holds the smallest
+    valid position's window: blocks wholly behind it are not touched
+    either (their table entries may be scratch).
     Returns (o: (B, kk, H*hd) f32, new_c, cache rows the read touched).
     """
     b, kk = positions.shape
@@ -281,9 +348,18 @@ def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
         }
     key_bytes = sum(int(np.prod(a.shape[2:])) * a.dtype.itemsize
                     for a in new_c.values())
+    # Many query rows a slot: a cache row costs its score and its value
+    # product for every one of them (4 operations a query element), and
+    # an item leaves that many f32 values -- folded into a carry a
+    # slot of that size.
+    many = 4 * q.shape[2] * kk * hd
     o, rows = bounded_read(
         q, new_c, positions, valid, block_table, page_size=page_size,
-        step=read_step(b, block_table.shape[1], page_size, key_bytes))
+        step=read_step(b, block_table.shape[1], page_size, key_bytes,
+                       **(dict(key_flops=many, stat_bytes=many,
+                               carry_bytes=many)
+                          if _many_queries(q) else {})),
+        window=window)
     return o, new_c, rows
 
 
@@ -302,7 +378,8 @@ _FLOPS_PER_BYTE = 240
 
 
 def read_step(slots: int, npages: int, page_size: int, key_bytes: int,
-              *, key_flops: int = 0, stat_bytes: int = 0) -> tuple[int, int]:
+              *, key_flops: int = 0, stat_bytes: int = 0,
+              carry_bytes: int = 0) -> tuple[int, int]:
     """(pages a block, blocks a step) of the bounded read, from what
     the table moves: `key_bytes` is one cache row, K and V and their
     scales. A block is at least a lane tile of keys and _BLOCK_BYTES; a
@@ -317,9 +394,20 @@ def read_step(slots: int, npages: int, page_size: int, key_bytes: int,
     the row's bytes, and `stat_bytes` (what an item leaves) beside a
     block's in the step. The K/V layouts pass neither: there both are
     a small part of the bytes (a chat tick: 0.4% and 0.8%), and their
-    steps are as PR 29 timed them."""
+    steps are as PR 29 timed them.
+
+    Where the loop carries ONE running softmax a slot and every item
+    is folded into it (the K/V read of a slot with many query rows,
+    bounded_read's running fold), each item reads and writes its slot's
+    `carry_bytes` whatever its block holds, so a block weighs at least
+    that, in whole lane tiles of keys: a 512-row chunk of 28 heads
+    (7.3 MB of carry, 32.6 KB a key) takes 512-key blocks, and its
+    chunk 25.5 ms where 128-key blocks took 31.6 (PERF.md section 6,
+    PR 32)."""
     weight = key_bytes + key_flops // _FLOPS_PER_BYTE
     keys = max(_LANES, -(-_BLOCK_BYTES // weight))
+    if carry_bytes:
+        keys = max(keys, -(-2 * carry_bytes // (weight * _LANES)) * _LANES)
     per_block = min(npages, -(-keys // page_size))
     blocks = slots * -(-npages // per_block)
     per_step = -(-_STEP_BYTES // (per_block * page_size * weight + stat_bytes))
@@ -327,10 +415,13 @@ def read_step(slots: int, npages: int, page_size: int, key_bytes: int,
 
 
 def _read_items(positions, valid, block_table, page_size: int,
-                step: tuple[int, int], dead_blocks: int):
+                step: tuple[int, int], dead_blocks: int, window: int = 0):
     """The flat list of (slot, block of pages) items both bounded reads
     walk, built on the device from `positions` and `valid`: a live
-    slot's blocks up to its deepest valid position, a dead slot's (no
+    slot's blocks up to its deepest valid position -- from block 0, or
+    under a sliding `window` from the block that holds the first key
+    its SMALLEST valid position still sees (position - window + 1) --
+    a dead slot's (no
     valid token) `dead_blocks` -- 1 for the K/V read, whose dead rows
     read one block of scratch, 0 for the latent read, which skips them.
     Returns (need, ends, steps, slot, live, item_pages, first_key):
@@ -345,6 +436,10 @@ def _read_items(positions, valid, block_table, page_size: int,
     # Each slot's blocks; its table, padded with scratch to whole blocks.
     depth = jnp.max(jnp.where(valid, positions, 0), axis=1)
     need = jnp.minimum(depth // width + 1, nblk)              # (B,)
+    if window:
+        lowest = jnp.min(jnp.where(valid, positions, depth[:, None]), axis=1)
+        first = jnp.maximum(lowest - window + 1, 0) // width  # (B,)
+        need = need - first
     if not dead_blocks:
         need = jnp.where(jnp.any(valid, axis=1), need, 0)
     ends = jnp.cumsum(need)
@@ -358,15 +453,17 @@ def _read_items(positions, valid, block_table, page_size: int,
     item = jnp.arange(-(-b * nblk // per_step) * per_step)
     slot = jnp.minimum(jnp.sum(item[:, None] >= ends[None, :], axis=1), b - 1)
     blk = item - (ends - need)[slot]
+    if window:
+        blk = blk + first[slot]
     live = item < ends[-1]
     item_pages = jnp.where(live[:, None],
                            blocks[slot * nblk + jnp.where(live, blk, 0)], 0)
     return need, ends, steps, slot, live, item_pages, blk * width
 
 
-@functools.partial(jax.jit, static_argnames=("page_size", "step"))
+@functools.partial(jax.jit, static_argnames=("page_size", "step", "window"))
 def bounded_read(q, c: dict, positions, valid, block_table, *,
-                 page_size: int, step: tuple[int, int]):
+                 page_size: int, step: tuple[int, int], window: int = 0):
     """The attention read over one layer's page pools `c`, bounded by
     what each slot holds; `step` = (per_block, per_step) is read_step's.
     Jitted, so a program of many layers traces it once and not once a
@@ -393,6 +490,14 @@ def bounded_read(q, c: dict, positions, valid, block_table, *,
     the loop would run once over the whole table, and the read IS the
     gather of the table and attend_kv.
 
+    Under a sliding `window` the mask also drops keys at or before a
+    row's position - window, and a slot's items start at its window's
+    first block (_read_items). Where a slot has many query rows
+    (_many_queries: a 512-row chunk) an item's statistics are not kept:
+    the loop carries one running (maximum, denominator, output) a slot
+    and folds each of a step's items into its slot's, as
+    bounded_read_latent does.
+
     Returns (o: (B, kk, H*hd) f32, cache rows the read touched: steps
     taken x rows a step, int32)."""
     b, kk, h, hd = q.shape
@@ -406,21 +511,23 @@ def bounded_read(q, c: dict, positions, valid, block_table, *,
         length = npages * page_size
         rows = {n: c[n][block_table].reshape(b, length, *c[n].shape[2:])
                 for n in c}
-        mask = jnp.arange(length)[None, None, :] <= positions[:, :, None]
+        mask = causal_mask(jnp.arange(length)[None, None, :],
+                           positions[:, :, None], window)
         o = attend_kv(q, rows["k"], rows["v"], mask,
                       cks=rows.get("ks"), cvs=rows.get("vs"))
         return o, jnp.int32(b * length)
 
     width = per_block * page_size                 # keys a block
-    need, ends, steps, slot, _, item_pages, first_key = _read_items(
-        positions, valid, block_table, page_size, step, dead_blocks=1)
+    need, ends, steps, slot, live, item_pages, first_key = _read_items(
+        positions, valid, block_table, page_size, step, dead_blocks=1,
+        window=window)
     qg = q.reshape(b, kk, hkv, g, hd)
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    stat = (slot.shape[0], hkv, g, kk)
+    running = _many_queries(q)
 
-    def take(i, carry):
-        m_buf, l_buf, o_buf = carry
-        at = i * per_step
+    def items(at):
+        """The `per_step` items from `at`: their slots, row maxima,
+        unnormalised probabilities and outputs."""
         sl = jax.lax.dynamic_slice_in_dim(slot, at, per_step)
         pages = jax.lax.dynamic_slice_in_dim(item_pages, at, per_step)
         key0 = jax.lax.dynamic_slice_in_dim(first_key, at, per_step)
@@ -433,8 +540,12 @@ def bounded_read(q, c: dict, positions, valid, block_table, *,
         if int8:
             logits = logits * jnp.transpose(
                 rows["ks"], (0, 2, 3, 1))[:, :, None, :, :]
-        mask = ((key0[:, None, None] + jnp.arange(width)[None, None, :])
-                <= positions[sl][:, :, None])[:, None, None, :, :]
+        mask = causal_mask(
+            key0[:, None, None] + jnp.arange(width)[None, None, :],
+            positions[sl][:, :, None], window)[:, None, None, :, :]
+        if running:     # an item past the list's end folds as nothing
+            mask = mask & jax.lax.dynamic_slice_in_dim(
+                live, at, per_step)[:, None, None, None, None]
         logits = jnp.where(mask, logits, NEG_INF)
         m = jnp.max(logits, axis=-1)
         p = jnp.where(mask, jnp.exp(logits - m[..., None]), 0.0)
@@ -446,6 +557,40 @@ def bounded_read(q, c: dict, positions, valid, block_table, *,
         else:
             o = jnp.einsum("ihgqk,ikhd->ihgqd", p.astype(rows["v"].dtype),
                            rows["v"], preferred_element_type=jnp.float32)
+        return sl, m, p, o
+
+    if running:
+        def fold(i, carry):
+            sl, m, p, o = items(i * per_step)
+            denom = jnp.sum(p, axis=-1)
+            for j in range(per_step):       # one slot's row of the carry
+                old = [jax.lax.dynamic_index_in_dim(x, sl[j], keepdims=False)
+                       for x in carry]
+                top = jnp.maximum(old[0], m[j])
+                keep, w = jnp.exp(old[0] - top), jnp.exp(m[j] - top)
+                new = (top, keep * old[1] + w * denom[j],
+                       keep[..., None] * old[2] + w[..., None] * o[j])
+                carry = tuple(
+                    jax.lax.dynamic_update_index_in_dim(x, n, sl[j], 0)
+                    for x, n in zip(carry, new))
+            return carry
+
+        stat = (b, hkv, g, kk)
+        _, denom, o = jax.lax.fori_loop(
+            0, steps, fold,
+            (jnp.full(stat, NEG_INF, jnp.float32),
+             jnp.zeros(stat, jnp.float32),
+             jnp.zeros(stat + (hd,), jnp.float32)))
+        o = o / jnp.where(denom > 0, denom, 1.0)[..., None]
+        o = jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(b, kk, h * hd)
+        return o, (steps * (per_step * width)).astype(jnp.int32)
+
+    stat = (slot.shape[0], hkv, g, kk)
+
+    def take(i, carry):
+        m_buf, l_buf, o_buf = carry
+        at = i * per_step
+        _, m, p, o = items(at)
         put = jax.lax.dynamic_update_slice_in_dim
         return (put(m_buf, m, at, 0), put(l_buf, jnp.sum(p, axis=-1), at, 0),
                 put(o_buf, o, at, 0))
@@ -463,6 +608,8 @@ def bounded_read(q, c: dict, positions, valid, block_table, *,
     top = jnp.max(m, axis=1, keepdims=True)
     w = jnp.where(read, jnp.exp(m - top), 0.0)
     denom = jnp.sum(w * l_buf[mine], axis=1)
+    if window:      # a row that is no request's may see no key at all
+        denom = jnp.where(denom > 0, denom, 1.0)
     o = jnp.sum(w[..., None] * o_buf[mine], axis=1) / denom[..., None]
     o = jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(b, kk, h * hd)
     return o, (steps * (per_step * width)).astype(jnp.int32)
@@ -575,31 +722,54 @@ def bounded_read_latent(q, pool, positions, valid, block_table, wuk, wuv, *,
 
 
 def paged_forward(model: TransformerLM, params, toks, positions, valid,
-                  cache: PagedKVCache):
+                  cache):
     """toks (B, kk) through the model against the paged cache — the
     paged twin of decode_block's contiguous path, same token_forward
     skeleton, attend swapped (by the pool's layout: K/V heads, or
-    latent rows where the model has latent attention).
+    latent rows where the model has latent attention). `cache` is one
+    PagedKVCache, or one a layer group for a model with windowed
+    layers beside global ones (TransformerLM.cache_groups' order):
+    each layer writes and reads its group's pools through its group's
+    table, a windowed group's under its window.
     positions/valid: (B, kk).
-    Returns (logits (B, kk, vocab) f32, new PagedKVCache); the new
-    cache carries this forward's `counts`: the expert layers' three
+    Returns (logits (B, kk, vocab) f32, the new cache in the form it
+    came); the new cache (of several: the first) carries this
+    forward's `counts`: the expert layers' three
     where the model has any (and zeros for a latent model without),
-    then the cache rows the read touched, all layers together."""
-    new_pages: list[dict] = []
-    rows_read = 0
+    then the cache rows the read touched, all layers together, then
+    where the model has windowed layers the rows their reads touched."""
+    caches = cache if isinstance(cache, tuple) else (cache,)
+    groups = model.cache_groups()
+    if [c.window for c in caches] != [w for w, _ in groups]:
+        raise ValueError(
+            f"the model's layer groups have windows {[w for w, _ in groups]}"
+            f"; the cache's {[c.window for c in caches]}")
+    group_of = {i: g for g, (_, layers) in enumerate(groups) for i in layers}
+    new_pages: list[list[dict]] = [[] for _ in caches]
+    rows_read = rows_window = 0
 
     def attend(i, q, k, v):
-        nonlocal rows_read
+        nonlocal rows_read, rows_window
+        g = group_of[i]
+        c, pools = caches[g], caches[g].pages[len(new_pages[g])]
         if model.attn is not None:
             o, new_c, n = paged_update_attend_latent(
-                cache.pages[i], q, k, positions, valid, cache.block_table,
-                cache.page_size, params["blocks"][i], model.attn)
-        else:
+                pools, q, k, positions, valid, c.block_table,
+                c.page_size, params["blocks"][i], model.attn)
+        elif len(caches) == 1 and not c.window:
             o, new_c, n = paged_update_attend(
-                cache.pages[i], q, k, v, positions, valid,
-                cache.block_table, cache.page_size)
+                pools, q, k, v, positions, valid, c.block_table,
+                c.page_size)
+        else:
+            with annotate("attn.window_read" if c.window
+                          else "attn.global_read"):
+                o, new_c, n = paged_update_attend(
+                    pools, q, k, v, positions, valid, c.block_table,
+                    c.page_size, c.window)
         rows_read += n
-        new_pages.append(new_c)
+        if c.window:
+            rows_window += n
+        new_pages[g].append(new_c)
         return o
 
     logits, counts = token_forward(model, params, toks, positions, attend,
@@ -609,7 +779,13 @@ def paged_forward(model: TransformerLM, params, toks, positions, valid,
         counts = jnp.zeros((3,), jnp.int32)
     counts = rows_read if counts is None else jnp.concatenate(
         [counts, rows_read])
-    return logits, dataclasses.replace(cache, pages=new_pages, counts=counts)
+    if model.window:
+        counts = jnp.concatenate(
+            [counts, jnp.reshape(jnp.asarray(rows_window, jnp.int32), (1,))])
+    new = tuple(
+        dataclasses.replace(c, pages=p, counts=None if g else counts)
+        for g, (c, p) in enumerate(zip(caches, new_pages)))
+    return logits, new if isinstance(cache, tuple) else new[0]
 
 
 def paged_decode_block(model: TransformerLM, params, toks, pos,
@@ -626,14 +802,15 @@ def paged_decode_block(model: TransformerLM, params, toks, pos,
     be checked, exactly as in contiguous decode_block).
     Returns (logits (B, k, vocab), new cache)."""
     b, kk = toks.shape
-    limit = cache.block_table.shape[1] * cache.page_size
+    first = cache[0] if isinstance(cache, tuple) else cache
+    limit = first.block_table.shape[1] * first.page_size
     if not isinstance(pos, jax.core.Tracer):
         hi = int(np.max(np.asarray(pos))) + kk
         if hi > limit:
             raise ValueError(
                 f"block reaching position {hi} out of range (block table "
-                f"covers {limit} = {cache.block_table.shape[1]} pages x "
-                f"{cache.page_size})"
+                f"covers {limit} = {first.block_table.shape[1]} pages x "
+                f"{first.page_size})"
             )
     pos = jnp.asarray(pos)
     if pos.ndim == 0:

@@ -185,3 +185,100 @@ class PagePool:
         # No writable page is ever shared; read-only pages are owned.
         assert set(self._readers) <= self._ro, "writable page shared"
         assert self._ro <= set(self._owner), "read-only page not owned"
+
+
+def window_pages_per_slot(window: int, chunk: int, page_size: int,
+                          max_len: int) -> int:
+    """The most pages one slot can hold in a windowed layer group: the
+    window's rows and one forward's new rows, plus a page for where
+    they lie in their pages; never more than a whole sequence."""
+    return min(pages_for(window + chunk, page_size) + 1,
+               pages_for(max_len, page_size))
+
+
+class WindowGroup:
+    """The page accounting of a model's WINDOWED layer group (layers
+    that see the last `window` keys only, beside global ones that see
+    all): its own PagePool and, a slot, its own block table
+    (`Slot.wpages`: logical block -> page, 0 where the slot holds
+    none). The global group is the scheduler's pool, unchanged; this
+    group follows it.
+
+    A slot holds here only what its next forward can read or write: it
+    TAKES a page when its rows grow into one and GIVES BACK every page
+    that lies wholly behind its window (`advance`, called before each
+    prefill chunk and each decode tick: rows [cached, cached + n) are
+    written, keys from cached - window + 1 on are read). So a slot
+    never holds more than `per_slot` = pages_for(window + chunk) + 1
+    pages whatever its depth, and the pool is sized to slots x that:
+    it never runs dry, and admission, growth and preemption stay the
+    global pool's to decide (the paged draft pool's precedent,
+    engine.PagedDraftProposer). What a slot holds is a function of its
+    `cached` alone, so the per-tick state digest, which has `cached`,
+    pins it too. A windowed pool under full coverage, with a dry path
+    of its own, is ROADMAP R5's remainder."""
+
+    def __init__(self, *, window: int, chunk: int, page_size: int,
+                 slots: int, max_len: int):
+        if window < 1 or chunk < 1:
+            raise ValueError(f"window {window}, chunk {chunk}: want >= 1")
+        self.window, self.chunk, self.page_size = window, chunk, page_size
+        self.per_slot = window_pages_per_slot(window, chunk, page_size,
+                                              max_len)
+        self.pool = PagePool(slots * self.per_slot + 1)
+        self._freed = 0     # pages given back behind a window, undrained
+
+    def advance(self, slot, rows: int) -> None:
+        """Before `slot` writes `rows` rows from `slot.cached`: give
+        back the pages wholly behind the window of its first new row,
+        take the pages the new rows grow into."""
+        ps, table, owner = self.page_size, slot.wpages, slot.req.rid
+        keep = max(slot.cached - self.window + 1, 0) // ps
+        behind = [p for p in table[slot.wfirst:keep] if p]
+        if behind:
+            self.pool.free(behind, owner)
+            self._freed += len(behind)
+            table[slot.wfirst:keep] = [0] * len(table[slot.wfirst:keep])
+        slot.wfirst = max(slot.wfirst, min(keep, len(table)))
+        want = pages_for(slot.cached + rows, ps)
+        if want > len(table):
+            fresh = max(len(table), keep)
+            got = self.pool.try_alloc(want - fresh, owner)
+            assert got is not None, "window pool sized to full coverage"
+            table.extend([0] * (fresh - len(table)) + got)
+
+    def drain_freed(self) -> int:
+        """Pages given back behind a window since the last call (the
+        tick record's `window_pages_freed`)."""
+        out, self._freed = self._freed, 0
+        return out
+
+    def release(self, slot) -> None:
+        """Every page `slot` holds here, back to the pool (its request
+        ended or was preempted)."""
+        pages = [p for p in slot.wpages[slot.wfirst:] if p]
+        if pages:
+            self.pool.free(pages, slot.req.rid)
+        slot.wpages, slot.wfirst = [], 0
+
+    def check(self, slots) -> None:
+        """The pool's invariant, and that the slots' tables and the
+        pool agree page for page, within the bound a slot."""
+        self.pool.check()
+        owner_of, held = self.pool._owner, 0
+        for s in slots:
+            if s.free:
+                assert not s.wpages, "a free slot holds windowed pages"
+                continue
+            mine = [p for p in s.wpages[s.wfirst:] if p]
+            assert not any(s.wpages[:s.wfirst]), "a page behind wfirst"
+            assert all(owner_of.get(p) == s.req.rid for p in mine), (
+                f"slot {s.idx}'s windowed table names a page it does "
+                "not own")
+            assert len(mine) <= self.per_slot, (
+                f"slot {s.idx} holds {len(mine)} windowed pages, over "
+                f"the bound {self.per_slot}")
+            held += len(mine)
+        assert held == len(owner_of), (
+            f"the slots' windowed tables hold {held} pages, the pool "
+            f"has issued {len(owner_of)}")

@@ -47,7 +47,7 @@ from collections.abc import Iterable
 
 import numpy as np
 
-from .pool import PagePool, pages_for
+from .pool import PagePool, WindowGroup, pages_for
 from .prefix_cache import PrefixCache
 
 
@@ -294,7 +294,12 @@ class Slot:
     with cached = matched tokens so prefill covers only the suffix.
     `cow` is a pending (src, dst) copy-on-write: the engine copies the
     shared src page into the private dst page before the slot's first
-    write (`cow_node` holds the transient source reference)."""
+    write (`cow_node` holds the transient source reference).
+
+    Layer groups: `pages` is the table of the global group (every
+    model has it); `wpages` / `wfirst` are the windowed group's, where
+    the model has one (pool.WindowGroup: logical block -> page, 0
+    where the slot holds none; blocks before `wfirst` went back)."""
 
     idx: int
     req: Request | None = None
@@ -306,6 +311,8 @@ class Slot:
     prefix_nodes: list = dataclasses.field(default_factory=list)
     cow: tuple[int, int] | None = None
     cow_node: object = None
+    wpages: list[int] = dataclasses.field(default_factory=list)
+    wfirst: int = 0
 
     @property
     def free(self) -> bool:
@@ -323,7 +330,8 @@ class Slot:
 class _SchedulerBase:
     def __init__(self, *, slots: int, pool: PagePool, page_size: int,
                  max_len: int, max_queue: int | None = None,
-                 prefix: PrefixCache | None = None):
+                 prefix: PrefixCache | None = None,
+                 window: WindowGroup | None = None):
         if slots < 1:
             raise ValueError(f"need at least one slot, got {slots}")
         if max_queue is not None and max_queue < 1:
@@ -334,6 +342,10 @@ class _SchedulerBase:
         self.max_len = max_len
         self.max_queue = max_queue
         self.prefix = prefix
+        # The windowed layer group's accounting (pool.WindowGroup), for
+        # a model that has windowed layers beside global ones; `pool`
+        # above is the global group's and decides admission alone.
+        self.window = window
         self.queue: deque[Request] = deque()
         # Incremental queue-membership signature (ISSUE 15): xor of
         # _rid_sig over queued rids, maintained by the _q_* helpers at
@@ -471,6 +483,7 @@ class _SchedulerBase:
         slot.prefix_nodes = []
         slot.cow = None
         slot.cow_node = None
+        slot.wpages, slot.wfirst = [], 0
         if acq is not None:
             # Prefix hit (ISSUE 9): shared pages lead the block table,
             # cached starts at the matched depth — prefill covers only
@@ -508,6 +521,8 @@ class _SchedulerBase:
         private = [p for p in slot.pages if p not in refset]
         if private:
             self.pool.free(private, rid)
+        if self.window is not None:
+            self.window.release(slot)
         slot.req = None
         slot.pages = []
         slot.refs = []
@@ -515,6 +530,16 @@ class _SchedulerBase:
         slot.cached = 0
         slot.target = 0
         slot.admit_seq = -1
+
+    def window_step(self, slot: Slot) -> None:
+        """Before `slot`'s next forward (a prefill chunk while it
+        prefills, else one decode row): the windowed group gives back
+        what fell behind the slot's window and takes what the new rows
+        grow into. Nothing where the model has no windowed group."""
+        if self.window is not None:
+            rows = (min(self.window.chunk, slot.target - slot.cached)
+                    if slot.prefilling else 1)
+            self.window.advance(slot, rows)
 
     def cow_complete(self, slot: Slot) -> None:
         """The engine copied slot.cow's src page into its private dst:
@@ -542,6 +567,10 @@ class _SchedulerBase:
         stay resident until the transfer completes or aborts. Returns
         (ordered block-table pages, private pages, prefix nodes)."""
         req = slot.req
+        if self.window is not None:
+            raise ValueError(
+                "hand-off moves one group's page set; a slot with a "
+                "windowed layer group holds two")
         assert slot.cow is None and slot.cow_node is None, (
             "detach with a pending COW — prefill cannot have completed"
         )
@@ -608,6 +637,8 @@ class _SchedulerBase:
         extent (no writable-shared page from the block table's point
         of view), and any pending COW destination is private."""
         self.pool.check()
+        if self.window is not None:
+            self.window.check(self.slots)
         ps = self.page_size
         for s in self.slots:
             if s.free:
