@@ -65,7 +65,12 @@ FULL = dict(
                # token that all heads read, no V pool.
                latent=dict(heads=128, kv_rank=512, nope=128, rope=64, v=128,
                            lanes=640, cache_dtype="bfloat16", slots=64,
-                           max_len=2048)),
+                           max_len=2048),
+               # ... and the expert layer of `smallthinker-21ba3b-
+               # instruct`: a 512-row chunk's 3,072 pairs over 64 held
+               # experts of (2560, 768).
+               experts=dict(rows=512, experts=64, top_k=6, dim=2560,
+                            width=768)),
 )
 # Same phases, same code paths, sizes a CPU finishes in seconds.
 TOY = dict(
@@ -81,7 +86,8 @@ TOY = dict(
                                    max_len=48)),
                latent=dict(heads=4, kv_rank=32, nope=8, rope=8, v=8,
                            lanes=128, cache_dtype="bfloat16", slots=4,
-                           max_len=64)),
+                           max_len=64),
+               experts=dict(rows=96, experts=8, top_k=2, dim=32, width=16)),
 )
 # A tick slower than this is a compile (or a stall) inside the serving
 # window: warm-up is supposed to have compiled every program.
@@ -398,6 +404,78 @@ def phase_kernels(cfg, dev, rehearsal):
                 jax.jit(read(loop))(c, q, row, blk)[0][live], want[live],
                 2e-2)
 
+    # The expert layer's two forms at `smallthinker`'s layout: a chunk's
+    # sorted pairs through the kernel (ops/pallas_expert_mlp) against
+    # the walk in 128-row steps of lax.ragged_dot, one draw from a plain
+    # softmax router and one skewed (half the experts get most of the
+    # pairs, some none). The rule must pick the kernel by itself; the
+    # walk is forced. Both put bf16 rows and matrices through the MXU
+    # with f32 accumulation and round the hidden rows to bf16 before
+    # `wd`: only the order of the f32 sums differs, which can move a
+    # hidden value by one bf16 step, 2^-8 of it, and the output by less
+    # of its largest: tolerance 2^-8. The walk has no reading under
+    # "highest" (XLA's grouped kernel refuses bf16 at that precision:
+    # "Bad lhs type"), so the twin under "highest" is the plain product,
+    # every row through every expert in f32 with the hidden rows
+    # rounded as the program rounds them; the layer's bf16 OUTPUT is
+    # half a step, 2^-9, from it by its own rounding (2.0e-3 to 2.4e-3
+    # in rehearsal): tolerance 2^-7 there, four such half steps.
+    from mpi_cuda_cnn_tpu.models.transformer import RoutedExperts
+    from mpi_cuda_cnn_tpu.parallel import ep
+
+    ex = sv["experts"]
+    spec = RoutedExperts(experts=ex["experts"], top_k=ex["top_k"],
+                         held=tuple(range(ex["experts"])), router="softmax",
+                         act="relu", reads="layer_input")
+    d, wd_ = ex["dim"], ex["width"]
+    bank = {m: jnp.asarray(rng.normal(size=(ex["experts"], *shape))
+                           / np.sqrt(shape[0]), "bfloat16")
+            for m, shape in (("wg", (d, wd_)), ("wu", (d, wd_)),
+                             ("wd", (wd_, d)))}
+    gate = jnp.asarray(rng.normal(size=(d, ex["experts"])) / np.sqrt(d),
+                       jnp.float32)
+    blk = {"experts": bank, "router": {"gate": gate}}
+    check(ep.tiled_products(ex["rows"], spec, bank),
+          f"experts: {ex['rows']} rows x top-{ex['top_k']} did not take "
+          "the kernel")
+    drawn = {}      # [pairs, experts hit, largest load] a draw
+    for draw, lift in (("uniform", 0.0), ("skewed", 3.0)):
+        x = jnp.asarray(rng.normal(size=(ex["rows"], d)), "bfloat16")
+        logits = (x.astype(jnp.float32) @ gate
+                  + lift * jnp.linspace(1.0, -1.0, ex["experts"]))
+        w, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), ex["top_k"])
+        routing = (ids.astype(jnp.int32), w / jnp.sum(w, -1, keepdims=True))
+
+        def layer(x, kernel):
+            if kernel:
+                return ep.moe_held_inference(x, blk, spec, routing=routing)
+            with mock.patch.object(ep, "tiled_products", lambda *a: False):
+                return ep.moe_held_inference(x, blk, spec, routing=routing)
+
+        def plain(x):
+            xf, (ids, w) = x.astype(jnp.float32), routing
+
+            def one(e, y):
+                g, u, dn = (bank[m][e].astype(jnp.float32)
+                            for m in ("wg", "wu", "wd"))
+                h = (jax.nn.relu(xf @ g) * (xf @ u)).astype(x.dtype)
+                return y + jnp.sum(jnp.where(ids == e, w, 0.0), axis=-1,
+                                   keepdims=True) * (
+                    h.astype(jnp.float32) @ dn)
+
+            return jax.lax.fori_loop(0, ex["experts"], one,
+                                     jnp.zeros(x.shape, jnp.float32))
+
+        mosaic(lambda x: layer(x, True)[0], x)
+        walked, counts = jax.jit(lambda x: layer(x, False))(x)
+        got, same = jax.jit(lambda x: layer(x, True))(x)
+        check(np.array_equal(counts, same) and int(counts[0])
+              == ex["rows"] * ex["top_k"],
+              f"experts {draw}: counts {counts} / {same}")
+        drawn[draw] = [int(c) for c in counts]
+        compare(f"experts_{draw}", got, twin(plain, x), 2.0 ** -7)
+        compare(f"experts_{draw}_as_served", got, walked, 2.0 ** -8)
+
     # int8 GEMV at the decode tick's widest matrices: the MLP pair
     # (w2's din = 4*dim is the contraction that overflowed VMEM untiled).
     dim = sv["dim"]
@@ -413,7 +491,8 @@ def phase_kernels(cfg, dev, rehearsal):
 
     rounded = {k: float(f"{v:.2e}") for k, v in errs.items()}
     check(not over, f"{'; '.join(over)} (all: {rounded})")
-    return {"max_rel_err": rounded, "mosaic_checked": not rehearsal}
+    return {"max_rel_err": rounded, "mosaic_checked": not rehearsal,
+            "experts_drawn": drawn}
 
 
 def phase_cnn(cfg, dev, rehearsal):

@@ -37,6 +37,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..obs.trace import annotate
+from ..ops.pallas_expert_mlp import expert_mlp, fits as expert_mlp_fits
 from ..ops.pallas_gemv import swiglu
 from ..utils.donation import donate_jit
 
@@ -363,10 +364,43 @@ def moe_mlp_inference(x, params: dict, *, n_experts: int, top_k: int = 1):
     return y.astype(x.dtype)
 
 
-# Sorted (token, expert) pairs a call of the grouped products
-# (moe_held_inference): a decode tick of 64 rows sends about 30 pairs
-# here, a 32-row chunk about 16.
+# Sorted (token, expert) pairs a step of moe_held_inference's WALK, the
+# form of the grouped products where few pairs land on this chip:
+# dots.vlm1's decode tick of 64 rows sends about 30 pairs to its 16 of
+# 256 experts, its 32-row chunk about 16, and XLA's grouped kernel pays
+# for every row of its tile in every group it touches (PERF.md section
+# 6, PR 28).
 _PAIR_CHUNK = 128
+
+
+def pairs_landing(rows: int, spec) -> int:
+    """The (token, expert) pairs of a forward of `rows` tokens that can
+    land on this chip's experts if the router spreads them evenly:
+    rows x top_k x held / experts. A shape, known when the program is
+    traced: 3,072 for smallthinker's 512-row chunk (64 of 64 held,
+    top-6), 192 for its tick of 32; 32 and 16 for dots.vlm1's tick and
+    chunk."""
+    return rows * spec.top_k * len(spec.held) // spec.experts
+
+
+def tiled_products(rows: int, spec, bank: dict) -> bool:
+    """Which form moe_held_inference's grouped products take, from
+    shapes alone. True: ops/pallas_expert_mlp's kernel over the (row
+    tile, expert) pairs that meet, where more pairs can land here than
+    ONE step of the walk holds — the walk would then stream an expert's
+    matrices again in every step its rows straddle and start three
+    grouped calls a step, 72 a layer at 3,072 pairs — and an expert's
+    matrices fit the kernel's VMEM twice over. Else the walk.
+    Placed on the v5e at smallthinker's shapes, 3 x (2560, 768) bf16 an
+    expert (PERF.md section 6, PR 33; ms a layer, walk / kernel): 3,072
+    pairs over 64 experts 2.48 / 1.25; 600 over 64 (a short last
+    chunk) 1.83 / 1.06; 192 over 60 (a full tick) 1.55 / 0.96; 90 over
+    48 1.23 / 0.77. Nothing was measured where one step holds all that
+    can land (dots.vlm1's 32 and 16): there the walk is one call a
+    product over the experts hit, and stays."""
+    wg = bank["wg"]
+    return (pairs_landing(rows, spec) > _PAIR_CHUNK
+            and expert_mlp_fits(wg.shape[1], wg.shape[2], wg.dtype.itemsize))
 
 
 def route_grouped(x, router: dict, spec):
@@ -435,12 +469,22 @@ def moe_held_inference(x, blk: dict, spec, valid=None, routing=None):
     Only chosen experts are computed: the (token, choice) pairs are
     sorted by held expert — pairs that chose an absent expert, or
     belong to a row outside `valid`, sort last and into no group — and
-    the three products of the gated expert MLP run as grouped matmuls
-    over the sorted rows (lax.ragged_dot: XLA's own grouped kernel on
-    the TPU), one group a held expert. Shapes are static at tokens x
-    top_k rows, the most that can land here, walked in chunks of which
-    only those that hold a pair are computed: nothing is dropped and
-    no capacity exists. A token's output depends on that token alone.
+    the three products of the gated expert MLP run grouped over the
+    sorted rows, one group a held expert, in one of two forms that
+    `tiled_products` reads off the shapes. Where few pairs can land
+    here (dots.vlm1's 16 and 32 of 256 and 512 rows) the rows are
+    WALKED in steps of 128, each step three lax.ragged_dot calls (XLA's
+    own grouped kernel on the TPU), and only the steps that hold a pair
+    are computed. Where many can (smallthinker's 3,072 a chunk, 192 a
+    tick) ONE kernel (ops/pallas_expert_mlp) visits every (128-row
+    tile, expert) pair that meets and computes the whole gated MLP
+    there, an expert's three matrices streamed once however its rows
+    fall. Either way shapes are static at tokens x top_k rows, the
+    most that can land here, the cost follows the pairs that are
+    there, nothing is dropped and no capacity exists: bf16 rows and
+    matrices into the MXU, f32 accumulation, the hidden rows rounded to
+    the rows' dtype before `wd`, the weights applied in f32 after the
+    products. A token's output depends on that token alone.
 
     x: (T, dim); blk: `router` {gate[, bias]}, `experts` {wg, wu:
     (held, dim, width), wd: (held, width, dim)}, and where the layer
@@ -458,10 +502,10 @@ def moe_held_inference(x, blk: dict, spec, valid=None, routing=None):
     local = jnp.asarray(local_of)[ids]                         # (T, k)
     if valid is not None:
         local = jnp.where(valid[:, None], local, n)
-    # The sorted pairs are taken `chunk` rows at a time, and only the
-    # chunks that hold a pair are computed: the grouped kernel pays for
-    # every row of its tile in every group, so 512 rows of which 30 are
-    # pairs cost what 512 pairs cost (PERF.md section 6, PR 28).
+    # The walk takes the sorted pairs `chunk` rows at a time, and only
+    # the chunks that hold a pair are computed: the grouped kernel pays
+    # for every row of its tile in every group, so 512 rows of which 30
+    # are pairs cost what 512 pairs cost (PERF.md section 6, PR 28).
     chunk = min(t * k, _PAIR_CHUNK)
     rows = -(-t * k // chunk) * chunk
     flat = jnp.pad(local.reshape(t * k), (0, rows - t * k), constant_values=n)
@@ -482,9 +526,15 @@ def moe_held_inference(x, blk: dict, spec, valid=None, routing=None):
         yc = lax.ragged_dot(h.astype(x.dtype), bank["wd"], size, **f32)
         return lax.dynamic_update_slice_in_dim(ys, yc, lo, 0)
 
-    with annotate("ep.held_experts"):
-        ys = lax.fori_loop(0, -(-ends[-1] // chunk), one_chunk,
-                           jnp.zeros((rows, x.shape[1]), jnp.float32))
+    if tiled_products(t, spec, bank):
+        # Many pairs: one kernel visits every (row tile, expert) that
+        # meets, an expert's three matrices streamed once.
+        with annotate("ep.held_experts.grouped"):
+            ys = expert_mlp(xs, sizes, bank, act, tile=chunk)
+    else:
+        with annotate("ep.held_experts"):
+            ys = lax.fori_loop(0, -(-ends[-1] // chunk), one_chunk,
+                               jnp.zeros((rows, x.shape[1]), jnp.float32))
     # Rows past the groups' end belong to no expert: whatever the
     # grouped product left there is not read.
     ys = jnp.where((flat[order] < n)[:, None],

@@ -88,6 +88,12 @@ TICK_COUNTS = ("moe_assignments", "moe_experts_hit", "moe_load_max",
 # ... and after them, where the model has windowed layers, the rows
 # THEIR reads touched (`kv_rows_read` stays all layers).
 WINDOW_COUNT = "kv_rows_read_window"
+# The same vector as the prefill CHUNK's program returned it, on the
+# record of an iteration that ran a chunk of a model with expert
+# layers: its first two elements, the pairs the held experts computed
+# for the chunk's rows and the held experts with at least one (summed
+# over the expert layers) — what the chunk's grouped products stream.
+CHUNK_COUNTS = ("chunk_moe_assignments", "chunk_moe_experts_hit")
 
 # The key order of run()'s tick record (`spans` closes it): the core's
 # shared fields and run()'s own, laid out as the trail's readers and the
@@ -96,7 +102,8 @@ TICK_LAYOUT = (
     "tick", "now", "mode", "queue", "running", "prefilling", "free_pages",
     "backlog", "arrived", "admitted", "prefill", "decoded", "finished",
     "aborted", "preempted", "blocked", "preempted_for", "terminal",
-    "state_crc", "compiled", *TICK_COUNTS, WINDOW_COUNT, "pages_held",
+    "state_crc", "compiled", *TICK_COUNTS, WINDOW_COUNT, *CHUNK_COUNTS,
+    "pages_held",
     "window_pages_freed", "squeezed", "spec",
     "prefix_hits", "prefix", "prefix_readmits",
 )
@@ -287,7 +294,7 @@ def _observe_run_tick(registry, rec: dict, out, core: ServeCore) -> None:
     registry.set("serve.prefill_backlog", rec["backlog"])
     if out.emitted:
         registry.inc("serve.tokens_emitted", out.emitted)
-    for name in (*TICK_COUNTS, WINDOW_COUNT):
+    for name in (*TICK_COUNTS, WINDOW_COUNT, *CHUNK_COUNTS):
         if name in rec:
             registry.set(f"serve.{name}", rec[name])
     for _, _, accepted in out.spec or ():
@@ -809,8 +816,9 @@ class PagedEngine:
         self._spans = None
         # The last decode tick's counters (PagedKVCache.counts), still
         # on the device: run() fetches them inside `record`, and only
-        # there.
+        # there. Beside them the last prefill chunk's.
         self._tick_counts = None
+        self._chunk_counts = None
         if spec != "off":
             kk = spec_k
 
@@ -992,7 +1000,7 @@ class PagedEngine:
         if self._spans is not None:
             self._spans.enter("prefill.dispatch")
         cache, nxt = self._prefill(view, self.params, *inputs)
-        self._keep(cache)
+        self._chunk_counts = self._keep(cache)
         return n, nxt
 
     def run_decode_tick(self, dslots) -> np.ndarray:
@@ -1095,6 +1103,13 @@ class PagedEngine:
                      "latent_rows_read" if self.model.attn is not None
                      else "kv_rows_read")
             fields.update(zip(names[-len(counted):], counted))
+        if (out.prefill is not None and self._chunk_counts is not None
+                and self.model.experts is not None):
+            # ... and this iteration's chunk's, as its program counted
+            # them: fetched here too, and nowhere else.
+            # mctpu: disable=MCT007
+            fields.update(zip(
+                CHUNK_COUNTS, np.asarray(self._chunk_counts).tolist()))
         if squeezes:
             # Pages an injected squeeze currently holds: the replay
             # reconstruction needs it to account the pool's free count

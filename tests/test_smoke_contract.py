@@ -52,6 +52,44 @@ with mock.patch.object(pallas_attention, "pallas_interpret", lambda: False):
 print("FOUR_CHIP_OK")
 """
 
+# The same for one chip and ops/pallas_expert_mlp's kernel at the
+# widths smallthinker-21ba3b-instruct publishes (PR 33): 3,072 sorted
+# rows of a 512-row chunk and a tick's 256, 64 experts of 3 x (2560,
+# 768) bf16 -- whole matrices as blocks, 2 x 11.8 MB of them in VMEM.
+_AOT_EXPERT_MLP = """
+import os
+os.environ.update(TPU_ACCELERATOR_TYPE="v5litepod-4",
+                  TPU_WORKER_HOSTNAMES="localhost", JAX_PLATFORMS="cpu")
+import sys
+from unittest import mock
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    chip = SingleDeviceSharding(topologies.get_topology_desc(
+        topology_name="v5e:2x2", platform="tpu").devices[0])
+except Exception as e:
+    print("NO_TOPOLOGY", e); sys.exit(0)
+from mpi_cuda_cnn_tpu.ops import pallas_expert_mlp as pem
+sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                             sharding=chip)
+bank = {"wg": sds((64, 2560, 768), "bfloat16"),
+        "wu": sds((64, 2560, 768), "bfloat16"),
+        "wd": sds((64, 768, 2560), "bfloat16")}
+assert pem.fits(2560, 768, 2) and not pem.fits(7168, 2048, 2)
+with mock.patch.object(pem, "pallas_interpret", lambda: False):
+    # ... the tick's under an ambient "highest", as chip_smoke.py's twin
+    # and a reference set it: Mosaic takes bf16 at one precision only.
+    for rows, ambient in ((3072, "default"), (256, "highest")):
+        with jax.default_matmul_precision(ambient):
+            text = jax.jit(lambda x, s, b: pem.expert_mlp(
+                x, s, b, jax.nn.relu)).lower(
+                sds((rows, 2560), "bfloat16"), sds((64,), "int32"),
+                bank).compile().as_text()
+        assert "tpu_custom_call" in text and "expert_mlp" in text
+print("EXPERT_MLP_OK")
+"""
+
 
 def test_chip_smoke_without_chip_exits_nonzero_and_prints_no_result():
     assert_refused_without_chip(run_script("chip_smoke.py", timeout=120))
@@ -91,4 +129,18 @@ def test_flash_kernel_compiles_for_a_four_chip_data_mesh():
     if "NO_TOPOLOGY" in proc.stdout:
         pytest.skip(f"no compile-only TPU topology here: {proc.stdout}")
     assert proc.returncode == 0 and "FOUR_CHIP_OK" in proc.stdout, \
+        proc.stderr[-3000:]
+
+
+def test_expert_mlp_kernel_compiles_at_the_published_widths():
+    """Interpret mode knows no VMEM: whole (2560, 768) matrices as
+    blocks, twice over, need the kernel's raised scope, and only the
+    chip's compiler can say that they fit (PR 33)."""
+    pytest.importorskip("libtpu")
+    proc = subprocess.run([sys.executable, "-c", _AOT_EXPERT_MLP],
+                          capture_output=True, text=True, timeout=300,
+                          cwd=REPO)
+    if "NO_TOPOLOGY" in proc.stdout:
+        pytest.skip(f"no compile-only TPU topology here: {proc.stdout}")
+    assert proc.returncode == 0 and "EXPERT_MLP_OK" in proc.stdout, \
         proc.stderr[-3000:]

@@ -661,6 +661,48 @@ def test_counts_are_fetched_by_a_sink_and_by_nothing_else(served):
         eng.run(requests(dm, lens=(5,), new=(4,)), tick_sink=lambda t: None)
 
 
+def test_a_chunks_counts_are_on_its_record_and_fetched_by_a_sink_alone(served):
+    """The prefill chunk's program returns the expert layers' counts
+    too: with a sink its pairs and experts hit are on the record of
+    every iteration that ran a chunk, and of no other; with none they
+    stay on the device."""
+    class NotForTheHost:
+        def __array__(self, *a, **kw):
+            raise AssertionError("the chunk's counts were fetched")
+
+    _, dm, model, _ = served
+    eng = engine(served)
+    ticks = []
+    res = eng.run(requests(dm, lens=(10, 5), new=(6, 4)),
+                  tick_sink=ticks.append)
+    assert res.status_counts() == {"finished": 2}
+    chunks = [t for t in ticks if t["prefill"] is not None]
+    assert len(chunks) == 3 + 2 and len(chunks) < len(ticks)
+    names = {"chunk_moe_assignments", "chunk_moe_experts_hit"}
+    assert all(names <= set(t) for t in chunks)
+    assert not any(names & set(t) for t in ticks if t["prefill"] is None)
+    layers, spec = model.depth, model.experts
+    for t in chunks:
+        # All experts are held: every valid row's every choice lands.
+        assert t["chunk_moe_assignments"] == (
+            t["prefill"][2] * spec.top_k * layers)
+        assert 0 < t["chunk_moe_experts_hit"] <= min(
+            t["chunk_moe_assignments"], len(spec.held) * layers)
+    prefill = eng._prefill
+
+    def counted(*args):
+        caches, nxt = prefill(*args)
+        return (dataclasses.replace(caches[0], counts=NotForTheHost()),
+                caches[1]), nxt
+
+    counted._cache_size = prefill._cache_size
+    eng._prefill = counted
+    res = eng.run(requests(dm, lens=(5, 9), new=(4, 6)))
+    assert res.status_counts() == {"finished": 2}
+    with pytest.raises(AssertionError, match="chunk's counts were fetched"):
+        eng.run(requests(dm, lens=(5,), new=(4,)), tick_sink=lambda t: None)
+
+
 def test_a_one_group_model_has_none_of_the_new_fields():
     model = TransformerLM(vocab=64, dim=32, heads=4, kv_heads=2, depth=2,
                           max_seq=64, pos="rope")
@@ -672,7 +714,8 @@ def test_a_one_group_model_has_none_of_the_new_fields():
     ticks = []
     eng.run([Request(rid=0, prompt=np.arange(9, dtype=np.int32),
                      max_new_tokens=4)], tick_sink=ticks.append)
-    new = {"kv_rows_read_window", "pages_held", "window_pages_freed"}
+    new = {"kv_rows_read_window", "pages_held", "window_pages_freed",
+           "chunk_moe_assignments", "chunk_moe_experts_hit"}
     assert ticks and not any(new & set(t) for t in ticks)
 
 
