@@ -70,7 +70,16 @@ FULL = dict(
                # instruct`: a 512-row chunk's 3,072 pairs over 64 held
                # experts of (2560, 768).
                experts=dict(rows=512, experts=64, top_k=6, dim=2560,
-                            width=768)),
+                            width=768),
+               # ... and `minicpm-sala`'s two mixers: a sparse layer's
+               # pools (2 K/V heads, a compressed key every 16 rows) with
+               # the selection's published sizes, and a linear layer's
+               # 32 states a slot.
+               sparse=dict(heads=32, kv_heads=2, head_dim=128,
+                           cache_dtype="bfloat16", slots=32, max_len=65536,
+                           chunk=512, select=dict(
+                               kernel=32, stride=16, block=64, topk=64,
+                               init_blocks=1, window=2048, dense_len=8192))),
 )
 # Same phases, same code paths, sizes a CPU finishes in seconds.
 TOY = dict(
@@ -87,7 +96,12 @@ TOY = dict(
                latent=dict(heads=4, kv_rank=32, nope=8, rope=8, v=8,
                            lanes=128, cache_dtype="bfloat16", slots=4,
                            max_len=64),
-               experts=dict(rows=96, experts=8, top_k=2, dim=32, width=16)),
+               experts=dict(rows=96, experts=8, top_k=2, dim=32, width=16),
+               sparse=dict(heads=4, kv_heads=2, head_dim=16,
+                           cache_dtype="bfloat16", slots=3, max_len=256,
+                           chunk=16, select=dict(
+                               kernel=4, stride=2, block=8, topk=2,
+                               init_blocks=1, window=16, dense_len=32))),
 )
 # A tick slower than this is a compile (or a stall) inside the serving
 # window: warm-up is supposed to have compiled every program.
@@ -403,6 +417,118 @@ def phase_kernels(cfg, dev, rehearsal):
         compare(f"paged_latent_b{b}_kk{kk}_as_served",
                 jax.jit(read(loop))(c, q, row, blk)[0][live], want[live],
                 2e-2)
+
+    # `minicpm-sala`'s two mixers at the cell's layout (32 slots of
+    # 65,536 rows, 2 K/V heads of 128 in bf16; a tick and a 512-row
+    # chunk of 32 heads). The SELECTED READ: a tick walks the union of
+    # its K/V heads' chosen blocks, a chunk walks every block to its
+    # depth under the selection's mask, both against the plain twin --
+    # the whole table gathered and attend_kv under the same mask, the
+    # chunk's queries 128 rows at a time so that its scores fit. The
+    # blocks are chosen once (select_blocks, as served) and handed to
+    # both sides: what is held here is the read, the selection is held
+    # to the reference's on the CPU (tests/test_sparse_linear.py).
+    # bf16 rows: as the K/V layouts above, 2 x 2^-8 under "highest",
+    # 2e-2 as served. The CHUNKED LINEAR PRODUCT (generate.
+    # linear_attend) against the token recurrence S = l S + k^T v, o =
+    # q S, one row at a time in f32 at "highest": under "highest" the
+    # chunk form rounds its decayed scores to the values' type (bf16:
+    # 2^-9 a term, 2^-8 on a sum of such terms and the state's share
+    # beside it), as served the MXU rounds q and k too: 2e-2.
+    from mpi_cuda_cnn_tpu.models.generate import linear_attend
+    from mpi_cuda_cnn_tpu.models.transformer import LinearAttn, SparseSelect
+
+    sp, ps = sv["sparse"], sv["page_size"]
+    sel = SparseSelect(**sp["select"])
+    h, hkv, hd, dtype = (sp["heads"], sp["kv_heads"], sp["head_dim"],
+                         sp["cache_dtype"])
+    per = -(-sp["max_len"] // ps)
+    pool = sp["slots"] * per + 1
+    c = {n: jnp.asarray(rng.standard_normal(
+        (pool, ps, hkv, hd), np.float32), dtype) for n in ("k", "v")}
+    kc = jnp.asarray(rng.standard_normal(
+        (pool, ps // sel.stride, hkv, hd), np.float32), dtype)
+    for b, kk in ((sp["slots"], 1), (1, sp["chunk"])):
+        q = jnp.asarray(rng.normal(size=(b, kk, h, hd)), dtype)
+        live = np.arange(b) % 3 != 1
+        table = jnp.asarray(np.where(live[:, None], np.stack([
+            rng.choice(np.arange(1, pool), per, replace=False)
+            for _ in range(b)]), 0).astype(np.int32))
+        # The tick's slots at any depth, most past dense_len; the chunk
+        # at the table's far end.
+        pos0 = (rng.integers(0, per * ps, (b, 1)) * live[:, None] if kk == 1
+                else np.full((b, 1), per * ps - kk))
+        positions = jnp.asarray(pos0 + np.arange(kk), jnp.int32)
+        valid = jnp.asarray(np.broadcast_to(live[:, None], (b, kk)))
+        chosen, _, nchosen = jax.jit(
+            lambda q, kc: paged_cache.select_blocks(
+                q, kc, positions, valid, table, ps, sel))(q, kc)
+        nb = chosen.shape[-1]
+        check(0 < int(nchosen) < int(np.sum(live)) * kk * hkv * nb,
+              f"sparse b{b} kk{kk}: {int(nchosen)} blocks chosen")
+        walk = None
+        step = paged_cache.read_step(b, per, ps, 2 * hkv * hd * 2)
+        if rehearsal:
+            step = (max(1, sel.block // ps), 3)
+        if kk == 1:
+            walk, step = paged_cache.chosen_walk(chosen, sel, step, ps)
+
+        def read(c, q):
+            return paged_cache.bounded_read(
+                q, c, positions, valid, table, chosen, walk, page_size=ps,
+                step=step, sel_block=sel.block)
+
+        def whole(c, q):
+            pieces = [paged_cache.bounded_read(
+                q[:, i:i + 128], c, positions[:, i:i + 128],
+                valid[:, i:i + 128], table, chosen[:, :, i:i + 128],
+                page_size=ps, step=(per, b), sel_block=sel.block)[0]
+                for i in range(0, kk, 128)]
+            return jnp.concatenate(pieces, axis=1)
+
+        want = twin(whole, c, q)
+        got, n = twin(read, c, q)
+        if kk == 1:
+            check(int(n) < int(np.sum(pos0 + 1)),
+                  f"sparse tick: the walk touched {int(n)} rows, the slots "
+                  f"hold {int(np.sum(pos0 + 1))}: no block was skipped")
+        compare(f"sparse_read_b{b}_kk{kk}", got[live], want[live],
+                2 * 2.0 ** -8)
+        compare(f"sparse_read_b{b}_kk{kk}_as_served",
+                jax.jit(read)(c, q)[0][live], want[live], 2e-2)
+    del c, kc
+
+    ld = LinearAttn().log_decay(h)
+
+    def recurrence(q, k, v, state, valid):
+        def one(s, row):
+            qt, kt, vt, ok = row                     # (B, H, hd), (B,)
+            new = jnp.exp(ld)[None, :, None, None] * s + jnp.einsum(
+                "bhd,bhe->bhde", kt, vt)
+            s = jnp.where(ok[:, None, None, None], new, s)
+            return s, jnp.einsum("bhd,bhde->bhe", qt, s) / np.sqrt(hd)
+        f32 = lambda x: jnp.swapaxes(x.astype(jnp.float32), 0, 1)  # noqa: E731
+        s, o = jax.lax.scan(one, state, (f32(q), f32(k), f32(v), valid.T))
+        return jnp.swapaxes(o, 0, 1).reshape(q.shape[0], q.shape[1], -1), s
+
+    for b, kk in ((sp["slots"], 1), (1, sp["chunk"])):
+        q, k, v = (jnp.asarray(rng.normal(size=(b, kk, h, hd)), dtype)
+                   for _ in range(3))
+        state = jnp.asarray(rng.normal(size=(b, h, hd, hd)), jnp.float32)
+        # The chunk's last rows are padding; every third slot of the
+        # tick is dead.
+        valid = jnp.asarray(np.arange(kk)[None, :] < kk - kk // 8 if kk > 1
+                            else (np.arange(b) % 3 != 1)[:, None])
+        want_o, want_s = twin(recurrence, q, k, v, state, valid)
+        form = lambda q, k, v, state: linear_attend(  # noqa: E731
+            q, k, v, state, valid, ld)
+        keep = np.asarray(valid)
+        for tag, (o, s_), tol in (
+                ("", twin(form, q, k, v, state), 2.0 ** -8),
+                ("_as_served", jax.jit(form)(q, k, v, state), 2e-2)):
+            compare(f"linear_b{b}_kk{kk}{tag}", np.asarray(o)[keep],
+                    np.asarray(want_o)[keep], tol)
+            compare(f"linear_state_b{b}_kk{kk}{tag}", s_, want_s, tol)
 
     # The expert layer's two forms at `smallthinker`'s layout: a chunk's
     # sorted pairs through the kernel (ops/pallas_expert_mlp) against

@@ -226,9 +226,13 @@ def token_forward(model: TransformerLM, params, toks, positions, attend,
     The block is data: what a layer computes is read off its params
     (transformer.norm: a gain alone is RMSNorm; TransformerLM.mlp: GELU
     `w1`/`w2`, gated `wg`/`wu`/`wd` at any width, or an `experts` bank;
-    `pos_emb` or none) and off the model where the params cannot say
+    `pos_emb` or none; `q_norm`/`k_norm` gains over a head's dims;
+    `o_norm`, a gain over each head's dims of the mixer's output;
+    `wgate`, an elementwise sigmoid gate on it read from the layer's
+    normed input) and off the model where the params cannot say
     (`attn`: K/V heads or one latent row; `layout`: which layers
-    rotate; `experts`: the router's kind and where it reads), so layers
+    rotate; `mixers`: which keep a state instead of keys; `experts`:
+    the router's kind and where it reads; the muP scales), so layers
     of different kinds ride one loop.
 
     toks: (B, k) int32; positions: (k,) shared across rows, or (B, k)
@@ -249,10 +253,15 @@ def token_forward(model: TransformerLM, params, toks, positions, attend,
     largest load (max)], None for a model without any.
     """
     x = params["tok_emb"][toks]                           # (B, k, dim)
+    if model.emb_scale != 1.0:
+        x = x * model.emb_scale
     if model.pos == "learned":
         # (k, dim) broadcasts over rows; (B, k, dim) indexes per row.
         x = x + params["pos_emb"][positions]
     eps, counts = model.norm_eps, None
+    # A residual branch's scale (muP's depth scaling), where there is one.
+    branch = ((lambda m: m) if model.residual_scale == 1.0
+              else (lambda m: m * model.residual_scale))
     for i, blk in enumerate(params["blocks"]):
         # A router that reads the layer's input chooses here, before
         # attention; its experts run on the stream after it.
@@ -260,13 +269,21 @@ def token_forward(model: TransformerLM, params, toks, positions, attend,
         y = norm(x, blk["ln1"], eps)
         q, k, v = model.project_qkv(blk, y, positions=positions, layer=i)
         o = attend(i, q, k, v)
-        x = x + qmatmul(o.astype(x.dtype), blk["wo"])
+        if "o_norm" in blk:     # RMSNorm over each head's dims, a gain
+            o = norm(o.reshape(*o.shape[:2], model.heads, -1),
+                     blk["o_norm"], eps).reshape(o.shape)
+        if "wgate" in blk:      # the output gate reads the layer's
+            o = o * jax.nn.sigmoid(     # normed input, elementwise
+                qmatmul(y, blk["wgate"]).astype(jnp.float32))
+        x = x + branch(qmatmul(o.astype(x.dtype), blk["wo"]))
         m, c = model.mlp(blk, norm(x, blk["ln2"], eps), valid, routing)
-        x = x + m
+        x = x + branch(m)
         if c is not None:
             counts = c if counts is None else jnp.concatenate(
                 [counts[:2] + c[:2], jnp.maximum(counts[2:], c[2:])])
     x = norm(x, params["ln_f"], eps)
+    if model.logit_scale != 1.0:
+        x = x * model.logit_scale
     return qmatmul(x, params["head"]).astype(jnp.float32), counts
 
 
@@ -292,7 +309,9 @@ def attend_kv(q, ck, cv, mask, cks=None, cvs=None):
     applied OUTSIDE the dots — a key row's scale is constant along the
     contracted head_dim so it factors onto the logits, a value row's
     folds into the probabilities before the PV contraction). mask:
-    (k, L) or (B, k, L) bool, True = attend; scores/softmax are f32.
+    (k, L) or (B, k, L) bool, True = attend, or (B, Hkv, k, L) where
+    each K/V head's queries see keys of their own (block selection);
+    scores/softmax are f32.
     Returns (B, k, H*hd) f32.
     """
     b, kk, h, hd = q.shape
@@ -310,7 +329,11 @@ def attend_kv(q, ck, cv, mask, cks=None, cvs=None):
         logits = logits * jnp.transpose(cks, (0, 2, 3, 1))[:, :, None, :, :]
     if mask.ndim == 2:
         mask = mask[None]                     # shared across rows
-    logits = jnp.where(mask[:, None, None, :, :], logits, NEG_INF)
+    if mask.ndim == 3:
+        mask = mask[:, None, None, :, :]      # ... and across heads
+    else:                                     # (B, Hkv, k, L): a K/V
+        mask = mask[:, :, None, :, :]         # head's own (selection)
+    logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     if int8:
         pv = probs * jnp.transpose(cvs, (0, 2, 3, 1))[:, :, None, :, :]
@@ -396,6 +419,52 @@ def attend_latent(q, rows, mask, wuk, wuv, a):
     ot = jnp.einsum("bmk,bkr->bmr", probs.reshape(b, h * kk, -1), c,
                     **f32).reshape(b, h, kk, a.kv_rank)
     return latent_values_up(ot, wuv)
+
+
+def linear_attend(q, k, v, state, valid, log_decay):
+    """Lightning attention (transformer.LinearAttn) over kk rows a
+    batch row, from the state the rows before them left: q, k, v
+    (B, kk, H, hd); state (B, H, hd, hd) f32, S = sum over earlier rows
+    of l^(rows since) k^T v; valid (B, kk) bool; log_decay (H,) = log l,
+    negative. With c_i the valid rows up to and including i:
+
+      o_i = (sum over valid j <= i of l^(c_i - c_j) (q_i . k_j) v_j
+             + l^(c_i) q_i S) / sqrt(hd)
+      S'  = l^(c_last) S + sum over valid j of l^(c_last - c_j) k_j^T v_j
+
+    -- the token recurrence S_t = l S_{t-1} + k_t^T v_t, o_t = q_t S_t
+    unrolled over the rows, so a tick's one row and a prefill chunk's
+    hundreds are one form. A row that is not valid adds no k^T v and
+    applies no decay (its own output is nobody's). Every exponent is
+    <= 0: nothing is divided by a decay, so a head that forgets within
+    a few rows underflows to 0 and never overflows. The (kk, kk)
+    products run in the inputs' type with f32 accumulation, as
+    attend_kv's do; the state is read and updated in f32 at HIGHEST (a
+    (hd, hd) product a head: nothing beside the rest).
+    Returns (o (B, kk, H*hd) f32, the new state)."""
+    b, kk, h, hd = q.shape
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    c = jnp.cumsum(valid, axis=1).astype(f32)                  # (B, kk)
+    ld = log_decay.astype(f32)
+    at = jnp.arange(kk)
+    seen = (valid[:, :, None] & valid[:, None, :]
+            & (at[:, None] >= at[None, :]))                    # (B, q, k)
+    d = jnp.where(seen[:, None], jnp.exp(
+        ld[None, :, None, None] * (c[:, :, None] - c[:, None, :])[:, None]),
+        0.0)                                                   # (B, H, q, k)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=f32) * d
+    o = jnp.einsum("bhqk,bkhd->bqhd", s.astype(v.dtype), v,
+                   preferred_element_type=f32)
+    carried = jnp.einsum("bqhd,bhde->bqhe", q.astype(f32), state,
+                         precision=hi)
+    o = o + carried * jnp.exp(ld[None, None, :] * c[:, :, None])[..., None]
+    n = c[:, -1]                                               # (B,)
+    w = jnp.where(valid[..., None], jnp.exp(
+        ld[None, None, :] * (n[:, None] - c)[..., None]), 0.0)  # (B, kk, H)
+    new = (jnp.exp(ld[None, :] * n[:, None])[..., None, None] * state
+           + jnp.einsum("bkhd,bkhe->bhde", k.astype(f32) * w[..., None],
+                        v.astype(f32), precision=hi))
+    return (o / jnp.sqrt(jnp.asarray(hd, f32))).reshape(b, kk, h * hd), new
 
 
 def attend_contiguous(c, q, k, v, pos, positions, window: int = 0):

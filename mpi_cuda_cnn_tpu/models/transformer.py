@@ -153,6 +153,59 @@ class RoutedExperts:
 
 
 @dataclasses.dataclass(frozen=True)
+class SparseSelect:
+    """Block selection of a softmax layer (InfLLM-V2, arXiv:2509.24663;
+    the MiniCPM4 family's `sparse_config`): a query reads, a K/V head,
+    the first `init_blocks` blocks of `block` keys, the blocks that
+    overlap its last `window` keys, and the `topk` best-scored of the
+    rest; while its position + 1 < `dense_len` it reads every block. A
+    block's score is the largest, over the COMPRESSED keys that overlap
+    it (the mean of `kernel` keys every `stride`), of the sum over the
+    K/V head's query heads of their softmax over all complete
+    compressed keys. There are no parameters: the selection reads q and
+    the cached k alone (serve/paged_cache.select_blocks)."""
+
+    kernel: int = 32
+    stride: int = 16
+    block: int = 64
+    topk: int = 64
+    init_blocks: int = 1
+    window: int = 2048
+    dense_len: int = 8192
+
+    def __post_init__(self):
+        if (min(self.kernel, self.stride, self.block, self.topk) < 1
+                or self.kernel % self.stride or self.block % self.stride
+                or self.kernel > self.block):
+            raise ValueError(f"{self}: want a kernel and a block of whole "
+                             "strides, the kernel no longer than a block")
+
+    def compressed(self, rows):
+        """Compressed keys that are complete once `rows` keys are there
+        (an array): key j covers rows j * stride .. j * stride + kernel
+        - 1."""
+        return jnp.maximum(rows - self.kernel + self.stride, 0) // self.stride
+
+
+@dataclasses.dataclass(frozen=True)
+class LinearAttn:
+    """Lightning attention (linear attention with a per-head decay;
+    Qin et al., arXiv:2401.04658): a layer keeps, a head, ONE
+    (head_dim, head_dim) f32 state S and no keys: S_t = l_h S_{t-1} +
+    k_t^T v_t, o_t = q_t S_t / sqrt(head_dim), l_h = exp(-2^(-`slope`
+    (h + 1) / heads)) (ALiBi-style slopes, the same in every layer).
+    The state is the slot's, not a page's
+    (serve/paged_cache.SlotStates)."""
+
+    slope: float = 8.0
+
+    def log_decay(self, heads: int):
+        """(heads,) f32: log l_h, negative."""
+        return -(2.0 ** (-self.slope * jnp.arange(1, heads + 1,
+                                                  dtype=jnp.float32) / heads))
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerLM:
     """Decoder-only LM: vocab -> dim, `depth` pre-LN blocks, tied LN head.
 
@@ -161,10 +214,14 @@ class TransformerLM:
     standard 4x GELU MLP. The cached decode forward
     (models/generate.token_forward) reads each layer's kind off its
     params instead (`norm`, `mlp`), and `attn` / `experts` /
-    `head_width` / `layout` below describe what the params alone
+    `head_width` / `layout` / `mixers` / `select` / `linear` and the
+    three scales below describe what the params alone
     cannot: latent attention's sizes, an expert layer's routing, a head
-    width that is not dim / heads, and which layers rotate and which
-    see a sliding window only. Such a model brings its own params tree
+    width that is not dim / heads, which layers rotate and which
+    see a sliding window only, which layers keep a recurrent state
+    instead of keys, which blocks a softmax layer's query reads, and
+    the muP scalings of the embedding, the residual branches and the
+    logits. Such a model brings its own params tree
     and is served through serve.PagedEngine.
 
     TPU sizing note (measured, PERF.md round-4 MFU ladder): prefer
@@ -200,9 +257,34 @@ class TransformerLM:
     layout: tuple[tuple[bool, bool], ...] | None = None
                            # per layer (rotary, windowed); None = every
                            # layer as `pos` says, none windowed
+    mixers: tuple[str, ...] | None = None
+                           # per layer "attn" (softmax over cached K/V)
+                           # or "linear" (a recurrent state, `linear`);
+                           # None = every layer "attn"
+    select: SparseSelect | None = None     # the "attn" layers read the
+                           # blocks this selects, not every key
+    linear: LinearAttn | None = None       # the "linear" layers' decay
+    emb_scale: float = 1.0       # the embedding times this (muP)
+    residual_scale: float = 1.0  # every residual branch times this
+    logit_scale: float = 1.0     # the final norm's output times this
     name: str = "transformer_lm"
 
     def __post_init__(self):
+        if self.mixers is not None:
+            if (len(self.mixers) != self.depth
+                    or set(self.mixers) - {"attn", "linear"}):
+                raise ValueError(f"mixers {self.mixers}: want {self.depth} "
+                                 "of 'attn' / 'linear'")
+            if ("linear" in self.mixers) != (self.linear is not None):
+                raise ValueError(f"linear {self.linear} with the mixers "
+                                 f"{self.mixers}")
+        elif self.linear is not None:
+            raise ValueError("a linear mixer needs `mixers` to say which "
+                             "layers it is")
+        if (self.select is not None or self.mixers is not None) and (
+                self.attn is not None or self.window):
+            raise ValueError("block selection and linear layers are of "
+                             "K/V heads without a sliding window")
         if self.layout is None:
             if self.window:
                 raise ValueError("a window needs a layout that says which "
@@ -239,15 +321,29 @@ class TransformerLM:
             return 0
         return self.window
 
+    def mixer(self, layer: int) -> str:
+        """"attn" or "linear": what `layer` mixes positions with."""
+        return "attn" if self.mixers is None else self.mixers[layer]
+
     def cache_groups(self) -> tuple[tuple[int, tuple[int, ...]], ...]:
         """The layer groups of the paged cache, (window, layers) each:
         layers that forget at the same distance share a page pool's
         accounting and a block table. The global layers (window 0)
-        first; a model without windowed layers has the one group."""
+        first; a model without windowed layers has the one group. The
+        "linear" layers are in none of them: their group holds NO
+        pages (`state_layers`)."""
         groups = {}
         for i in range(self.depth):
-            groups.setdefault(self.layer_window(i), []).append(i)
+            if self.mixer(i) == "attn":
+                groups.setdefault(self.layer_window(i), []).append(i)
         return tuple((w, tuple(groups[w])) for w in sorted(groups))
+
+    def state_layers(self) -> tuple[int, ...]:
+        """The page-less layer group: the "linear" layers, each of
+        which keeps one fixed (heads, head_dim, head_dim) f32 array a
+        slot whatever the slot's depth (paged_cache.SlotStates)."""
+        return tuple(i for i in range(self.depth)
+                     if self.mixer(i) == "linear")
 
     @property
     def n_kv(self) -> int:
@@ -263,11 +359,15 @@ class TransformerLM:
 
     def _gpt2_block_only(self, what: str) -> None:
         if (self.attn is not None or self.experts is not None
-                or self.head_width or self.layout is not None):
+                or self.head_width or self.layout is not None
+                or self.mixers is not None or self.select is not None
+                or (self.emb_scale, self.residual_scale,
+                    self.logit_scale) != (1.0, 1.0, 1.0)):
             raise ValueError(
                 f"TransformerLM.{what} knows the LayerNorm/GELU block with "
                 "K/V heads; a model with latent attention, held experts, "
-                "its own head width or a per-layer layout brings its "
+                "its own head width, a per-layer layout, block selection, "
+                "linear layers or muP scalings brings its "
                 "params tree and is served through "
                 "serve.PagedEngine (models/generate.token_forward)")
 
@@ -349,6 +449,8 @@ class TransformerLM:
         if self.attn is not None:
             return self._project_latent(blk, y, positions, w)
         h, hd, hkv = self.heads, self.head_dim, self.n_kv
+        if layer is not None and self.mixer(layer) == "linear":
+            hkv = h         # a linear layer's k and v are a head each
         if hkv == h:
             qkv = qmatmul(y, w(blk["wqkv"]))        # (B, S, 3*dim)
             q, k, v = jnp.split(qkv, 3, axis=-1)
@@ -359,6 +461,9 @@ class TransformerLM:
         q = q.reshape(b, s, h, hd)
         k = k.reshape(b, s, hkv, hd)
         v = v.reshape(b, s, hkv, hd)
+        if "q_norm" in blk:     # RMSNorm over each head's dims, a gain
+            q = _rmsnorm(q, blk["q_norm"]["g"], self.norm_eps)
+            k = _rmsnorm(k, blk["k_norm"]["g"], self.norm_eps)
         if self.rotary(layer):
             q = rope(q, positions, base=self.rope_theta)
             k = rope(k, positions, base=self.rope_theta)

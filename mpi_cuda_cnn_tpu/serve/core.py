@@ -90,7 +90,8 @@ def build_scheduler(*, slots: int, num_pages: int, page_size: int,
                     mode: str = "continuous", spill_fn=None,
                     readmit_fn=None, tier_fault_poll=None,
                     route_keys: set | None = None,
-                    window: tuple[int, int] | None = None):
+                    window: tuple[int, int] | None = None,
+                    states: bool = False):
     """A fresh PagePool with the scheduler over it and, where asked,
     the prefix tree and the host tier under it (reached afterwards as
     `sched.pool`, `sched.prefix`, `sched.prefix.tier`). Prefix sharing
@@ -103,7 +104,21 @@ def build_scheduler(*, slots: int, num_pages: int, page_size: int,
     cannot yet mean anything for such a group is refused here: a
     prefix hit (which layers would it be a hit for? a windowed layer
     forgot the prefix) and with it the host tier's spill of prefix
-    pages."""
+    pages.
+
+    `states` says the model also keeps a recurrent state a slot (a
+    group without pages, paged_cache.SlotStates). The scheduler has
+    nothing to account for it -- a slot's state is the slot's, and the
+    forward that starts a request at position 0, admitted or readmitted
+    after a preemption, starts it from zero -- but counts those starts
+    (`sched.state_resets`), and a prefix hit is refused: it would start
+    a request past position 0 with pages to share and no state to
+    start from."""
+    if states and prefix:
+        raise ValueError(
+            "a prefix hit shares K/V pages and starts the request behind "
+            "them; this model's linear layers would need the state the "
+            "prefix left, and nobody kept it (ROADMAP R6)")
     if window is not None and host_pages > 0:
         raise ValueError(
             "spill moves the prefix cache's pages of ONE layer group; a "
@@ -134,7 +149,7 @@ def build_scheduler(*, slots: int, num_pages: int, page_size: int,
     pcache = (PrefixCache(pool, page_size, tier, route_keys=route_keys)
               if prefix else None)
     kw = dict(slots=slots, pool=pool, page_size=page_size, max_len=max_len,
-              max_queue=max_queue, prefix=pcache)
+              max_queue=max_queue, prefix=pcache, states=states)
     if window is not None:
         kw["window"] = WindowGroup(
             window=window[0], chunk=window[1], page_size=page_size,
@@ -153,7 +168,7 @@ class StepOutcome:
     __slots__ = ("swept", "rejected", "admitted", "prefill", "decoded",
                  "spec", "emitted", "progressed", "preempted_pairs",
                  "blocked", "prefix_tick", "new_fin", "new_drop",
-                 "state_crc", "window_freed")
+                 "state_crc", "window_freed", "state_resets")
 
     @property
     def moved(self) -> bool:
@@ -261,6 +276,8 @@ class ServeCore:
                 self.compute.copy_page(*slot.cow)
                 sched.cow_complete(slot)
             sched.window_step(slot)
+            if slot.cached == 0 and sched.state_resets is not None:
+                sched.state_resets += 1     # this chunk starts from zero
             n, nxt = self.compute.prefill_chunk(slot)
             slot.cached += n
             self.prefill_chunks += 1
@@ -367,6 +384,9 @@ class ServeCore:
                            if self.prefix is not None else None)
         out.window_freed = (sched.window.drain_freed()
                             if sched.window is not None else None)
+        out.state_resets = sched.state_resets
+        if sched.state_resets:
+            sched.state_resets = 0
         out.new_fin = sched.finished[self._n_fin:]
         out.new_drop = sched.dropped[self._n_drop:]
         self._n_fin, self._n_drop = len(sched.finished), len(sched.dropped)
@@ -413,6 +433,12 @@ class ServeCore:
             fields["pages_held"] = [p.usable - p.free_pages
                                     for p in (sched.pool, sched.window.pool)]
             fields["window_pages_freed"] = out.window_freed
+        if out.state_resets is not None:
+            # A model with a recurrent state a slot: the pages issued,
+            # as above, and the slots whose state this iteration's
+            # chunk started from zero (an admission or a readmission).
+            fields["pages_held"] = [sched.pool.usable - sched.pool.free_pages]
+            fields["state_resets"] = out.state_resets
         if out.spec is not None:
             # Speculative round detail (ISSUE 14): [rid, proposed,
             # accepted] per slot — `mctpu trace` derives the round's

@@ -67,7 +67,9 @@ from .host_tier import TIER_SPILL_SITE
 from .paged_cache import (
     PagedKVCache,
     PagePool,
+    SlotStates,
     init_paged_cache,
+    init_slot_states,
     paged_forward,
     pages_for,
 )
@@ -88,6 +90,12 @@ TICK_COUNTS = ("moe_assignments", "moe_experts_hit", "moe_load_max",
 # ... and after them, where the model has windowed layers, the rows
 # THEIR reads touched (`kv_rows_read` stays all layers).
 WINDOW_COUNT = "kv_rows_read_window"
+# ... and last, where the model's softmax layers select the blocks they
+# read or it has linear layers: the compressed keys the selection
+# scored, the blocks it chose (K/V heads and layers summed), and the
+# recurrent states written (decoding slots x linear layers).
+SELECT_COUNTS = ("index_rows_read", "sparse_blocks_selected",
+                 "state_slots_updated")
 # The same vector as the prefill CHUNK's program returned it, on the
 # record of an iteration that ran a chunk of a model with expert
 # layers: its first two elements, the pairs the held experts computed
@@ -103,7 +111,7 @@ TICK_LAYOUT = (
     "backlog", "arrived", "admitted", "prefill", "decoded", "finished",
     "aborted", "preempted", "blocked", "preempted_for", "terminal",
     "state_crc", "compiled", *TICK_COUNTS, WINDOW_COUNT, *CHUNK_COUNTS,
-    "pages_held",
+    *SELECT_COUNTS, "pages_held", "state_resets",
     "window_pages_freed", "squeezed", "spec",
     "prefix_hits", "prefix", "prefix_readmits",
 )
@@ -294,7 +302,8 @@ def _observe_run_tick(registry, rec: dict, out, core: ServeCore) -> None:
     registry.set("serve.prefill_backlog", rec["backlog"])
     if out.emitted:
         registry.inc("serve.tokens_emitted", out.emitted)
-    for name in (*TICK_COUNTS, WINDOW_COUNT, *CHUNK_COUNTS):
+    for name in (*TICK_COUNTS, WINDOW_COUNT, *CHUNK_COUNTS, *SELECT_COUNTS,
+                 "state_resets"):
         if name in rec:
             registry.set(f"serve.{name}", rec[name])
     for _, _, accepted in out.spec or ():
@@ -732,6 +741,15 @@ class PagedEngine:
                 "speculation rolls back rejected rows' pages in ONE "
                 "layer group; a windowed group may already have given "
                 "back the pages a rollback would need")
+        # The page-less group (TransformerLM.state_layers): one state a
+        # slot a linear layer, `_states`; a slot's row of each is the
+        # slot's own, so there is nothing to allocate or to free.
+        self._states = init_slot_states(model, slots) or None
+        if self._states is not None and spec != "off":
+            raise ValueError(
+                "speculation takes rejected rows back by freeing their "
+                "pages; a linear layer's state has already absorbed them "
+                "and cannot be rolled back")
         tmpl = init_paged_cache(
             model, slots=slots, num_pages=num_pages, page_size=page_size,
             dtype=self.cache_dtype, max_len=self.max_len,
@@ -845,14 +863,20 @@ class PagedEngine:
 
     # -- host-side helpers ------------------------------------------------
 
-    def _cache_view(self, table):
+    def _cache_view(self, table, rows=()):
         """The device cache under `table`: a (rows, table width) block
         table and one PagedKVCache, or for a model with a windowed
-        group the two groups' tables and one PagedKVCache each."""
+        group the two groups' tables and one PagedKVCache each; for a
+        model with linear layers a tuple of that PagedKVCache and the
+        slots' states, `rows` naming each batch row's slot."""
         if self._window is None:
-            return PagedKVCache(pages=self._pages,
+            view = PagedKVCache(pages=self._pages,
                                 block_table=jnp.asarray(table),
                                 page_size=self.page_size)
+            if self._states is None:
+                return view
+            return view, SlotStates(
+                states=self._states, rows=jnp.asarray(rows, jnp.int32))
         return tuple(
             PagedKVCache(pages=pages, block_table=jnp.asarray(t),
                          page_size=self.page_size, window=window)
@@ -875,6 +899,9 @@ class PagedEngine:
 
     def _keep(self, cache):
         """Adopt a program's returned cache; returns its counts."""
+        if self._states is not None:
+            cache, store = cache
+            self._states = store.states
         if self._window is None:
             self._pages = cache.pages
             return cache.counts
@@ -886,6 +913,11 @@ class PagedEngine:
             raise ValueError(
                 f"{what} moves the pages of ONE layer group; this model "
                 "has a windowed group beside the global one")
+        if self._states is not None:
+            raise ValueError(
+                f"{what} moves a slot as its pages; this model's linear "
+                "layers keep a state a slot that is no page and would "
+                "stay behind")
 
     def compiled_programs(self) -> int:
         """Compiled forms held by the engine's jitted programs, summed
@@ -995,7 +1027,7 @@ class PagedEngine:
         n = min(self.prefill_chunk, slot.target - slot.cached)
         toks = np.zeros((1, self.prefill_chunk), np.int32)
         toks[0, :n] = ctx[slot.cached : slot.cached + n]
-        view = self._cache_view(self._tables(1, [(0, slot)]))
+        view = self._cache_view(self._tables(1, [(0, slot)]), [slot.idx])
         inputs = (jnp.asarray(toks), jnp.int32(slot.cached), jnp.int32(n))
         if self._spans is not None:
             self._spans.enter("prefill.dispatch")
@@ -1018,7 +1050,8 @@ class PagedEngine:
             pos[s.idx] = s.cached
             live[s.idx] = True
         view = self._cache_view(
-            self._tables(self.slots, [(s.idx, s) for s in dslots]))
+            self._tables(self.slots, [(s.idx, s) for s in dslots]),
+            range(self.slots))
         inputs = (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(live))
         if self._spans is not None:
             self._spans.enter("tick.dispatch")
@@ -1097,6 +1130,9 @@ class PagedEngine:
             # the device beside the tokens, read here and nowhere else.
             # mctpu: disable=MCT007
             counted = np.asarray(self._tick_counts).tolist()
+            if self.model.select is not None or self._states is not None:
+                fields.update(zip(SELECT_COUNTS, counted[-3:]))
+                del counted[-3:]
             if self._window is not None:
                 fields[WINDOW_COUNT] = counted.pop()
             names = (*TICK_COUNTS[:3],
@@ -1234,7 +1270,7 @@ class PagedEngine:
             readmit_fn=self.readmit_page,
             tier_fault_poll=((lambda seq: faults.poll(TIER_SPILL_SITE, seq))
                              if faults is not None else None),
-            window=self._window,
+            window=self._window, states=self._states is not None,
         )
         core = ServeCore(EngineCompute(self), sched, proposer=proposer,
                          spec_k=self.spec_k)

@@ -240,6 +240,11 @@ class Replica:
                 "the fleet's hand-off and failover move a slot as the page "
                 "set of ONE layer group; this replica's model has a "
                 "windowed group beside the global one")
+        if getattr(engine, "_states", None) is not None:
+            raise ValueError(
+                "the fleet's hand-off and failover move a slot as its page "
+                "set; this replica's model keeps a recurrent state a slot "
+                "that is no page and would stay behind")
         sched = build_scheduler(
             slots=slots, num_pages=num_pages, page_size=page_size,
             max_len=max_len, max_queue=max_queue, prefix=prefix,

@@ -80,6 +80,25 @@ too, one running softmax a slot carried and folded inside the loop. A
 model with one group, and every read of a tick or a 32-row chunk, is
 the code it was.
 
+SELECTED READS AND A GROUP WITHOUT PAGES (PR 34). Where the model's
+softmax layers SELECT the blocks a query reads (transformer.
+SparseSelect) a layer's pools hold, beside `k` and `v`, the COMPRESSED
+keys `kc`: the mean of `kernel` keys every `stride`, one row a stride
+of a page, on the same block table, written by the forward whose row
+completes it (`_write_compressed`) and freed with the page. A read
+then has three steps: the selection scores the slot's complete
+compressed keys and takes the blocks (`select_blocks`: a mask a query
+row and K/V head over the table's blocks); a tick's one row a slot
+walks, through the SAME loop, the union of its K/V heads' chosen
+blocks instead of every block up to its depth (`_read_items`'
+`chosen`), each head masked to its own; a chunk's many rows, whose
+choices together cover nearly every block, walk every block to the
+depth under the same mask. The model's LINEAR layers are a group that
+holds no pages at all: `SlotStates`, one (heads, head_dim, head_dim)
+f32 array a slot a layer whatever the depth, which a forward that
+starts a slot at position 0 starts from zero and which only valid rows
+update (models/generate.linear_attend).
+
 What was measured on the v5e (PERF.md section 6, PR 29; one tick's
 reads at chat's shapes, 8 layers): whole-table gather 20.6 ms, bounded
 2.1 ms; the former Pallas kernel (one page a grid step over the whole
@@ -109,9 +128,10 @@ from ..models.generate import (
     causal_mask,
     latent_query_rows,
     latent_values_up,
+    linear_attend,
     token_forward,
 )
-from ..models.transformer import TransformerLM
+from ..models.transformer import SparseSelect, TransformerLM
 from ..obs.trace import annotate
 from ..ops.attention import NEG_INF
 
@@ -165,6 +185,35 @@ jax.tree_util.register_dataclass(
 )
 
 
+@dataclasses.dataclass
+class SlotStates:
+    """The recurrent layer group's store (TransformerLM.state_layers):
+    NO pages and no block table -- `states` holds one (slots, heads,
+    head_dim, head_dim) f32 array a linear layer, a slot's row its
+    whole memory of the sequence, the same bytes at depth 1 and at
+    65,536. `rows` (B,) names, for each batch row of the forward, the
+    slot whose state it continues (a tick: every slot in order; a
+    prefill chunk: the one slot). A forward whose first valid row sits
+    at position 0 starts that slot from zero, whatever the store
+    holds: a slot is clean for its next request, and for the same
+    request readmitted after a preemption, without anyone clearing it.
+    It rides beside the model's PagedKVCache(s) in one tuple."""
+
+    states: list
+    rows: jnp.ndarray             # (B,) int32
+
+
+jax.tree_util.register_dataclass(
+    SlotStates, data_fields=["states", "rows"], meta_fields=[])
+
+
+def init_slot_states(model: TransformerLM, slots: int) -> list:
+    """Zero states, one (slots, heads, head_dim, head_dim) f32 array a
+    linear layer (SlotStates.states); [] for a model without any."""
+    shape = (slots, model.heads, model.head_dim, model.head_dim)
+    return [jnp.zeros(shape, jnp.float32) for _ in model.state_layers()]
+
+
 def init_paged_cache(model: TransformerLM, *, slots: int, num_pages: int,
                      page_size: int, dtype=jnp.float32,
                      max_len: int | None = None,
@@ -173,7 +222,8 @@ def init_paged_cache(model: TransformerLM, *, slots: int, num_pages: int,
     or for a model with windowed layers beside global ones a tuple of
     them, one a layer group (TransformerLM.cache_groups), each with the
     pools of ITS layers and a table of its own; `window_pages` sizes a
-    windowed group's pools (default: as `num_pages`).
+    windowed group's pools (default: as `num_pages`). A model's linear
+    layers have no pools here: their states are `init_slot_states`'.
 
     num_pages INCLUDES the reserved scratch page 0, so num_pages - 1
     pages are allocatable; max_len (default model.max_seq) bounds any
@@ -206,6 +256,12 @@ def init_paged_cache(model: TransformerLM, *, slots: int, num_pages: int,
         return PagedKVCache(pages=pages, block_table=table,
                             page_size=page_size)
     int8 = jnp.dtype(dtype) == jnp.int8
+    sel = model.select
+    if sel is not None and (int8 or page_size % sel.stride):
+        raise ValueError(
+            f"a selecting model's compressed keys are means of float K "
+            f"rows, a whole number of them a page; got cache dtype "
+            f"{jnp.dtype(dtype)}, page_size {page_size}, stride {sel.stride}")
 
     def pools(layers: int, num_pages: int) -> list[dict]:
         shape = (num_pages, page_size, model.n_kv, model.head_dim)
@@ -222,11 +278,17 @@ def init_paged_cache(model: TransformerLM, *, slots: int, num_pages: int,
             else:
                 pages.append({"k": jnp.zeros(shape, dtype),
                               "v": jnp.zeros(shape, dtype)})
+            if sel is not None:     # the compressed keys, a row a stride
+                pages[-1]["kc"] = jnp.zeros(
+                    (num_pages, page_size // sel.stride) + shape[2:], dtype)
         return pages
 
     groups = model.cache_groups()
+    if not groups:
+        raise ValueError("the paged cache holds the K/V of a model's 'attn' "
+                         "layers; this model has none")
     if len(groups) == 1:
-        return PagedKVCache(pages=pools(model.depth, num_pages),
+        return PagedKVCache(pages=pools(len(groups[0][1]), num_pages),
                             block_table=table, page_size=page_size,
                             window=groups[0][0])
     return tuple(
@@ -305,8 +367,147 @@ def _many_queries(q) -> bool:
     return h * kk * hd * 4 > _BLOCK_BYTES
 
 
+def _write_compressed(kc, kpool, positions, valid, block_table,
+                      page_size: int, sel: SparseSelect):
+    """The compressed keys that this forward's rows COMPLETE, into the
+    pool `kc` (pages, page_size // stride, Hkv, hd): compressed key j
+    is the mean of the keys at positions j * stride .. j * stride +
+    kernel - 1, complete when the last of them is written, and lives
+    on the page of its FIRST position (so it is freed with it). A
+    batch row's valid positions are consecutive (a chunk's rows, a
+    tick's one): of kk rows at most ceil(kk / stride) end a kernel;
+    their `kernel` keys are read back from `kpool`, this forward's own
+    among them, through the block table (they may lie in pages an
+    earlier chunk wrote), averaged in f32 and stored in the pool's
+    type. A row that ends none writes scratch page 0. Returns the new
+    `kc`."""
+    b, kk = positions.shape
+    stride, kernel = sel.stride, sel.kernel
+    ncand = -(-kk // stride)
+    hi = jnp.max(jnp.where(valid, positions, -1), axis=1)          # (B,)
+    lo = jnp.min(jnp.where(valid, positions, hi[:, None]), axis=1)
+    # The c-th position >= lo that ends a stride, and its kernel's rows.
+    ends = ((lo // stride + 1) * stride - 1)[:, None] + stride * jnp.arange(
+        ncand)[None, :]                                            # (B, C)
+    done = (ends <= hi[:, None]) & (ends >= kernel - 1)
+    rows = jnp.maximum(ends[:, :, None] - (kernel - 1)
+                       + jnp.arange(kernel)[None, None, :], 0)     # (B, C, K)
+    page = jnp.take_along_axis(
+        block_table, (rows // page_size).reshape(b, -1), axis=1)
+    keys = kpool[page.reshape(-1), (rows % page_size).reshape(-1)].reshape(
+        b, ncand, kernel, *kpool.shape[2:])
+    mean = jnp.mean(keys.astype(jnp.float32), axis=2).astype(kc.dtype)
+    first = jnp.maximum(ends - (kernel - 1), 0)        # key j's position
+    to = jnp.take_along_axis(block_table, first // page_size, axis=1)
+    return kc.at[jnp.where(done, to, 0).reshape(-1),
+                 jnp.where(done, first % page_size // stride, 0).reshape(-1)
+                 ].set(mean.reshape(b * ncand, *kc.shape[2:]))
+
+
+# What the selection's scores of one step may take (f32): the query
+# heads of a K/V head are scored a few at a time against every
+# compressed key of the table, and summed.
+_SCORE_BYTES = 64 << 20
+
+
+def _best_blocks(far, k: int):
+    """(..., blocks) bool: the k best-scored of the blocks that are
+    candidates (`far` >= 0; the others hold -1), all of them where
+    they are fewer. No scatter: every block above the k-th best
+    score, and of the blocks that EQUAL it the lowest-numbered that
+    fill the rest -- lax.top_k's own order of ties."""
+    kth = jax.lax.top_k(far, k)[0][..., -1:]
+    above, ties = far > kth, far == kth
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    return (far >= 0) & (above | (ties & (jnp.cumsum(ties, axis=-1) <= room)))
+
+
+def select_blocks(q, kc, positions, valid, block_table, page_size: int,
+                  sel: SparseSelect):
+    """The blocks each query row reads, a K/V head (SparseSelect,
+    InfLLM-V2): q (B, kk, H, hd) against the slot's compressed keys
+    `kc` (gathered whole through the block table: a row a stride, 1/16
+    of the K rows' bytes). For the query at position t and K/V head g:
+    p_h = softmax over the compressed keys complete at t of q_h . kc_j
+    / sqrt(hd), for each of g's query heads; P_j their sum; a block's
+    score the largest P_j over the compressed keys that overlap it;
+    chosen = the first `init_blocks` blocks, the blocks overlapping
+    keys t - window + 1 .. t, and the `topk` best-scored of the other
+    blocks up to t's own (ties to the lower block: lax.top_k's order);
+    every block up to t's own while t + 1 < dense_len.
+    Returns (chosen (B, Hkv, kk, blocks of the table) bool, compressed
+    keys the valid rows scored, blocks the valid rows chose -- both
+    int32, K/V heads summed)."""
+    b, kk, h, hd = q.shape
+    hkv = kc.shape[2]
+    g = h // hkv
+    ncomp = block_table.shape[1] * kc.shape[1]
+    r, lead = sel.block // sel.stride, sel.kernel // sel.stride - 1
+    nb = -(-block_table.shape[1] * page_size // sel.block)
+    comp = kc[block_table].reshape(b, ncomp, hkv, hd)
+    have = sel.compressed(positions + 1)                       # (B, kk)
+    there = (jnp.arange(ncomp)[None, None, :] < have[:, :, None])[:, None]
+    qg = q.reshape(b, kk, hkv, g, hd)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    few = max(1, min(g, _SCORE_BYTES // (4 * b * hkv * kk * ncomp)))
+    total = jnp.zeros((b, hkv, kk, ncomp), jnp.float32)
+    for g0 in range(0, g, few):
+        s = jnp.einsum("bqhgd,bjhd->bhgqj", qg[:, :, :, g0:g0 + few], comp,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(there[:, :, None], s, NEG_INF)
+        total = total + jnp.sum(
+            jnp.where(there[:, :, None], jax.nn.softmax(s, axis=-1), 0.0),
+            axis=2)
+    # Compressed key j overlaps block j // r and, its kernel reaching
+    # `lead` strides further, the block after: pad `lead` in front and
+    # a block's score is the largest of its own r entries and the next
+    # block's first `lead`.
+    padded = jnp.pad(total, ((0, 0),) * 3 + ((lead, (nb + 1) * r - ncomp
+                                              - lead),))
+    score = jnp.max(padded[..., : nb * r].reshape(b, hkv, kk, nb, r), axis=-1)
+    if lead:
+        score = jnp.maximum(score, jnp.max(
+            padded[..., r:].reshape(b, hkv, kk, nb, r)[..., :lead], axis=-1))
+    t = positions[:, None, :, None]                            # (B, 1, kk, 1)
+    blk = jnp.arange(nb)[None, None, None, :]
+    upto = blk <= t // sel.block
+    near = (blk < sel.init_blocks) | (
+        blk >= jnp.maximum(t - sel.window + 1, 0) // sel.block)
+    far = jnp.where(upto & ~near, score, -1.0)      # a score is >= 0
+    picked = _best_blocks(far, min(sel.topk, nb))
+    chosen = upto & jnp.where(t + 1 < sel.dense_len, True, near | picked)
+    live = valid[:, None, :]
+    return (chosen,
+            (hkv * jnp.sum(jnp.where(valid, have, 0))).astype(jnp.int32),
+            jnp.sum(jnp.where(live[..., None], chosen, False)).astype(
+                jnp.int32))
+
+
+def chosen_walk(chosen, sel: SparseSelect, step: tuple[int, int],
+                page_size: int):
+    """The items a tick walks in place of every block to its depth:
+    `chosen` (B, Hkv, 1, blocks) -> ((need (B,), blocks (B, most)), the
+    walk's step). A slot's items are the UNION of its K/V heads' chosen
+    blocks, ascending, a selection block each (each head is masked to
+    its own in the read); `most` is what a slot can choose at most:
+    every block under dense_len, or the near ones and each head's
+    topk. A step takes as many of them as move what `step`, read_step's
+    for whole blocks of the table, moved."""
+    union = jnp.any(chosen[:, :, 0], axis=1)                   # (B, nb)
+    most = min(union.shape[1], max(
+        -(-sel.dense_len // sel.block),
+        sel.init_blocks + -(-sel.window // sel.block) + 1
+        + chosen.shape[1] * sel.topk))
+    walk = (jnp.sum(union, axis=1).astype(jnp.int32),
+            jnp.argsort(~union, axis=1, stable=True)[:, :most].astype(
+                jnp.int32))
+    return walk, (sel.block // page_size,
+                  max(1, step[0] * step[1] * page_size // sel.block))
+
+
 def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
-                        page_size: int, window: int = 0):
+                        page_size: int, window: int = 0,
+                        select: SparseSelect | None = None):
     """One layer's paged write + attention read.
 
     q: (B, kk, H, hd); k/v: (B, kk, Hkv, hd); positions: (B, kk)
@@ -323,7 +524,15 @@ def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
     - window, and the read starts at the block that holds the smallest
     valid position's window: blocks wholly behind it are not touched
     either (their table entries may be scratch).
-    Returns (o: (B, kk, H*hd) f32, new_c, cache rows the read touched).
+
+    Under a block selection `select` (the pools then hold `kc` too):
+    the compressed keys these rows complete are written after the
+    keys, `select_blocks` chooses each row's blocks a K/V head, and
+    the read sees a key only in a chosen block. One row a slot (a
+    tick) walks the union of its K/V heads' chosen blocks, one item a
+    selection block; more rows walk every block to the depth, masked.
+    Returns (o: (B, kk, H*hd) f32, new_c, cache rows the read touched)
+    and, under a selection, (compressed keys scored, blocks chosen).
     """
     b, kk = positions.shape
     hkv, hd = k.shape[2], k.shape[3]
@@ -353,14 +562,31 @@ def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
     # an item leaves that many f32 values -- folded into a carry a
     # slot of that size.
     many = 4 * q.shape[2] * kk * hd
-    o, rows = bounded_read(
-        q, new_c, positions, valid, block_table, page_size=page_size,
-        step=read_step(b, block_table.shape[1], page_size, key_bytes,
-                       **(dict(key_flops=many, stat_bytes=many,
-                               carry_bytes=many)
-                          if _many_queries(q) else {})),
-        window=window)
-    return o, new_c, rows
+    step = read_step(b, block_table.shape[1], page_size, key_bytes,
+                     **(dict(key_flops=many, stat_bytes=many,
+                             carry_bytes=many)
+                        if _many_queries(q) else {}))
+    if select is None:
+        o, rows = bounded_read(
+            q, new_c, positions, valid, block_table, page_size=page_size,
+            step=step, window=window)
+        return o, new_c, rows
+    with annotate("attn.sparse_select"):
+        kc = _write_compressed(c["kc"], new_c["k"], positions, valid,
+                               block_table, page_size, select)
+        chosen, scored, nchosen = select_blocks(
+            q, kc, positions, valid, block_table, page_size, select)
+    npages = block_table.shape[1]
+    walk = None
+    if (kk == 1 and select.block % page_size == 0
+            and step[1] < b * -(-npages // step[0])):
+        # One row a slot and a table worth a loop.
+        walk, step = chosen_walk(chosen, select, step, page_size)
+    with annotate("attn.sparse_read"):
+        o, rows = bounded_read(
+            q, new_c, positions, valid, block_table, chosen, walk,
+            page_size=page_size, step=step, sel_block=select.block)
+    return o, {**new_c, "kc": kc}, rows, (scored, nchosen)
 
 
 # What one (slot, block) item of the bounded read moves at least, and
@@ -415,7 +641,8 @@ def read_step(slots: int, npages: int, page_size: int, key_bytes: int,
 
 
 def _read_items(positions, valid, block_table, page_size: int,
-                step: tuple[int, int], dead_blocks: int, window: int = 0):
+                step: tuple[int, int], dead_blocks: int, window: int = 0,
+                walk=None):
     """The flat list of (slot, block of pages) items both bounded reads
     walk, built on the device from `positions` and `valid`: a live
     slot's blocks up to its deepest valid position -- from block 0, or
@@ -428,42 +655,57 @@ def _read_items(positions, valid, block_table, page_size: int,
     blocks a slot needs (B,), their running total, the loop's trip
     count (a value), and per item its slot, whether the list holds it,
     its pages (scratch where it does not) and its first key's
-    position. The list is padded to whole steps."""
+    position. The list is padded to whole steps.
+
+    `walk` = (need (B,), blocks (B, most)) names each slot's blocks
+    instead (a block selection's, paged_update_attend): a slot's items
+    are the first need[slot] entries of its row, whatever their
+    numbers, and no depth is looked at."""
     b, npages = block_table.shape
     per_block, per_step = step
     nblk = -(-npages // per_block)
     width = per_block * page_size                 # keys a block
     # Each slot's blocks; its table, padded with scratch to whole blocks.
-    depth = jnp.max(jnp.where(valid, positions, 0), axis=1)
-    need = jnp.minimum(depth // width + 1, nblk)              # (B,)
-    if window:
-        lowest = jnp.min(jnp.where(valid, positions, depth[:, None]), axis=1)
-        first = jnp.maximum(lowest - window + 1, 0) // width  # (B,)
-        need = need - first
-    if not dead_blocks:
-        need = jnp.where(jnp.any(valid, axis=1), need, 0)
+    if walk is None:
+        most = nblk
+        depth = jnp.max(jnp.where(valid, positions, 0), axis=1)
+        need = jnp.minimum(depth // width + 1, nblk)              # (B,)
+        if window:
+            lowest = jnp.min(jnp.where(valid, positions, depth[:, None]),
+                             axis=1)
+            first = jnp.maximum(lowest - window + 1, 0) // width  # (B,)
+            need = need - first
+        if not dead_blocks:
+            need = jnp.where(jnp.any(valid, axis=1), need, 0)
+    else:
+        need, named = walk
+        most = named.shape[1]
     ends = jnp.cumsum(need)
     steps = -(-ends[-1] // per_step)
     blocks = jnp.pad(block_table, ((0, 0), (0, nblk * per_block - npages))
                      ).reshape(b * nblk, per_block)
     # The flat list: item i is block i - (ends - need)[slot] of the
-    # slot whose run of items holds i. Past the list's end there is no
-    # item: what the last step computes there (scratch pages) is never
-    # folded.
-    item = jnp.arange(-(-b * nblk // per_step) * per_step)
+    # slot whose run of items holds i (under a walk: that entry of its
+    # row). Past the list's end there is no item: what the last step
+    # computes there (scratch pages) is never folded.
+    item = jnp.arange(-(-b * most // per_step) * per_step)
     slot = jnp.minimum(jnp.sum(item[:, None] >= ends[None, :], axis=1), b - 1)
     blk = item - (ends - need)[slot]
     if window:
         blk = blk + first[slot]
     live = item < ends[-1]
+    if walk is not None:
+        blk = named[slot, jnp.where(live, blk, 0)]
     item_pages = jnp.where(live[:, None],
                            blocks[slot * nblk + jnp.where(live, blk, 0)], 0)
     return need, ends, steps, slot, live, item_pages, blk * width
 
 
-@functools.partial(jax.jit, static_argnames=("page_size", "step", "window"))
-def bounded_read(q, c: dict, positions, valid, block_table, *,
-                 page_size: int, step: tuple[int, int], window: int = 0):
+@functools.partial(jax.jit, static_argnames=("page_size", "step", "window",
+                                             "sel_block"))
+def bounded_read(q, c: dict, positions, valid, block_table, chosen=None,
+                 walk=None, *, page_size: int, step: tuple[int, int],
+                 window: int = 0, sel_block: int = 0):
     """The attention read over one layer's page pools `c`, bounded by
     what each slot holds; `step` = (per_block, per_step) is read_step's.
     Jitted, so a program of many layers traces it once and not once a
@@ -498,6 +740,13 @@ def bounded_read(q, c: dict, positions, valid, block_table, *,
     and folds each of a step's items into its slot's, as
     bounded_read_latent does.
 
+    Under a block selection `chosen` (B, Hkv, kk, blocks of `sel_block`
+    keys; select_blocks) a K/V head's queries see a key only where its
+    block is chosen: one more term of the mask, in every form. `walk`
+    (need, blocks) then names the items of a slot's ONE row, a
+    selection block each (_read_items), in place of every block up to
+    its depth.
+
     Returns (o: (B, kk, H*hd) f32, cache rows the read touched: steps
     taken x rows a step, int32)."""
     b, kk, h, hd = q.shape
@@ -507,20 +756,36 @@ def bounded_read(q, c: dict, positions, valid, block_table, *,
     int8 = c["k"].dtype == jnp.int8
     per_block, per_step = step
     nblk = -(-npages // per_block)
-    if per_step >= b * nblk:
+
+    if per_step >= b * nblk and walk is None:
         length = npages * page_size
         rows = {n: c[n][block_table].reshape(b, length, *c[n].shape[2:])
                 for n in c}
         mask = causal_mask(jnp.arange(length)[None, None, :],
                            positions[:, :, None], window)
+        if chosen is not None:      # every block's verdict, a key each
+            mask = mask[:, None] & jnp.repeat(
+                chosen, sel_block, axis=-1)[..., :length]
         o = attend_kv(q, rows["k"], rows["v"], mask,
                       cks=rows.get("ks"), cvs=rows.get("vs"))
         return o, jnp.int32(b * length)
 
     width = per_block * page_size                 # keys a block
+    if chosen is not None:
+        # An item's keys are one run of whole selection blocks (or lie
+        # in one): its verdicts are a SLICE of its slot's, blocks
+        # first so that the slice is one piece -- a gather a key cost
+        # 5 ms an item on the chip (PERF.md section 6, PR 34).
+        if width % sel_block and sel_block % width:
+            raise ValueError(f"items of {width} keys and selection blocks "
+                             f"of {sel_block}: want whole ones of the other")
+        by_block = jnp.transpose(chosen, (0, 3, 1, 2))    # (B, nb, Hkv, kk)
+        nsel = max(1, width // sel_block)
     need, ends, steps, slot, live, item_pages, first_key = _read_items(
         positions, valid, block_table, page_size, step, dead_blocks=1,
-        window=window)
+        window=window, walk=walk)
+    if walk is not None:
+        nblk = walk[1].shape[1]     # a slot's items at most
     qg = q.reshape(b, kk, hkv, g, hd)
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     running = _many_queries(q)
@@ -543,6 +808,12 @@ def bounded_read(q, c: dict, positions, valid, block_table, *,
         mask = causal_mask(
             key0[:, None, None] + jnp.arange(width)[None, None, :],
             positions[sl][:, :, None], window)[:, None, None, :, :]
+        if chosen is not None:
+            picks = by_block[sl[:, None], (key0 // sel_block)[:, None]
+                             + jnp.arange(nsel)[None, :]]  # (P, nsel, Hkv, kk)
+            mask = mask & jnp.repeat(
+                jnp.transpose(picks, (0, 2, 3, 1)), width // nsel,
+                axis=-1)[:, :, None]
         if running:     # an item past the list's end folds as nothing
             mask = mask & jax.lax.dynamic_slice_in_dim(
                 live, at, per_step)[:, None, None, None, None]
@@ -721,6 +992,14 @@ def bounded_read_latent(q, pool, positions, valid, block_table, wuk, wuv, *,
     return out, (steps * (per_step * width)).astype(jnp.int32)
 
 
+def _carried_state(states, first):
+    """What a forward continues: each batch row's slot's state
+    (B, heads, head_dim, head_dim), or zeros where the row's first
+    valid position `first` (B,) is 0 -- a request's first chunk, on
+    first admission or after a preemption, whoever had the slot."""
+    return jnp.where((first == 0)[:, None, None, None], 0.0, states)
+
+
 def paged_forward(model: TransformerLM, params, toks, positions, valid,
                   cache):
     """toks (B, kk) through the model against the paged cache — the
@@ -737,22 +1016,57 @@ def paged_forward(model: TransformerLM, params, toks, positions, valid,
     forward's `counts`: the expert layers' three
     where the model has any (and zeros for a latent model without),
     then the cache rows the read touched, all layers together, then
-    where the model has windowed layers the rows their reads touched."""
-    caches = cache if isinstance(cache, tuple) else (cache,)
+    where the model has windowed layers the rows their reads touched,
+    then where it selects blocks or has linear layers three more: the
+    compressed keys the valid rows scored, the blocks they chose (K/V
+    heads and layers summed), and the states written (slots with a
+    valid row x linear layers). A model with linear layers brings
+    their `SlotStates` as one more element of the tuple, after its
+    PagedKVCache(s), and gets it back there."""
+    every = cache if isinstance(cache, tuple) else (cache,)
+    caches = tuple(c for c in every if not isinstance(c, SlotStates))
+    store = next((c for c in every if isinstance(c, SlotStates)), None)
     groups = model.cache_groups()
     if [c.window for c in caches] != [w for w, _ in groups]:
         raise ValueError(
             f"the model's layer groups have windows {[w for w, _ in groups]}"
             f"; the cache's {[c.window for c in caches]}")
+    if len(model.state_layers()) != (
+            0 if store is None else len(store.states)):
+        raise ValueError(
+            f"the model's linear layers {model.state_layers()} want one "
+            "state each in a SlotStates beside the paged cache")
     group_of = {i: g for g, (_, layers) in enumerate(groups) for i in layers}
     new_pages: list[list[dict]] = [[] for _ in caches]
-    rows_read = rows_window = 0
+    new_states: list = []
+    rows_read = rows_window = scored = nchosen = updated = 0
 
     def attend(i, q, k, v):
-        nonlocal rows_read, rows_window
+        nonlocal rows_read, rows_window, scored, nchosen, updated
+        if model.mixer(i) == "linear":
+            # This row's slot's state; from zero where its first valid
+            # row sits at position 0 (a slot's new request, or the same
+            # one readmitted). Only slots with a valid row are written.
+            with annotate("attn.linear_state"):
+                states = store.states[len(new_states)]
+                any_valid = jnp.any(valid, axis=1)
+                first = jnp.min(jnp.where(valid, positions,
+                                          jnp.iinfo(jnp.int32).max), axis=1)
+                old = _carried_state(states[store.rows], first)
+                o, new = linear_attend(q, k, v, old, valid,
+                                       model.linear.log_decay(model.heads))
+                to = jnp.where(any_valid, store.rows, states.shape[0])
+                new_states.append(states.at[to].set(new, mode="drop"))
+                updated += jnp.sum(any_valid)
+            return o
         g = group_of[i]
         c, pools = caches[g], caches[g].pages[len(new_pages[g])]
-        if model.attn is not None:
+        if model.select is not None:
+            o, new_c, n, (ns, nc) = paged_update_attend(
+                pools, q, k, v, positions, valid, c.block_table,
+                c.page_size, select=model.select)
+            scored, nchosen = scored + ns, nchosen + nc
+        elif model.attn is not None:
             o, new_c, n = paged_update_attend_latent(
                 pools, q, k, positions, valid, c.block_table,
                 c.page_size, params["blocks"][i], model.attn)
@@ -782,9 +1096,14 @@ def paged_forward(model: TransformerLM, params, toks, positions, valid,
     if model.window:
         counts = jnp.concatenate(
             [counts, jnp.reshape(jnp.asarray(rows_window, jnp.int32), (1,))])
+    if model.select is not None or store is not None:
+        counts = jnp.concatenate([counts, jnp.stack([
+            jnp.asarray(n, jnp.int32) for n in (scored, nchosen, updated)])])
     new = tuple(
         dataclasses.replace(c, pages=p, counts=None if g else counts)
         for g, (c, p) in enumerate(zip(caches, new_pages)))
+    if store is not None:
+        new = new + (dataclasses.replace(store, states=new_states),)
     return logits, new if isinstance(cache, tuple) else new[0]
 
 

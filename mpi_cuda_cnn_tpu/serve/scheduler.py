@@ -331,7 +331,7 @@ class _SchedulerBase:
     def __init__(self, *, slots: int, pool: PagePool, page_size: int,
                  max_len: int, max_queue: int | None = None,
                  prefix: PrefixCache | None = None,
-                 window: WindowGroup | None = None):
+                 window: WindowGroup | None = None, states: bool = False):
         if slots < 1:
             raise ValueError(f"need at least one slot, got {slots}")
         if max_queue is not None and max_queue < 1:
@@ -346,6 +346,15 @@ class _SchedulerBase:
         # a model that has windowed layers beside global ones; `pool`
         # above is the global group's and decides admission alone.
         self.window = window
+        # A model that keeps a recurrent state a slot beside its pages
+        # (paged_cache.SlotStates): nothing to allocate -- the state
+        # row is the slot's, and the chunk that starts a request at
+        # position 0 (first admission, or readmission after `preempt`,
+        # which recomputes from 0) starts it from zero -- so all that
+        # is kept is how many such starts the iteration saw, for the
+        # tick record (core.ServeCore.work counts, settle drains). None
+        # where the model has no such state.
+        self.state_resets: int | None = 0 if states else None
         self.queue: deque[Request] = deque()
         # Incremental queue-membership signature (ISSUE 15): xor of
         # _rid_sig over queued rids, maintained by the _q_* helpers at
@@ -571,6 +580,11 @@ class _SchedulerBase:
             raise ValueError(
                 "hand-off moves one group's page set; a slot with a "
                 "windowed layer group holds two")
+        if self.state_resets is not None:
+            raise ValueError(
+                "hand-off moves a slot as its page set; this model keeps "
+                "a recurrent state a slot that is no page and would stay "
+                "behind")
         assert slot.cow is None and slot.cow_node is None, (
             "detach with a pending COW — prefill cannot have completed"
         )

@@ -91,6 +91,55 @@ print("EXPERT_MLP_OK")
 """
 
 
+# The same for minicpm-sala's two mixers at the cell's layout (PR 34):
+# one sparse layer's write, selection and read over 32 slots x 65,536
+# rows of 2 K/V heads, and one linear layer's product over 32 heads of
+# 128 -- a tick (argv 1 = "tick") or a 512-row chunk ("chunk"). No
+# kernel of this repo's is in them: what the compile shows is that
+# XLA's own forms fit beside 10.5 GB of weights, cache and states.
+_AOT_SPARSE_LINEAR = """
+import os
+os.environ.update(TPU_ACCELERATOR_TYPE="v5litepod-4",
+                  TPU_WORKER_HOSTNAMES="localhost", JAX_PLATFORMS="cpu")
+import sys
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    chip = SingleDeviceSharding(topologies.get_topology_desc(
+        topology_name="v5e:2x2", platform="tpu").devices[0])
+except Exception as e:
+    print("NO_TOPOLOGY", e); sys.exit(0)
+from mpi_cuda_cnn_tpu.models.generate import linear_attend
+from mpi_cuda_cnn_tpu.models.transformer import LinearAttn, SparseSelect
+from mpi_cuda_cnn_tpu.serve import paged_cache
+sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                             sharding=chip)
+b, kk = (32, 1) if sys.argv[1] == "tick" else (1, 512)
+pages, sel = 32 * 4096 + 1, SparseSelect()
+pools = {"k": sds((pages, 16, 2, 128), "bfloat16"),
+         "v": sds((pages, 16, 2, 128), "bfloat16"),
+         "kc": sds((pages, 1, 2, 128), "bfloat16")}
+sparse = jax.jit(lambda c, q, k, v, pos, ok, table:
+                 paged_cache.paged_update_attend(
+                     c, q, k, v, pos, ok, table, 16, select=sel)).lower(
+    pools, sds((b, kk, 32, 128), "bfloat16"), sds((b, kk, 2, 128), "bfloat16"),
+    sds((b, kk, 2, 128), "bfloat16"), sds((b, kk), "int32"),
+    sds((b, kk), "bool"), sds((b, 4096), "int32")).compile()
+text = sparse.as_text()
+assert ("while" in text) and "attn.sparse_select" in text
+row = sds((b, kk, 32, 128), "bfloat16")
+linear = jax.jit(lambda q, k, v, s, ok: linear_attend(
+    q, k, v, s, ok, LinearAttn().log_decay(32))).lower(
+    row, row, row, sds((b, 32, 128, 128), "float32"),
+    sds((b, kk), "bool")).compile()
+for name, c in (("sparse", sparse), ("linear", linear)):
+    temp = c.memory_analysis().temp_size_in_bytes
+    assert temp < 1 << 30, (name, temp)
+print("SPARSE_LINEAR_OK")
+"""
+
+
 def test_chip_smoke_without_chip_exits_nonzero_and_prints_no_result():
     assert_refused_without_chip(run_script("chip_smoke.py", timeout=120))
 
@@ -143,4 +192,22 @@ def test_expert_mlp_kernel_compiles_at_the_published_widths():
     if "NO_TOPOLOGY" in proc.stdout:
         pytest.skip(f"no compile-only TPU topology here: {proc.stdout}")
     assert proc.returncode == 0 and "EXPERT_MLP_OK" in proc.stdout, \
+        proc.stderr[-3000:]
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+def test_sparse_and_linear_mixers_compile_at_the_published_widths(program):
+    """The selection's scores of a 512-row chunk against 4,096
+    compressed keys, its top-64 of 1,024 blocks for 1,024 (row, head)
+    pairs, the masked read of every block to 65,536 rows and the
+    (32, 512, 512) decay products are shapes no CPU test reaches; only
+    the chip's compiler can say that each layer's temporaries stay
+    under 1 GB beside the cell's 10.5 GB (PR 34)."""
+    pytest.importorskip("libtpu")
+    proc = subprocess.run([sys.executable, "-c", _AOT_SPARSE_LINEAR, program],
+                          capture_output=True, text=True, timeout=300,
+                          cwd=REPO)
+    if "NO_TOPOLOGY" in proc.stdout:
+        pytest.skip(f"no compile-only TPU topology here: {proc.stdout}")
+    assert proc.returncode == 0 and "SPARSE_LINEAR_OK" in proc.stdout, \
         proc.stderr[-3000:]
