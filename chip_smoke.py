@@ -460,7 +460,7 @@ def phase_kernels(cfg, dev, rehearsal):
                 else np.full((b, 1), per * ps - kk))
         positions = jnp.asarray(pos0 + np.arange(kk), jnp.int32)
         valid = jnp.asarray(np.broadcast_to(live[:, None], (b, kk)))
-        chosen, _, nchosen = jax.jit(
+        chosen, _, _, nchosen = jax.jit(
             lambda q, kc: paged_cache.select_blocks(
                 q, kc, positions, valid, table, ps, sel))(q, kc)
         nb = chosen.shape[-1]

@@ -92,10 +92,12 @@ TICK_COUNTS = ("moe_assignments", "moe_experts_hit", "moe_load_max",
 WINDOW_COUNT = "kv_rows_read_window"
 # ... and last, where the model's softmax layers select the blocks they
 # read or it has linear layers: the compressed keys the selection
-# scored, the blocks it chose (K/V heads and layers summed), and the
-# recurrent states written (decoding slots x linear layers).
-SELECT_COUNTS = ("index_rows_read", "sparse_blocks_selected",
-                 "state_slots_updated")
+# scored (K/V heads and layers summed), the compressed keys its gathers
+# moved (layers summed: the K/V heads share a gathered row), the blocks
+# it chose (as the first), and the recurrent states written (decoding
+# slots x linear layers).
+SELECT_COUNTS = ("index_rows_read", "index_rows_gathered",
+                 "sparse_blocks_selected", "state_slots_updated")
 # The same vector as the prefill CHUNK's program returned it, on the
 # record of an iteration that ran a chunk of a model with expert
 # layers: its first two elements, the pairs the held experts computed
@@ -1131,8 +1133,9 @@ class PagedEngine:
             # mctpu: disable=MCT007
             counted = np.asarray(self._tick_counts).tolist()
             if self.model.select is not None or self._states is not None:
-                fields.update(zip(SELECT_COUNTS, counted[-3:]))
-                del counted[-3:]
+                last = len(SELECT_COUNTS)
+                fields.update(zip(SELECT_COUNTS, counted[-last:]))
+                del counted[-last:]
             if self._window is not None:
                 fields[WINDOW_COUNT] = counted.pop()
             names = (*TICK_COUNTS[:3],

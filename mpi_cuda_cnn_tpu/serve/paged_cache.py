@@ -99,6 +99,15 @@ f32 array a slot a layer whatever the depth, which a forward that
 starts a slot at position 0 starts from zero and which only valid rows
 update (models/generate.linear_attend).
 
+THE SELECTION COSTS WHAT IT SELECTS AMONG (PR 35). A tick scores the
+compressed keys its decoding slots hold, item by item through the
+same list and loop (`_live_scores`: a slot without a valid row has no
+item, a live one its complete keys in whole blocks), where it gathered
+every slot's whole table of them; a chunk's one slot, and any table
+that one step covers, still gathers the table. The k-th best block
+score is found by counting over the scores' bits, exactly, where a
+sort of every score found it (`_best_blocks`).
+
 What was measured on the v5e (PERF.md section 6, PR 29; one tick's
 reads at chat's shapes, 8 layers): whole-table gather 20.6 ms, bounded
 2.1 ms; the former Pallas kernel (one page a grid step over the whole
@@ -415,45 +424,146 @@ def _best_blocks(far, k: int):
     candidates (`far` >= 0; the others hold -1), all of them where
     they are fewer. No scatter: every block above the k-th best
     score, and of the blocks that EQUAL it the lowest-numbered that
-    fill the rest -- lax.top_k's own order of ties."""
-    kth = jax.lax.top_k(far, k)[0][..., -1:]
-    above, ties = far > kth, far == kth
+    fill the rest -- the order of ties `lax.top_k` has.
+
+    The k-th best score is found WITHOUT A SORT, and exactly. A
+    candidate's f32 bit pattern, read as an integer, is ordered as its
+    value is (a score is >= 0), so with `key` = that integer + 1, and 0
+    for a block that is no candidate, the k-th largest key is the
+    largest T that at least k keys reach: its 31 bits are settled from
+    the top, each by one compare and one count over the blocks. A whole
+    sort of 1,024 scores a (row, K/V head) pair, of which one value was
+    used, was 2.47 ms a layer of the benchmark's 512-row chunk
+    (PERF.md section 6, PR 35). Fewer candidates than k leave T at 0:
+    every candidate is above it."""
+    key = jnp.where(
+        far >= 0,
+        (jax.lax.bitcast_convert_type(far, jnp.int32) & 0x7FFFFFFF) + 1, 0)
+
+    def settle(i, kth):
+        higher = kth | jnp.left_shift(1, 30 - i)
+        return jnp.where(
+            jnp.sum(key >= higher[..., None], axis=-1) >= k, higher, kth)
+
+    kth = jax.lax.fori_loop(
+        0, 31, settle, jnp.zeros(far.shape[:-1], jnp.int32))[..., None]
+    above, ties = key > kth, key == kth
     room = k - jnp.sum(above, axis=-1, keepdims=True)
     return (far >= 0) & (above | (ties & (jnp.cumsum(ties, axis=-1) <= room)))
+
+
+def _live_scores(qg, kc, have, valid, block_table, step: tuple[int, int],
+                 scale):
+    """A tick's scores of the compressed keys that its slots HOLD: qg
+    (B, 1, Hkv, g, hd) against each slot's first `have` (B, 1)
+    compressed keys, nothing of a slot without a valid row and nothing
+    past a slot's depth. The items are the K/V read's (`_read_items`
+    over the pool `kc`, whose page holds `kc.shape[1]` keys; `step` =
+    (pages a block, blocks a step), `_index_step`'s): a loop
+    whose trip count is a value gathers a step's items' pages of `kc`,
+    scores them against their slot's query heads and leaves the scaled
+    scores at their place in one f32 buffer. An item past the list's
+    end repeats the last one: the same scores to the same place.
+    Returns (scores (B, Hkv, g, 1, compressed keys of the table),
+    NEG_INF where no item wrote; rows gathered: the items' -- the last
+    step's repeats are not counted -- int32)."""
+    b, _, hkv, g, hd = qg.shape
+    cpp = kc.shape[1]                             # compressed keys a page
+    ncomp = block_table.shape[1] * cpp
+    per_block, per_step = step
+    nblk = -(-block_table.shape[1] // per_block)
+    width = per_block * cpp                       # compressed keys a block
+    _, ends, steps, slot, _, item_pages, first_key = _read_items(
+        have - 1, valid & (have > 0), block_table, cpp, step, dead_blocks=0)
+
+    def take(i, buf):
+        at = jnp.minimum(i * per_step + jnp.arange(per_step), ends[-1] - 1)
+        sl, key0 = slot[at], first_key[at]
+        rows = kc[item_pages[at].reshape(-1)].reshape(
+            per_step, width, hkv, hd)
+        s = jnp.einsum("ihgd,ijhd->ihgj", qg[sl, 0], rows,
+                       preferred_element_type=jnp.float32) * scale
+        for n in range(per_step):
+            buf = jax.lax.dynamic_update_slice(
+                buf, s[n][None, :, :, None, :], (sl[n], 0, 0, 0, key0[n]))
+        return buf
+
+    buf = jax.lax.fori_loop(
+        0, steps, take,
+        jnp.full((b, hkv, g, 1, nblk * width), NEG_INF, jnp.float32))
+    return buf[..., :ncomp], (ends[-1] * width).astype(jnp.int32)
+
+
+# What a page gathered alone weighs at least, in bytes' time: XLA's
+# gather moves a 512 B page of compressed keys in the ~12 ns that 4 KB
+# of a K/V page take (40 GB/s against the bounded read's 310: PERF.md
+# section 6, PR 35).
+_GATHER_BYTES = 4 << 10
+
+
+def _index_step(slots: int, npages: int, kc) -> tuple[int, int]:
+    """read_step for the compressed pool `kc` (pages, keys a page, Hkv,
+    hd): a key weighs its bytes, or its share of what its page weighs
+    gathered alone. The benchmark's tick (32 slots x 4,096 pages of one
+    512 B key): blocks of 256 keys, 8 a step."""
+    cpp = kc.shape[1]
+    row = int(np.prod(kc.shape[2:])) * kc.dtype.itemsize
+    return read_step(slots, npages, cpp, max(row, _GATHER_BYTES // cpp))
 
 
 def select_blocks(q, kc, positions, valid, block_table, page_size: int,
                   sel: SparseSelect):
     """The blocks each query row reads, a K/V head (SparseSelect,
-    InfLLM-V2): q (B, kk, H, hd) against the slot's compressed keys
-    `kc` (gathered whole through the block table: a row a stride, 1/16
-    of the K rows' bytes). For the query at position t and K/V head g:
+    InfLLM-V2): q (B, kk, H, hd) against the slot's compressed keys in
+    the pool `kc` (a row a stride, 1/16 of the K rows' bytes). For the
+    query at position t and K/V head g:
     p_h = softmax over the compressed keys complete at t of q_h . kc_j
     / sqrt(hd), for each of g's query heads; P_j their sum; a block's
     score the largest P_j over the compressed keys that overlap it;
     chosen = the first `init_blocks` blocks, the blocks overlapping
     keys t - window + 1 .. t, and the `topk` best-scored of the other
-    blocks up to t's own (ties to the lower block: lax.top_k's order);
+    blocks up to t's own (ties to the lower block: _best_blocks);
     every block up to t's own while t + 1 < dense_len.
+
+    What is scored follows what is LIVE where the table is worth it:
+    one query row a slot (a tick) over a table that `read_step` gives
+    a loop scores, item by item, the compressed keys that slots with a
+    valid row hold (`_live_scores`) -- the gather of every slot's whole
+    table, 32 x 4,096 rows of 512 B where 7 slots decode 600-900 keys
+    deep, was 2.31 ms a layer of the benchmark's tick (PERF.md section
+    6, PR 35). Many rows of one slot (a chunk), and every table that
+    one step covers, gather the table through the block table and
+    score it whole. The softmax, the sums and the maxima that follow
+    are one code over either's scores.
     Returns (chosen (B, Hkv, kk, blocks of the table) bool, compressed
-    keys the valid rows scored, blocks the valid rows chose -- both
-    int32, K/V heads summed)."""
+    keys the valid rows scored, rows of `kc` the gathers moved, blocks
+    the valid rows chose -- three int32, K/V heads summed but for the
+    gathered rows, which they share)."""
     b, kk, h, hd = q.shape
     hkv = kc.shape[2]
     g = h // hkv
-    ncomp = block_table.shape[1] * kc.shape[1]
+    npages, cpp = block_table.shape[1], kc.shape[1]
+    ncomp = npages * cpp
     r, lead = sel.block // sel.stride, sel.kernel // sel.stride - 1
-    nb = -(-block_table.shape[1] * page_size // sel.block)
-    comp = kc[block_table].reshape(b, ncomp, hkv, hd)
+    nb = -(-npages * page_size // sel.block)
     have = sel.compressed(positions + 1)                       # (B, kk)
     there = (jnp.arange(ncomp)[None, None, :] < have[:, :, None])[:, None]
     qg = q.reshape(b, kk, hkv, g, hd)
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    few = max(1, min(g, _SCORE_BYTES // (4 * b * hkv * kk * ncomp)))
+    step = _index_step(b, npages, kc)
+    if kk == 1 and step[1] < b * -(-npages // step[0]):
+        few = g
+        raw, gathered = _live_scores(qg, kc, have, valid, block_table, step,
+                                     scale)
+    else:
+        few = max(1, min(g, _SCORE_BYTES // (4 * b * hkv * kk * ncomp)))
+        comp = kc[block_table].reshape(b, ncomp, hkv, hd)
+        raw, gathered = None, jnp.int32(b * ncomp)
     total = jnp.zeros((b, hkv, kk, ncomp), jnp.float32)
     for g0 in range(0, g, few):
-        s = jnp.einsum("bqhgd,bjhd->bhgqj", qg[:, :, :, g0:g0 + few], comp,
-                       preferred_element_type=jnp.float32) * scale
+        s = raw if raw is not None else jnp.einsum(
+            "bqhgd,bjhd->bhgqj", qg[:, :, :, g0:g0 + few], comp,
+            preferred_element_type=jnp.float32) * scale
         s = jnp.where(there[:, :, None], s, NEG_INF)
         total = total + jnp.sum(
             jnp.where(there[:, :, None], jax.nn.softmax(s, axis=-1), 0.0),
@@ -479,6 +589,7 @@ def select_blocks(q, kc, positions, valid, block_table, page_size: int,
     live = valid[:, None, :]
     return (chosen,
             (hkv * jnp.sum(jnp.where(valid, have, 0))).astype(jnp.int32),
+            gathered,
             jnp.sum(jnp.where(live[..., None], chosen, False)).astype(
                 jnp.int32))
 
@@ -532,7 +643,7 @@ def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
     tick) walks the union of its K/V heads' chosen blocks, one item a
     selection block; more rows walk every block to the depth, masked.
     Returns (o: (B, kk, H*hd) f32, new_c, cache rows the read touched)
-    and, under a selection, (compressed keys scored, blocks chosen).
+    and, under a selection, select_blocks' three counts.
     """
     b, kk = positions.shape
     hkv, hd = k.shape[2], k.shape[3]
@@ -574,7 +685,7 @@ def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
     with annotate("attn.sparse_select"):
         kc = _write_compressed(c["kc"], new_c["k"], positions, valid,
                                block_table, page_size, select)
-        chosen, scored, nchosen = select_blocks(
+        chosen, *counted = select_blocks(
             q, kc, positions, valid, block_table, page_size, select)
     npages = block_table.shape[1]
     walk = None
@@ -586,7 +697,7 @@ def paged_update_attend(c: dict, q, k, v, positions, valid, block_table,
         o, rows = bounded_read(
             q, new_c, positions, valid, block_table, chosen, walk,
             page_size=page_size, step=step, sel_block=select.block)
-    return o, {**new_c, "kc": kc}, rows, (scored, nchosen)
+    return o, {**new_c, "kc": kc}, rows, counted
 
 
 # What one (slot, block) item of the bounded read moves at least, and
@@ -1017,12 +1128,13 @@ def paged_forward(model: TransformerLM, params, toks, positions, valid,
     where the model has any (and zeros for a latent model without),
     then the cache rows the read touched, all layers together, then
     where the model has windowed layers the rows their reads touched,
-    then where it selects blocks or has linear layers three more: the
-    compressed keys the valid rows scored, the blocks they chose (K/V
-    heads and layers summed), and the states written (slots with a
-    valid row x linear layers). A model with linear layers brings
-    their `SlotStates` as one more element of the tuple, after its
-    PagedKVCache(s), and gets it back there."""
+    then where it selects blocks or has linear layers four more: the
+    compressed keys the valid rows scored, the compressed keys the
+    selection's gathers moved, the blocks the valid rows chose (layers
+    summed; K/V heads too, but for the gathers they share), and the
+    states written (slots with a valid row x linear layers). A model
+    with linear layers brings their `SlotStates` as one more element
+    of the tuple, after its PagedKVCache(s), and gets it back there."""
     every = cache if isinstance(cache, tuple) else (cache,)
     caches = tuple(c for c in every if not isinstance(c, SlotStates))
     store = next((c for c in every if isinstance(c, SlotStates)), None)
@@ -1039,10 +1151,11 @@ def paged_forward(model: TransformerLM, params, toks, positions, valid,
     group_of = {i: g for g, (_, layers) in enumerate(groups) for i in layers}
     new_pages: list[list[dict]] = [[] for _ in caches]
     new_states: list = []
-    rows_read = rows_window = scored = nchosen = updated = 0
+    rows_read = rows_window = updated = 0
+    selected = [0, 0, 0]    # compressed keys scored, gathered; blocks chosen
 
     def attend(i, q, k, v):
-        nonlocal rows_read, rows_window, scored, nchosen, updated
+        nonlocal rows_read, rows_window, selected, updated
         if model.mixer(i) == "linear":
             # This row's slot's state; from zero where its first valid
             # row sits at position 0 (a slot's new request, or the same
@@ -1062,10 +1175,10 @@ def paged_forward(model: TransformerLM, params, toks, positions, valid,
         g = group_of[i]
         c, pools = caches[g], caches[g].pages[len(new_pages[g])]
         if model.select is not None:
-            o, new_c, n, (ns, nc) = paged_update_attend(
+            o, new_c, n, counted = paged_update_attend(
                 pools, q, k, v, positions, valid, c.block_table,
                 c.page_size, select=model.select)
-            scored, nchosen = scored + ns, nchosen + nc
+            selected = [a + b for a, b in zip(selected, counted)]
         elif model.attn is not None:
             o, new_c, n = paged_update_attend_latent(
                 pools, q, k, positions, valid, c.block_table,
@@ -1098,7 +1211,7 @@ def paged_forward(model: TransformerLM, params, toks, positions, valid,
             [counts, jnp.reshape(jnp.asarray(rows_window, jnp.int32), (1,))])
     if model.select is not None or store is not None:
         counts = jnp.concatenate([counts, jnp.stack([
-            jnp.asarray(n, jnp.int32) for n in (scored, nchosen, updated)])])
+            jnp.asarray(n, jnp.int32) for n in (*selected, updated)])])
     new = tuple(
         dataclasses.replace(c, pages=p, counts=None if g else counts)
         for g, (c, p) in enumerate(zip(caches, new_pages)))

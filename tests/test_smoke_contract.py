@@ -128,6 +128,9 @@ sparse = jax.jit(lambda c, q, k, v, pos, ok, table:
     sds((b, kk), "bool"), sds((b, 4096), "int32")).compile()
 text = sparse.as_text()
 assert ("while" in text) and "attn.sparse_select" in text
+# PR 35: a tick gathers no slot's whole table of compressed keys (32 x
+# 4,096 rows), a chunk finds its 64th-best block score without a sort.
+assert "[131072,2,128]" not in text if kk == 1 else " sort(" not in text
 row = sds((b, kk, 32, 128), "bfloat16")
 linear = jax.jit(lambda q, k, v, s, ok: linear_attend(
     q, k, v, s, ok, LinearAttn().log_decay(32))).lower(

@@ -185,17 +185,20 @@ def test_prefill_then_paged_decode_matches_the_reference(served, read,
     # nothing else (a block chosen differently moves a logit by 1e-2).
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=0)
     assert float(jnp.std(want)) > 0.4
-    rows, scored, nchosen, updated = np.asarray(caches[0].counts).tolist()
+    rows, scored, gathered, nchosen, updated = np.asarray(
+        caches[0].counts).tolist()
     # The last tick, position 99: 49 compressed keys are complete (4
     # rows every 2 of 100), the one K/V head chooses block 0, blocks
     # 10..12 (keys 84..99) and 2 more, and 3 linear layers wrote the
     # one live slot's state.
     assert (scored, nchosen, updated) == (49, 6, 3)
     if read == "whole":
-        assert rows == 2 * MAX_LEN
+        assert (rows, gathered) == (2 * MAX_LEN, 2 * MAX_LEN // 2)
     else:   # the live slot's 6 blocks and the dead slot's 1: 7 items of
-        # 8 keys, 3 a step
-        assert rows == 3 * 3 * 8
+        # 8 keys, 3 a step; the selection gathered the live slot's 49
+        # compressed keys in blocks of 2 pages of 2: 13 items, the
+        # dead slot none
+        assert (rows, gathered) == (3 * 3 * 8, 13 * 4)
 
 
 # -- 2. the recurrence ---------------------------------------------------------
@@ -313,9 +316,10 @@ def test_the_blocks_chosen_are_the_references_at_every_depth(written):
     at = jnp.arange(t)
     want = FAM.reference.chosen_blocks(
         dm, q.reshape(t, hkv, h // hkv, -1), kc, at, 32 * PAGE // 8)
-    got, scored, nchosen = select_blocks(
+    got, scored, gathered, nchosen = select_blocks(
         q[None], pools["kc"], at[None], jnp.ones((1, t), bool), table, PAGE,
         sel)
+    assert int(gathered) == 32 * PAGE // 2      # many rows: the table, whole
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want))
     counts = np.asarray(want).sum(-1)                       # (hkv, t)
     # Under dense_len every block up to the query's; past it block 0,
@@ -328,6 +332,142 @@ def test_the_blocks_chosen_are_the_references_at_every_depth(written):
     assert int(nchosen) == counts.sum()
     assert int(scored) == hkv * sum(max(p + 1 - 4 + 2, 0) // 2
                                     for p in range(t))
+
+
+# A tick: slot -> its one row's position, None a slot with no valid row.
+TICK = (None, 0, 20, None, 109, 63, 2, None, 31, 32)
+
+
+def tick_of(written, pages: int, depths=TICK):
+    """`depths` as one tick over tables of `pages` pages: slot s's
+    query is row depths[s] of the fixture's, its compressed keys the
+    ones complete at that depth, on pages of its own; EVERY other row
+    of the pool -- scratch, the dead slots' pages, a live slot's pages
+    past its last complete key -- is NaN."""
+    sel, dm, q, k, _, _ = written
+    kc = np.asarray(FAM.reference.compressed_keys(dm, k))   # (J, hkv, hd)
+    cpp, b = PAGE // sel.stride, len(depths)
+    held = 110 // PAGE + 1
+    pool = np.full((1 + b * held, cpp) + kc.shape[1:], np.nan, np.float32)
+    table = np.zeros((b, pages), np.int32)
+    table[:, :held] = 1 + np.arange(b * held).reshape(b, held)
+    for s, d in enumerate(depths):
+        if d is not None:
+            have = int(sel.compressed(d + 1))
+            mine = pool[table[s, :held]].reshape(-1, *kc.shape[1:])
+            mine[:have] = kc[:have]
+            pool[table[s, :held]] = mine.reshape(held, cpp, *kc.shape[1:])
+    live = np.array([d is not None for d in depths])
+    at = np.array([d or 0 for d in depths])
+    return (q[at][:, None], jnp.asarray(pool), jnp.asarray(at[:, None]),
+            jnp.asarray(live[:, None]), jnp.asarray(table)), live, at
+
+
+@pytest.mark.parametrize("table", ["patched_step", "large"])
+def test_the_blocks_chosen_are_the_references_at_every_depth_of_a_tick(
+        written, table, monkeypatch):
+    """Ten slots at depths under and past dense_len (32), one at depth
+    0, three dead. The loop by a patched step over tables of 32 pages
+    (blocks of 2 pages = 4 compressed keys), and by the code's own
+    choice over tables of 16,384 pages (blocks of 512 keys, 8 a step)."""
+    sel, dm, q, k, _, _ = written
+    hkv, g = k.shape[1], q.shape[1] // k.shape[1]
+    pages = 32 if table == "patched_step" else 1 << 14
+    args, live, at = tick_of(written, pages)
+    whole = select_blocks(*args, PAGE, sel) if pages == 32 else None
+    if table == "patched_step":
+        monkeypatch.setattr(paged_cache, "read_step", lambda *a, **k: LOOP)
+    step = paged_cache._index_step(len(TICK), pages, args[1])
+    assert step[1] < len(TICK) * -(-pages // step[0])       # a loop's worth
+    block = step[0] * PAGE // sel.stride
+    got, scored, gathered, nchosen = jax.jit(
+        lambda *a: select_blocks(*a, PAGE, sel))(*args)
+    nb = pages * PAGE // sel.block
+    want = FAM.reference.chosen_blocks(
+        dm, q[at[live]].reshape(-1, hkv, g, q.shape[-1]),
+        FAM.reference.compressed_keys(dm, k), jnp.asarray(at[live]), nb)
+    np.testing.assert_array_equal(
+        np.asarray(got)[live, :, 0], np.transpose(np.asarray(want), (1, 0, 2)))
+    have = np.asarray(sel.compressed(at[live] + 1))
+    assert int(scored) == hkv * have.sum()
+    assert int(nchosen) == np.asarray(want).sum()
+    # The live slots' keys in whole blocks; a slot with no complete key
+    # (depths 0 and 2) and a dead slot: nothing.
+    assert int(gathered) == (-(-have // block) * block).sum()
+    assert int(gathered) < len(TICK) * pages * PAGE // sel.stride
+    if whole is not None:       # ... and what the table read whole gives
+        np.testing.assert_array_equal(np.asarray(got)[live],
+                                      np.asarray(whole[0])[live])
+        assert [int(x) for x in whole[1:]] == [
+            int(scored), len(TICK) * 32 * PAGE // sel.stride, int(nchosen)]
+    none = select_blocks(*args[:3], jnp.zeros_like(args[3]), args[4], PAGE,
+                         sel)
+    assert [int(x) for x in none[1:]] == [0, 0, 0]
+
+
+def test_the_selections_step_at_the_benchmarks_table():
+    """32 slots x 4,096 pages of one 512 B compressed key: a page
+    gathered alone weighs 4 KB, so a slot's keys are rounded up to 256
+    and a step takes 8 such blocks (PERF.md section 6, PR 35); a tiny
+    preset's table is one step, gathered whole."""
+    pool = jax.ShapeDtypeStruct((9, 1, 2, 128), jnp.bfloat16)
+    assert paged_cache._index_step(32, 4096, pool) == (256, 8)
+    tiny = jax.ShapeDtypeStruct((9, 2, 1, 16), jnp.float32)
+    per_block, per_step = paged_cache._index_step(3, 32, tiny)
+    assert per_step >= 3 * -(-32 // per_block)
+
+
+def best_by_sort(far, k):
+    """The form _best_blocks replaced: the k-th best by lax.top_k."""
+    kth = jax.lax.top_k(far, k)[0][..., -1:]
+    above, ties = far > kth, far == kth
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    return (far >= 0) & (above | (ties & (jnp.cumsum(ties, axis=-1) <= room)))
+
+
+def _random_far():
+    rng = np.random.default_rng(6)
+    far = rng.random((3, 2, 5, 64), np.float32).round(1)    # many ties
+    return np.where(rng.random(far.shape) < 0.4, -1.0, far)
+
+
+NORMAL = float(np.finfo(np.float32).tiny)   # the smallest normal f32
+HARD = {
+    "all_equal": ([[0.25] * 6], 3),
+    "all_zero": ([[0.0] * 6], 3),
+    "signed_zeros": ([[0.0, -0.0, 0.0, -0.0, -1.0, 0.5]], 3),
+    "no_candidate": ([[-1.0] * 6], 2),
+    "fewer_candidates_than_k": ([[-1.0, 0.3, -1.0, 0.0, -1.0, -1.0]], 4),
+    "exactly_k": ([[0.2, -1.0, 0.7, -1.0, 0.2, -1.0]], 3),
+    "k_is_every_block": ([[0.2, -1.0, 0.7, 0.0, 0.2, 0.1]], 6),
+    "ties_across_the_kth_place": ([[0.5, 0.3, 0.3, 0.1, 0.3, -1.0, 0.3],
+                                   [0.3, 0.3, 0.3, 0.3, 0.5, 0.5, 0.5]], 3),
+    "smallest_normals": ([[NORMAL, 2 * NORMAL, 0.0, 3 * NORMAL, NORMAL,
+                           -1.0]], 2),
+    "denormals": ([[1e-45, 3e-45, 0.0, 1e-40, 1e-45, -1.0]], 2),
+    "one_beside_minus_one": ([[1.0, -1.0, 1.0, -1.0, 0.5, 1.0]], 2),
+    "largest_and_sums_past_one": ([[3.4e38, 16.0, 1.0, 1.0000001, 2.0]], 3),
+    "random_k1": (_random_far(), 1),
+    "random_k7": (_random_far(), 7),
+    "random_k64": (_random_far(), 64),
+}
+
+
+@pytest.mark.parametrize("case", HARD)
+def test_the_kth_best_score_without_a_sort_is_the_sorts(case):
+    far, k = HARD[case]
+    far = np.asarray(far, np.float32)
+    got = np.asarray(jax.jit(paged_cache._best_blocks, static_argnums=1)(
+        jnp.asarray(far), k))
+    if case != "denormals":     # the float compares of the replaced form
+        # read a denormal as zero on this backend; the integer keys order it
+        np.testing.assert_array_equal(got, np.asarray(best_by_sort(
+            jnp.asarray(far), k)))
+    # ... and the definition, by hand: of the candidates the k first in
+    # descending order of score, ties to the lower block.
+    rank = np.argsort(np.argsort(-far, axis=-1, kind="stable"), axis=-1)
+    np.testing.assert_array_equal(got, (far >= 0) & (rank < k))
+    assert (got.sum(-1) == np.minimum(k, (far >= 0).sum(-1))).all()
 
 
 # -- 4. kept faults (benchmarks/tests/test_sparse_linear_tiny.py's) ------------
@@ -476,6 +616,8 @@ def test_the_tick_record_counts_the_selection_and_the_states(served, plain):
                        for p in at)
             assert low <= t["sparse_blocks_selected"] <= high
             assert t["kv_rows_read"] == 3 * MAX_LEN    # one table, whole
+            # ... and its compressed keys, one every 2 rows, as whole
+            assert t["index_rows_gathered"] == 3 * MAX_LEN // 2
         else:
             assert "state_slots_updated" not in t
         for _, rid in t["decoded"]:
@@ -517,8 +659,8 @@ def test_an_older_model_has_none_of_the_new_fields():
     ticks = []
     eng.run([Request(rid=0, prompt=np.arange(9, dtype=np.int32),
                      max_new_tokens=4)], tick_sink=ticks.append)
-    new = {"index_rows_read", "sparse_blocks_selected", "state_slots_updated",
-           "state_resets", "pages_held"}
+    new = {"index_rows_read", "index_rows_gathered", "sparse_blocks_selected",
+           "state_slots_updated", "state_resets", "pages_held"}
     assert ticks and not any(new & set(t) for t in ticks)
     assert [len(np.asarray(eng._tick_counts))] == [1]
 
