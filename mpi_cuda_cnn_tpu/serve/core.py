@@ -26,7 +26,8 @@ sink, the registry. What differs between drivers goes in as what it is:
   the step's `now`;
 - `spans`: an obs.trace.PhaseSpans the core tells each phase boundary
   (the driver opens the iteration in `schedule`; the compute tells the
-  same recorder its dispatch boundaries), or None;
+  same recorder its dispatch boundaries) and whose `fetch` reads the
+  completing chunk's token, or None;
 - `on_emit` / `on_prefill_done`: the fleet's fenced commit and its
   prefill->decode handoff.
 
@@ -296,9 +297,13 @@ class ServeCore:
                 sched.note_prefill_complete(slot)
                 # Sanctioned sync: int() ONLY on the completing chunk,
                 # where the token is emitted — mid-prompt chunks
-                # pipeline the device array untouched.
-                # mctpu: disable=MCT007
-                first = int(nxt)
+                # pipeline the device array untouched. A recorder
+                # times the copy apart from the wait (part `fetch`).
+                if spans is not None:
+                    first = spans.fetch(nxt, int)
+                else:
+                    # mctpu: disable=MCT007
+                    first = int(nxt)
                 now = clock()
                 if spans is not None:
                     spans.enter("emit", now)

@@ -60,7 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.transformer import TransformerLM
-from ..obs.trace import PhaseSpans
+from ..obs.trace import PhaseSpans, part
 from ..utils.donation import donate_jit
 from .core import EngineCompute, ServeCore, build_scheduler, observe_tick
 from .host_tier import TIER_SPILL_SITE
@@ -642,8 +642,9 @@ class PagedDraftProposer:
 
 def _closes_spans(run):
     """PagedEngine.run, with its phase recorder taken off the engine
-    and its open phase closed however the run ends — a failed pool
-    check, the idle RuntimeError and an injected fault leave mid-phase."""
+    and its open phase closed and its collection and compile hooks
+    taken down however the run ends — a failed pool check, the idle
+    RuntimeError and an injected fault leave mid-phase."""
 
     @functools.wraps(run)
     def wrapper(self, *args, **kwargs):
@@ -653,6 +654,7 @@ def _closes_spans(run):
             spans, self._spans = self._spans, None
             if spans is not None:
                 spans.close()
+                spans.unwatch()
 
     return wrapper
 
@@ -1022,15 +1024,19 @@ class PagedEngine:
         EngineCompute for run() and the fleet alike. Inside a run()
         that records spans, its recorder (already in `prefill.build`)
         is told where building the inputs ends and the dispatch
-        begins."""
+        begins, and times the block table and the puts as parts."""
         ctx = np.concatenate(
             [slot.req.prompt, np.asarray(slot.req.out, np.int32)]
         )
         n = min(self.prefill_chunk, slot.target - slot.cached)
         toks = np.zeros((1, self.prefill_chunk), np.int32)
         toks[0, :n] = ctx[slot.cached : slot.cached + n]
-        view = self._cache_view(self._tables(1, [(0, slot)]), [slot.idx])
-        inputs = (jnp.asarray(toks), jnp.int32(slot.cached), jnp.int32(n))
+        with part(self._spans, "tables"):
+            view = self._cache_view(self._tables(1, [(0, slot)]),
+                                    [slot.idx])
+        with part(self._spans, "puts"):
+            inputs = (jnp.asarray(toks), jnp.int32(slot.cached),
+                      jnp.int32(n))
         if self._spans is not None:
             self._spans.enter("prefill.dispatch")
         cache, nxt = self._prefill(view, self.params, *inputs)
@@ -1043,7 +1049,8 @@ class PagedEngine:
         (index by slot.idx); cached/emit bookkeeping is the caller's.
         Inside a run() that records spans, its recorder (already in
         `tick.build`) is told where the dispatch and the wait for the
-        tokens begin."""
+        tokens begin, and times its parts: the block tables, the puts,
+        the copy of the ready tokens (`fetch`)."""
         toks = np.zeros((self.slots,), np.int32)
         pos = np.zeros((self.slots,), np.int32)
         live = np.zeros((self.slots,), bool)
@@ -1051,10 +1058,13 @@ class PagedEngine:
             toks[s.idx] = s.req.out[-1]
             pos[s.idx] = s.cached
             live[s.idx] = True
-        view = self._cache_view(
-            self._tables(self.slots, [(s.idx, s) for s in dslots]),
-            range(self.slots))
-        inputs = (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(live))
+        with part(self._spans, "tables"):
+            view = self._cache_view(
+                self._tables(self.slots, [(s.idx, s) for s in dslots]),
+                range(self.slots))
+        with part(self._spans, "puts"):
+            inputs = (jnp.asarray(toks), jnp.asarray(pos),
+                      jnp.asarray(live))
         if self._spans is not None:
             self._spans.enter("tick.dispatch")
         cache, nxt = self._tick(view, self.params, *inputs)
@@ -1065,6 +1075,8 @@ class PagedEngine:
         del view, inputs
         if self._spans is not None:
             self._spans.enter("tick.wait")
+            # The same read, its copy timed apart from the wait.
+            return self._spans.fetch(nxt, np.asarray)
         # THE sanctioned sync: one host transfer per BATCHED tick
         # (every live slot's token in one array), not per sequence.
         # mctpu: disable=MCT007
@@ -1087,9 +1099,12 @@ class PagedEngine:
             toks[s.idx, :w] = u
             pos[s.idx] = s.cached
             valid[s.idx, :w] = True
-        view = self._cache_view(
-            self._tables(self.slots, [(s.idx, s) for s, _, _ in rounds]))
-        inputs = (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(valid))
+        with part(self._spans, "tables"):
+            view = self._cache_view(self._tables(
+                self.slots, [(s.idx, s) for s, _, _ in rounds]))
+        with part(self._spans, "puts"):
+            inputs = (jnp.asarray(toks), jnp.asarray(pos),
+                      jnp.asarray(valid))
         if self._spans is not None:
             self._spans.enter("tick.dispatch")
         cache, picks = self._spec(view, self.params, *inputs)
@@ -1097,10 +1112,12 @@ class PagedEngine:
         del view, inputs    # as in run_decode_tick: before the read
         if self._spans is not None:
             self._spans.enter("tick.wait")
-        # The sanctioned sync: one host transfer per BATCHED verify
-        # round (every slot's picks in one array), not per sequence.
-        # mctpu: disable=MCT007
-        picks = np.asarray(picks)
+            picks = self._spans.fetch(picks, np.asarray)
+        else:
+            # The sanctioned sync: one host transfer per BATCHED verify
+            # round (every slot's picks in one array), not per sequence.
+            # mctpu: disable=MCT007
+            picks = np.asarray(picks)
         return [picks[s.idx, :w] for s, _, w in rounds]
 
     def _tick_record(self, core: ServeCore, out, *, tick: int, now: float,
@@ -1206,7 +1223,14 @@ class PagedEngine:
         watchdog's window are two of the same stamps. `compiled` counts
         the forms the engine's programs have compiled since the run
         began (before its first dispatch), so a compile inside a run
-        shows as a step and a warmed run reads 0 throughout.
+        shows as a step and a warmed run reads 0 throughout. Beside
+        the spans (ISSUE 36): `parts`, the pieces of a phase timed
+        where they run — `*.build/tables` (block tables and cache
+        view), `*.build/puts` (the inputs' puts), `*.wait/fetch` (the
+        copy of tokens already ready; the wait for them is the rest of
+        the phase), `bookkeep/check` (the pool check); `gc_s`, the
+        iteration's seconds of garbage collection by generation; and
+        `stops`, its generation-2 collections and jax compiles.
 
         Prefix sharing + SLO policy (ISSUE 9): `prefix=True` puts a
         PrefixCache over the run's pool — a request whose prompt shares
@@ -1299,6 +1323,8 @@ class PagedEngine:
         core.clock = lambda: time_fn() - t0
         spans = core.spans = self._spans = (
             PhaseSpans("serve.iter", time_fn, t0) if want_ticks else None)
+        if spans is not None:
+            spans.watch("serve")
         compiled0 = self.compiled_programs() if want_ticks else 0
         while sched.unfinished:
             iter_t0 = time_fn() - t0
@@ -1381,7 +1407,8 @@ class PagedEngine:
             # the pool is the one to have.
             check_failed = None
             try:
-                sched.check()
+                with part(spans, "check"):
+                    sched.check()
             except AssertionError as e:
                 if not want_ticks:
                     raise
@@ -1410,6 +1437,7 @@ class PagedEngine:
             # (the profiler's start among them) lies between two
             # records' spans and inside none.
             tick_rec["spans"] = spans.end()
+            tick_rec.update(spans.extras())
             if tick_sink is not None:
                 tick_sink(tick_rec)
             if registry is not None:

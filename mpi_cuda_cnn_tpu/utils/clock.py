@@ -10,6 +10,9 @@ wall clock and has no business being injectable:
   markers (utils/logging.py's `# run 2026-...` line). Record "t"
   fields stay relative (the schema's cross-process contract); the
   marker is documentation for a human scanning an append-mode file.
+- `epoch_seconds()` — the clock jax stamps its compile events with
+  (`jax.monitoring` time spans): read once, beside the injected clock,
+  to map those stamps onto it (obs/trace.PhaseSpans.watch).
 
 Raw `time.time` / `time.monotonic` / `datetime.now` reads anywhere
 else are MCT002 findings: either the caller should take an injectable
@@ -24,7 +27,7 @@ from __future__ import annotations
 
 import time
 
-__all__ = ["utc_stamp"]
+__all__ = ["epoch_seconds", "utc_stamp"]
 
 
 def utc_stamp(fmt: str = "%Y-%m-%dT%H:%M:%SZ") -> str:
@@ -32,3 +35,10 @@ def utc_stamp(fmt: str = "%Y-%m-%dT%H:%M:%SZ") -> str:
     names only — never for measuring durations (inject a clock) and
     never into record "t" fields (those are relative by schema)."""
     return time.strftime(fmt, time.gmtime())
+
+
+def epoch_seconds() -> float:
+    """`time.time()`: what jax.monitoring stamps a compile's start and
+    end with. Only to map such stamps onto an injected clock — never
+    to measure a duration of this program's own."""
+    return time.time()

@@ -25,13 +25,18 @@ class Clock:
 
 class Spans:
     """obs.trace.PhaseSpans' `enter`, recorded: a boundary the loop
-    read the clock for carries its stamp, the others None."""
+    read the clock for carries its stamp, the others None. Its `fetch`
+    reads and records nothing."""
 
     def __init__(self, log):
         self.log = log
 
     def enter(self, phase, t=None):
         self.log.append(("enter", phase, t))
+
+    def fetch(self, x, read):
+        """The completing chunk's read: no device, nothing to wait for."""
+        return read(x)
 
 
 class Compute:

@@ -2,8 +2,11 @@
 time by what the host was doing, on the tick record and on the
 profiler's host track; the `compiled` counter; the idle branch's clock
 repair; and the benchmark's nine readers that turn the spans into
-per-layer metrics, each on hand-written records with known answers."""
+per-layer metrics, each on hand-written records with known answers.
+Since ISSUE 36 also a phase's parts, the run's stops (generation-2
+collections, jax compiles) and the six readers of them."""
 
+import gc
 import re
 import sys
 from pathlib import Path
@@ -116,18 +119,149 @@ def test_wait_on_a_completing_chunk_only(params):
 PARENT_CLOCK_READS = {"continuous": 44, "static": 55}
 
 
+def time_span_listeners():
+    from jax._src import monitoring
+
+    return monitoring.get_event_time_span_listeners()
+
+
 @pytest.mark.parametrize("mode", ["continuous", "static"])
 def test_bare_run_records_nothing_and_reads_the_clock_as_the_parent(
         params, mode, monkeypatch):
     def no_recorder(*a, **kw):
         raise AssertionError("a run nobody listens to built a recorder")
 
+    def no_sync(*a, **kw):
+        raise AssertionError("a run nobody listens to waited apart")
+
+    def no_listener(*a, **kw):
+        raise AssertionError("a run nobody listens to heard compiles")
+
     monkeypatch.setattr(engine_mod, "PhaseSpans", no_recorder)
-    clock = TickingClock()
+    monkeypatch.setattr(jax, "block_until_ready", no_sync)
+    monkeypatch.setattr(jax.monitoring, "register_event_time_span_listener",
+                        no_listener)
+    hooks = list(gc.callbacks), time_span_listeners()
+
+    class Watched(TickingClock):
+        def __call__(self):     # no collection hook at any read
+            assert gc.callbacks == hooks[0]
+            return super().__call__()
+
+    clock = Watched()
     res = make_engine(params).run(requests(), mode=mode, time_fn=clock,
                                   sleep_fn=clock.advance)
     assert all(r.status == "finished" for r in res.requests)
     assert clock.reads == PARENT_CLOCK_READS[mode]
+    assert (gc.callbacks, time_span_listeners()) == hooks
+
+
+def test_parts_lie_inside_their_phase_and_carry_its_name(params):
+    _, ticks = serve(make_engine(params), requests(), TickingClock())
+    seen = set()
+    for t in ticks:
+        assert {"parts", "gc_s", "stops"} <= set(t)
+        phases = {n: (a, b) for n, a, b in t["spans"]}
+        for name, a, b in t["parts"]:
+            phase, part = name.rsplit("/", 1)
+            lo, hi = phases[phase]
+            assert lo < a < b < hi, (name, phases[phase])
+            seen.add(name)
+        # one tables and one puts part a dispatch, a fetch a wait
+        names = [n for n, _, _ in t["parts"]]
+        for phase in ("prefill", "tick"):
+            ran = f"{phase}.dispatch" in phases
+            assert names.count(f"{phase}.build/tables") == ran
+            assert names.count(f"{phase}.build/puts") == ran
+            assert (names.count(f"{phase}.wait/fetch")
+                    == (f"{phase}.wait" in phases))
+        assert names.count("bookkeep/check") == 1
+    assert seen == {"prefill.build/tables", "prefill.build/puts",
+                    "prefill.wait/fetch", "tick.build/tables",
+                    "tick.build/puts", "tick.wait/fetch", "bookkeep/check"}
+
+
+def test_fetch_reads_a_ready_result_inside_the_wait(params, monkeypatch):
+    """With a recorder the wait for the tokens and their copy are two
+    things: every fetch part begins after a block_until_ready."""
+    order = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: (order.append("ready"), real(x))[1])
+    made = []
+
+    class Kept(PhaseSpans):
+        def part(self, name):
+            order.append(name)
+            return super().part(name)
+
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            made.append(self)
+
+    monkeypatch.setattr(engine_mod, "PhaseSpans", Kept)
+    serve(make_engine(params), requests(), TickingClock())
+    fetches = [i for i, n in enumerate(order) if n == "fetch"]
+    assert fetches and all(order[i - 1] == "ready" for i in fetches)
+    assert order.count("ready") == len(fetches)
+
+
+def test_a_forced_collection_is_a_gc_stop(params):
+    """A collection in the sink (between two records) lands on the
+    next record: its seconds in `gc_s[2]`, itself in `stops`."""
+    ticks = []
+
+    def sink(rec):
+        ticks.append(rec)
+        if rec["tick"] == 1:
+            gc.collect()
+
+    clock = TickingClock()
+    make_engine(params).run(requests(), time_fn=clock,
+                            sleep_fn=clock.advance, tick_sink=sink)
+    after = ticks[2]
+    gcs = [s for s in after["stops"] if s[0] == "gc"]
+    assert gcs and all(s[3] == 2 and s[1] < s[2] for s in gcs)
+    assert ticks[1]["spans"][-1][2] < gcs[0][1] < after["spans"][0][1]
+    assert after["gc_s"][2] == pytest.approx(
+        sum(b - a for _, a, b, _ in gcs), abs=1e-5)
+
+
+def test_a_new_jitted_function_is_a_compile_stop_by_name(params):
+    ticks = []
+
+    def fresh_probe_fn(x):
+        return x * 3 + 1
+
+    def sink(rec):
+        ticks.append(rec)
+        if rec["tick"] == 1:
+            jax.jit(fresh_probe_fn)(np.arange(5))
+
+    make_engine(params).run(requests(), tick_sink=sink)
+    stops = [s for s in ticks[2]["stops"] if s[0] == "compile"]
+    named = {s[3] for s in stops if "fresh_probe_fn" in s[3]}
+    assert {n.split(":")[0] for n in named} == {
+        "jaxpr_trace", "jaxpr_to_mlir_module", "backend_compile"}
+    # on the run's clock: after the record before it, before its own end
+    for _, a, b, _ in stops:
+        assert ticks[1]["spans"][-1][2] - 0.05 < a <= b < \
+            ticks[2]["spans"][-1][2]
+
+
+def test_a_recorded_run_takes_its_hooks_down(params, monkeypatch):
+    hooks = list(gc.callbacks), time_span_listeners()
+    serve(make_engine(params), requests(), TickingClock())
+    assert (gc.callbacks, time_span_listeners()) == hooks
+    engine = make_engine(params)
+
+    def broken_tick(dslots):
+        raise RuntimeError("injected")
+
+    monkeypatch.setattr(engine, "run_decode_tick", broken_tick)
+    with pytest.raises(RuntimeError, match="injected"):
+        serve(engine, requests(), TickingClock())
+    assert (gc.callbacks, time_span_listeners()) == hooks
 
 
 def test_same_tokens_and_state_crc_with_and_without_a_sink(params):
@@ -259,7 +393,9 @@ def test_speculative_round_has_the_ticks_three_spans(params):
 def test_phases_reach_the_profilers_host_track(tmp_path):
     """Each span lies under a TraceAnnotation `<prefix>/<phase>` whose
     argument is the iteration's index — the same span, on the clock
-    the device trace is on."""
+    the device trace is on; a part under `<prefix>/<phase>/<part>`
+    nested inside its phase's, a generation-2 collection under
+    `serve.gc/2`."""
     from jax.profiler import ProfileData
 
     opts = jax.profiler.ProfileOptions()
@@ -267,18 +403,41 @@ def test_phases_reach_the_profilers_host_track(tmp_path):
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
         rec = PhaseSpans("serve.iter")
+        rec.watch("serve")
         rec.begin(7, "schedule")
+        with rec.part("check"):
+            gc.collect()
         rec.enter("tick.wait")
+        got = rec.fetch(jax.numpy.arange(3), np.asarray)
         spans = rec.end()
+        extras = rec.extras()
+        rec.unwatch()
     finally:
         jax.profiler.stop_trace()
     assert [n for n, _, _ in spans] == ["schedule", "tick.wait"]
     assert spans[0][2] == spans[1][1]
+    assert list(got) == [0, 1, 2]
+    assert [n for n, _, _ in extras["parts"]] == ["schedule/check",
+                                                  "tick.wait/fetch"]
+    assert [s[0] for s in extras["stops"] if s[0] == "gc"] == ["gc"]
     data = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
-    seen = {e.name: dict(e.stats) for p in data.planes for line in p.lines
-            for e in line.events if e.name.startswith("serve.iter/")}
-    assert seen == {"serve.iter/schedule": {"tick": 7},
-                    "serve.iter/tick.wait": {"tick": 7}}
+    events = {e.name: e for p in data.planes for line in p.lines
+              for e in line.events if e.name.startswith("serve.")}
+    assert {n: dict(e.stats) for n, e in events.items()} == {
+        "serve.iter/schedule": {"tick": 7},
+        "serve.iter/tick.wait": {"tick": 7},
+        "serve.iter/schedule/check": {"tick": 7},
+        "serve.iter/tick.wait/fetch": {"tick": 7},
+        "serve.gc/2": {}}
+
+    def inside(inner, outer):
+        i, o = events[inner], events[outer]
+        return (o.start_ns <= i.start_ns and i.start_ns + i.duration_ns
+                <= o.start_ns + o.duration_ns)
+
+    assert inside("serve.iter/schedule/check", "serve.iter/schedule")
+    assert inside("serve.gc/2", "serve.iter/schedule/check")
+    assert inside("serve.iter/tick.wait/fetch", "serve.iter/tick.wait")
 
 
 # -- the benchmark's readers ----------------------------------------------
@@ -289,29 +448,49 @@ def reader(name: str):
 
 
 def iteration(t0: float, *, tick_ms: float = 20.0, host_ms: float = 1.0,
-              chunk: bool = True, compiled: int = 0) -> dict:
+              chunk: bool = True, decode: bool = True, compiled: int = 0,
+              fetch_ms: float = 0.5, check_ms: float = 0.05,
+              gc_s=(0.0, 0.0, 0.0), stops=()) -> dict:
     """One hand-written record starting at t0: schedule 0.2 ms, build
     `host_ms` in all, dispatches 0.1 ms each, a wait of `tick_ms`,
-    emit + bookkeep 0.3 ms, record 0.1 ms."""
-    at, spans = t0, []
+    emit + bookkeep 0.3 ms, record 0.1 ms. Parts: a quarter of each
+    build its tables and a quarter its puts, the wait's last `fetch_ms`,
+    bookkeep's first `check_ms`; `stops` as (kind, start, end, what)
+    with start and end in ms after t0."""
+    at, spans, parts = t0, [], []
 
     def add(name, ms):
         nonlocal at
         spans.append([name, round(at, 6), round(at + ms / 1e3, 6)])
         at += ms / 1e3
 
+    def part(name, since_ms, ms):
+        a = spans[-1][1] + since_ms / 1e3
+        parts.append([f"{spans[-1][0]}/{name}", round(a, 6),
+                      round(a + ms / 1e3, 6)])
+
     add("schedule", 0.2)
     add("prefill.build", host_ms / 2)
+    part("tables", 0.0, host_ms / 8)
+    part("puts", host_ms / 8, host_ms / 8)
     if chunk:
         add("prefill.dispatch", 0.1)
     add("grow", 0.1)
-    add("tick.build", host_ms / 2)
-    add("tick.dispatch", 0.1)
-    add("tick.wait", tick_ms)
-    add("emit", 0.1)
+    if decode:
+        add("tick.build", host_ms / 2)
+        part("tables", 0.0, host_ms / 8)
+        part("puts", host_ms / 8, host_ms / 8)
+        add("tick.dispatch", 0.1)
+        add("tick.wait", tick_ms)
+        part("fetch", tick_ms - fetch_ms, fetch_ms)
+        add("emit", 0.1)
     add("bookkeep", 0.2)
+    part("check", 0.0, check_ms)
     add("record", 0.1)
-    return {"spans": spans, "compiled": compiled, "now": spans[-1][1]}
+    return {"spans": spans, "compiled": compiled, "now": spans[-1][1],
+            "parts": parts, "gc_s": list(gc_s),
+            "stops": [[k, round(t0 + a / 1e3, 6), round(t0 + b / 1e3, 6), w]
+                      for k, a, b, w in stops]}
 
 
 def window(special=None, first_tick: int = 0) -> list[dict]:
@@ -385,15 +564,84 @@ READINGS = [
             first_tick=40), {}, 1.0),
 ]
 
+# ISSUE 36's readers of the parts and stops.
+PART_READINGS = [
+    ("host_fetch_ms", QUIET, {}, 0.5),
+    # six iterations that read no tokens (a mid-prompt chunk alone):
+    # not among those the median is over
+    ("host_fetch_ms", window({i: {"decode": False} for i in range(6)}),
+     {}, 0.5),
+    ("host_fetch_ms", window({i: {"fetch_ms": 2.5} for i in range(6)}),
+     {}, 2.5),
+    ("host_check_ms", QUIET, {}, 0.05),
+    ("host_check_ms", window({i: {"check_ms": 0.15} for i in range(3)}),
+     {}, 0.05),
+    # two builds of host_ms / 8 each: 2 x 0.125
+    ("host_tables_ms", QUIET, {}, 0.25),
+    ("host_tables_ms", window({i: {"decode": False} for i in range(6)}),
+     {}, 0.125),
+    ("host_puts_ms", QUIET, {}, 0.25),
+    ("host_puts_ms", window({i: {"host_ms": 4.0} for i in range(6)}),
+     {}, 1.0),
+    ("host_gc_ms", QUIET, {}, 0.0),
+    # every generation, every record: 1 + 2 + 150 ms
+    ("host_gc_ms", window({3: {"gc_s": (0.001, 0.002, 0.0)},
+                           7: {"gc_s": (0.0, 0.0, 0.15)}}), {}, 153.0),
+    ("compile_ms_in_window", QUIET, {}, 0.0),
+    # a trace, one nested in it (counted once), a compile after it:
+    # 10 + 20 ms; a collection is no compile
+    ("compile_ms_in_window",
+     window({5: {"stops": [("compile", 1, 11, "jaxpr_trace:f"),
+                           ("compile", 3, 5, "jaxpr_trace:g"),
+                           ("compile", 11, 31, "backend_compile:jit_f"),
+                           ("gc", 40, 90, 2)]}}), {}, 30.0),
+    # one that began before the window's first span counts from there
+    ("compile_ms_in_window",
+     window({0: {"stops": [("compile", -5, 2, "backend_compile:jit_h")]}}),
+     {}, 2.0),
+]
 
-@pytest.mark.parametrize("name,ticks,extra,want", READINGS,
-                         ids=[f"{r[0]}-{i}" for i, r in enumerate(READINGS)])
+
+@pytest.mark.parametrize(
+    "name,ticks,extra,want", READINGS + PART_READINGS,
+    ids=[f"{r[0]}-{i}" for i, r in enumerate(READINGS + PART_READINGS)])
 def test_reader_on_hand_written_records(name, ticks, extra, want):
     got = reader(name).read(ctx_of(ticks, **extra))
     assert got == pytest.approx(want, abs=1e-6)
 
 
-@pytest.mark.parametrize("name", sorted({r[0] for r in READINGS}))
+NEW_FIELDS = ("parts", "gc_s", "stops")
+
+
+@pytest.mark.parametrize(
+    "name,ticks,extra,want", READINGS,
+    ids=[f"{r[0]}-{i}" for i, r in enumerate(READINGS)])
+def test_span_readers_read_the_same_without_the_parts(name, ticks, extra,
+                                                      want):
+    """The nine readers of the spans take no notice of the new fields:
+    the same value from the records of an engine from before them."""
+    old = [{k: v for k, v in t.items() if k not in NEW_FIELDS}
+           for t in ticks]
+    assert reader(name).read(ctx_of(old, **extra)) == pytest.approx(
+        want, abs=1e-6)
+
+
+@pytest.mark.parametrize("name", sorted({r[0] for r in PART_READINGS}))
+def test_part_reader_reads_nothing_from_records_without_the_fields(name):
+    """The parent commit under this benchmark: spans and no parts,
+    `gc_s` or `stops` — None, and no exception."""
+    old = [{k: v for k, v in t.items() if k not in NEW_FIELDS}
+           for t in QUIET]
+    assert reader(name).read(ctx_of(old)) is None
+    # one record without them (a mixed stream) is as good as none
+    mixed = [dict(t) for t in QUIET]
+    for k in NEW_FIELDS:
+        del mixed[3][k]
+    assert reader(name).read(ctx_of(mixed)) is None
+
+
+@pytest.mark.parametrize(
+    "name", sorted({r[0] for r in READINGS + PART_READINGS}))
 def test_reader_reads_nothing_from_records_without_spans(name):
     """An engine from before the spans (the parent commit under this
     benchmark): None, never a made-up value, and no exception."""
