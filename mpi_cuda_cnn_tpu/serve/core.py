@@ -35,7 +35,9 @@ Two rules stay each driver's own, and are written there: WHEN to sweep
 (`step`'s `sweep` argument — the engine every iteration, because anyone
 holding a Request may cancel it; the fleet only when a deadline exists
 or a cancel was flagged, because the O(queue) scan would dominate a
-storm) and how often to `sched.check()` the pool.
+storm) and how often to check the pool, and in which form (the engine
+`sched.check_changed()` every iteration and `sched.check()` at the
+run's end; the fleet `sched.check()` every `check_every` steps).
 """
 
 from __future__ import annotations

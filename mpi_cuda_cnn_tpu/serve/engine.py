@@ -46,7 +46,8 @@ watchdog breach lands in `ServeResult.events` (obs `fault` records —
 serve/bench.py writes them to the JSONL sink). Every submitted request
 leaves with a terminal status; aborted slots return their pages through
 the ownership-checked PagePool.free, and the pool invariant is checked
-every iteration.
+every iteration (over what changed since the last check) and whole at
+the run's end.
 """
 
 from __future__ import annotations
@@ -112,8 +113,8 @@ TICK_LAYOUT = (
     "tick", "now", "mode", "queue", "running", "prefilling", "free_pages",
     "backlog", "arrived", "admitted", "prefill", "decoded", "finished",
     "aborted", "preempted", "blocked", "preempted_for", "terminal",
-    "state_crc", "compiled", *TICK_COUNTS, WINDOW_COUNT, *CHUNK_COUNTS,
-    *SELECT_COUNTS, "pages_held", "state_resets",
+    "state_crc", "compiled", "checked", *TICK_COUNTS, WINDOW_COUNT,
+    *CHUNK_COUNTS, *SELECT_COUNTS, "pages_held", "state_resets",
     "window_pages_freed", "squeezed", "spec",
     "prefix_hits", "prefix", "prefix_readmits",
 )
@@ -1142,6 +1143,9 @@ class PagedEngine:
             "terminal": [terminal_fields(r)
                          for r in out.new_fin + out.new_drop],
             "compiled": compiled,
+            # What the iteration's pool check verified: [pages, slots],
+            # both pools summed (Scheduler.check_changed).
+            "checked": list(sched.checked),
         })
         if out.decoded and self._tick_counts is not None:
             # What this tick's forward counted, all layers together
@@ -1228,7 +1232,8 @@ class PagedEngine:
         where they run — `*.build/tables` (block tables and cache
         view), `*.build/puts` (the inputs' puts), `*.wait/fetch` (the
         copy of tokens already ready; the wait for them is the rest of
-        the phase), `bookkeep/check` (the pool check); `gc_s`, the
+        the phase), `bookkeep/check` (the pool check, whose pages and
+        slots verified are the record's `checked`); `gc_s`, the
         iteration's seconds of garbage collection by generation; and
         `stops`, its generation-2 collections and jax compiles.
 
@@ -1400,15 +1405,16 @@ class PagedEngine:
             core.settle(out)
             state_chain = zlib.crc32(out.state_crc.to_bytes(4, "little"),
                                      state_chain)
-            # The engine checks the pool every iteration. The check is
-            # timed in `bookkeep`, but where records were asked for its
-            # failure is raised only once this iteration's record has
-            # reached the sink: the record of the iteration that broke
-            # the pool is the one to have.
+            # The engine checks the pool every iteration, over what
+            # changed since the last check (the full scan is the run's
+            # end's). The check is timed in `bookkeep`, but where
+            # records were asked for its failure is raised only once
+            # this iteration's record has reached the sink: the record
+            # of the iteration that broke the pool is the one to have.
             check_failed = None
             try:
                 with part(spans, "check"):
-                    sched.check()
+                    sched.check_changed()
             except AssertionError as e:
                 if not want_ticks:
                     raise

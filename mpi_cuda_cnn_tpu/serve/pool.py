@@ -36,6 +36,11 @@ class PagePool:
     refcount conservation (every reader entry sits on an owned,
     read-only page, no duplicate grants) and that no writable page is
     ever shared — the copy-on-write safety story in one invariant.
+
+    `check_changed()` proves the same invariant at the cost of what
+    changed since the last check of either form: every mutator enters
+    the pages it changes in a journal (page -> its owner at the last
+    check, None where it was free), which the next check drains.
     """
 
     def __init__(self, num_pages: int):
@@ -45,9 +50,11 @@ class PagePool:
         # Pop from the end -> pages issue in ascending order
         # (deterministic layouts for tests and debugging).
         self._free = list(range(num_pages - 1, 0, -1))
+        self._free_set = set(self._free)      # the same pages, for `in`
         self._owner: dict[int, object] = {}
         self._readers: dict[int, list] = {}   # page -> live reader refs
         self._ro: set[int] = set()            # read-only (shareable) pages
+        self._touched: dict[int, object] = {}  # the journal, undrained
 
     @property
     def usable(self) -> int:
@@ -68,8 +75,10 @@ class PagePool:
         if n > len(self._free):
             return None
         pages = [self._free.pop() for _ in range(n)]
+        self._free_set.difference_update(pages)
         for p in pages:
             self._owner[p] = owner
+            self._touched.setdefault(p, None)
         return pages
 
     def free(self, pages: list[int], owner) -> None:
@@ -88,9 +97,10 @@ class PagePool:
                     f"reader(s) — refusing to free a shared page"
                 )
         for p in pages:
-            del self._owner[p]
+            self._touched.setdefault(p, self._owner.pop(p))
             self._ro.discard(p)
             self._free.append(p)
+            self._free_set.add(p)
 
     # -- refcounted sharing (ISSUE 9) -----------------------------------
 
@@ -105,6 +115,7 @@ class PagePool:
                 f"page {page} is owned by {got}, not {old_owner} — "
                 "refusing the ownership transfer"
             )
+        self._touched.setdefault(page, got)
         self._owner[page] = new_owner
         if readonly:
             self._ro.add(page)
@@ -122,6 +133,7 @@ class PagePool:
                 f"page {page} is owned by {got}, not {owner} — "
                 "refusing to freeze it"
             )
+        self._touched.setdefault(page, got)
         self._ro.add(page)
 
     def share(self, page: int, reader) -> None:
@@ -140,6 +152,7 @@ class PagePool:
             raise RuntimeError(
                 f"reader {reader} already holds a reference on page {page}"
             )
+        self._touched.setdefault(page, self._owner[page])
         rl.append(reader)
 
     def unshare(self, page: int, reader) -> None:
@@ -150,6 +163,7 @@ class PagePool:
             raise RuntimeError(
                 f"reader {reader} holds no reference on page {page}"
             )
+        self._touched.setdefault(page, self._owner.get(page))
         rl.remove(reader)
         if not rl:
             del self._readers[page]
@@ -160,31 +174,62 @@ class PagePool:
     def is_shared(self, page: int) -> bool:
         return page in self._ro
 
-    def check(self) -> None:
-        """The no-leak / no-double-book invariant, extended (ISSUE 9)
-        with refcount conservation and the no-writable-shared-page
-        guarantee."""
+    def _check_counts(self) -> None:
+        """What both forms of the check verify whole: the counts, and
+        the scratch page kept out of circulation."""
         assert len(self._free) + len(self._owner) == self.usable, (
             f"page leak: {len(self._free)} free + {len(self._owner)} "
             f"owned != {self.usable} usable"
         )
-        assert not (set(self._free) & set(self._owner)), "page double-booked"
-        assert 0 not in self._owner and 0 not in self._free, (
+        assert 0 not in self._owner and 0 not in self._free_set, (
             "scratch page 0 entered circulation"
         )
+
+    def _check_readers(self, p: int, rl: list) -> None:
         # Refcount conservation: every reader entry sits on an owned
         # page, lists are non-empty (emptied lists are deleted), and no
         # reader holds two references on one page.
+        assert p in self._owner, f"readers on unowned page {p}"
+        assert rl, f"empty reader list retained for page {p}"
+        assert len(rl) == len({id(r) if isinstance(r, (list, dict))
+                               else r for r in rl}), (
+            f"duplicate reader reference on page {p}"
+        )
+
+    def check(self) -> None:
+        """The no-leak / no-double-book invariant, extended with
+        refcount conservation and the no-writable-shared-page
+        guarantee; over every page. Drains the journal."""
+        self._check_counts()
+        assert set(self._free) == self._free_set, "free list and set differ"
+        assert not (self._free_set & set(self._owner)), "page double-booked"
         for p, rl in self._readers.items():
-            assert p in self._owner, f"readers on unowned page {p}"
-            assert rl, f"empty reader list retained for page {p}"
-            assert len(rl) == len({id(r) if isinstance(r, (list, dict))
-                                   else r for r in rl}), (
-                f"duplicate reader reference on page {p}"
-            )
+            self._check_readers(p, rl)
         # No writable page is ever shared; read-only pages are owned.
         assert set(self._readers) <= self._ro, "writable page shared"
         assert self._ro <= set(self._owner), "read-only page not owned"
+        self._touched = {}
+
+    def check_changed(self) -> dict[int, object]:
+        """`check` over the pages changed since the last check, and the
+        counts: a page the mutators did not touch is as the last check
+        proved it. Returns the drained journal (page -> its owner at the
+        last check), for the slots' checks above the pool."""
+        self._check_counts()
+        assert len(self._free_set) == len(self._free), "page double-booked"
+        for p in self._touched:
+            owned = p in self._owner
+            assert owned != (p in self._free_set), (
+                f"page {p} double-booked" if owned
+                else f"page leak: page {p} neither free nor owned")
+            rl = self._readers.get(p)
+            if rl is not None:
+                self._check_readers(p, rl)
+                assert p in self._ro, "writable page shared"
+            if p in self._ro:
+                assert owned, "read-only page not owned"
+        touched, self._touched = self._touched, {}
+        return touched
 
 
 def window_pages_per_slot(window: int, chunk: int, page_size: int,
@@ -227,6 +272,9 @@ class WindowGroup:
                                               max_len)
         self.pool = PagePool(slots * self.per_slot + 1)
         self._freed = 0     # pages given back behind a window, undrained
+        # The pages each slot's table held when a check last walked it:
+        # their sum is what the tables hold, as the pool must have issued.
+        self._held = [0] * slots
 
     def advance(self, slot, rows: int) -> None:
         """Before `slot` writes `rows` rows from `slot.cached`: give
@@ -261,24 +309,46 @@ class WindowGroup:
             self.pool.free(pages, slot.req.rid)
         slot.wpages, slot.wfirst = [], 0
 
+    def check_slot(self, s) -> None:
+        """That `s`'s table and the pool agree page for page, within the
+        bound a slot; counts the pages it holds toward `check_held`."""
+        if s.free:
+            assert not s.wpages, "a free slot holds windowed pages"
+            self._held[s.idx] = 0
+            return
+        owner_of = self.pool._owner
+        mine = [p for p in s.wpages[s.wfirst:] if p]
+        assert not any(s.wpages[:s.wfirst]), "a page behind wfirst"
+        assert all(owner_of.get(p) == s.req.rid for p in mine), (
+            f"slot {s.idx}'s windowed table names a page it does "
+            "not own")
+        assert len(mine) <= self.per_slot, (
+            f"slot {s.idx} holds {len(mine)} windowed pages, over "
+            f"the bound {self.per_slot}")
+        self._held[s.idx] = len(mine)
+
+    def check_held(self) -> None:
+        """The tables hold what the pool has issued, by the counts of
+        each slot's last walk (`check_slot`)."""
+        held = sum(self._held)
+        assert held == len(self.pool._owner), (
+            f"the slots' windowed tables hold {held} pages, the pool "
+            f"has issued {len(self.pool._owner)}")
+
+    def check_changed(self) -> tuple[int, set]:
+        """The pool's `check_changed`. Returns the pages verified and
+        the owners, before and after, of those that changed: the slots
+        whose tables the caller walks again (`check_slot`), beside those
+        that changed, before `check_held`."""
+        touched = self.pool.check_changed()
+        owner_of = self.pool._owner
+        return len(touched), {*touched.values(),
+                              *(owner_of.get(p) for p in touched)}
+
     def check(self, slots) -> None:
         """The pool's invariant, and that the slots' tables and the
         pool agree page for page, within the bound a slot."""
         self.pool.check()
-        owner_of, held = self.pool._owner, 0
         for s in slots:
-            if s.free:
-                assert not s.wpages, "a free slot holds windowed pages"
-                continue
-            mine = [p for p in s.wpages[s.wfirst:] if p]
-            assert not any(s.wpages[:s.wfirst]), "a page behind wfirst"
-            assert all(owner_of.get(p) == s.req.rid for p in mine), (
-                f"slot {s.idx}'s windowed table names a page it does "
-                "not own")
-            assert len(mine) <= self.per_slot, (
-                f"slot {s.idx} holds {len(mine)} windowed pages, over "
-                f"the bound {self.per_slot}")
-            held += len(mine)
-        assert held == len(owner_of), (
-            f"the slots' windowed tables hold {held} pages, the pool "
-            f"has issued {len(owner_of)}")
+            self.check_slot(s)
+        self.check_held()

@@ -385,6 +385,12 @@ class _SchedulerBase:
         # caller (the fleet's per-replica step loop) skip the O(queue)
         # sweep() scan on ticks where nothing can possibly expire.
         self.has_deadlines = False
+        # Each slot as the last check saw it (`_snapshot`), which the
+        # per-iteration check compares with; and [pages verified, slots
+        # walked] by the last check of either form (the tick record's
+        # `checked`).
+        self._seen = [self._snapshot(s) for s in self.slots]
+        self.checked = (0, 0)
 
     def submit(self, requests: Iterable[Request]) -> None:
         """Enqueue requests (FCFS by arrival). Structurally impossible
@@ -645,34 +651,83 @@ class _SchedulerBase:
         slot.target = cached
         return slot
 
+    def _check_slot(self, s: Slot) -> None:
+        """The slot-level sharing invariants: every shared page a slot
+        references sits strictly below its written extent (no
+        writable-shared page from the block table's point of view), and
+        any pending COW destination is private."""
+        if s.free:
+            assert not s.refs and s.cow is None
+            return
+        refset = set(s.refs)
+        assert len(refset) == len(s.refs), "duplicate slot ref"
+        ps = self.page_size
+        for i, p in enumerate(s.pages if refset else ()):
+            if p in refset:
+                assert self.pool.is_shared(p), (
+                    f"slot ref page {p} is not a shared pool page"
+                )
+                assert (i + 1) * ps <= s.cached, (
+                    f"shared page {p} extends into slot {s.idx}'s "
+                    "writable region"
+                )
+        if s.cow is not None:
+            assert s.cow[1] in s.pages and s.cow[1] not in refset, (
+                "COW destination is not a private slot page"
+            )
+
+    @staticmethod
+    def _snapshot(s: Slot) -> tuple:
+        """What the slot's invariants read of it, in O(1): its request,
+        the objects and lengths of its tables (a table changed in place
+        changes its length, or `wfirst`), its COW pair; `cached`, whose
+        fall alone can break them; the objects themselves, held so that
+        no id is reused while the snapshot lives."""
+        return ((id(s.req), id(s.pages), len(s.pages), id(s.refs),
+                 len(s.refs), s.cow, id(s.wpages), len(s.wpages), s.wfirst),
+                s.cached, (s.req, s.pages, s.refs, s.wpages))
+
     def check(self) -> None:
-        """Pool invariant + the slot-level sharing invariants: every
-        shared page a slot references sits strictly below its written
-        extent (no writable-shared page from the block table's point
-        of view), and any pending COW destination is private."""
+        """Pool invariant + the slot-level sharing invariants
+        (`_check_slot`), over every page and every slot."""
         self.pool.check()
         if self.window is not None:
             self.window.check(self.slots)
-        ps = self.page_size
         for s in self.slots:
-            if s.free:
-                assert not s.refs and s.cow is None
-                continue
-            refset = set(s.refs)
-            assert len(refset) == len(s.refs), "duplicate slot ref"
-            for i, p in enumerate(s.pages):
-                if p in refset:
-                    assert self.pool.is_shared(p), (
-                        f"slot ref page {p} is not a shared pool page"
-                    )
-                    assert (i + 1) * ps <= s.cached, (
-                        f"shared page {p} extends into slot {s.idx}'s "
-                        "writable region"
-                    )
-            if s.cow is not None:
-                assert s.cow[1] in s.pages and s.cow[1] not in refset, (
-                    "COW destination is not a private slot page"
-                )
+            self._check_slot(s)
+        self._seen = [self._snapshot(s) for s in self.slots]
+        self.checked = (self.pool.usable + (
+            self.window.pool.usable if self.window is not None else 0),
+            len(self.slots))
+
+    def check_changed(self) -> None:
+        """`check` at the cost of what changed since the last check of
+        either form (the engine's, every iteration): the pools' counts,
+        the pages their mutators touched, and the slots whose snapshot
+        changed, whose `cached` fell, one of whose shared pages was
+        touched or whose windowed pages changed hands — every slot whose
+        invariants read something that changed. A field written behind
+        every mutator can escape it; the full `check` cannot."""
+        touched = self.pool.check_changed()
+        pages, rids = len(touched), ()
+        if self.window is not None:
+            wpages, rids = self.window.check_changed()
+            pages += wpages
+        walked = 0
+        for i, s in enumerate(self.slots):
+            now = self._snapshot(s)
+            key, cached, _ = self._seen[i]
+            if (now[0] != key or s.cached < cached
+                    or (s.refs and not touched.keys().isdisjoint(s.refs))
+                    or (s.req is not None and s.req.rid in rids)):
+                self._check_slot(s)
+                if self.window is not None:
+                    self.window.check_slot(s)
+                walked += 1
+            self._seen[i] = now
+        if self.window is not None:
+            self.window.check_held()
+        self.checked = (pages, walked)
 
     def _on_terminal(self, req: Request, now: float) -> None:
         """Hook: a request just reached a terminal status (finished or

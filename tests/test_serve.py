@@ -3,6 +3,7 @@ contiguous decode path, page-pool accounting invariants, and the
 continuous-vs-static scheduler comparison — all deterministic on CPU."""
 
 import dataclasses
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +18,13 @@ from mpi_cuda_cnn_tpu.serve.paged_cache import (
     init_paged_cache,
     pages_for,
 )
-from mpi_cuda_cnn_tpu.serve.scheduler import ContinuousScheduler, Request
+from mpi_cuda_cnn_tpu.serve.core import ServeCore, build_scheduler
+from mpi_cuda_cnn_tpu.serve.pool import WindowGroup
+from mpi_cuda_cnn_tpu.serve.scheduler import (
+    ContinuousScheduler,
+    Request,
+    _SchedulerBase,
+)
 
 MODEL = TransformerLM(vocab=13, dim=32, heads=4, depth=2, max_seq=48)
 GQA = TransformerLM(vocab=13, dim=32, heads=4, depth=2, max_seq=48,
@@ -763,3 +770,331 @@ def test_scheduler_and_engine_rejections():
     with pytest.raises(ValueError, match="never be admitted"):
         engine.run([Request(rid=0, prompt=np.zeros(8, np.int32),
                             max_new_tokens=4)], mode="continuous")
+
+
+# -- the per-iteration pool check (Scheduler.check_changed) ---------------
+
+
+class _Device:
+    """ServeCore's compute, stood in for: a chunk writes what the
+    scheduler asked, every token is 1, a copy-on-write copies nothing."""
+
+    def prefill_chunk(self, slot):
+        return min(4, slot.target - slot.cached), 1
+
+    def decode(self, dslots):
+        return {s.idx: 1 for s in dslots}
+
+    def copy_page(self, src, dst):
+        pass
+
+
+# A pool too small for three slots' longest requests (preemption), with
+# a prefix cache (hits, copy-on-write, inserts, reclaim) or a windowed
+# layer group (pages taken and given back behind the window).
+STORMS = {"prefix": dict(prefix=True), "window": dict(window=(8, 4))}
+
+
+def _storm(kind, seed):
+    """A scheduler of `kind` and a `step(t)` that drives it through one
+    ServeCore iteration of a seeded mix: arrivals that share a template
+    prefix or not, cancellations, prefix reclaim; admission, chunks,
+    growth, preemption and finishes are the scheduler's own."""
+    rng = np.random.default_rng(seed)
+    sched = build_scheduler(mode="continuous", slots=3, num_pages=14,
+                            page_size=4, max_len=48, **STORMS[kind])
+    core = ServeCore(_Device(), sched)
+    tmpl = rng.integers(0, 13, 24).astype(np.int32)
+    rids = itertools.count()
+
+    def step(t):
+        if rng.random() < 0.4:
+            n = int(rng.integers(3, 25))
+            prompt = (tmpl[:n].copy() if rng.random() < 0.7
+                      else rng.integers(0, 13, n).astype(np.int32))
+            core.submit(Request(rid=next(rids), prompt=prompt,
+                                max_new_tokens=int(rng.integers(2, 25)),
+                                arrival=float(t)))
+        busy = [s for s in sched.slots if not s.free]
+        if busy and rng.random() < 0.05:
+            busy[int(rng.integers(len(busy)))].req.cancel()
+        if sched.prefix is not None and rng.random() < 0.15:
+            sched.prefix.reclaim(int(rng.integers(1, 4)))
+        return core.step(float(t))
+
+    return sched, step
+
+
+@pytest.mark.parametrize("kind,seed", [(k, s) for k in STORMS
+                                       for s in (0, 1, 2)])
+def test_the_per_iteration_check_passes_where_the_full_scan_does(kind, seed):
+    sched, step = _storm(kind, seed)
+    freed = verified = 0
+    for t in range(150):
+        out = step(t)
+        freed += out.window_freed or 0
+        sched.check_changed()
+        verified += sched.checked[0]
+        if t % 3 == 0:
+            sched.check()
+    sched.check_changed()
+    assert sched.checked == (0, 0)     # nothing changed since the last
+    sched.check()
+    usable = sched.pool.usable + (sched.window.pool.usable
+                                  if sched.window is not None else 0)
+    assert sched.checked == (usable, 3)
+    # The storm took every path, and the check verified what it touched.
+    assert sched.preemptions > 0 and sched.finished and sched.dropped
+    if kind == "prefix":
+        stats = sched.prefix.stats
+        assert stats["hits"] and stats["cow_copies"] and stats["evictions"]
+    else:
+        assert freed > 10
+    assert 0 < verified < 150 * usable / 2
+
+
+def _leak(sched, mp):
+    real = PagePool.free
+
+    def free(self, pages, owner):           # loses one page it freed
+        real(self, pages, owner)
+        self._free_set.discard(self._free.pop())
+    mp.setattr(PagePool, "free", free)
+    return True
+
+
+def _double_book(sched, mp):
+    real = PagePool.try_alloc
+
+    def try_alloc(self, n, owner):          # issues a page it keeps free
+        got = real(self, n, owner)
+        if got:
+            self._free.append(got[0])
+            self._free_set.add(got[0])
+        return got
+    mp.setattr(PagePool, "try_alloc", try_alloc)
+    return True
+
+
+def _page_zero(sched, mp):
+    real = PagePool.try_alloc
+
+    def try_alloc(self, n, owner):          # issues the scratch page
+        got = real(self, n, owner)
+        if got:
+            p = got[-1]
+            del self._owner[p]
+            self._free.append(p)
+            self._free_set.add(p)
+            self._owner[0], got[-1] = owner, 0
+        return got
+    mp.setattr(PagePool, "try_alloc", try_alloc)
+    return True
+
+
+def _readers_on_unowned(sched, mp):
+    shared = [p for p, rl in sched.pool._readers.items() if rl]
+    if not shared:
+        return False
+    real = PagePool.free
+
+    def free(self, pages, owner):           # frees a page with readers
+        kept = {p: self._readers.pop(p) for p in pages if p in self._readers}
+        real(self, pages, owner)
+        self._readers.update(kept)
+    mp.setattr(PagePool, "free", free)
+    sched.pool.free([shared[0]], sched.pool._owner[shared[0]])
+    return True
+
+
+def _writable_shared(sched, mp):
+    private = [p for s in sched.slots if not s.free
+               for p in s.pages if p not in s.refs]
+    if not private:
+        return False
+    real = PagePool.share
+
+    def share(self, page, reader):          # shares a writable page
+        ro = page in self._ro
+        self._ro.add(page)
+        real(self, page, reader)
+        if not ro:
+            self._ro.discard(page)
+    mp.setattr(PagePool, "share", share)
+    sched.pool.share(private[0], "intruder")
+    return True
+
+
+def _shared_in_writable_region(sched, mp):
+    real = _SchedulerBase._bind
+
+    def bind(self, slot, req, pages, now, acq=None):  # forgets the match
+        real(self, slot, req, pages, now, acq)
+        if slot.refs:
+            slot.cached = 0
+    mp.setattr(type(sched), "_bind", bind)
+    return True
+
+
+def _cow_onto_shared(sched, mp):
+    real = _SchedulerBase._bind
+
+    def bind(self, slot, req, pages, now, acq=None):  # copies onto its src
+        real(self, slot, req, pages, now, acq)
+        if slot.cow is not None:
+            slot.cow = (slot.cow[0], slot.cow[0])
+    mp.setattr(type(sched), "_bind", bind)
+    return True
+
+
+def _shared_page_freed_under_its_reader(sched, mp):
+    slot = next((s for s in sched.slots if s.refs), None)
+    if slot is None:
+        return False
+    page, pool = slot.refs[0], sched.pool
+    for reader in list(pool._readers.get(page, ())):   # every reference
+        pool.unshare(page, reader)
+    pool.free([page], pool._owner[page])                # and the page
+    return True
+
+
+def _extent_falls_under_shared_pages(sched, mp):
+    real = ContinuousScheduler.grow_for_decode
+
+    def grow(self, now=0.0, spec_k=1):      # rewinds a slot it kept
+        held = {s.idx: len(s.pages) for s in self.slots}
+        survivors = real(self, now, spec_k)
+        for s in survivors:
+            if s.refs and len(s.pages) == held[s.idx]:
+                s.cached = 0
+        return survivors
+    mp.setattr(ContinuousScheduler, "grow_for_decode", grow)
+    return True
+
+
+def _window_page_changes_hands():
+    seen = {}
+
+    def fault(sched, mp):
+        # A page of a slot whose table did not change since the iteration
+        # before changes hands: only its old owner names what changed.
+        now = {s.idx: (id(s.wpages), len(s.wpages), s.wfirst)
+               for s in sched.slots if not s.free}
+        still = [s for s in sched.slots if not s.free
+                 and seen.get(s.idx) == now[s.idx] and any(s.wpages)]
+        seen.clear()
+        seen.update(now)
+        others = {s.req.rid for s in sched.slots if not s.free}
+        if not still or len(others) < 2:
+            return False
+        slot = still[0]
+        page = next(p for p in slot.wpages[slot.wfirst:] if p)
+        sched.window.pool.adopt(page, slot.req.rid,
+                                min(others - {slot.req.rid}))
+        return True
+    return fault
+
+
+def _window_table_names_a_free_page(sched, mp):
+    real = WindowGroup.advance
+
+    def advance(self, slot, rows):          # enters a page not issued
+        n = len(slot.wpages)
+        real(self, slot, rows)
+        if len(slot.wpages) > n:
+            slot.wpages[-1] = self.pool._free[-1]
+    mp.setattr(WindowGroup, "advance", advance)
+    return True
+
+
+def _window_start_passes_a_page(sched, mp):
+    slot = next((s for s in sched.slots if not s.free and s.wpages[s.wfirst:]
+                 and s.wpages[s.wfirst] and any(s.wpages[s.wfirst + 1:])),
+                None)
+    if slot is None:
+        return False
+    slot.wfirst += 1        # a slot's own field: its snapshot has it
+    return True
+
+
+def _window_page_issued_to_no_table(sched, mp):
+    real = WindowGroup.advance
+
+    def advance(self, slot, rows):          # takes a page it never enters
+        real(self, slot, rows)
+        if not self.pool.try_alloc(1, slot.req.rid):
+            raise RuntimeError("the windowed pool ran dry")
+    mp.setattr(WindowGroup, "advance", advance)
+    return True
+
+
+# name -> (a maker of the fault, the storms it applies to)
+FAULTS = {
+    "leak": (lambda: _leak, STORMS),
+    "double_booking": (lambda: _double_book, STORMS),
+    "page_zero_in_circulation": (lambda: _page_zero, STORMS),
+    "readers_on_an_unowned_page": (lambda: _readers_on_unowned, ["prefix"]),
+    "writable_page_shared": (lambda: _writable_shared, STORMS),
+    "shared_page_in_the_writable_region": (
+        lambda: _shared_in_writable_region, ["prefix"]),
+    "cow_destination_shared": (lambda: _cow_onto_shared, ["prefix"]),
+    "shared_page_freed_under_its_reader": (
+        lambda: _shared_page_freed_under_its_reader, ["prefix"]),
+    "written_extent_falls_under_shared_pages": (
+        lambda: _extent_falls_under_shared_pages, ["prefix"]),
+    "windowed_page_changes_hands": (_window_page_changes_hands, ["window"]),
+    "windowed_table_names_a_free_page": (
+        lambda: _window_table_names_a_free_page, ["window"]),
+    "windowed_page_kept_behind_the_window": (
+        lambda: _window_start_passes_a_page, ["window"]),
+    "windowed_page_issued_to_no_table": (
+        lambda: _window_page_issued_to_no_table, ["window"]),
+}
+
+
+def _first_failure(kind, make_fault, check):
+    """The iteration at which `check` first raises, in a storm whose
+    fault is armed from iteration 20 on (and the one it fired at)."""
+    sched, step = _storm(kind, 0)
+    fault = make_fault()
+    fired = None
+    with pytest.MonkeyPatch.context() as mp:
+        for t in range(150):
+            step(t)
+            if t >= 20 and fired is None and fault(sched, mp):
+                fired = t
+            try:
+                check(sched)
+            except AssertionError:
+                return fired, t
+    return fired, None
+
+
+@pytest.mark.parametrize("name,kind", [(n, k) for n, (_, kinds) in
+                                       FAULTS.items() for k in kinds])
+def test_the_per_iteration_check_fails_where_the_full_scan_does(name, kind):
+    fault = FAULTS[name][0]
+    fired, changed = _first_failure(kind, fault,
+                                    _SchedulerBase.check_changed)
+    assert (fired, changed) == _first_failure(kind, fault,
+                                              _SchedulerBase.check)
+    assert changed is not None and changed >= fired
+
+
+@pytest.mark.parametrize("kind", STORMS)
+def test_a_field_written_behind_every_mutator_is_the_full_scans(kind):
+    """Where the per-iteration check's reach ends: a page no mutator
+    touched is as the last check left it, so a field written behind
+    every mutator can escape it; the full scan, the run's end's,
+    cannot."""
+    sched, step = _storm(kind, 0)
+    for t in range(40):
+        step(t)
+    sched.check_changed()
+    page = next(p for s in sched.slots if not s.free
+                for p in s.pages if p not in s.refs)
+    sched.pool._readers[page] = ["ghost"]
+    sched.check_changed()
+    assert sched.checked == (0, 0)
+    with pytest.raises(AssertionError, match="writable page shared"):
+        sched.check()

@@ -363,11 +363,11 @@ def test_a_failed_pool_check_still_delivers_the_iterations_record(
 
     calls = []
 
-    def check(self):
+    def check_changed(self):
         calls.append(1)
         assert len(calls) < 3, "pool broken"
 
-    monkeypatch.setattr(ContinuousScheduler, "check", check)
+    monkeypatch.setattr(ContinuousScheduler, "check_changed", check_changed)
     ticks = []
     clock = TickingClock()
     with pytest.raises(AssertionError, match="pool broken"):
@@ -376,6 +376,65 @@ def test_a_failed_pool_check_still_delivers_the_iterations_record(
                                 tick_sink=ticks.append)
     assert [t["tick"] for t in ticks] == [0, 1, 2]
     assert ticks[-1]["spans"][-1][0] == "record"
+
+
+@pytest.mark.parametrize("sink", [False, True], ids=["bare", "recorded"])
+def test_checked_is_read_only_for_a_record(params, monkeypatch, sink):
+    """The iteration's pool check verifies what changed since the last
+    one, and its record says how much: `checked`, [pages, slots]. A
+    bare run reads it nowhere."""
+    from mpi_cuda_cnn_tpu.serve.scheduler import _SchedulerBase
+
+    reads = []
+
+    def get(self):
+        reads.append(1)
+        return self.__dict__["_checked"]
+
+    def put(self, value):
+        self.__dict__["_checked"] = value
+
+    monkeypatch.setattr(_SchedulerBase, "checked", property(get, put),
+                        raising=False)
+    ticks = []
+    clock = TickingClock()
+    res = make_engine(params).run(requests(), time_fn=clock,
+                                  sleep_fn=clock.advance,
+                                  tick_sink=ticks.append if sink else None)
+    assert all(r.status == "finished" for r in res.requests)
+    assert len(reads) == len(ticks) == (len(ticks) if sink else 0)
+    if sink:
+        got = [t["checked"] for t in ticks]
+        # Two slots over a pool of 15 pages: the first iteration admits
+        # both requests (two pages each), and no check is a whole scan.
+        assert got[0] == [4, 2]
+        assert all(p < 15 and s <= 2 for p, s in got)
+        assert [0, 0] in got
+
+
+def test_a_pool_field_written_behind_the_mutators_fails_the_run_at_its_end(
+        params, monkeypatch):
+    """The per-iteration check sees what a mutator touched; a field
+    written behind all of them is the run-end full scan's to catch,
+    after every iteration's record was delivered."""
+    from mpi_cuda_cnn_tpu.serve.core import ServeCore
+
+    real = ServeCore.settle
+
+    def settle(self, out):
+        if self.steps == 2:             # a page no request ever takes
+            self.sched.pool._readers[self.sched.pool.num_pages - 1] = [0]
+        return real(self, out)
+
+    clean, want = serve(make_engine(params), requests(), TickingClock())
+    monkeypatch.setattr(ServeCore, "settle", settle)
+    ticks = []
+    clock = TickingClock()
+    with pytest.raises(AssertionError, match="readers on unowned page"):
+        make_engine(params).run(requests(), time_fn=clock,
+                                sleep_fn=clock.advance,
+                                tick_sink=ticks.append)
+    assert [t["tick"] for t in ticks] == [t["tick"] for t in want]
 
 
 def test_speculative_round_has_the_ticks_three_spans(params):

@@ -506,14 +506,14 @@ def test_storms_leave_both_pools_whole(served, storm):
     elif storm == "static":
         run_kw["mode"] = "static"
     checked = []
-    real = WindowGroup.check
+    real = WindowGroup.check_changed
 
-    def check(group, slots):
+    def check_changed(group):
         checked.append(group.pool.free_pages)
-        return real(group, slots)
+        return real(group)
 
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(WindowGroup, "check", check)
+        mp.setattr(WindowGroup, "check_changed", check_changed)
         res = engine(served, **kw).run(reqs, **run_kw)
     assert len(res.requests) == 7
     assert len(checked) > 20 and len(set(checked)) > 3      # every iteration
