@@ -72,11 +72,18 @@ FULL = dict(
                # experts of (2560, 768), whole matrices a block; and of
                # `command-a-plus-05-2026`: a 512-row chunk's ~512 pairs
                # over 16 held of 128 experts of (4096, 4096), in width
-               # slices.
+               # slices; and two decode ticks: `xing4.0-29b-a4b`'s 32
+               # rows x top-4 over 64 whole experts of (3584, 1024), and
+               # Command A+'s 48 rows x top-8 over its 16 sliced ones.
                experts=dict(rows=512, experts=64, top_k=6, dim=2560,
-                            width=768),
+                            width=768, act="relu"),
                experts_sliced=dict(rows=512, experts=128, held=16, top_k=8,
-                                   dim=4096, width=4096),
+                                   dim=4096, width=4096, act="silu"),
+               experts_tick=dict(rows=32, experts=64, top_k=4, dim=3584,
+                                 width=1024, act="silu"),
+               experts_sliced_tick=dict(rows=48, experts=128, held=16,
+                                        top_k=8, dim=4096, width=4096,
+                                        act="silu"),
                # ... and `minicpm-sala`'s two mixers: a sparse layer's
                # pools (2 K/V heads, a compressed key every 16 rows) with
                # the selection's published sizes, and a linear layer's
@@ -106,9 +113,15 @@ TOY = dict(
                latent=dict(heads=4, kv_rank=32, nope=8, rope=8, v=8,
                            lanes=128, cache_dtype="bfloat16", slots=4,
                            max_len=64),
-               experts=dict(rows=96, experts=8, top_k=2, dim=32, width=16),
+               experts=dict(rows=96, experts=8, top_k=2, dim=32, width=16,
+                            act="relu"),
                experts_sliced=dict(rows=96, experts=16, held=8, top_k=4,
-                                   dim=32, width=16),
+                                   dim=32, width=16, act="silu"),
+               experts_tick=dict(rows=8, experts=8, top_k=2, dim=32,
+                                 width=16, act="silu"),
+               experts_sliced_tick=dict(rows=12, experts=16, held=8,
+                                        top_k=4, dim=32, width=16,
+                                        act="silu"),
                sparse=dict(heads=4, kv_heads=2, head_dim=16,
                            cache_dtype="bfloat16", slots=3, max_len=256,
                            chunk=16, select=dict(
@@ -207,6 +220,116 @@ def check_data_parallel(recs: dict, ndev: int) -> dict:
 
 
 # ------------------------------------------------------------- phases
+
+
+def expert_layers(sv, rng, rehearsal, compare, mosaic, twin) -> dict:
+    """The expert layer's kernel against its walk (phase_kernels' block,
+    callable alone), at `smallthinker`'s chunk (`experts`: whole
+    matrices a block), `command-a-plus`'s chunk (`experts_sliced`: 16
+    held experts of 128 too wide for whole blocks, taken in width
+    slices) and the two decode ticks (`experts_tick`: xing4.0's one
+    128-row tile over 64 whole experts; `experts_sliced_tick`: Command
+    A+'s 48 rows over its sliced ones): sorted pairs through the kernel
+    (ops/pallas_expert_mlp) against the walk in 128-row steps of
+    lax.ragged_dot, one draw from a plain softmax router and one skewed
+    (some experts get most of the pairs, some none). The rule must pick
+    the kernel's form by itself, at a tick as at a chunk; the walk is
+    forced. Both put bf16 rows and matrices through the MXU with f32
+    accumulation and round the hidden rows to bf16 before `wd`: only
+    the order of the f32 sums differs (in slices: four partial sums of
+    the down product added in f32), which can move a hidden value by one
+    bf16 step, 2^-8 of it, and the output by less of its largest:
+    tolerance 2^-8. The walk has no reading under "highest" (XLA's
+    grouped kernel refuses bf16 at that precision: "Bad lhs type"), so
+    the twin under "highest" is the plain product, every row through
+    every held expert in f32 with the hidden rows rounded as the program
+    rounds them; the layer's bf16 OUTPUT is half a step, 2^-9, from it
+    by its own rounding (2.0e-3 to 2.4e-3 in rehearsal): tolerance 2^-7
+    there, four such half steps. In rehearsal the toy experts fit whole,
+    so the sliced layouts halve what the VMEM is taken to hold. Returns
+    [pairs, experts hit, largest load] a draw."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mpi_cuda_cnn_tpu.models.transformer import RoutedExperts
+    from mpi_cuda_cnn_tpu.parallel import ep
+
+    drawn = {}
+    for layout in ("experts", "experts_sliced", "experts_tick",
+                   "experts_sliced_tick"):
+        ex = sv[layout]
+        held = ex.get("held", ex["experts"])
+        spec = RoutedExperts(experts=ex["experts"], top_k=ex["top_k"],
+                             held=tuple(range(held)), router="softmax",
+                             act=ex["act"], reads="layer_input")
+        d, wd_ = ex["dim"], ex["width"]
+        bank = {m: jnp.asarray(rng.normal(size=(held, *shape))
+                               / np.sqrt(shape[0]), "bfloat16")
+                for m, shape in (("wg", (d, wd_)), ("wu", (d, wd_)),
+                                 ("wd", (wd_, d)))}
+        gate = jnp.asarray(rng.normal(size=(d, ex["experts"])) / np.sqrt(d),
+                           jnp.float32)
+        blk = {"experts": bank, "router": {"gate": gate}}
+        sliced = layout.startswith("experts_sliced")
+        halved = (mock.patch.object(ep, "width_slice",
+                                    lambda dim, width, b: width // 2)
+                  if rehearsal and sliced else contextlib.nullcontext())
+        with halved:
+            tn = ep.tiled_products(bank)
+        check(0 < tn < wd_ if sliced else tn == wd_,
+              f"{layout}: {ex['rows']} rows x top-{ex['top_k']} took "
+              f"blocks {tn} wide of {wd_}")
+        for draw, lift in (("uniform", 0.0), ("skewed", 3.0)):
+            name = f"{layout}_{draw}"
+            x = jnp.asarray(rng.normal(size=(ex["rows"], d)), "bfloat16")
+            logits = (x.astype(jnp.float32) @ gate
+                      + lift * jnp.linspace(1.0, -1.0, ex["experts"]))
+            w, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                   ex["top_k"])
+            routing = (ids.astype(jnp.int32),
+                       w / jnp.sum(w, -1, keepdims=True))
+
+            # The banks are the programs' arguments, as the engine's params
+            # are: closed over, they would be GBs of constants to compile.
+            def layer(x, blk, routing, kernel):
+                if kernel:
+                    with halved:
+                        return ep.moe_held_inference(x, blk, spec,
+                                                     routing=routing)
+                with mock.patch.object(ep, "tiled_products",
+                                       lambda *a: False):
+                    return ep.moe_held_inference(x, blk, spec,
+                                                 routing=routing)
+
+            def plain(x, bank, routing):
+                xf, (ids, w) = x.astype(jnp.float32), routing
+                act = ep._GATE_ACT[spec.act]
+
+                def one(e, y):
+                    g, u, dn = (bank[m][e].astype(jnp.float32)
+                                for m in ("wg", "wu", "wd"))
+                    h = (act(xf @ g) * (xf @ u)).astype(x.dtype)
+                    return y + jnp.sum(jnp.where(ids == e, w, 0.0), axis=-1,
+                                       keepdims=True) * (
+                        h.astype(jnp.float32) @ dn)
+
+                return jax.lax.fori_loop(0, held, one,
+                                         jnp.zeros(x.shape, jnp.float32))
+
+            mosaic(lambda *a: layer(*a, True)[0], x, blk, routing)
+            walked, counts = jax.jit(lambda *a: layer(*a, False))(
+                x, blk, routing)
+            got, same = jax.jit(lambda *a: layer(*a, True))(x, blk, routing)
+            landed = int(np.isin(np.asarray(ids), np.arange(held)).sum())
+            check(np.array_equal(counts, same) and int(counts[0]) == landed,
+                  f"{name}: counts {counts} / {same}, {landed} landed")
+            drawn[name] = [int(c) for c in counts]
+            compare(name, got, twin(plain, x, bank, routing), 2.0 ** -7)
+            compare(f"{name}_as_served", got, walked, 2.0 ** -8)
+    return drawn
 
 
 def phase_kernels(cfg, dev, rehearsal):
@@ -543,102 +666,7 @@ def phase_kernels(cfg, dev, rehearsal):
                     np.asarray(want_o)[keep], tol)
             compare(f"linear_state_b{b}_kk{kk}{tag}", s_, want_s, tol)
 
-    # The expert layer's kernel against its walk, at `smallthinker`'s
-    # layout (`experts`: whole matrices a block) and at `command-a-plus`'s
-    # (`experts_sliced`: 16 held experts of 128 too wide for whole
-    # blocks, taken in width slices): a chunk's sorted pairs through the
-    # kernel (ops/pallas_expert_mlp) against the walk in 128-row steps of
-    # lax.ragged_dot, one draw from a plain softmax router and one skewed
-    # (some experts get most of the pairs, some none). The rule must
-    # pick the kernel's form by itself; the walk is forced. Both put bf16
-    # rows and matrices through the MXU with f32 accumulation and round
-    # the hidden rows to bf16 before `wd`: only the order of the f32 sums
-    # differs (in slices: four partial sums of the down product added in
-    # f32), which can move a hidden value by one bf16 step, 2^-8 of it,
-    # and the output by less of its largest: tolerance 2^-8. The walk has
-    # no reading under "highest" (XLA's grouped kernel refuses bf16 at
-    # that precision: "Bad lhs type"), so the twin under "highest" is
-    # the plain product, every row through every held expert in f32 with
-    # the hidden rows rounded as the program rounds them; the layer's
-    # bf16 OUTPUT is half a step, 2^-9, from it by its own rounding (2.0e-3
-    # to 2.4e-3 in rehearsal): tolerance 2^-7 there, four such half steps.
-    # In rehearsal the toy experts fit whole, so the sliced layout halves
-    # what the VMEM is taken to hold (the rule's pair count still picks).
-    from mpi_cuda_cnn_tpu.models.transformer import RoutedExperts
-    from mpi_cuda_cnn_tpu.parallel import ep
-
-    drawn = {}      # [pairs, experts hit, largest load] a draw
-    for layout in ("experts", "experts_sliced"):
-        ex = sv[layout]
-        held = ex.get("held", ex["experts"])
-        spec = RoutedExperts(experts=ex["experts"], top_k=ex["top_k"],
-                             held=tuple(range(held)), router="softmax",
-                             act="relu" if layout == "experts" else "silu",
-                             reads="layer_input")
-        d, wd_ = ex["dim"], ex["width"]
-        bank = {m: jnp.asarray(rng.normal(size=(held, *shape))
-                               / np.sqrt(shape[0]), "bfloat16")
-                for m, shape in (("wg", (d, wd_)), ("wu", (d, wd_)),
-                                 ("wd", (wd_, d)))}
-        gate = jnp.asarray(rng.normal(size=(d, ex["experts"])) / np.sqrt(d),
-                           jnp.float32)
-        blk = {"experts": bank, "router": {"gate": gate}}
-        sliced = layout == "experts_sliced"
-        halved = (mock.patch.object(ep, "width_slice",
-                                    lambda dim, width, b: width // 2)
-                  if rehearsal and sliced else contextlib.nullcontext())
-        with halved:
-            tn = ep.tiled_products(ex["rows"], spec, bank)
-        check(0 < tn < wd_ if sliced else tn == wd_,
-              f"{layout}: {ex['rows']} rows x top-{ex['top_k']} took "
-              f"blocks {tn} wide of {wd_}")
-        for draw, lift in (("uniform", 0.0), ("skewed", 3.0)):
-            name = f"{layout}_{draw}"
-            x = jnp.asarray(rng.normal(size=(ex["rows"], d)), "bfloat16")
-            logits = (x.astype(jnp.float32) @ gate
-                      + lift * jnp.linspace(1.0, -1.0, ex["experts"]))
-            w, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
-                                   ex["top_k"])
-            routing = (ids.astype(jnp.int32),
-                       w / jnp.sum(w, -1, keepdims=True))
-
-            # The banks are the programs' arguments, as the engine's params
-            # are: closed over, they would be GBs of constants to compile.
-            def layer(x, blk, routing, kernel):
-                if kernel:
-                    with halved:
-                        return ep.moe_held_inference(x, blk, spec,
-                                                     routing=routing)
-                with mock.patch.object(ep, "tiled_products",
-                                       lambda *a: False):
-                    return ep.moe_held_inference(x, blk, spec,
-                                                 routing=routing)
-
-            def plain(x, bank, routing):
-                xf, (ids, w) = x.astype(jnp.float32), routing
-                act = ep._GATE_ACT[spec.act]
-
-                def one(e, y):
-                    g, u, dn = (bank[m][e].astype(jnp.float32)
-                                for m in ("wg", "wu", "wd"))
-                    h = (act(xf @ g) * (xf @ u)).astype(x.dtype)
-                    return y + jnp.sum(jnp.where(ids == e, w, 0.0), axis=-1,
-                                       keepdims=True) * (
-                        h.astype(jnp.float32) @ dn)
-
-                return jax.lax.fori_loop(0, held, one,
-                                         jnp.zeros(x.shape, jnp.float32))
-
-            mosaic(lambda *a: layer(*a, True)[0], x, blk, routing)
-            walked, counts = jax.jit(lambda *a: layer(*a, False))(
-                x, blk, routing)
-            got, same = jax.jit(lambda *a: layer(*a, True))(x, blk, routing)
-            landed = int(np.isin(np.asarray(ids), np.arange(held)).sum())
-            check(np.array_equal(counts, same) and int(counts[0]) == landed,
-                  f"{name}: counts {counts} / {same}, {landed} landed")
-            drawn[name] = [int(c) for c in counts]
-            compare(name, got, twin(plain, x, bank, routing), 2.0 ** -7)
-            compare(f"{name}_as_served", got, walked, 2.0 ** -8)
+    drawn = expert_layers(sv, rng, rehearsal, compare, mosaic, twin)
 
     # The stream mix (ops/pallas_hyper_mix) against its jax.numpy twin,
     # at a chunk with a short `rows` and at a tick: bf16 streams, phi,

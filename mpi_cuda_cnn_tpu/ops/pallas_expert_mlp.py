@@ -1,8 +1,8 @@
 """The gated expert MLP over sorted (token, expert) pairs: each touched
 expert's matrices streamed about once.
 
-parallel/ep.moe_held_inference sorts a forward's pairs by held expert.
-Where many land on this chip, this kernel takes the sorted rows in row
+parallel/ep.moe_held_inference sorts a forward's pairs by held expert,
+a chunk's or a tick's, and this kernel takes the sorted rows in row
 tiles and visits every (row tile, expert) pair that meets — a tile
 that lies inside one expert's rows once, a tile that straddles k
 experts k times: at most tiles + experts hit - 1 visits. One grid step

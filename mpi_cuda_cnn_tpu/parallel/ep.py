@@ -364,50 +364,33 @@ def moe_mlp_inference(x, params: dict, *, n_experts: int, top_k: int = 1):
     return y.astype(x.dtype)
 
 
-# Sorted (token, expert) pairs a step of moe_held_inference's WALK, the
-# form of the grouped products where few pairs land on this chip:
-# dots.vlm1's decode tick of 64 rows sends about 30 pairs to its 16 of
-# 256 experts, its 32-row chunk about 16, and XLA's grouped kernel pays
-# for every row of its tile in every group it touches (PERF.md section
-# 6, PR 28).
+# Rows a step of moe_held_inference's WALK, and a tile of the expert
+# kernel's: the sorted (token, expert) pairs are padded to a multiple of
+# it, or taken whole where fewer (a toy tick's).
 _PAIR_CHUNK = 128
 
 
-def pairs_landing(rows: int, spec) -> int:
-    """The (token, expert) pairs of a forward of `rows` tokens that can
-    land on this chip's experts if the router spreads them evenly:
-    rows x top_k x held / experts. A shape, known when the program is
-    traced: 3,072 for smallthinker's 512-row chunk (64 of 64 held,
-    top-6), 192 for its tick of 32; 32 and 16 for dots.vlm1's tick and
-    chunk."""
-    return rows * spec.top_k * len(spec.held) // spec.experts
-
-
-def tiled_products(rows: int, spec, bank: dict) -> int:
+def tiled_products(bank: dict) -> int:
     """Which form moe_held_inference's grouped products take, from
     shapes alone: 0 for the walk, else the width of the blocks that
     ops/pallas_expert_mlp's kernel takes an expert's matrices in. The
-    kernel runs where more pairs can land here than ONE step of the
-    walk holds — the walk
-    would then stream an expert's matrices again in every step its rows
-    straddle and start three grouped calls a step, 72 a layer at 3,072
-    pairs. It takes whole matrices where an expert's three fit its
-    VMEM twice over (the width itself) and width slices where they do
-    not (`width_slice`: 1,024 of Command A+'s 4,096), so three forms:
-    - the walk: dots.vlm1's tick and chunk (32 and 16 pairs), Command
-      A+'s tick (~48): one step holds all that can land, the walk is
-      one call a product over the experts hit, and stays;
-    - whole matrices: smallthinker's chunk and tick (3,072 and 192
-      over 64 experts of 3 x (2560, 768) bf16). Placed on the v5e
-      (PERF.md section 6, PR 33; ms a layer, walk / kernel): 3,072
-      pairs 2.48 / 1.25; 600 (a short last chunk) 1.83 / 1.06; 192
-      over 60 (a full tick) 1.55 / 0.96; 90 over 48 1.23 / 0.77;
-    - width slices: Command A+'s 512-row chunk (~512 pairs over 16
-      held experts of 3 x (4096, 4096) bf16, 96 MiB; ms a layer, walk /
-      kernel, PERF.md section 6, PR 39: 4.72 / 3.52).
-    Where no slice fits, the walk."""
-    if pairs_landing(rows, spec) <= _PAIR_CHUNK:
-        return 0
+    kernel streams each expert that rows land on once, the next one's
+    fetch under this one's products, however many pairs land: whole
+    matrices where an expert's three fit its VMEM twice over (the width
+    itself), width slices where they do not (`width_slice`: 1,024 of
+    Command A+'s 4,096, 512 of dots.vlm1's 2,048). On the v5e the walk
+    lost to it at every shape measured (PERF.md section 6; ms a layer,
+    walk / kernel):
+    - whole matrices: smallthinker's 3 x (2560, 768) bf16, 3,072 pairs
+      over 64 experts (its 512-row chunk) 2.48 / 1.25, 192 over 60 (a
+      tick) 1.55 / 0.96; xing4.0's 32-row tick, ~60 pairs over ~37 of
+      64 experts of 3 x (3584, 1024), 1.58 / 1.27 in the traced tick;
+    - width slices: Command A+'s 512-row chunk, ~512 pairs over 16 held
+      experts of 3 x (4096, 4096), 4.72 / 3.52; in the traced programs
+      its 48-row tick (~48 pairs) 2.52 / 2.02, dots.vlm1's 64-row tick
+      (~30 pairs over ~11 of 16 held experts of 3 x (7168, 2048)) 1.88
+      / 1.71 and its 32-row chunk (~16) 1.02 / 0.86.
+    The walk stays where no slice fits."""
     wg = bank["wg"]
     return width_slice(wg.shape[1], wg.shape[2], wg.dtype.itemsize)
 
@@ -480,16 +463,16 @@ def moe_held_inference(x, blk: dict, spec, valid=None, routing=None):
     belong to a row outside `valid`, sort last and into no group — and
     the three products of the gated expert MLP run grouped over the
     sorted rows, one group a held expert, in one of two forms that
-    `tiled_products` reads off the shapes. Where few pairs can land
-    here (dots.vlm1's 16 and 32 of 256 and 512 rows) the rows are
-    WALKED in steps of 128, each step three lax.ragged_dot calls (XLA's
-    own grouped kernel on the TPU), and only the steps that hold a pair
-    are computed. Where many can (smallthinker's 3,072 a chunk, 192 a
-    tick; Command A+'s ~512 a chunk) ONE kernel (ops/pallas_expert_mlp)
+    `tiled_products` reads off the shapes. Wherever an expert's
+    matrices fit the kernel whole or in width slices — every served
+    configuration's chunk and tick — ONE kernel (ops/pallas_expert_mlp)
     computes the whole gated MLP: with an expert's whole matrices over
     every (128-row tile, expert) pair that meets, or, where they are
     too large for its VMEM, in width slices over each expert's own
     128-row windows; either streams an expert's matrices about once.
+    Where no slice fits, the rows are WALKED in steps of 128, each step
+    three lax.ragged_dot calls (XLA's own grouped kernel on the TPU),
+    and only the steps that hold a pair are computed.
     Either way shapes are static at tokens x top_k rows, the
     most that can land here, the cost follows the pairs that are
     there, nothing is dropped and no capacity exists: bf16 rows and
@@ -513,10 +496,10 @@ def moe_held_inference(x, blk: dict, spec, valid=None, routing=None):
     local = jnp.asarray(local_of)[ids]                         # (T, k)
     if valid is not None:
         local = jnp.where(valid[:, None], local, n)
-    # The walk takes the sorted pairs `chunk` rows at a time, and only
-    # the chunks that hold a pair are computed: the grouped kernel pays
-    # for every row of its tile in every group, so 512 rows of which 30
-    # are pairs cost what 512 pairs cost (PERF.md section 6, PR 28).
+    # The kernel's row tile and the walk's step. The walk computes only
+    # the steps that hold a pair: XLA's grouped kernel pays for every
+    # row of its tile in every group, so 512 rows of which 30 are pairs
+    # cost what 512 pairs cost (PERF.md section 6).
     chunk = min(t * k, _PAIR_CHUNK)
     rows = -(-t * k // chunk) * chunk
     flat = jnp.pad(local.reshape(t * k), (0, rows - t * k), constant_values=n)
@@ -537,10 +520,10 @@ def moe_held_inference(x, blk: dict, spec, valid=None, routing=None):
         yc = lax.ragged_dot(h.astype(x.dtype), bank["wd"], size, **f32)
         return lax.dynamic_update_slice_in_dim(ys, yc, lo, 0)
 
-    tn = tiled_products(t, spec, bank)
+    tn = tiled_products(bank)
     if tn:
-        # Many pairs: one kernel, an expert's matrices whole or in
-        # width slices, each streamed about once.
+        # One kernel, an expert's matrices whole or in width slices,
+        # each streamed about once.
         whole = tn == bank["wg"].shape[2]
         with annotate("ep.held_experts.grouped" if whole
                       else "ep.held_experts.sliced"):

@@ -1,17 +1,14 @@
 """The three forms of moe_held_inference's grouped products.
 
-Where few (token, expert) pairs can land on this chip the sorted pairs
-are walked in 128-row steps of lax.ragged_dot; where many can, one
-Pallas kernel visits every (row tile, expert) that meets and streams
-each touched expert once a visit (ops/pallas_expert_mlp, interpreted on
-the CPU), its matrices whole where they fit the kernel's VMEM and in
-width slices where they do not.
+One Pallas kernel visits every (row tile, expert) that meets and
+streams each touched expert once a visit (ops/pallas_expert_mlp,
+interpreted on the CPU), its matrices whole where they fit the kernel's
+VMEM and in width slices where they do not; where no slice fits, the
+sorted pairs are walked in 128-row steps of lax.ragged_dot.
 Which form a program holds is read off shapes alone. Each is held
 here to the walk and to the plain product that puts every token
 through every expert.
 """
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -94,6 +91,10 @@ CASES = {
     "valid_512_of_512": (ALL_HELD, 512, _uniform, 512),
     "held_share_by_row_count": (SHARE, 2560, _uniform, None),
     "routing_from_outside": (ALL_HELD, 512, _from_outside, None),
+    # A decode tick's one step: 128 pair rows in one tile, every expert
+    # held; and 256 pair rows of which ~16 land on a held share.
+    "one_step_all_held": (ALL_HELD, 64, _uniform, None),
+    "one_step_held_share": (SHARE, 32, _uniform, None),
 }
 
 
@@ -135,9 +136,9 @@ def held_to_the_walk_and_the_plain_product(case, scope, monkeypatch):
 
 @pytest.mark.parametrize("case", CASES)
 def test_the_tiled_form_is_the_walk_and_the_plain_product(case, monkeypatch):
-    spec, rows = CASES[case][:2]
+    spec = CASES[case][0]
     # The rule, not a flag, picks the kernel's whole matrices here ...
-    assert ep.tiled_products(rows, spec, block(spec)["experts"]) == WIDTH
+    assert ep.tiled_products(block(spec)["experts"]) == WIDTH
     held_to_the_walk_and_the_plain_product(
         case, "ep.held_experts.grouped", monkeypatch)
 
@@ -149,46 +150,57 @@ def test_the_sliced_form_is_the_walk_and_the_plain_product(case,
     half an expert's width: two slices a visit, summed in f32 before
     the expert's rows are written; every case's experts straddle tiles
     (uniform: ~128 rows an expert over 128-row tiles)."""
-    spec, rows = CASES[case][:2]
+    spec = CASES[case][0]
     monkeypatch.setattr(ep, "width_slice", lambda dim, width, b: width // 2)
-    assert ep.tiled_products(rows, spec, block(spec)["experts"]) == WIDTH // 2
+    assert ep.tiled_products(block(spec)["experts"]) == WIDTH // 2
     held_to_the_walk_and_the_plain_product(
         case, "ep.held_experts.sliced", monkeypatch)
 
 
 def test_the_form_is_read_off_shapes_alone():
-    """Pairs that can land: rows x top_k x held / experts. The walk at
-    dots.vlm1's 16 and 32 (its 32-row chunk and 64-row tick over 16 of
-    256 experts, top-8) and at Command A+'s 48 (its 48-row tick over 16
-    of 128, top-8); whole matrices at smallthinker's 3,072 (a 512-row
-    chunk, all 64 held, top-6); width slices where an expert is too
-    large for the kernel's VMEM whole: Command A+'s 512 (a 512-row
-    chunk), and dots.vlm1's experts under more rows than it serves."""
-    dots = RoutedExperts(experts=256, held=tuple(range(16)), top_k=8,
-                         groups=8, top_groups=4, scale=2.5)
-    small = RoutedExperts(experts=64, held=tuple(range(64)), top_k=6,
-                          router="softmax", act="relu", reads="layer_input")
-    cmd_a = RoutedExperts(experts=128, held=tuple(range(16)), top_k=8)
+    """The kernel wherever an expert's matrices fit it, whatever the
+    pairs that can land (rows x top_k x held / experts) — the rule reads
+    the bank's shapes and nothing else: width slices of 512 for
+    dots.vlm1's 16 of 256 experts of (7168, 2048) at its 32-row chunk
+    (16 pairs) and 64-row tick (32); slices of 1,024 for Command A+'s 16
+    of 128 of (4096, 4096) at its 48-row tick (48) and 512-row chunk
+    (512); whole matrices for smallthinker's 64 of (2560, 768) at a
+    16-row tick (96) and a 512-row chunk (3,072), and for xing4.0's 64
+    of (3584, 1024) at its 32-row tick (128). The walk where not even
+    128 columns fit twice."""
     bank = lambda n, d, w: {"wg": jax.ShapeDtypeStruct(
         (n, d, w), jnp.bfloat16)}
-    assert ep.pairs_landing(32, dots) == 16
-    assert ep.pairs_landing(64, dots) == 32
-    assert ep.pairs_landing(512, small) == 3072
-    assert ep.pairs_landing(32, small) == 192
-    assert ep.pairs_landing(512, cmd_a) == 512
-    assert ep.pairs_landing(48, cmd_a) == 48
-    assert not ep.tiled_products(32, dots, bank(16, 7168, 2048))
-    assert not ep.tiled_products(64, dots, bank(16, 7168, 2048))
-    assert ep.tiled_products(512, small, bank(64, 2560, 768)) == 768
-    assert not ep.tiled_products(16, small, bank(64, 2560, 768))
-    assert ep.tiled_products(512, cmd_a, bank(16, 4096, 4096)) == 1024
-    assert not ep.tiled_products(48, cmd_a, bank(16, 4096, 4096))
-    assert ep.tiled_products(1 << 16, dots, bank(16, 7168, 2048)) == 512
-    # Nothing but the shapes is asked: a spec with other names for the
-    # same sizes answers the same.
-    other = dataclasses.replace(small, router="sigmoid", act="silu",
-                                reads="block")
-    assert ep.tiled_products(512, other, bank(64, 2560, 768)) == 768
+    assert ep.tiled_products(bank(16, 7168, 2048)) == 512
+    assert ep.tiled_products(bank(16, 4096, 4096)) == 1024
+    assert ep.tiled_products(bank(64, 2560, 768)) == 768
+    assert ep.tiled_products(bank(64, 3584, 1024)) == 1024
+    assert not ep.tiled_products(bank(16, 65536, 4096))
+    # One layer's program at each of those ticks holds the kernel's
+    # form and no step of the walk.
+    for spec, rows, (d, w), form in (
+            (RoutedExperts(experts=256, held=tuple(range(16)), top_k=8,
+                           groups=8, top_groups=4, scale=2.5), 64,
+             (7168, 2048), "sliced"),
+            (RoutedExperts(experts=128, held=tuple(range(16)), top_k=8),
+             48, (4096, 4096), "sliced"),
+            (RoutedExperts(experts=64, held=tuple(range(64)), top_k=6,
+                           router="softmax", act="relu",
+                           reads="layer_input"), 16, (2560, 768), "grouped"),
+            (RoutedExperts(experts=64, held=tuple(range(64)), top_k=4), 32,
+             (3584, 1024), "grouped")):
+        n = len(spec.held)
+        shapes = {"wg": (n, d, w), "wu": (n, d, w), "wd": (n, w, d)}
+        blk = {"experts": {m: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+                           for m, s in shapes.items()},
+               "router": {"gate": jax.ShapeDtypeStruct(
+                              (d, spec.experts), jnp.float32),
+                          "bias": jax.ShapeDtypeStruct(
+                              (spec.experts,), jnp.float32)}}
+        text = jax.jit(lambda x, blk: ep.moe_held_inference(
+            x, blk, spec)).lower(jax.ShapeDtypeStruct(
+                (rows, d), jnp.bfloat16), blk).as_text(debug_info=True)
+        assert f"ep.held_experts.{form}" in text
+        assert "ragged_dot" not in text
 
 
 @pytest.mark.parametrize("dim, width, itemsize, want", [
