@@ -23,6 +23,10 @@ scaffold); the scheduler owns the acceptance-aware page accounting
 
 Both donate the page pools, so the cache updates in place across ticks
 (utils/donation discipline; the pool is the engine's dominant buffer).
+Each takes everything the host makes for it — tokens, positions,
+flags, every layer group's block table, the linear layers' state rows
+— as ONE int32 array (`Packed`), put on the device by one transfer,
+and slices its inputs and builds its cache view inside.
 Sampling is greedy — the serving benches measure schedule/memory
 effects, and greedy keeps static-vs-continuous token streams bitwise
 comparable per request.
@@ -54,9 +58,11 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import time
 import zlib
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -117,7 +123,7 @@ TICK_LAYOUT = (
     "tick", "now", "mode", "queue", "running", "prefilling", "free_pages",
     "backlog", "arrived", "admitted", "prefill", "decoded", "finished",
     "aborted", "preempted", "blocked", "preempted_for", "terminal",
-    "state_crc", "compiled", "checked", *TICK_COUNTS, WINDOW_COUNT,
+    "state_crc", "compiled", "checked", "puts", *TICK_COUNTS, WINDOW_COUNT,
     *CHUNK_COUNTS, *SELECT_COUNTS, *HC_COUNTS, "pages_held", "state_resets",
     "window_pages_freed", "squeezed", "spec",
     "prefix_hits", "prefix", "prefix_readmits",
@@ -664,6 +670,37 @@ def _closes_spans(run):
     return wrapper
 
 
+class Packed:
+    """Where each of one program's host-made inputs lies in the ONE
+    int32 array that carries them all to the device: `fields`, (name,
+    shape) pairs, laid end to end in order. The same `split` gives the
+    host writable views into a fresh buffer and gives the program, from
+    that buffer's one device copy, each input by a static slice and a
+    reshape — so a dispatch makes one transfer, whatever its count of
+    tokens, positions, flags, block tables and state rows."""
+
+    def __init__(self, *fields):
+        self.slices, end = [], 0
+        for name, shape in fields:
+            start, end = end, end + math.prod(shape)
+            self.slices.append((name, start, end, shape))
+        self.size = end
+
+    def split(self, flat) -> dict:
+        """Each field of `flat` (a host buffer or the program's copy of
+        it) by name, shaped: a view of the buffer, a slice in the
+        program."""
+        return {name: flat[a:b].reshape(shape)
+                for name, a, b, shape in self.slices}
+
+    def host(self):
+        """A fresh zeroed buffer and its fields' views, to be filled.
+        Fresh each dispatch: a put may still read the last one, and on
+        the CPU its device copy may be that very memory."""
+        buf = np.zeros(self.size, np.int32)
+        return buf, self.split(buf)
+
+
 class PagedEngine:
     """Greedy serving engine over a paged KV cache.
 
@@ -771,38 +808,62 @@ class PagedEngine:
             self._pages = tuple(c.pages for c in tmpl)
             self._table_width = tmpl[0].block_table.shape[1]
 
-        def tick(cache: PagedKVCache, params, toks, pos, live):
+        # Each program's host-made inputs reach it as ONE int32 array
+        # (Packed): its own head, then every layer group's block table
+        # of its batch rows, then where the model has linear layers
+        # each batch row's slot (SlotStates.rows). The programs take
+        # the pools and states (`store`) beside it and build their
+        # cache view inside.
+        groups = 1 if self._window is None else 2
+
+        def layout(*head, rows):
+            tables = ("tables", (groups, rows, self._table_width))
+            states = [("rows", (rows,))] if self._states is not None else []
+            return Packed(*head, tables, *states)
+
+        chunk = prefill_chunk
+        self._layouts = {
+            "prefill": layout(("toks", (1, chunk)), ("pos0", ()),
+                              ("n_valid", ()), rows=1),
+            "tick": layout(("toks", (slots,)), ("pos", (slots,)),
+                           ("live", (slots,)), rows=slots),
+        }
+
+        def tick(store, params, packed):
+            x, cache = self._unpack("tick", store, packed)
             logits, cache = paged_forward(
-                model, params, toks[:, None], pos[:, None], live[:, None],
-                cache,
+                model, params, x["toks"][:, None], x["pos"][:, None],
+                x["live"][:, None] != 0, cache,
             )
             return cache, jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
 
-        chunk = prefill_chunk
-
-        def prefill(cache: PagedKVCache, params, toks, pos0, n_valid):
+        def prefill(store, params, packed):
+            x, cache = self._unpack("prefill", store, packed)
+            pos0, n_valid = x["pos0"], x["n_valid"]
             positions = pos0 + jnp.arange(chunk)[None, :]
             valid = (jnp.arange(chunk) < n_valid)[None, :]
             logits, cache = paged_forward(
-                model, params, toks, positions, valid, cache
+                model, params, x["toks"], positions, valid, cache
             )
             nxt = jnp.argmax(logits[0, jnp.maximum(n_valid - 1, 0)])
             return cache, nxt.astype(jnp.int32)
 
-        def copy(pages, src, dst):
+        def copy(pages, pair):
             # Copy-on-write (ISSUE 9): duplicate one physical page's
             # rows across every layer's pools — the divergent request
             # writes into the copy, the shared source stays read-only.
+            src, dst = pair[0], pair[1]
             return [
                 {name: c[name].at[dst].set(c[name][src]) for name in c}
                 for c in pages
             ]
 
-        def adopt(pages, src_pool, src, dst):
+        def adopt(pages, src_pool, pairs):
             # Cross-engine KV transfer (ISSUE 13): scatter the sender
             # pool's page rows (keys, values, int8 scales alike) into
             # this engine's pools at the destination indices — the
             # device half of the prefill->decode handoff.
+            src, dst = pairs[0], pairs[1]
             return [
                 {name: c[name].at[dst].set(s[name][src]) for name in c}
                 for c, s in zip(pages, src_pool)
@@ -846,13 +907,21 @@ class PagedEngine:
         # there. Beside them the last prefill chunk's.
         self._tick_counts = None
         self._chunk_counts = None
+        # Host->device transfers the engine made since the last tick
+        # record read them (`_put`; the record's `puts`).
+        self._puts = 0
         if spec != "off":
             kk = spec_k
+            self._layouts["spec"] = layout(
+                ("toks", (slots, kk)), ("pos", (slots,)),
+                ("valid", (slots, kk)), rows=slots)
 
-            def spec_tick(cache: PagedKVCache, params, toks, pos, valid):
-                positions = pos[:, None] + jnp.arange(kk)[None, :]
+            def spec_tick(store, params, packed):
+                x, cache = self._unpack("spec", store, packed)
+                positions = x["pos"][:, None] + jnp.arange(kk)[None, :]
                 logits, cache = paged_forward(
-                    model, params, toks, positions, valid, cache
+                    model, params, x["toks"], positions, x["valid"] != 0,
+                    cache
                 )
                 return cache, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
@@ -872,39 +941,51 @@ class PagedEngine:
 
     # -- host-side helpers ------------------------------------------------
 
-    def _cache_view(self, table, rows=()):
-        """The device cache under `table`: a (rows, table width) block
-        table and one PagedKVCache, or for a model with a windowed
-        group the two groups' tables and one PagedKVCache each; for a
-        model with linear layers a tuple of that PagedKVCache and the
-        slots' states, `rows` naming each batch row's slot."""
+    def _unpack(self, program: str, store, packed):
+        """Inside `program`: its inputs out of the one int32 array
+        `packed` (Packed.split: static slices) and the cache view it
+        reads and writes — over the pools and states of `store`, one
+        PagedKVCache under the global group's table, or for a model
+        with a windowed group one a group; for a model with linear
+        layers a tuple of that PagedKVCache and the slots' states, each
+        batch row continuing the slot the packed `rows` name."""
+        x = self._layouts[program].split(packed)
+        pages, states = store
         if self._window is None:
-            view = PagedKVCache(pages=self._pages,
-                                block_table=jnp.asarray(table),
+            view = PagedKVCache(pages=pages, block_table=x["tables"][0],
                                 page_size=self.page_size)
-            if self._states is None:
-                return view
-            return view, SlotStates(
-                states=self._states, rows=jnp.asarray(rows, jnp.int32))
-        return tuple(
-            PagedKVCache(pages=pages, block_table=jnp.asarray(t),
-                         page_size=self.page_size, window=window)
-            for pages, t, window in zip(self._pages, table,
-                                        (0, self._window[0])))
+            if states is None:
+                return x, view
+            return x, (view, SlotStates(states=states, rows=x["rows"]))
+        return x, tuple(
+            PagedKVCache(pages=p, block_table=t, page_size=self.page_size,
+                         window=window)
+            for p, t, window in zip(pages, x["tables"],
+                                    (0, self._window[0])))
 
-    def _tables(self, rows: int, slots):
-        """Block tables of `rows` rows for `slots` ((row, slot) pairs):
-        the global group's, and the windowed group's beside it where
-        the model has one (`_cache_view`'s argument)."""
-        table = np.zeros((rows, self._table_width), np.int32)
+    def _tables(self, x, slots) -> None:
+        """Write the block tables of `slots` ((batch row, slot) pairs)
+        straight into `x`, a packed buffer's fields (Packed.host): the
+        global group's, and the windowed group's after it where the
+        model has one, rows no pair names left on scratch; and where
+        the model has linear layers each batch row's slot (a row no
+        pair names continues its own: the tick's dead rows)."""
+        tables = x["tables"]
         for i, s in slots:
-            table[i, : len(s.pages)] = s.pages
-        if self._window is None:
-            return table
-        wtable = np.zeros((rows, self._table_width), np.int32)
-        for i, s in slots:
-            wtable[i, : len(s.wpages)] = s.wpages
-        return table, wtable
+            tables[0, i, : len(s.pages)] = s.pages
+            if self._window is not None:
+                tables[1, i, : len(s.wpages)] = s.wpages
+        if "rows" in x:
+            rows = x["rows"]
+            rows[:] = np.arange(rows.size)
+            for i, s in slots:
+                rows[i] = s.idx
+
+    def _put(self, host):
+        """The host->device transfer of `host` (a buffer or a tree of
+        them), counted for the tick record: one array, one transfer."""
+        self._puts += len(jax.tree.leaves(host))
+        return jax.device_put(host)
 
     def _keep(self, cache):
         """Adopt a program's returned cache; returns its counts."""
@@ -949,8 +1030,8 @@ class PagedEngine:
         caller (ServeCore.work) releases the shared source's reference
         via scheduler.cow_complete afterwards."""
         self._one_group("copy-on-write")
-        self._pages = self._copy(self._pages, jnp.int32(src),
-                                 jnp.int32(dst))
+        self._pages = self._copy(self._pages,
+                                 self._put(np.array([src, dst], np.int32)))
 
     def spill_page(self, page: int):
         """Fetch one device page's KV rows (every layer's keys/values,
@@ -972,10 +1053,7 @@ class PagedEngine:
         so garbage rows cannot enter the pools)."""
         self._one_group("readmission")
         self._pages = self._restore(
-            self._pages,
-            [{name: jnp.asarray(h[name]) for name in h} for h in payload],
-            jnp.int32(page),
-        )
+            self._pages, *self._put((payload, np.int32(page))))
 
     def adopt_pages(self, src_engine: "PagedEngine", src_pages,
                     dst_pages) -> None:
@@ -1008,14 +1086,11 @@ class PagedEngine:
         # the sanctioned garbage sink, never read as live data.
         n = len(src_pages)
         width = 1 << max(n - 1, 0).bit_length()
-        src = np.zeros(width, np.int32)
-        dst = np.zeros(width, np.int32)
-        src[:n] = src_pages
-        dst[:n] = dst_pages
-        self._pages = self._adopt(
-            self._pages, src_engine._pages,
-            jnp.asarray(src), jnp.asarray(dst),
-        )
+        pairs = np.zeros((2, width), np.int32)
+        pairs[0, :n] = src_pages
+        pairs[1, :n] = dst_pages
+        self._pages = self._adopt(self._pages, src_engine._pages,
+                                  self._put(pairs))
 
     def run_prefill_chunk(self, slot):
         """Advance `slot`'s prefill by one chunk on the device. Returns
@@ -1029,22 +1104,24 @@ class PagedEngine:
         EngineCompute for run() and the fleet alike. Inside a run()
         that records spans, its recorder (already in `prefill.build`)
         is told where building the inputs ends and the dispatch
-        begins, and times the block table and the puts as parts."""
+        begins, and times as parts the block table written into the
+        packed inputs (`tables`) and their one transfer (`puts`)."""
         ctx = np.concatenate(
             [slot.req.prompt, np.asarray(slot.req.out, np.int32)]
         )
         n = min(self.prefill_chunk, slot.target - slot.cached)
-        toks = np.zeros((1, self.prefill_chunk), np.int32)
-        toks[0, :n] = ctx[slot.cached : slot.cached + n]
+        buf, x = self._layouts["prefill"].host()
+        x["toks"][0, :n] = ctx[slot.cached : slot.cached + n]
+        x["pos0"][...] = slot.cached
+        x["n_valid"][...] = n
         with part(self._spans, "tables"):
-            view = self._cache_view(self._tables(1, [(0, slot)]),
-                                    [slot.idx])
+            self._tables(x, [(0, slot)])
         with part(self._spans, "puts"):
-            inputs = (jnp.asarray(toks), jnp.int32(slot.cached),
-                      jnp.int32(n))
+            packed = self._put(buf)
         if self._spans is not None:
             self._spans.enter("prefill.dispatch")
-        cache, nxt = self._prefill(view, self.params, *inputs)
+        cache, nxt = self._prefill((self._pages, self._states), self.params,
+                                   packed)
         self._chunk_counts = self._keep(cache)
         return n, nxt
 
@@ -1054,30 +1131,27 @@ class PagedEngine:
         (index by slot.idx); cached/emit bookkeeping is the caller's.
         Inside a run() that records spans, its recorder (already in
         `tick.build`) is told where the dispatch and the wait for the
-        tokens begin, and times its parts: the block tables, the puts,
-        the copy of the ready tokens (`fetch`)."""
-        toks = np.zeros((self.slots,), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
-        live = np.zeros((self.slots,), bool)
+        tokens begin, and times its parts: the block tables written
+        into the packed inputs, their one transfer (`puts`), the copy
+        of the ready tokens (`fetch`)."""
+        buf, x = self._layouts["tick"].host()
         for s in dslots:
-            toks[s.idx] = s.req.out[-1]
-            pos[s.idx] = s.cached
-            live[s.idx] = True
+            x["toks"][s.idx] = s.req.out[-1]
+            x["pos"][s.idx] = s.cached
+            x["live"][s.idx] = 1
         with part(self._spans, "tables"):
-            view = self._cache_view(
-                self._tables(self.slots, [(s.idx, s) for s in dslots]),
-                range(self.slots))
+            self._tables(x, [(s.idx, s) for s in dslots])
         with part(self._spans, "puts"):
-            inputs = (jnp.asarray(toks), jnp.asarray(pos),
-                      jnp.asarray(live))
+            packed = self._put(buf)
         if self._spans is not None:
             self._spans.enter("tick.dispatch")
-        cache, nxt = self._tick(view, self.params, *inputs)
+        cache, nxt = self._tick((self._pages, self._states), self.params,
+                                packed)
+        # Adopting the returned cache drops the donated pools' old
+        # handles (a few per layer) now, under the device's work — not
+        # after the read below, where freeing them is time the device
+        # stands idle (0.4 ms at 42 layers).
         self._tick_counts = self._keep(cache)
-        # The donated pools' old handles (a few per layer) go now, under
-        # the device's work — not after the read below, where freeing
-        # them is time the device stands idle (0.4 ms at 42 layers).
-        del view, inputs
         if self._spans is not None:
             self._spans.enter("tick.wait")
             # The same read, its copy timed apart from the wait.
@@ -1096,25 +1170,20 @@ class PagedEngine:
         routed to the scratch page. Returns each slot's per-row greedy
         picks (the verify_fn contract run_round consumes). Spans as in
         run_decode_tick."""
-        kk = self.spec_k
-        toks = np.zeros((self.slots, kk), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
-        valid = np.zeros((self.slots, kk), bool)
+        buf, x = self._layouts["spec"].host()
         for s, u, w in rounds:
-            toks[s.idx, :w] = u
-            pos[s.idx] = s.cached
-            valid[s.idx, :w] = True
+            x["toks"][s.idx, :w] = u
+            x["pos"][s.idx] = s.cached
+            x["valid"][s.idx, :w] = 1
         with part(self._spans, "tables"):
-            view = self._cache_view(self._tables(
-                self.slots, [(s.idx, s) for s, _, _ in rounds]))
+            self._tables(x, [(s.idx, s) for s, _, _ in rounds])
         with part(self._spans, "puts"):
-            inputs = (jnp.asarray(toks), jnp.asarray(pos),
-                      jnp.asarray(valid))
+            packed = self._put(buf)
         if self._spans is not None:
             self._spans.enter("tick.dispatch")
-        cache, picks = self._spec(view, self.params, *inputs)
-        self._tick_counts = self._keep(cache)
-        del view, inputs    # as in run_decode_tick: before the read
+        cache, picks = self._spec((self._pages, self._states), self.params,
+                                  packed)
+        self._tick_counts = self._keep(cache)   # as in run_decode_tick
         if self._spans is not None:
             self._spans.enter("tick.wait")
             picks = self._spans.fetch(picks, np.asarray)
@@ -1150,7 +1219,12 @@ class PagedEngine:
             # What the iteration's pool check verified: [pages, slots],
             # both pools summed (Scheduler.check_changed).
             "checked": list(sched.checked),
+            # Host->device transfers the iteration made: one a program
+            # it dispatched (its Packed inputs), more only where a page
+            # was copied, handed over or read back in.
+            "puts": self._puts,
         })
+        self._puts = 0
         if out.decoded and self._tick_counts is not None:
             # What this tick's forward counted, all layers together
             # (paged_cache.paged_forward): one small array that left
@@ -1239,13 +1313,15 @@ class PagedEngine:
         began (before its first dispatch), so a compile inside a run
         shows as a step and a warmed run reads 0 throughout. Beside
         the spans (ISSUE 36): `parts`, the pieces of a phase timed
-        where they run — `*.build/tables` (block tables and cache
-        view), `*.build/puts` (the inputs' puts), `*.wait/fetch` (the
-        copy of tokens already ready; the wait for them is the rest of
-        the phase), `bookkeep/check` (the pool check, whose pages and
-        slots verified are the record's `checked`); `gc_s`, the
-        iteration's seconds of garbage collection by generation; and
-        `stops`, its generation-2 collections and jax compiles.
+        where they run — `*.build/tables` (the block tables and state
+        rows written into the program's packed inputs), `*.build/puts`
+        (their one transfer to the device, counted as the record's
+        `puts`), `*.wait/fetch` (the copy of tokens already ready; the
+        wait for them is the rest of the phase), `bookkeep/check` (the
+        pool check, whose pages and slots verified are the record's
+        `checked`); `gc_s`, the iteration's seconds of garbage
+        collection by generation; and `stops`, its generation-2
+        collections and jax compiles.
 
         Prefix sharing + SLO policy (ISSUE 9): `prefix=True` puts a
         PrefixCache over the run's pool — a request whose prompt shares
@@ -1341,6 +1417,7 @@ class PagedEngine:
         if spans is not None:
             spans.watch("serve")
         compiled0 = self.compiled_programs() if want_ticks else 0
+        self._puts = 0
         while sched.unfinished:
             iter_t0 = time_fn() - t0
             if spans is not None:

@@ -459,7 +459,7 @@ def test_freed_pages_return_to_the_pool_and_are_never_read(served):
         seen["group"] = group
         return real_step(group, slot, rows)
 
-    def tables(self, rows, slots):
+    def tables(self, x, slots):
         # Before every program: every windowed page that no slot holds
         # (the freed ones among them) is filled with a large value.
         group = seen.get("group")
@@ -470,7 +470,7 @@ def test_freed_pages_return_to_the_pool_and_are_never_read(served):
             self._pages = (self._pages[0], [
                 {n: a.at[idx].set(1e4) for n, a in c.items()}
                 for c in self._pages[1]])
-        return real_tables(self, rows, slots)
+        return real_tables(self, x, slots)
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(WindowGroup, "advance", advance)
